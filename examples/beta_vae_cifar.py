@@ -17,7 +17,7 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import multidisttorch_tpu as mdt  # noqa: E402
 from multidisttorch_tpu.data import load_cifar10  # noqa: E402
-from multidisttorch_tpu.hpo import TrialConfig, run_hpo  # noqa: E402
+from multidisttorch_tpu.hpo import TrialConfig, all_completed, run_hpo  # noqa: E402
 from multidisttorch_tpu.models import ConvVAE  # noqa: E402
 
 
@@ -64,10 +64,13 @@ def main():
     )
     for r in results:
         print(
-            f"trial {r.trial_id} (beta={r.config.beta}): "
+            f"trial {r.trial_id} (beta={r.config.beta}) [{r.status}]: "
             f"test loss {r.final_test_loss:.2f}, wall {r.wall_s:.2f}s"
         )
+    # A diverged (or, under resilient=True, failed) trial is a recorded
+    # result, not an exception: the exit code says whether all trained.
+    return 0 if all_completed(results) else 1
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -22,7 +22,7 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import multidisttorch_tpu as mdt  # noqa: E402
 from multidisttorch_tpu.data import load_mnist  # noqa: E402
-from multidisttorch_tpu.hpo import TrialConfig, run_hpo  # noqa: E402
+from multidisttorch_tpu.hpo import TrialConfig, all_completed, run_hpo  # noqa: E402
 from multidisttorch_tpu.models import MoEVAE, moe_vae_ep_shardings  # noqa: E402
 
 
@@ -77,11 +77,14 @@ def main():
     )
     for r in results:
         print(
-            f"trial {r.trial_id} ({experts[r.trial_id]} experts): "
+            f"trial {r.trial_id} ({experts[r.trial_id]} experts) [{r.status}]: "
             f"train loss {r.final_train_loss:.4f}, "
             f"test loss {r.final_test_loss:.4f}, wall {r.wall_s:.2f}s"
         )
+    # A diverged (or, under resilient=True, failed) trial is a recorded
+    # result, not an exception: the exit code says whether all trained.
+    return 0 if all_completed(results) else 1
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -22,7 +22,7 @@ import jax
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import multidisttorch_tpu as mdt  # noqa: E402
 from multidisttorch_tpu.data import load_mnist  # noqa: E402
-from multidisttorch_tpu.hpo import TrialConfig, run_hpo  # noqa: E402
+from multidisttorch_tpu.hpo import TrialConfig, all_completed, run_hpo  # noqa: E402
 
 
 def main():
@@ -103,12 +103,15 @@ def main():
     )
     for r in results:
         print(
-            f"trial {r.trial_id}: {r.steps} steps, "
+            f"trial {r.trial_id} [{r.status}]: {r.steps} steps, "
             f"final train loss {r.final_train_loss:.4f}, "
             f"test loss {r.final_test_loss:.4f}, wall {r.wall_s:.2f}s "
             f"-> {r.out_dir}"
         )
+    # A diverged (or, under resilient=True, failed) trial is a recorded
+    # result, not an exception: the exit code says whether all trained.
+    return 0 if all_completed(results) else 1
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -24,13 +24,6 @@ Public API (mirrors the reference's ``from utils import *`` surface,
 - group-aware logging: :func:`log0`
 """
 
-from multidisttorch_tpu.utils.compat import ensure_partitionable_rng
-
-# Mesh-topology-invariant RNG is a framework-level correctness contract
-# here (TP/stacked/DP parity tests all depend on it); see the shim's
-# docstring for the measured drift under the legacy lowering.
-ensure_partitionable_rng()
-
 from multidisttorch_tpu.parallel.cluster import (
     ProcessEnv,
     coordinator_address,

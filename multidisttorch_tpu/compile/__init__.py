@@ -1,9 +1,9 @@
-"""The compile subsystem: kill the compile tax (ROADMAP item 2).
+"""The compile subsystem: kill the compile tax.
 
-Every new shape bucket used to pay the full ``lower→compile`` on the
-driver's hot path, and the persistent XLA cache had been disabled since
-PR 1 (deserialized XLA:CPU executables corrupt the heap on the pinned
-jaxlib). Three layers re-attack it:
+Every new shape bucket pays a full ``lower→compile`` on first sight.
+jax's plain persistent cache carries compiles from one process to the
+next (``utils/compile_cache.enable_compile_cache``, on from every entry
+point); the layers here work inside a process and around that cache:
 
 - :mod:`~multidisttorch_tpu.compile.registry` +
   :mod:`~multidisttorch_tpu.compile.programs` — a process-lifetime
@@ -16,10 +16,9 @@ jaxlib). Three layers re-attack it:
   compiles every bucket's programs on worker threads, so trial
   admission never blocks the host loop on XLA.
 - :mod:`~multidisttorch_tpu.compile.cache` — the **quarantined
-  persistent cache**: CRC32 sidecars + a subprocess canary-execute
-  protocol gate jax's on-disk executable cache; TPU enables after a
-  passed canary, XLA:CPU stays quarantined-only (sacrificial
-  processes excepted).
+  persistent cache**, a CPU-world drill: CRC32 sidecars + a subprocess
+  canary-execute protocol in front of jax's on-disk executable cache,
+  enabling it only in processes that declared themselves sacrificial.
 - :mod:`~multidisttorch_tpu.compile.coldstart` — the **cold-start
   books' benchmark**: ``bench.py --coldstart`` measures cold vs
   precompiled vs cache-warm admission latency with a bit-parity gate.

@@ -1,12 +1,10 @@
-"""Quarantined persistent executable cache: trust is earned per run.
+"""Quarantined persistent executable cache: a CPU-world drill.
 
-PR 1 had to disable jax's persistent compilation cache outright:
-deserialized XLA:CPU executables corrupted the heap on the pinned
-jaxlib (``utils/compile_cache.py`` — the seed suite's resume segfault),
-and a corrupted heap fails *later, somewhere else*, so no in-process
-check can clear it. This module re-opens the cache behind two
-mechanical defenses plus a policy gate, so "is the cache safe here?"
-stops being a guess:
+On the chip the compile cache is jax's plain persistent cache, turned
+on by every entry point (``utils/compile_cache.enable_compile_cache``).
+This module is the older, guarded way in, kept for the cold-start drill
+(``compile/coldstart.py``) and the preflight's read-only cache probe
+until ROADMAP C7 decides what of it the TPU path needs:
 
 1. **Per-entry CRC32 sidecars** (the checkpoint layer's pattern,
    ``train/checkpoint.py``): :func:`seal_cache` records each entry's
@@ -15,28 +13,24 @@ stops being a guess:
    unsealed files of unknown provenance) to ``quarantine/`` — jax sees
    a miss and cold-compiles, never a garbled blob.
 2. **Subprocess canary-execute quarantine** (:func:`canary_quarantine`):
-   before any trial process enables cache *reads*, three sacrificial
-   children prove the full deserialize-and-run path on THIS toolchain:
+   three sacrificial children prove the full deserialize-and-run path:
    a cold child (cache off) banks the reference output bits; a warmup
    child (cache on) guarantees the canary entry exists on disk; a warm
    child (cache on) necessarily deserializes it, runs the canary batch,
    and must **bit-match** the cold reference. A crash, hang, or
-   mismatch in the warm child is the PR 1 failure mode caught in a
-   process we built to lose — the verdict quarantines the entries and
-   the trial process never loads them.
-3. **Backend gate** (:func:`cache_policy`): a passed canary enables the
-   cache in-process on **TPU** (the production cold-start path). On
-   **XLA:CPU the cache stays quarantined-only** even after a passed
-   canary — the known corruption is nondeterministic-late, so
-   deserialized CPU executables only ever run in processes explicitly
-   marked sacrificial (``MDT_CACHE_SACRIFICIAL=1``, e.g. the coldstart
-   bench's warm child, which is parity-gated against the cold child) or
-   under the pre-existing force knob (``MDT_FORCE_COMPILE_CACHE=1``).
+   mismatch in the warm child quarantines the entries.
+3. **Policy gate** (:func:`cache_policy`): a passed canary enables the
+   cache only in a process that declared itself sacrificial
+   (``MDT_CACHE_SACRIFICIAL=1``, e.g. the coldstart drill's warm child,
+   which is parity-gated against the cold child); any other process
+   gets the verdict ``quarantined_only``.
 
-:func:`enable_quarantined_cache` composes the three into the one safe
-opt-in: scan → canary → gate → enable (or a classified refusal). The
-preflight engine (``utils/preflight.py``) reuses :func:`cache_probe`
-for its compile-cache stage.
+:func:`enable_quarantined_cache` composes the three: scan → canary →
+gate → enable (or a classified refusal). Its canary children each
+initialise the backend, and a chip belongs to one process at a time, so
+it refuses any platform but ``cpu``. The preflight engine
+(``utils/preflight.py``), whose own process never touches jax, reuses
+:func:`cache_probe` for its compile-cache stage.
 """
 
 from __future__ import annotations
@@ -51,7 +45,10 @@ import zlib
 from typing import Callable, Optional
 
 from multidisttorch_tpu.telemetry.events import get_bus
-from multidisttorch_tpu.utils.compile_cache import default_cache_dir
+from multidisttorch_tpu.utils.compile_cache import (
+    default_cache_dir,
+    enable_compile_cache,
+)
 
 SIDECAR_SUFFIX = ".mdtcrc"
 QUARANTINE_DIR = "quarantine"
@@ -262,13 +259,11 @@ def _run_canary_child(
     crashing deserializer must never take the caller down."""
     env = dict(os.environ)
     # Each mode configures its cache via argv + jax.config ONLY — an
-    # inherited cache env (a developer shell's JAX_COMPILATION_CACHE_DIR,
-    # bench.py's CPU-fallback opt-in) would point the COLD child at the
-    # suspect cache, and a cold reference that deserialized the same
+    # inherited JAX_COMPILATION_CACHE_DIR would point the COLD child at
+    # the suspect cache, and a cold reference that deserialized the same
     # corrupt entry as the warm child bit-matches it: the gate this
-    # protocol exists for would pass the exact PR 1 failure.
+    # protocol exists for would pass the failure it is there to catch.
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    env.pop("MDT_FORCE_COMPILE_CACHE", None)
     if platform:
         env["JAX_PLATFORMS"] = platform
     arg = "-" if mode == "cold" else cache_dir
@@ -400,17 +395,11 @@ def is_sacrificial_process() -> bool:
     return os.environ.get("MDT_CACHE_SACRIFICIAL") == "1"
 
 
-def cache_policy(platform: str, *, sacrificial: Optional[bool] = None) -> str:
-    """Where a passed canary leads: ``enabled`` (TPU — the production
-    cold-start path this subsystem exists for; or a process that
-    declared itself sacrificial / forced), ``quarantined_only``
-    (XLA:CPU default — the known PR 1 corruption class fails late, so
-    even a passed canary only licenses sacrificial children)."""
-    if platform == "tpu":
-        return ENABLED
+def cache_policy(*, sacrificial: Optional[bool] = None) -> str:
+    """Where a passed canary leads: ``enabled`` in a process that
+    declared itself sacrificial, ``quarantined_only`` in any other —
+    a passed canary only licenses sacrificial children."""
     if sacrificial if sacrificial is not None else is_sacrificial_process():
-        return ENABLED
-    if os.environ.get("MDT_FORCE_COMPILE_CACHE") == "1":
         return ENABLED
     return QUARANTINED_ONLY
 
@@ -421,10 +410,7 @@ def _enable(cache_dir: str) -> bool:
     try:
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", 0.0
-        )
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        enable_compile_cache()  # thresholds; the directory set above stands
     except Exception:  # noqa: BLE001 — the cache is an optimization
         return False
     return True
@@ -467,7 +453,7 @@ def cache_probe(
 def enable_quarantined_cache(
     cache_dir: Optional[str] = None,
     *,
-    platform: Optional[str] = None,
+    platform: str = "cpu",
     scan: bool = True,
     canary: bool = True,
     sacrificial: Optional[bool] = None,
@@ -481,16 +467,18 @@ def enable_quarantined_cache(
     "canary": ..., "cache_dir": ...}``. The invariant callers rely on:
     **this process's jax config points at the cache only when the
     verdict is** ``enabled`` **— which requires a passed canary** (or
-    an explicit ``canary=False`` + force, which is the caller saying
-    "I am the canary"). Everything else leaves the config untouched
-    and the sweep cold-compiling, exactly as safe as PR 1's disable.
+    an explicit ``canary=False``). Everything else leaves the config
+    untouched.
     """
     cache_dir = cache_dir or default_cache_dir()
     out: dict = {"cache_dir": cache_dir, "enabled": False}
-    if platform is None:
-        import jax
-
-        platform = jax.default_backend()
+    if platform != "cpu":
+        raise ValueError(
+            "enable_quarantined_cache is a CPU-world drill: its canary "
+            "starts three children that each initialise the backend, and "
+            f"a chip belongs to one process at a time (platform={platform!r}"
+            "). On the chip use utils.compile_cache.enable_compile_cache."
+        )
     out["platform"] = platform
     if scan:
         out["scan"] = scan_cache(cache_dir)
@@ -513,7 +501,7 @@ def enable_quarantined_cache(
         out["verdict"] = can["verdict"]
         _emit("cache_quarantined", dir=cache_dir, reason=can["verdict"])
         return out
-    policy = cache_policy(platform or "", sacrificial=sacrificial)
+    policy = cache_policy(sacrificial=sacrificial)
     if policy != ENABLED:
         out["verdict"] = policy
         _emit("cache_quarantined", dir=cache_dir, reason=policy)

@@ -1,5 +1,9 @@
 """Cold-start benchmark: cold vs precompiled vs cache-warm admission.
 
+A CPU-world drill: every mode runs in a fresh child process, and a chip
+belongs to one process at a time, so ``bench.py --coldstart`` refuses
+any platform but ``cpu``. Admission cost on the chip is ROADMAP A6.
+
 The compile subsystem's banked evidence (``bench.py --coldstart``). One
 fixed multi-bucket sweep — ``len(COLDSTART_HIDDENS)`` shape buckets
 (distinct hidden dims), one trial each, one submesh, so every admission
@@ -86,7 +90,13 @@ def _child_main(mode: str, out_dir: str, tel_dir: str, cache_dir: str) -> int:
 
     telemetry.configure(tel_dir)
     cache_rec = None
-    if mode == "seed":
+    if mode in ("cold", "farm"):
+        # run_hpo turns jax's persistent cache on (utils/compile_cache);
+        # these legs exist to measure compiling.
+        import jax
+
+        jax.config.update("jax_enable_compilation_cache", False)
+    elif mode == "seed":
         # Cache writer: plain enable (this child is sacrificial by
         # role — it exists to populate the dir), then seal what landed.
         _cache._enable(cache_dir)
@@ -151,10 +161,9 @@ def _run_child(
     os.makedirs(tel_dir, exist_ok=True)
     env = dict(os.environ)
     # Each mode configures its own cache explicitly — an inherited
-    # cache env (bench.py's CPU-fallback opt-in, a developer shell)
-    # would silently warm the cold leg and fake the whole comparison.
+    # cache env would silently warm the cold leg and fake the whole
+    # comparison.
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    env.pop("MDT_FORCE_COMPILE_CACHE", None)
     if mode in ("farm", "warm"):
         # Pin the farm width for machine-comparable artifacts: two
         # workers overlap each item's init+train compiles, so even

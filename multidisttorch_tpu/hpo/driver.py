@@ -98,6 +98,7 @@ from multidisttorch_tpu.telemetry import device as tele_device
 from multidisttorch_tpu.telemetry.anomaly import get_monitor
 from multidisttorch_tpu.telemetry.events import get_bus
 from multidisttorch_tpu.telemetry.metrics import get_registry
+from multidisttorch_tpu.utils.compile_cache import enable_compile_cache
 from multidisttorch_tpu.utils.imaging import save_image_grid
 from multidisttorch_tpu.utils.logging import log0, log0_enabled
 
@@ -217,6 +218,14 @@ class TrialResult:
     # stacked lane this is the lane's share of the bucket's stacked
     # state; for a pipelined trial, the sum over its stages.
     optimizer_state_bytes: int = 0
+
+
+def all_completed(results: Sequence[TrialResult]) -> bool:
+    """Whether every trial ran to its end. A ``failed`` (under
+    ``resilient=True``) or ``diverged`` trial is a recorded result that
+    ``run_hpo`` returns normally, so a caller whose exit code should
+    mean "the sweep trained" (the examples) asks here."""
+    return all(r.status in ("completed", "resumed_complete") for r in results)
 
 
 def config_mismatch_vs_meta(cfg: TrialConfig, meta: dict) -> dict:
@@ -2794,6 +2803,7 @@ def run_hpo(
         import contextlib
 
         trace_ctx = contextlib.nullcontext()
+    enable_compile_cache()
     _install_drain_handlers()
     # The precompile farm (if the body starts one) is stashed here so
     # EVERY exit path — completion, failure isolation re-raise,

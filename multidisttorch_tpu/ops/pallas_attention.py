@@ -14,12 +14,11 @@ probabilities from the saved per-row logsumexp — the standard
 flash-attention memory trade).
 
 Layout contract matches ``make_ring_attention``: ``(batch, seq, heads,
-head_dim)``; bf16 or f32 in, accumulation always f32. Off-TPU the
-kernels run in interpreter mode (bit-exact semantics, used by the CPU
-test suite). Sequence lengths divisible by 128 tile at the MXU edge;
-other lengths run as one whole-sequence block (see
-:func:`flash_attention`). The dense fallback applies only when Pallas
-itself is unavailable.
+head_dim)``; bf16 or f32 in, accumulation always f32. The kernels
+compile through Mosaic; the CPU test suite runs them in interpreter
+mode by asking for it (``ops/pallas_mode.py``). Sequence lengths
+divisible by 128 tile at the MXU edge; other lengths run as one
+whole-sequence block (see :func:`flash_attention`).
 """
 
 from __future__ import annotations
@@ -28,24 +27,10 @@ from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from multidisttorch_tpu.utils.compat import (
-    pallas_tpu_compiler_params,
-    shard_map as compat_shard_map,
-)
-from multidisttorch_tpu.ops.ring_attention import dense_attention_reference
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from multidisttorch_tpu.ops.pallas_mode import pallas_interpret
 
 
 # Q/K tile edge: 128 matches the MXU systolic array; shorter sequences
@@ -74,13 +59,17 @@ def _out_struct(shape, dtype, like):
     staged forward, parallel/pipeline.py) a pallas_call must declare
     its outputs' VMA explicitly or tracing rejects it; propagating the
     input's vma makes the kernels VMA-transparent (outside shard_map
-    ``typeof(x).vma`` is empty and this is a no-op). Jaxlibs that
-    predate VMA typing (0.4.x — no ``jax.typeof``, no ``vma=`` kwarg,
-    and shard_map runs with the legacy ``check_rep`` checker instead,
-    utils/compat.py) need no annotation at all."""
-    if hasattr(jax, "typeof"):
-        return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    ``typeof(x).vma`` is empty and this is a no-op)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
+
+
+def _row_spec(bq, index_map):
+    """Block over a per-row statistic (logsumexp, delta) stored as
+    ``(BH, 1, T)``: the TPU lowering wants a block's last two dims to be
+    multiples of (8, 128) or the whole array dim, which a ``(1, bq)``
+    block of a ``(BH, T)`` array is not once BH > 1. The unit middle
+    dim is the whole dim, and T rides the lanes."""
+    return pl.BlockSpec((1, 1, bq), index_map, memory_space=pltpu.VMEM)
 
 
 # ---------------------------------------------------------------------
@@ -147,7 +136,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc,
         o_ref[0] = (acc[:] / denom).astype(o_ref.dtype)
         # logsumexp per row — the one residual the backward needs to
         # rebuild p without the (Tq, Tk) matrix.
-        lse_ref[0] = (m_sc[:] + jnp.log(denom))[:, 0]
+        lse_ref[0, 0] = (m_sc[:] + jnp.log(denom))[:, 0]
 
 
 def _fwd_call(q, k, v, scale, causal):
@@ -171,24 +160,23 @@ def _fwd_call(q, k, v, scale, causal):
         out_specs=(
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i),
-                         memory_space=pltpu.VMEM),
+            _row_spec(bq, lambda b, i, j: (b, 0, i)),
         ),
         out_shape=(
             _out_struct((bh, t, d), q.dtype, q),
-            _out_struct((bh, t), jnp.float32, q),
+            _out_struct((bh, 1, t), jnp.float32, q),
         ),
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),   # acc
             pltpu.VMEM((bq, 1), jnp.float32),   # running max
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(q, k, v)
-    return o, lse
+    return o, lse[:, 0]
 
 
 # ---------------------------------------------------------------------
@@ -226,12 +214,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (block_q, block_k), 1
             )
             s = jnp.where(cols <= rows, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, None])  # exact probs via saved lse
+        p = jnp.exp(s - lse_ref[0, 0][:, None])  # exact probs via saved lse
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0, 0][:, None]) * scale
         dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -279,7 +267,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (block_q, block_k), 1
             )
             s = jnp.where(cols <= rows, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, None])  # (block_q, block_k)
+        p = jnp.exp(s - lse_ref[0, 0][:, None])  # (block_q, block_k)
         dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -288,7 +276,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0, 0][:, None]) * scale
         dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -321,13 +309,12 @@ def _bwd_call(q, k, v, o, lse, do, scale, causal, g_lse=None):
     wide = lambda blk: pl.BlockSpec(
         (1, blk, d), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM
     )
-    row = pl.BlockSpec((1, bq), lambda b, i, j: (b, i),
-                       memory_space=pltpu.VMEM)
+    row = _row_spec(bq, lambda b, i, j: (b, 0, i))
     other = lambda blk: pl.BlockSpec(
         (1, blk, d), lambda b, i, j: (b, j, 0), memory_space=pltpu.VMEM
     )
-    other_row = pl.BlockSpec((1, bq), lambda b, i, j: (b, j),
-                             memory_space=pltpu.VMEM)
+    other_row = _row_spec(bq, lambda b, i, j: (b, 0, j))
+    lse, delta = lse[:, None], delta[:, None]  # (bh, 1, t) row layout
 
     dq = pl.pallas_call(
         partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -337,10 +324,10 @@ def _bwd_call(q, k, v, o, lse, do, scale, causal, g_lse=None):
         out_specs=wide(bq),
         out_shape=_out_struct(q.shape, q.dtype, q),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -358,10 +345,10 @@ def _bwd_call(q, k, v, o, lse, do, scale, causal, g_lse=None):
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -416,8 +403,6 @@ def flash_attention(q, k, v, *, causal: bool = False):
     dK/dV — while non-causal inputs (where appended keys WOULD be
     attended) raise instead of blowing VMEM at Mosaic compile time.
     """
-    if not _HAVE_PALLAS:
-        return dense_attention_reference(q, k, v, causal=causal)
     b, t, h, d = q.shape
     if t % _BLOCK and t > _MAX_WHOLE_BLOCK:
         if not causal:
@@ -555,7 +540,7 @@ def _make_ring_flash_cached(mesh, causal: bool, head_axis=None):
 
     def fn(q, k, v):
         scale = 1.0 / (q.shape[-1] ** 0.5)
-        return compat_shard_map(
+        return jax.shard_map(
             partial(
                 _ring_flash_local,
                 axis_name=DATA_AXIS,
@@ -590,9 +575,8 @@ def make_ring_flash_attention(trial, *, causal: bool = False,
     composition the long-context design is built around: ICI ring for
     the cross-chip half, VMEM blocking for the within-chip half.
     Compiled functions are memoized per ``(mesh, causal, head_axis)``
-    like :func:`make_ring_attention`; without Pallas the plain ring
-    (HBM-block hops) is returned instead. The returned callable
-    exposes ``.head_sharded``.
+    like :func:`make_ring_attention`. The returned callable exposes
+    ``.head_sharded``.
     """
     from multidisttorch_tpu.ops.ring_attention import (
         _resolve_head_axis,
@@ -600,11 +584,6 @@ def make_ring_flash_attention(trial, *, causal: bool = False,
     )
     from multidisttorch_tpu.parallel.mesh import TrialMesh
 
-    if not _HAVE_PALLAS:
-        from multidisttorch_tpu.ops.ring_attention import make_ring_attention
-
-        return make_ring_attention(trial, causal=causal,
-                                   shard_heads=shard_heads)
     mesh = trial.mesh if isinstance(trial, TrialMesh) else trial
     head_axis = _resolve_head_axis(mesh, shard_heads)
     return _wrap_head_check(

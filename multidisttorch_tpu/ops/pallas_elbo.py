@@ -13,9 +13,8 @@ Differentiable via ``jax.custom_vjp``: the backward kernel computes
   d/dlogvar  = beta * 0.5 * (exp(logvar) - 1)
 all scaled by the upstream cotangent.
 
-Falls back to interpreter mode off-TPU (bit-exact semantics, usable in
-CPU tests), and the public entry point degrades to the plain jnp
-implementation if Pallas is unavailable.
+Compiles through Mosaic; the CPU tests run it in interpreter mode by
+asking for it (``ops/pallas_mode.py``).
 """
 
 from __future__ import annotations
@@ -24,20 +23,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from multidisttorch_tpu.ops.losses import elbo_loss_sum
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from multidisttorch_tpu.ops.pallas_mode import pallas_interpret
 
 
 # VMEM working-set budget for one grid step (both passes keep ≤4 operand
@@ -48,11 +37,15 @@ _VMEM_BUDGET_BYTES = 4 * 2**20
 
 
 def _block_rows(logits, x, mu, logvar) -> int:
-    """Largest divisor of ``batch`` whose 7-buffer working set fits the
-    VMEM budget (whole rows only: the feature dims stay unsplit, so the
-    reduction needs no cross-column accumulator). Sized from the actual
-    operand dtypes — bf16 blocks are half the bytes of f32, so the bf16
-    train path gets twice the rows per grid step."""
+    """Rows per grid step: the largest divisor of ``batch`` that is a
+    whole number of sublane tiles and whose 7-buffer working set fits
+    the VMEM budget (whole rows only: the feature dims stay unsplit, so
+    the reduction needs no cross-column accumulator). Sized from the
+    actual operand dtypes — bf16 blocks are half the bytes of f32, so
+    the bf16 train path gets twice the rows per grid step. The TPU
+    lowering takes a row block only in multiples of the sublane tile
+    (8 rows of f32, 16 of bf16) or as the whole batch, so a batch with
+    no such divisor runs as one block."""
     batch, d = logits.shape
     latent = mu.shape[1]
     # Worst-case resident set (the bwd pass): logits, x, dlogits wide;
@@ -60,13 +53,14 @@ def _block_rows(logits, x, mu, logvar) -> int:
     per_row = d * (2 * logits.dtype.itemsize + x.dtype.itemsize) + latent * 2 * (
         mu.dtype.itemsize + logvar.dtype.itemsize
     )
-    target = max(1, _VMEM_BUDGET_BYTES // per_row)
+    tile = 32 // min(a.dtype.itemsize for a in (logits, x, mu, logvar))
+    target = max(tile, _VMEM_BUDGET_BYTES // per_row)
     if batch <= target:
         return batch
-    for bb in range(target, 0, -1):
+    for bb in range(target - target % tile, 0, -tile):
         if batch % bb == 0:
             return bb
-    return batch  # unreachable (bb=1 always divides)
+    return batch
 
 
 def _fwd_kernel(logits_ref, x_ref, mu_ref, logvar_ref, out_ref, *, beta):
@@ -144,7 +138,7 @@ def _fwd(logits, x, mu, logvar, beta):
         out_specs=pl.BlockSpec(
             (1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM
         ),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(logits, x, mu, logvar)
     return out[0, 0], (logits, x, mu, logvar)
 
@@ -170,7 +164,7 @@ def _bwd(beta, residuals, g):
         ),
         in_specs=[wide(), wide(), narrow(), narrow()],
         out_specs=(wide(), narrow(), narrow()),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(logits, x, mu, logvar)
     # x is data: propagate its true cotangent (-logits * g) for
     # completeness even though training never differentiates w.r.t. it.
@@ -185,9 +179,3 @@ def _bwd(beta, residuals, g):
 
 fused_elbo_loss_sum.defvjp(_fwd, _bwd)
 
-
-def elbo_loss_sum_auto(logits, x, mu, logvar, beta=1.0):
-    """Use the fused kernel when Pallas is available, else plain jnp."""
-    if _HAVE_PALLAS:
-        return fused_elbo_loss_sum(logits, x, mu, logvar, beta)
-    return elbo_loss_sum(logits, x, mu, logvar, beta)

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from multidisttorch_tpu.utils.compat import shard_map as compat_shard_map
 from multidisttorch_tpu.parallel.mesh import DATA_AXIS, TrialMesh
 
 
@@ -121,7 +120,7 @@ def _make_ring_attention_cached(
 
     def fn(q, k, v):
         scale = 1.0 / (q.shape[-1] ** 0.5)
-        return compat_shard_map(
+        return jax.shard_map(
             partial(
                 _ring_attention_local,
                 axis_name=axis_name,

@@ -175,7 +175,7 @@ def select_platform(
     which forces a torch backend ahead of autodetection. Here the
     analogous knob forces the JAX platform (``cpu``/``tpu``/a plugin
     name) *before* backend initialization — e.g. ``MDT_PLATFORM=cpu``
-    keeps a job off a wedged TPU plugin entirely. An empty/unset var
+    keeps a job off a wedged TPU host entirely. An empty/unset var
     means "no override" (falls back to ``default``, usually None).
 
     Must be called before anything touches a JAX backend: raises an
@@ -189,24 +189,11 @@ def select_platform(
         return None
     import jax
 
-    try:
-        from jax._src import xla_bridge
+    # jax has no public "is a backend up yet?" query; the private table
+    # exists on the installed jax (0.9.0, pinned in pyproject.toml).
+    from jax._src import xla_bridge
 
-        already_initialized = bool(xla_bridge._backends)
-    except Exception:
-        # Private probe gone (jax upgrade): we can no longer tell whether
-        # a late override would silently no-op. Say so instead of
-        # guessing — the whole point of this knob is no silent no-ops.
-        import warnings
-
-        warnings.warn(
-            "cannot verify JAX backend-init state (jax internals moved); "
-            f"MDT_PLATFORM={platform!r} may silently not take effect if "
-            "a backend was already initialized",
-            RuntimeWarning,
-            stacklevel=2,
-        )
-        already_initialized = False
+    already_initialized = bool(xla_bridge._backends)
     if already_initialized:
         if jax.default_backend() != platform.split(",")[0]:
             raise RuntimeError(
@@ -250,6 +237,9 @@ def initialize_runtime(
         return _initialized_env.num_processes, _initialized_env.process_id
 
     select_platform(environ)
+    from multidisttorch_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     penv = detect_process_env(environ)
     if penv.num_processes > 1:
         import jax
@@ -395,19 +385,16 @@ def _env_timeout(env_var: str, default: Optional[float]) -> Optional[float]:
 
 def coordination_client():
     """The distributed runtime's coordination-service client, or None
-    (single-process, or jax's internals moved).
+    (single-process).
 
     The sideband channel for cross-host agreement that must work even
-    when the accelerator backend cannot (a wedged TPU plugin, or
+    when the accelerator backend cannot (a wedged TPU host, or
     XLA:CPU's missing multiprocess computations): a host barrier and a
     key-value store served by the coordinator process, independent of
     any compiled collective."""
-    try:
-        from jax._src import distributed
+    from jax._src import distributed
 
-        return distributed.global_state.client
-    except Exception:  # pragma: no cover — jax internals moved
-        return None
+    return distributed.global_state.client
 
 
 _UNBOUNDED_MS = 2**31 - 1  # "no deadline" for coordination-service waits

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from multidisttorch_tpu.utils.compat import shard_map as compat_shard_map
 from multidisttorch_tpu.parallel.mesh import DATA_AXIS, TrialMesh
 
 
@@ -38,17 +37,9 @@ def pvary(x, axis_names):
 
     Needed when a loop carry starts as a mesh-invariant constant but
     becomes device-varying through the body (ppermute, axis_index, shard
-    data) — the initial carry must already hold the annotation. Wraps
-    the JAX API spelling drift: ``jax.lax.pcast(..., to="varying")``
-    (current) vs ``jax.lax.pvary``; on jaxlibs that predate VMA typing
-    altogether (0.4.x — shard_map's ``check_rep`` has no per-value
-    annotation), the correct annotation is no annotation.
+    data) — the initial carry must already hold the annotation.
     """
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis_names, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_names)  # pragma: no cover
-    return x
+    return jax.lax.pcast(x, axis_names, to="varying")
 
 
 @lru_cache(maxsize=None)
@@ -57,7 +48,7 @@ def _gather_fn(mesh: Mesh):
     # construction, but shard_map's varying-axis inference cannot prove
     # replication through all_gather.
     return jax.jit(
-        compat_shard_map(
+        jax.shard_map(
             lambda s: jax.lax.all_gather(s, DATA_AXIS, axis=0, tiled=True),
             mesh=mesh,
             in_specs=P(DATA_AXIS),
@@ -73,7 +64,7 @@ def _reduce_fn(mesh: Mesh, op: str):
     # Each member device contributes one row of x; squeeze the per-device
     # shard's leading dim so the reduced result has shape x.shape[1:].
     return jax.jit(
-        compat_shard_map(
+        jax.shard_map(
             lambda s: reducer(jnp.squeeze(s, axis=0), DATA_AXIS),
             mesh=mesh,
             in_specs=P(DATA_AXIS),

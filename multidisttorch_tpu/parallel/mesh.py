@@ -74,6 +74,11 @@ class TrialMesh:
     mesh: Mesh
     global_ranks: tuple[int, ...]  # indices into the global device list
 
+    def __post_init__(self):
+        from multidisttorch_tpu.utils.compile_cache import guard_submesh
+
+        guard_submesh(self.devices)
+
     @property
     def devices(self) -> tuple[jax.Device, ...]:
         return tuple(self.mesh.devices.ravel().tolist())

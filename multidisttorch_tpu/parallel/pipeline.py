@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from multidisttorch_tpu.utils.compat import shard_map as compat_shard_map
 from multidisttorch_tpu.parallel.mesh import DATA_AXIS, PIPE_AXIS, TrialMesh
 
 
@@ -171,7 +170,7 @@ def pipeline_apply(
                 f"{data_size} data shard(s) x {num_microbatches} "
                 "microbatches of equal size"
             )
-        return compat_shard_map(
+        return jax.shard_map(
             partial(
                 _pipeline_local,
                 stage_fn=stage_fn,
@@ -417,7 +416,7 @@ def pipeline_apply_stages(
             in_shapes.append(tuple(out_aval.shape[1:]))
         width = max(math.prod(s) for s in in_shapes)
 
-        return compat_shard_map(
+        return jax.shard_map(
             partial(
                 _pipeline_stages_local,
                 stage_fns=tuple(stage_fns),

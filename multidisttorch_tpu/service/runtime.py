@@ -353,7 +353,9 @@ class SweepService:
         import jax
 
         from multidisttorch_tpu.data.datasets import synthetic_mnist
+        from multidisttorch_tpu.utils.compile_cache import enable_compile_cache
 
+        enable_compile_cache()
         self.service_dir = service_dir
         os.makedirs(service_dir, exist_ok=True)
         devs = list(jax.devices()) if devices is None else list(devices)

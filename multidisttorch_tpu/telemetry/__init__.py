@@ -106,14 +106,11 @@ def configure(
         # else the launcher env (the same source jax.distributed
         # auto-initializes from).
         num_processes, process_id = 1, 0
-        try:
-            from jax._src import distributed as _jdist
+        from jax._src import distributed as _jdist
 
-            if getattr(_jdist.global_state, "client", None) is not None:
-                num_processes = _jdist.global_state.num_processes or 1
-                process_id = _jdist.global_state.process_id or 0
-        except Exception:
-            pass
+        if _jdist.global_state.client is not None:
+            num_processes = _jdist.global_state.num_processes or 1
+            process_id = _jdist.global_state.process_id or 0
         if num_processes <= 1:
             from multidisttorch_tpu.parallel.cluster import (
                 detect_process_env,

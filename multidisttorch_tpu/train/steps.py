@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import optax
 from flax import struct
 
-from multidisttorch_tpu.utils.compat import shard_map as compat_shard_map
 from multidisttorch_tpu.models.vae import VAE
 from multidisttorch_tpu.ops.losses import elbo_loss_sum
 from multidisttorch_tpu.parallel.mesh import DATA_AXIS, TrialMesh
@@ -257,7 +256,7 @@ def _build_step_fn(
             # shard_map and psum the partial sums instead — each chip
             # reduces only its own batch rows.
             def loss_impl(logits, x, mu, logvar, beta):
-                return compat_shard_map(
+                return jax.shard_map(
                     lambda lo, xx, m, lv: jax.lax.psum(
                         fused_elbo_loss_sum(lo, xx, m, lv, beta), _AXIS
                     ),

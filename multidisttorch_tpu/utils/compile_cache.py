@@ -1,44 +1,12 @@
-"""Shared persistent-XLA-compile-cache switch.
+"""Where the persistent XLA compilation cache lives.
 
-One policy for every CPU-compiling entry point (test harness, multichip
-dryrun, bench CPU fallback): cache compiled executables on disk keyed
-by HLO hash — staleness is impossible by construction, and the measured
-effect is ~4.5x on compile-dominated runs. Kept OUT of any process that
-compiles for the real TPU: the rare chip window gets the exact,
-known-good compile path (callers enforce that policy; this module just
-centralizes the mechanism so the three call sites cannot drift).
-
-DISABLED BY DEFAULT on this toolchain: XLA:CPU executables
-*deserialized* from the persistent cache corrupt the heap on the pinned
-jaxlib (0.4.36 — its CPU thunk-runtime serialization is still
-experimental). Reproduced deterministically: warm the cache with the
-HPO train step, then rebuild the identical program so compilation takes
-the cache-read path — the deserialized executable's first few runs die
-in ``malloc: chunk_main_arena`` / SIGSEGV (this was the seed suite's
-``test_resume_continues_from_checkpoint`` abort that killed every test
-after ``test_hpo.py``). A corrupted process loses whole artifacts and
-test runs; a cold compile only loses seconds.
-
-Two opt-in paths exist now:
-
-- ``MDT_FORCE_COMPILE_CACHE=1`` — the raw escape hatch for
-  environments whose jaxlib serializes CPU executables correctly
-  ("I am the canary"). This module's :func:`cache_is_safe` gate.
-- **The safe path** (docs/COMPILE.md):
-  ``multidisttorch_tpu.compile.cache.enable_quarantined_cache`` — a
-  CRC-sidecar scan over every entry, a subprocess canary-execute
-  protocol (a sacrificial child must deserialize, run, and bit-match
-  a cold-compiled reference before this process touches the cache),
-  and a backend gate (TPU enables on a passed canary; XLA:CPU stays
-  quarantined-only — deserialized CPU executables run only in
-  processes marked ``MDT_CACHE_SACRIFICIAL=1``). The coldstart bench
-  (``bench.py --coldstart``) measures the win behind a bit-parity
-  gate; ``tools/preflight.py --compile-cache`` probes cache health
-  without enabling anything.
-
-This module stays the shared *mechanism* (cache dir resolution, the
-raw config flip); the quarantine layer is the *policy* that makes
-enabling it sane on this toolchain.
+One rule for every entry point (``initialize_runtime``, ``run_hpo``,
+the sweep service, ``chip_smoke.py``): the cache is JAX's own
+persistent compilation cache, placed from outside by
+``JAX_COMPILATION_CACHE_DIR`` and otherwise kept at one fixed path,
+``.jax_cache`` at the checkout root. The directory is part of the
+cache key, so a directory that moves — a temp dir, a pid, a timestamp —
+never hits; nothing on the training path may hold the cache in one.
 """
 
 from __future__ import annotations
@@ -57,34 +25,67 @@ def default_cache_dir() -> str:
     return os.path.join(os.path.dirname(pkg), ".jax_cache")
 
 
-def cache_is_safe() -> bool:
-    """Whether persistent-cache *reads* are trusted on this toolchain.
+def enable_compile_cache() -> str:
+    """Turn JAX's persistent compilation cache on for this process and
+    return the directory in effect.
 
-    Opt-in only (``MDT_FORCE_COMPILE_CACHE=1``): the pinned jaxlib's
-    XLA:CPU executable deserialization corrupts the heap (module
-    docstring), and there is no runtime probe that can prove a given
-    jaxlib safe — a corrupted heap fails later, somewhere else.
+    A directory already configured stands — jax reads
+    ``JAX_COMPILATION_CACHE_DIR`` into its config at import, so where
+    that is set no directory is set in code. Only when none is
+    configured does the cache go to ``<checkout>/.jax_cache``. Every
+    compile qualifies: jax's default thresholds (1 s of compile time)
+    would skip the VAE's programs, which compile in less.
     """
-    return os.environ.get("MDT_FORCE_COMPILE_CACHE") == "1"
-
-
-def enable_persistent_compile_cache(cache_dir: str | None = None) -> bool:
-    """Point jax at a persistent compilation cache; every compile
-    qualifies (min time/size zero). Best-effort: returns False and
-    changes nothing if the cache is unsafe on this toolchain
-    (:func:`cache_is_safe`), the directory can't be created, or the jax
-    build lacks the knobs — the cache is an optimization, never a new
-    failure mode."""
     import jax
 
-    if not cache_is_safe():
-        return False
-    path = cache_dir or default_cache_dir()
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:
-        return False
-    return True
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", default_cache_dir())
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return jax.config.jax_compilation_cache_dir
+
+
+def submesh_defeats_cache(devices, world_size: int) -> bool:
+    """Whether programs over ``devices`` cannot be trusted to the
+    persistent cache: more than one TPU chip, fewer than the whole
+    world. Measured on a four-chip v5e host (libtpu 0.0.34, PR 21): an
+    executable with collectives over chips [2, 3], written to the cache
+    by one process and deserialized by the next, dies at its first run
+    with ``FAILED_PRECONDITION: The program continuator has halted
+    unexpectedly`` — every time, whatever ran before it. The same
+    program over [0, 1] or over all four chips, and single-chip programs
+    on any chip, deserialize and run. The fault is below jax (it hands
+    the deserializer the right devices), so the guard is conservative:
+    any multi-chip strict subset."""
+    return devices[0].platform == "tpu" and 1 < len(devices) < world_size
+
+
+def guard_submesh(devices) -> None:
+    """Called wherever a trial submesh is made (``TrialMesh``). If the
+    submesh defeats the cache (:func:`submesh_defeats_cache`), turn the
+    persistent cache off for the rest of this process: jax's switch is
+    process-wide and programs compile lazily, so there is no narrower
+    place to stand. Programs already compiled are unaffected; later ones
+    compile cold and write nothing."""
+    import warnings
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    if not jax.config.jax_enable_compilation_cache:
+        return
+    world = len(jax.devices(devices[0].platform))
+    if not submesh_defeats_cache(devices, world):
+        return
+    jax.config.update("jax_enable_compilation_cache", False)
+    # jax decides once per process whether the cache is in use; make it
+    # decide again.
+    compilation_cache.reset_cache()
+    warnings.warn(
+        f"persistent compile cache turned off for this process: a "
+        f"{len(devices)}-chip submesh of a {world}-chip TPU world was "
+        "carved, and cached executables with collectives over such a "
+        "submesh fail when deserialized (utils/compile_cache.py)",
+        RuntimeWarning,
+        stacklevel=4,  # past TrialMesh's __post_init__ and __init__
+    )

@@ -504,11 +504,9 @@ def test_heap_corrupting_entry_never_loads_in_trial_process(tmp_path):
 
 
 def test_passed_canary_on_cpu_stays_quarantined_only(tmp_path, monkeypatch):
-    # XLA:CPU policy: even a PASSED canary licenses only sacrificial
-    # processes — the known corruption class fails late, so the trial
-    # process keeps cold-compiling.
+    # Even a PASSED canary licenses only sacrificial processes: any
+    # other process keeps its cache configuration untouched.
     monkeypatch.delenv("MDT_CACHE_SACRIFICIAL", raising=False)
-    monkeypatch.delenv("MDT_FORCE_COMPILE_CACHE", raising=False)
     d = str(tmp_path / "cache")
     ok_runner = _scripted_runner({
         "cold": {"ok": True, "bits": "aa"},
@@ -521,20 +519,20 @@ def test_passed_canary_on_cpu_stays_quarantined_only(tmp_path, monkeypatch):
     assert jax.config.jax_compilation_cache_dir == prev
 
 
-def test_passed_canary_enables_for_tpu_and_sacrificial(tmp_path):
+def test_passed_canary_enables_sacrificial_and_refuses_a_chip(tmp_path):
     ok_runner = _scripted_runner({
         "cold": {"ok": True, "bits": "aa"},
         "warmup": {"ok": True, "bits": "aa"},
         "warm": {"ok": True, "bits": "aa"},
     })
     prev = jax.config.jax_compilation_cache_dir
-    try:
-        d = str(tmp_path / "tpu_cache")
-        out = enable_quarantined_cache(d, platform="tpu", runner=ok_runner)
-        assert out["enabled"] and out["verdict"] == "enabled"
-        assert jax.config.jax_compilation_cache_dir == d
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+    # The canary's three children each need the backend; a chip belongs
+    # to one process at a time, so a TPU platform is refused outright.
+    with pytest.raises(ValueError, match="CPU-world drill"):
+        enable_quarantined_cache(
+            str(tmp_path / "tpu_cache"), platform="tpu", runner=ok_runner
+        )
+    assert jax.config.jax_compilation_cache_dir == prev
     try:
         d2 = str(tmp_path / "sac_cache")
         out = enable_quarantined_cache(
@@ -611,13 +609,11 @@ def test_canary_child_env_never_inherits_cache_dir(monkeypatch):
         return _P()
 
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/suspect")
-    monkeypatch.setenv("MDT_FORCE_COMPILE_CACHE", "1")
     monkeypatch.setattr(_sp, "run", fake_run)
     for mode in ("cold", "warmup", "warm"):
         r = _run_canary_child(mode, "/tmp/x", None, 5.0)
         assert r["ok"]
         assert "JAX_COMPILATION_CACHE_DIR" not in captured["env"]
-        assert "MDT_FORCE_COMPILE_CACHE" not in captured["env"]
 
 
 def test_registry_lru_bound_evicts_terminal_only():

@@ -291,3 +291,74 @@ def test_drives_transformer_lm():
     # training continues and improves
     s2, m3 = step_flash(s1, tokens)
     assert float(m3["loss"]) < float(m1["loss"])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "shape",
+    [
+        (16, 512, 8, 64),  # the LM bench shape
+        (2, 2048, 8, 64),  # T > 1024, tiled
+        (2, 1100, 4, 64),  # causal pad to 1152
+        (2, 200, 4, 64),  # one whole-sequence block, B*H > 1
+    ],
+)
+def test_lowers_for_tpu(monkeypatch, shape, dtype):
+    # Lowered for the TPU from this CPU process with interpret mode
+    # off, forward and backward: the check a builder without a chip can
+    # run. The (1, bq) logsumexp block over a (B*H, T) array failed it
+    # for every B*H > 1 until the rows moved to a (B*H, 1, T) layout.
+    monkeypatch.delenv("MDT_PALLAS_INTERPRET")
+    qkv = [jax.ShapeDtypeStruct(shape, dtype)] * 3
+    fwd = lambda q, k, v: flash_attention(q, k, v, causal=True)
+    bwd = jax.grad(
+        lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2),
+    )
+    for fn in (fwd, bwd):
+        jax.jit(fn).trace(*qkv).lower(lowering_platforms=("tpu",))
+
+
+def test_ring_flash_lowers_for_tpu(monkeypatch):
+    from multidisttorch_tpu.ops.pallas_attention import (
+        make_ring_flash_attention,
+    )
+    from multidisttorch_tpu.parallel.mesh import setup_groups
+
+    monkeypatch.delenv("MDT_PALLAS_INTERPRET")
+    (trial,) = setup_groups(1, devices=jax.devices()[:4])
+    ring = make_ring_flash_attention(trial, causal=True)
+    qkv = [jax.ShapeDtypeStruct((2, 1024, 4, 64), jnp.float32)] * 3
+    bwd = jax.grad(lambda q, k, v: jnp.sum(ring(q, k, v) ** 2), (0, 1, 2))
+    for fn in (ring, bwd):
+        jax.jit(fn).trace(*qkv).lower(lowering_platforms=("tpu",))
+
+
+@pytest.mark.slow  # loads libtpu (~5 s) and is not part of tier-1
+def test_kernels_compile_for_a_v5e_topology(monkeypatch):
+    # One step past lowering, still without a chip: libtpu's compile-only
+    # client takes the programs through the real TPU compiler, Mosaic
+    # included, for a described v5e host (VMEM limits and all).
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from multidisttorch_tpu.ops.pallas_elbo import fused_elbo_loss_sum
+
+    monkeypatch.delenv("MDT_PALLAS_INTERPRET")
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    on_chip = SingleDeviceSharding(topo.devices[0])
+    aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=on_chip)
+    qkv = [aval((16, 512, 8, 64), jnp.bfloat16)] * 3
+    attn = lambda q, k, v: flash_attention(q, k, v, causal=True)
+    jax.jit(attn).lower(*qkv).compile()
+    jax.jit(
+        jax.grad(lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(), (0, 1, 2))
+    ).lower(*qkv).compile()
+    elbo_args = (
+        aval((4096, 784), jnp.bfloat16), aval((4096, 784), jnp.float32),
+        aval((4096, 20), jnp.bfloat16), aval((4096, 20), jnp.bfloat16),
+    )
+    loss = lambda l, x, m, lv: fused_elbo_loss_sum(l, x, m, lv, 1.0)
+    jax.jit(loss).lower(*elbo_args).compile()
+    jax.jit(jax.grad(loss, (0, 2, 3))).lower(*elbo_args).compile()

@@ -140,7 +140,7 @@ def test_bf16_inputs_match_plain(arrays):
 
 def test_bf16_multi_block_accumulator(monkeypatch):
     # The round-4 hardware failure ("Invalid dtype for `swap`: Ref
-    # float32 vs value bfloat16", BENCH_r05.json's embedded r4 payload)
+    # float32 vs value bfloat16", artifacts/bench_tpu_r4_flagship.json)
     # lived in the fwd kernel's SMEM accumulator when bf16 operands
     # crossed a multi-block grid — the one path the earlier bf16 test
     # (single block) and multi-block test (f32) each missed. Interpret
@@ -149,8 +149,8 @@ def test_bf16_multi_block_accumulator(monkeypatch):
     # force the grid>1 accumulate store, and values must still match the
     # plain path (the explicit .astype(out_ref.dtype) casts keep the
     # stored dtype equal to the ref dtype by construction — the same
-    # program Mosaic compiles; bench_kernel_smoke banks the hardware
-    # proof each TPU window).
+    # program Mosaic compiles; test_lowers_for_tpu below runs the
+    # lowering's own check, chip_smoke.py the hardware proof).
     from multidisttorch_tpu.ops import pallas_elbo
 
     monkeypatch.setattr(pallas_elbo, "_VMEM_BUDGET_BYTES", 64 * 1024)
@@ -269,3 +269,40 @@ def test_fused_loss_sharded_submesh_matches_plain():
         s1.params,
         s2.params,
     )
+
+
+def _lower_for_tpu(fn, *avals):
+    """Lower ``fn`` for the TPU from this CPU process, interpret mode
+    off: the Pallas TPU lowering (block-shape rules, ref/value dtype
+    checks on every store) runs without a chip."""
+    return jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("batch", [128, 4096])  # flagship; multi-block
+def test_lowers_for_tpu(monkeypatch, batch, dtype):
+    # The callers' shapes (chip_smoke.py phase 6): activations at the
+    # train dtype, f32 targets. Would have caught the round-4 swap dtype
+    # error; a row block that is not a whole number of sublane tiles
+    # fails here too.
+    monkeypatch.delenv("MDT_PALLAS_INTERPRET")
+    wide = jax.ShapeDtypeStruct((batch, 784), dtype)
+    x = jax.ShapeDtypeStruct((batch, 784), jnp.float32)
+    narrow = jax.ShapeDtypeStruct((batch, 20), dtype)
+    loss = lambda l, x, m, lv: fused_elbo_loss_sum(l, x, m, lv, 1.0)
+    _lower_for_tpu(loss, wide, x, narrow, narrow)
+    _lower_for_tpu(
+        jax.grad(loss, argnums=(0, 2, 3)), wide, x, narrow, narrow
+    )
+
+
+def test_block_rows_are_whole_sublane_tiles():
+    from multidisttorch_tpu.ops.pallas_elbo import _block_rows
+
+    for batch in (250, 1000, 4096, 10000):
+        for dt, tile in ((jnp.float32, 8), (jnp.bfloat16, 16)):
+            bb = _block_rows(
+                jnp.zeros((batch, 784), dt), jnp.zeros((batch, 784)),
+                jnp.zeros((batch, 20), dt), jnp.zeros((batch, 20), dt),
+            )
+            assert bb == batch or bb % tile == 0, (batch, dt, bb)

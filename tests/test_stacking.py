@@ -206,7 +206,14 @@ def test_stacked_eval_step_matches_unstacked(trial, model):
             trial, model, beta=betas[k], with_recon=False, masked=True
         )
         ref = ev(su, batch, weights)
-        assert float(out["loss_sum"][k]) == float(ref["loss_sum"])
+        # The vmapped K-lane program and the plain one are different
+        # XLA:CPU programs; on the installed jaxlib (0.9.0) their f32
+        # loss sums over 16 rows x 784 features differ by 1 ulp
+        # (5610.0728 vs 5610.0732). Bound: 4 ulp.
+        np.testing.assert_array_max_ulp(
+            np.float32(out["loss_sum"][k]), np.float32(ref["loss_sum"]),
+            maxulp=4,
+        )
 
 
 def test_stacked_iterator_matches_trial_iterator(trial):

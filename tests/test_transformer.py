@@ -289,8 +289,8 @@ def test_lm_per_block_remat_gradients_and_losses_match():
         )(params)
 
     # atol floor sits at a few f32 ULPs of the typical grad magnitude:
-    # XLA:CPU on the pinned jaxlib reassociates the recomputed-forward
-    # reductions up to ~2 ulp (observed max 1.9e-8 on 0.4.36), which the
+    # XLA:CPU reassociates the recomputed-forward
+    # reductions up to ~2 ulp (observed max 1.9e-8), which the
     # old 1e-8 floor flagged as a failure.
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(
