@@ -1,0 +1,12 @@
+"""Device time of one optimizer step under the ``optimizer`` scope: Adam
+and the parameter update (``scope_reduce.py``)."""
+
+from benchmark import scope_reduce
+
+LAYER = "step programs"
+UNIT = "ms"
+MOVES = "tokens_per_s_per_chip"
+
+
+def read(record: dict):
+    return scope_reduce.ms_per_step(record, parts=("optimizer",))

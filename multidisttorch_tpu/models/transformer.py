@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from multidisttorch_tpu.ops.ring_attention import dense_attention_reference
+from multidisttorch_tpu.utils.profiling import SCOPE_ATTN_CORE, SCOPE_MLP
 
 
 def _layer_ctors(mod):
@@ -56,7 +57,9 @@ def _attention_residual(mod, x, dense, ln):
     q = dense(d, "q")(y).reshape(b, t, h, d // h)
     k = dense(d, "k")(y).reshape(b, t, h, d // h)
     v = dense(d, "v")(y).reshape(b, t, h, d // h)
-    attn = mod.attention(q, k, v).reshape(b, t, d)
+    with jax.named_scope(SCOPE_ATTN_CORE):
+        attn = mod.attention(q, k, v)
+    attn = attn.reshape(b, t, d)
     return x + dense(d, "proj")(attn)
 
 
@@ -74,9 +77,11 @@ class Block(nn.Module):
         x = _attention_residual(self, x, dense, ln)
         d = x.shape[-1]
         y = ln("ln_mlp")(x)
-        y = dense(4 * d, "up")(y)
-        y = nn.gelu(y)
-        return x + dense(d, "down")(y)
+        with jax.named_scope(SCOPE_MLP):
+            y = dense(4 * d, "up")(y)
+            y = nn.gelu(y)
+            y = dense(d, "down")(y)
+        return x + y
 
 
 def _default_causal(attn):

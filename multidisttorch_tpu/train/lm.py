@@ -25,6 +25,7 @@ import optax
 
 from multidisttorch_tpu.parallel.mesh import DATA_AXIS, TrialMesh
 from multidisttorch_tpu.train.steps import TrainState
+from multidisttorch_tpu.utils.profiling import SCOPE_LOSS, SCOPE_OPTIMIZER
 
 
 def _logits(out):
@@ -183,14 +184,16 @@ def _build_lm_step_fn(model, tx, aux_loss_weight):
     def step_fn(state: TrainState, tokens: jax.Array):
         def loss_fn(params):
             out = model.apply({"params": params}, tokens)
-            loss = lm_loss_mean(_logits(out), tokens)
+            with jax.named_scope(SCOPE_LOSS):
+                loss = lm_loss_mean(_logits(out), tokens)
             if isinstance(out, tuple):
                 loss = loss + aux_loss_weight * out[1]
             return loss
 
         loss, grads = jax.value_and_grad(loss_fn)(state.params)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         return (
             TrainState(
                 params=new_params, opt_state=new_opt, step=state.step + 1

@@ -28,6 +28,15 @@ def trial_timer(label: str = "", printer=print):
     printer(f"{label}{' ' if label else ''}Done. time: {t1 - t0:f}")
 
 
+# The ``jax.named_scope``s of the LM step, around work no flax module
+# names (``models/transformer.py``, ``train/lm.py``). In a capture, group
+# the device's operations by these and by flax's names (``block_3/q``).
+SCOPE_ATTN_CORE = "attn_core"
+SCOPE_MLP = "mlp"
+SCOPE_LOSS = "loss"
+SCOPE_OPTIMIZER = "optimizer"
+
+
 @contextlib.contextmanager
 def profile_trace(log_dir: str):
     """Capture a JAX profiler trace (view with TensorBoard's profile
