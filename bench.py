@@ -1236,6 +1236,7 @@ def bench_lm() -> dict:
     """
     from multidisttorch_tpu.models.transformer import TransformerLM
     from multidisttorch_tpu.ops.pallas_attention import make_flash_attention
+    from multidisttorch_tpu.ops.ring_attention import dense_attention_reference
     from multidisttorch_tpu.parallel.mesh import setup_groups
     from multidisttorch_tpu.train.lm import (
         create_lm_state,
@@ -1289,7 +1290,13 @@ def bench_lm() -> dict:
             agreement,
         )
 
-    variants = {"dense_xla": timed(None)}
+    # Named, not None: on one TPU chip a model given no attention runs
+    # the flash kernel by itself (models/transformer.py::_default_causal).
+    variants = {
+        "dense_xla": timed(
+            lambda q, k, v: dense_attention_reference(q, k, v, causal=True)
+        )
+    }
     if on_tpu:  # interpret-mode flash timings are meaningless off-TPU
         # A kernel the chip refuses fails the run: a dense number under
         # "attention_winner" would hide that the race never happened.

@@ -486,26 +486,42 @@ def _lm_steps(devices, attention) -> np.ndarray:
 def phase_kernels(devices) -> None:
     """Every Pallas kernel the package exports, compiled, forward and
     backward, f32 and bf16, at the shapes its callers use, against its
-    plain-XLA reference; then the LM step with dense and with flash
-    attention."""
+    plain-XLA reference; then the LM step with dense attention, with
+    the flash kernel injected and with nothing injected, which on one
+    chip is the kernel too."""
     from multidisttorch_tpu.ops.pallas_attention import make_flash_attention
+    from multidisttorch_tpu.ops.ring_attention import dense_attention_reference
 
     for dtype in (jnp.float32, jnp.bfloat16):
         _kernel_elbo(128, dtype)  # the flagship's loss call
         _kernel_elbo(4096, dtype)  # multi-block grid, SMEM accumulator
         _kernel_flash(16, 512, 8, 64, dtype)  # the LM bench shape
-        _kernel_flash(2, 2048, 8, 64, dtype)  # T > 1024, tiled
+        _kernel_flash(2, 2048, 8, 64, dtype)  # T > 1024: two blocks of 1,024
         _kernel_flash(2, 1100, 4, 64, dtype)  # causal pad to 1152
         _kernel_flash(2, 200, 4, 64, dtype)  # one whole-sequence block
+        _kernel_flash(2, 1024, 4, 128, dtype)  # one head a lane block
+    _kernel_flash(16, 1024, 16, 64, jnp.bfloat16)  # the cell lm-dense
+    _kernel_flash(64, 256, 16, 64, jnp.bfloat16)  # the cell lm-short-t256
     say("  fused ELBO and flash attention match their references")
     if len(devices) >= 4:
         _kernel_ring_flash(devices)
         say("  ring-flash on 4 chips matches dense")
-    dense = _lm_steps(devices, None)
+    # With nothing injected a one-chip model takes the kernel by itself
+    # (models/transformer.py::_default_causal), so the dense side names
+    # the dense function.
+    dense = _lm_steps(
+        devices, lambda q, k, v: dense_attention_reference(q, k, v, causal=True)
+    )
     flash = _lm_steps(devices, make_flash_attention(causal=True))
+    default = _lm_steps(devices, None)
     say(f"  LM losses dense {dense.tolist()} flash {flash.tolist()}")
     check(dense[-1] < dense[0], f"LM loss did not fall: {dense}")
     _close(flash, dense, rtol=2e-2, atol=0, what="LM loss, flash vs dense attention")
+    check(
+        np.array_equal(default, flash),
+        f"LM loss, no attention injected {default.tolist()} vs flash {flash.tolist()}: "
+        "on one chip both are the kernel",
+    )
 
 
 def phase_backend() -> list:

@@ -8,7 +8,8 @@ stack whose attention implementation is injected — pass
 ``ops.ring_attention.make_ring_attention(trial, causal=True)`` and the
 sequence dimension shards across the trial's device axis (context
 length scales with devices, each chip holding ``T/N`` of the sequence);
-pass nothing and it runs the dense reference. Same params either way,
+pass nothing and it runs single-chip attention, the blockwise kernel
+on one TPU chip and the dense path elsewhere. Same params either way,
 so ring-vs-dense is directly comparable (tested).
 
 TPU-first details: pre-LN (stable without warmup games), learned
@@ -25,6 +26,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from multidisttorch_tpu.ops.pallas_attention import (
+    default_takes_kernel,
+    flash_attention,
+)
 from multidisttorch_tpu.ops.ring_attention import dense_attention_reference
 from multidisttorch_tpu.utils.profiling import SCOPE_ATTN_CORE, SCOPE_MLP
 
@@ -84,11 +89,39 @@ class Block(nn.Module):
         return x + y
 
 
+def _placement(x):
+    """``(device_kind, num_devices)`` of the mesh the computation's
+    operands were placed on, as tracing sees it: a jitted function's
+    values carry the abstract mesh of its committed ``NamedSharding``
+    arguments (every state ``create_lm_state`` makes and every batch a
+    ``TrialMesh`` places). ``None`` where there is none to see: an
+    uncommitted or single-device array, shapes alone."""
+    mesh = jax.typeof(x).sharding.mesh
+    return None if mesh.empty else (mesh.abstract_device.device_kind, mesh.size)
+
+
 def _default_causal(attn):
-    """The dense causal reference when no attention was injected."""
+    """The attention a model runs when none was injected: the blockwise
+    kernel (``ops.pallas_attention.flash_attention``, the code
+    ``make_flash_attention`` hands out) where
+    ``ops.pallas_attention.default_takes_kernel`` says it applies — a
+    TPU, operands on one device, a sequence length and heads the
+    kernel tiles — and the dense path everywhere else: the CPU, a
+    placement tracing cannot see, a data-parallel batch or
+    tensor-parallel heads over several chips (a bare ``pallas_call``
+    has no partitioning rule; GSPMD would gather its operands), a
+    pipeline stage under ``shard_map``, a T of 200. Decided while
+    tracing, from the operands alone; there is no switch."""
     if attn is not None:
         return attn
-    return lambda q, k, v: dense_attention_reference(q, k, v, causal=True)
+
+    def causal(q, k, v):
+        placed = _placement(q)
+        if placed and default_takes_kernel(*placed, *q.shape[1:]):
+            return flash_attention(q, k, v, causal=True)
+        return dense_attention_reference(q, k, v, causal=True)
+
+    return causal
 
 
 def _lm_embed(mod, tokens):
@@ -136,8 +169,12 @@ def _lm_param_shapes(trial, model):
 class TransformerLM(nn.Module):
     """Decoder-only LM: ``(B, T) int32 tokens -> (B, T, vocab) logits``.
 
-    ``attention`` must be causal; ``None`` uses the dense single-device
-    reference. For sequence parallelism pass
+    ``attention`` must be causal. ``None`` is exact causal attention
+    local to each head, by the path the operands allow
+    (:func:`_default_causal`): the blockwise Pallas kernel on a single
+    TPU chip at the lengths and head widths it tiles, XLA's dense path
+    everywhere else, several chips included (so ``None`` stays
+    shardable over heads and batch). For sequence parallelism pass
     ``make_ring_attention(trial, causal=True)`` and shard the token
     batch's T dimension over the trial's data axis.
     """
@@ -207,7 +244,8 @@ def transformer_tp_shardings(
             f"axis ({m})"
         )
     if shard_attention == "auto":
-        # per-head-local attention paths: the dense default, or a ring
+        # per-head-local attention paths: the default (dense wherever
+        # the operands span several chips, _default_causal), or a ring
         # built with head sharding (its shard_map splits heads over the
         # model axis itself — fn.head_sharded marks it). A plain flash
         # callable sets head_sharded=False explicitly: its single

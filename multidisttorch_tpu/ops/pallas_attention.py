@@ -1,28 +1,45 @@
-"""Blockwise (flash) causal attention as Pallas TPU kernels.
+"""Blockwise (flash) attention as one pair of Pallas TPU kernels.
 
-The reference framework has no attention at all (SURVEY.md §5); this
-repo's long-context story is ring attention across chips
-(``ops/ring_attention.py``) — but *within* one chip the attention block
-still materializes the full ``(B, H, Tq, Tk)`` score matrix in HBM,
-which caps single-chip context length and wastes bandwidth on the
-framework's own TransformerLM. This module is the single-chip half of
-the long-context design: an exact, online-softmax attention that tiles
-Q/K/V into VMEM blocks, keeps the running max/sum in VMEM scratch, and
-never writes scores to HBM. Forward and backward are both Pallas
-kernels wired through ``jax.custom_vjp`` (the backward recomputes
-probabilities from the saved per-row logsumexp — the standard
-flash-attention memory trade).
+Within one chip a dense attention writes the full ``(B, H, Tq, Tk)``
+score matrix to HBM and reads it back several times a pass; at
+16 x 16 heads x 1,024^2 that was 45% of GPT-2 medium's step. This module
+is the exact online-softmax attention that never does: a forward kernel
+and ONE fused backward kernel (dQ, dK and dV from a single recomputation
+of the probabilities out of the saved per-row logsumexp), wired through
+``jax.custom_vjp``. It is what a single-chip ``TransformerLM`` runs when
+no attention is injected (``models/transformer.py::_default_causal``
+says when), what ``make_flash_attention`` hands out, and the hop of
+``make_ring_flash_attention``.
 
-Layout contract matches ``make_ring_attention``: ``(batch, seq, heads,
-head_dim)``; bf16 or f32 in, accumulation always f32. The kernels
-compile through Mosaic; the CPU test suite runs them in interpreter
-mode by asking for it (``ops/pallas_mode.py``). Sequence lengths
-divisible by 128 tile at the MXU edge; other lengths run as one
-whole-sequence block (see :func:`flash_attention`).
+What runs where:
+
+- **Layout.** ``(batch, seq, heads, head_dim)`` in and out, as
+  ``make_ring_attention``. Head widths of 32, 64 or a multiple of 128
+  are read straight from the projections' ``(B, T, H*D)`` array, 128
+  lanes (two heads of 64) a block, so no transpose to ``(B, H, T, D)``
+  is ever made and every load and store is lane-dense. A head inside a
+  block is picked by zeroing the other heads' lanes of q (and dO): a
+  128-deep contraction costs the MXU what a 64-deep one does. Other
+  widths (the tests' 8, 16, 20) run the same kernels over a flattened
+  ``(B*H, T, D)`` copy.
+- **Blocks.** One grid step is one query block against the whole K/V
+  sequence, which stays in VMEM (256 KB each at T=1,024); a loop inside
+  the kernel walks the K/V blocks below the diagonal unmasked and the
+  diagonal block masked, so nothing above the diagonal is fetched or
+  computed. The block edge follows T: the largest of ``_BLOCKS`` that
+  divides it, else the whole sequence.
+- **Dtypes.** Operands go into the MXU as they come (bf16 stays bf16),
+  every matmul accumulates in f32, the softmax statistics and the
+  logsumexp are f32, and probabilities and score gradients are cast to
+  the operand dtype for the second matmuls, as XLA's dense path does.
+
+The kernels compile through Mosaic; the CPU test suite runs them in
+interpreter mode by asking for it (``ops/pallas_mode.py``).
 """
 
 from __future__ import annotations
 
+import math
 from functools import lru_cache, partial
 
 import jax
@@ -33,24 +50,76 @@ from jax.experimental.pallas import tpu as pltpu
 from multidisttorch_tpu.ops.pallas_mode import pallas_interpret
 
 
-# Q/K tile edge: 128 matches the MXU systolic array; shorter sequences
-# use the whole sequence as one block.
-_BLOCK = 128
+# Query/key block edges, best first: the edge is the largest that
+# divides T; a T that none divides runs as one whole-sequence block.
+# Under the causal mask a query block meets its own K/V block in steps
+# of some queries, each step against the keys up to its own: half a
+# block a step forward (wide matmuls, 3/4 of the square), 256 queries
+# backward (5/8 of it). Both from the chip race at 16 x 1,024 and 64 x
+# 256, bf16, head width 64 (PERF.md section 6): smaller blocks lose to
+# the per-block work, one step a block to the triangle it wastes.
+_BLOCKS = (1024, 512, 256, 128)
+_LANES = 128  # the MXU's and a vreg's width
 _NEG_INF = -1e30  # finite sentinel: -inf rows poison exp() on the VPU
 
-# Largest non-128-divisible T allowed to run as one whole-sequence
-# block. The whole-block path keeps the (T, T) f32 score tile plus
-# three (T, d) operand tiles resident in VMEM — ~4.5 MB at T=1024,
-# d=64, comfortably inside a v5e core's budget; at T=8256 the score
-# tile alone is 272 MB and the kernel fails at Mosaic compile time.
-# Above this, causal inputs are padded to the tile edge (exact — see
-# flash_attention) and non-causal inputs get a clear error instead of
-# a compile-time blowup (ADVICE r4).
+# Largest T no block edge divides that may run as one whole-sequence
+# block. The (T, T) f32 score tile is 4 MB at T=1024; at T=8256 it alone
+# is 272 MB and Mosaic refuses the kernel. Above this, causal inputs are
+# padded to the tile edge (exact, see flash_attention) and non-causal
+# inputs get a clear error.
 _MAX_WHOLE_BLOCK = 1024
 
 
-def _blocks(t: int) -> int:
-    return _BLOCK if t % _BLOCK == 0 else t
+def _block_for(t: int) -> int:
+    return next((b for b in _BLOCKS if t % b == 0), t)
+
+
+def _fwd_step(blk: int) -> int:
+    return blk // 2 if blk % 256 == 0 else blk
+
+
+def _bwd_step(blk: int) -> int:
+    return 256 if blk % 256 == 0 else blk
+
+
+def default_takes_kernel(
+    device_kind: str, num_devices: int, seq_len: int, num_heads: int,
+    head_dim: int,
+) -> bool:
+    """Whether a model that was given no attention runs this kernel
+    (``models/transformer.py::_default_causal`` asks, with what tracing
+    shows of the operands' placement) or XLA's dense path.
+
+    - a TPU: Mosaic compiles for nothing else, and the CPU suite's
+      interpreter is for tests that inject the kernel;
+    - operands on one device: a bare ``pallas_call`` has no
+      partitioning rule, so over a data- or model-parallel mesh GSPMD
+      would gather q, k and v onto every chip;
+    - a T that 128 divides, from 256 on: the shortest length raced
+      against dense on the chip (64 x 256: 1.8 against 2.7 ms a layer;
+      16 x 1,024: 2.3 against 10.6; PERF.md section 6);
+    - heads read straight from the projections' array, at the widths
+      run on the chip: 128, or 64 in pairs (25 heads of 64 would take
+      the flattened layout, which was not raced).
+    """
+    return (
+        device_kind.startswith("TPU")
+        and num_devices == 1
+        and seq_len >= 256
+        and seq_len % _BLOCKS[-1] == 0
+        and head_dim in (64, 128)
+        and _heads_per_block(num_heads, head_dim) is not None
+    )
+
+
+def _heads_per_block(h: int, d: int) -> int | None:
+    """How many heads share one lane block of the ``(B, T, H*D)`` array;
+    ``None`` when the width does not pack and the flattened
+    ``(B*H, T, D)`` layout is used."""
+    if d % _LANES == 0:
+        return 1
+    g = _LANES // d
+    return g if d in (32, 64) and h % g == 0 else None
 
 
 def _out_struct(shape, dtype, like):
@@ -63,373 +132,442 @@ def _out_struct(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
-def _row_spec(bq, index_map):
-    """Block over a per-row statistic (logsumexp, delta) stored as
-    ``(BH, 1, T)``: the TPU lowering wants a block's last two dims to be
-    multiples of (8, 128) or the whole array dim, which a ``(1, bq)``
-    block of a ``(BH, T)`` array is not once BH > 1. The unit middle
-    dim is the whole dim, and T rides the lanes."""
-    return pl.BlockSpec((1, 1, bq), index_map, memory_space=pltpu.VMEM)
-
-
 # ---------------------------------------------------------------------
-# forward
+# the kernels. Arrays are (N, T, C*W): C lane blocks of W = g*d lanes,
+# g heads of width d each. Per-row statistics (logsumexp, delta) are
+# (N, C, g, T): T rides the lanes and the (g, block) tile's first dim is
+# the whole array dim, which is what the TPU's 8x128 block rule wants.
 # ---------------------------------------------------------------------
 
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc,
-                *, scale, causal, block_q, block_k):
-    """Grid (BH, nq, nk), nk innermost ("arbitrary"): one Q block's
-    online-softmax accumulation across K blocks, carried in VMEM
-    scratch; outputs written on the last K step."""
-    iq, ik = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
 
-    @pl.when(ik == 0)
-    def _init():
-        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc[:] = jnp.zeros_like(acc)
+def _dot(a, b, dims=_NN, *, interpret=False):
+    """f32-accumulated matmul of the operands as they come. A caller's
+    ``default_matmul_precision`` (``"highest"`` in the f32 parity
+    checks) speaks of f32 operands and is left to them; bf16 operands
+    have the MXU's one precision, and Mosaic refuses a request for more
+    ("Bad lhs type"). The interpreter runs on XLA:CPU, which lacks a
+    bf16 x bf16 = f32 dot for some of the shapes met here, so there the
+    operands are widened first: the same numbers, since a product of
+    two bf16 values is exact in f32 and both ways accumulate in f32."""
+    if interpret:
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    precision = None if a.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    return jax.lax.dot_general(
+        a, b, dims, precision=precision, preferred_element_type=jnp.float32
+    )
 
-    # Causal: K blocks strictly above the diagonal contribute nothing.
-    # (`causal` is static; the block comparison is traced — they can't
-    # share one boolean expression.)
-    q_start = iq * block_q
-    k_start = ik * block_k
 
-    def _block():
-        q = q_ref[0].astype(jnp.float32)  # (block_q, d)
-        k = k_ref[0].astype(jnp.float32)  # (block_k, d)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (block_q, block_k)
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            cols = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(cols <= rows, s, _NEG_INF)
-        m_prev = m_sc[:]  # (block_q, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)  # rows at _NEG_INF underflow to 0 exactly
-        corr = jnp.exp(m_prev - m_new)
-        l_sc[:] = l_sc[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_sc[:] = m_new
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+def _head_lanes(g: int, d: int, rows: int):
+    """One boolean ``(rows, g*d)`` lane mask per head of the block, or
+    ``[None]`` when the block is a single head."""
+    if g == 1:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, g * d), 1)
+    return [(lane >= h * d) & (lane < (h + 1) * d) for h in range(g)]
 
+
+def _only(x, lanes):
+    """``x`` with every other head's lanes zeroed: contracting it over
+    the whole block is the one head's product."""
+    return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
+
+
+def _merge(per_head, lanes):
+    """Each head's own lanes out of its ``(rows, W)`` array."""
+    out = per_head[0]
+    for x, m in zip(per_head[1:], lanes[1:]):
+        out = jnp.where(m, x, out)
+    return out
+
+
+def _transposed(x):
+    """``x.T`` through f32: Mosaic transposes 32-bit tiles."""
+    return x.astype(jnp.float32).T.astype(x.dtype)
+
+
+def _walk(tile, i, nk, blk, sub, causal):
+    """Run ``tile(j, at, n, nkeys, own)`` — the block's queries
+    ``at..at+n`` against the first ``nkeys`` keys of K/V block ``j`` —
+    over everything query block ``i`` sees: all ``nk`` blocks whole,
+    or under the causal mask blocks ``0..i-1`` whole and block ``i``
+    in steps of ``sub`` queries, each against the keys up to its own
+    (``own``: the last ``n`` keys are the queries themselves and get
+    the triangle). Nothing above the diagonal is touched."""
+
+    def body(j, carry):
+        tile(j, 0, blk, blk, False)
+        return carry
+
+    jax.lax.fori_loop(0, i if causal else nk, body, 0)
     if causal:
-        pl.when(k_start <= q_start + block_q - 1)(_block)
-    else:
-        _block()
+        for at in range(0, blk, sub):
+            tile(i, at, sub, at + sub, True)
 
-    @pl.when(ik == nk - 1)
-    def _emit():
-        l = l_sc[:]
-        denom = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc[:] / denom).astype(o_ref.dtype)
-        # logsumexp per row — the one residual the backward needs to
+
+def _triangle(s, n, keys_axis):
+    """Mask the queries' own ``n`` keys, the last ``n`` along
+    ``keys_axis`` of ``s``, to key <= query."""
+    key = jax.lax.broadcasted_iota(jnp.int32, (n, n), keys_axis)
+    query = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1 - keys_axis)
+    if s.shape[keys_axis] == n:
+        return jnp.where(key <= query, s, _NEG_INF)
+    seen, own = jnp.split(s, [s.shape[keys_axis] - n], axis=keys_axis)
+    own = jnp.where(key <= query, own, _NEG_INF)
+    return jnp.concatenate([seen, own], axis=keys_axis)
+
+
+def _one_branch(body):
+    """A kernel whose whole ``body(i, *refs)`` (``i``: the query block)
+    sits in a branch that is always taken. Under a ``check_vma``
+    ``shard_map`` (a pipeline stage) the Pallas interpreter binds a
+    kernel's top-level reads and writes again and rejects a varying
+    block indexed by plain constants; what is inside a branch it leaves
+    as traced, and there ``program_id`` is not resolved any more, hence
+    ``i`` from out here. Mosaic pays one predicated region."""
+
+    def kernel(*refs, **statics):
+        i = pl.program_id(2)
+        pl.when(i >= 0)(lambda: body(i, *refs, **statics))
+
+    return kernel
+
+
+@_one_branch
+def _fwd_kernel(i, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_t, m_sc, l_sc, v_t,
+                *, scale, fold, causal, g, d, blk, sub, nk, interpret):
+    """Grid (N, C, nq), nq sequential: one query block's online softmax
+    over the K/V blocks it sees, each head of the lane block in turn.
+
+    Everything is kept keys x queries: the scores are ``k @ q.T``, so
+    the softmax's max and sum run down the sublanes (plain VPU work; a
+    reduction along the lanes goes through the XLU and was most of the
+    forward's time), the statistics are lane-major rows that the
+    logsumexp is stored from as they are, and the output accumulates as
+    ``(d, blk)`` a head from ``v.T @ p.T``. q is transposed once a grid
+    step, V once a sequence, the output once on its way out."""
+
+    dot = partial(_dot, interpret=interpret)
+
+    @pl.when(i == 0)
+    def _v_transposed():
+        for j in range(nk):
+            v_t[j] = _transposed(v_ref[0, j * blk:(j + 1) * blk, :])
+
+    q = q_ref[0]  # (blk, W)
+    if fold:  # a power of two: exact in any float dtype
+        q = q * scale
+    q_t = [_transposed(_only(q, m)) for m in _head_lanes(g, d, blk)]
+    m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_t[...] = jnp.zeros_like(acc_t)
+
+    def tile(j, at, n, nkeys, own):
+        k = k_ref[0, pl.ds(pl.multiple_of(j * blk, blk), nkeys), :]
+        cols = slice(at, at + n)
+        for h in range(g):
+            q_th = q_t[h][:, cols]
+            s = dot(k, q_th)  # (keys, queries) f32
+            if not fold:
+                s = s * scale
+            if own:
+                s = _triangle(s, n, 0)
+            m_prev = m_sc[h, :, cols]  # (1, n)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - m_new)  # masked entries underflow to 0 exactly
+            corr = jnp.exp(m_prev - m_new)
+            l_sc[h, :, cols] = (
+                l_sc[h, :, cols] * corr + jnp.sum(p, axis=0, keepdims=True)
+            )
+            v_h = v_t[j, h * d:(h + 1) * d, :nkeys]  # (d, keys)
+            acc_t[h, :, cols] = (
+                acc_t[h, :, cols] * corr + dot(v_h, p.astype(v_h.dtype))
+            )
+            m_sc[h, :, cols] = m_new
+
+    _walk(tile, i, nk, blk, sub, causal)
+
+    out_t = []
+    for h in range(g):
+        l = l_sc[h]
+        l = jnp.where(l > 0, l, 1.0)
+        out_t.append(acc_t[h] / l)
+        # logsumexp per row: the one residual the backward needs to
         # rebuild p without the (Tq, Tk) matrix.
-        lse_ref[0, 0] = (m_sc[:] + jnp.log(denom))[:, 0]
+        lse_ref[0, 0, h] = (m_sc[h] + jnp.log(l))[0]
+    o_ref[0] = jnp.concatenate(out_t, axis=0).T.astype(o_ref.dtype)
 
 
-def _fwd_call(q, k, v, scale, causal):
-    bh, t, d = q.shape
-    bq, bk = _blocks(t), _blocks(t)
-    kernel = partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk
-    )
-    grid = (bh, t // bq, t // bk)
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            _row_spec(bq, lambda b, i, j: (b, 0, i)),
+@_one_branch
+def _bwd_kernel(i, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, g_lse_ref,
+                dq_ref, dk_ref, dv_ref, dk_t, dv_t, dq_acc,
+                *, scale, fold, causal, g, d, blk, sub, nk, interpret):
+    """Grid (N, C, nq), nq sequential: one query block against the K/V
+    blocks it sees, queries x keys. Scores, probabilities and their
+    gradients are made once a tile and feed all three products. dQ
+    leaves with its block; dK and dV accumulate transposed, ``(W,
+    blk)`` a K/V block, across the query blocks and are written on the
+    last one: with q and dO transposed once a grid step, every matmul
+    of a tile is a plain ``a @ b`` or ``a @ b.T``."""
+
+    dot = partial(_dot, interpret=interpret)
+
+    @pl.when(i == 0)
+    def _init():
+        dk_t[...] = jnp.zeros_like(dk_t)
+        dv_t[...] = jnp.zeros_like(dv_t)
+
+    q, do = q_ref[0], do_ref[0]  # (blk, W)
+    if fold:
+        q = q * scale
+    lanes = _head_lanes(g, d, blk)
+    q_h = [_only(q, m) for m in lanes]
+    do_h = [_only(do, m) for m in lanes]
+    q_t = _transposed(q)  # (W, blk): head h is rows h*d..
+    do_t = do.astype(jnp.float32).T
+    # delta = rowsum(dO * O) a head, made here from the transposed
+    # blocks, where a head is a run of sublanes: no pass of XLA's over
+    # an f32 product in HBM. A cotangent of the logsumexp (ring-flash's
+    # hop weights) folds in at no cost: the score gradient is
+    # ds = p * (dp - delta + g_lse).
+    d_o = do_t * o_ref[0].astype(jnp.float32).T
+    do_t = do_t.astype(do.dtype)
+    lse, delta = [], []
+    for h in range(g):
+        row = jnp.sum(d_o[h * d:(h + 1) * d], axis=0) - g_lse_ref[0, 0, h]
+        delta.append(row[:, None])  # (blk, 1)
+        lse.append(lse_ref[0, 0, h][:, None])
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def tile(j, at, n, nkeys, own):
+        keys = pl.ds(pl.multiple_of(j * blk, blk), nkeys)
+        k, v = k_ref[0, keys, :], v_ref[0, keys, :]
+        rows = slice(at, at + n)
+        for h in range(g):
+            head = slice(h * d, (h + 1) * d)
+            s = dot(q_h[h][rows], k, _NT)  # (queries, keys) f32
+            if not fold:
+                s = s * scale
+            if own:
+                s = _triangle(s, n, 1)
+            p = jnp.exp(s - lse[h][rows])  # exact probabilities via saved lse
+            dp = dot(do_h[h][rows], v, _NT)
+            ds = (p * (dp - delta[h][rows])).astype(k.dtype)
+            dv_t[j, head, :nkeys] = dv_t[j, head, :nkeys] + dot(
+                do_t[head, rows], p.astype(v.dtype)
+            )
+            dk_t[j, head, :nkeys] = dk_t[j, head, :nkeys] + dot(
+                q_t[head, rows], ds
+            )
+            dq_acc[h, rows, :] = dq_acc[h, rows, :] + dot(ds, k)
+
+    _walk(tile, i, nk, blk, sub, causal)
+
+    dq = _merge([dq_acc[h] for h in range(g)], lanes) * scale
+    dq_ref[0] = dq.astype(dq_ref.dtype)
+
+    @pl.when(i == nk - 1)
+    def _emit():
+        for j in range(nk):
+            rows = slice(j * blk, (j + 1) * blk)
+            dk = dk_t[j].T  # (blk, W); q carried the scale if folded
+            if not fold:
+                dk = dk * scale
+            dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
+            dv_ref[0, rows, :] = dv_t[j].T.astype(dv_ref.dtype)
+
+
+def _specs(t, w, g, blk):
+    block = pl.BlockSpec((1, blk, w), lambda n, c, i: (n, i, c),
+                         memory_space=pltpu.VMEM)
+    whole = pl.BlockSpec((1, t, w), lambda n, c, i: (n, 0, c),
+                         memory_space=pltpu.VMEM)
+    stat = pl.BlockSpec((1, 1, g, blk), lambda n, c, i: (n, c, 0, i),
+                        memory_space=pltpu.VMEM)
+    return block, whole, stat
+
+
+def _params(t, w, blk, itemsize):
+    """Double-buffered operands and results, the f32 accumulators and a
+    handful of (blk, blk) f32 tiles, with room to spare: Mosaic's own
+    default (16 MiB) is too small from T=4,096 on."""
+    resident = 8 * t * w * itemsize + 2 * t * w * 4
+    tiles = 8 * blk * blk * 4 + 16 * blk * w * 4
+    return pltpu.CompilerParams(
+        # what a sequence's first query block sets up, the later use
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=int(
+            min(max(32 << 20, 2 * (resident + tiles)), 100 << 20)
         ),
+    )
+
+
+def _statics(scale, causal, g, d, blk, t, sub, interpret):
+    # a power-of-two scale (head widths 16, 64, 256) is folded into q
+    return dict(
+        scale=scale, fold=math.frexp(scale)[0] == 0.5, causal=causal,
+        g=g, d=d, blk=blk, sub=sub, nk=t // blk, interpret=interpret,
+    )
+
+
+# One traced and lowered function for every layer of a model: the inner
+# jit makes the 24 blocks of a step share it instead of tracing and
+# lowering some hundred kernel bodies one by one.
+@partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def _fwd_call(q, k, v, scale, causal, g, d, blk, interpret):
+    n, t, cw = q.shape
+    w, c = g * d, cw // (g * d)
+    block, whole, stat = _specs(t, w, g, blk)
+    return pl.pallas_call(
+        partial(
+            _fwd_kernel,
+            **_statics(scale, causal, g, d, blk, t, _fwd_step(blk), interpret),
+        ),
+        grid=(n, c, t // blk),
+        in_specs=[block, whole, whole],
+        out_specs=(block, stat),
         out_shape=(
-            _out_struct((bh, t, d), q.dtype, q),
-            _out_struct((bh, 1, t), jnp.float32, q),
+            _out_struct(q.shape, q.dtype, q),
+            _out_struct((n, c, g, t), jnp.float32, q),
         ),
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),   # acc
-            pltpu.VMEM((bq, 1), jnp.float32),   # running max
-            pltpu.VMEM((bq, 1), jnp.float32),   # running sum
+            pltpu.VMEM((g, d, blk), jnp.float32),  # output, transposed
+            pltpu.VMEM((g, 1, blk), jnp.float32),  # running max
+            pltpu.VMEM((g, 1, blk), jnp.float32),  # running sum
+            pltpu.VMEM((t // blk, w, blk), v.dtype),  # V, transposed
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=pallas_interpret(),
+        compiler_params=_params(t, w, blk, q.dtype.itemsize),
+        interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
-    return o, lse[:, 0]
 
 
-# ---------------------------------------------------------------------
-# backward
-# ---------------------------------------------------------------------
-
-
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_acc, *, scale, causal, block_q, block_k):
-    """Grid (BH, nq, nk): dQ for one Q block, accumulated across K."""
-    iq, ik = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    q_start = iq * block_q
-    k_start = ik * block_k
-
-    def _block():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            cols = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(cols <= rows, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])  # exact probs via saved lse
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0, 0][:, None]) * scale
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    if causal:
-        pl.when(k_start <= q_start + block_q - 1)(_block)
-    else:
-        _block()
-
-    @pl.when(ik == nk - 1)
-    def _emit():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, scale, causal, block_q, block_k):
-    """Grid (BH, nk, nq): dK/dV for one K block, accumulated across Q."""
-    ik, iq = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
-
-    @pl.when(iq == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    q_start = iq * block_q
-    k_start = ik * block_k
-
-    def _block():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            cols = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(cols <= rows, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])  # (block_q, block_k)
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0, 0][:, None]) * scale
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    if causal:
-        pl.when(k_start <= q_start + block_q - 1)(_block)
-    else:
-        _block()
-
-    @pl.when(iq == nq - 1)
-    def _emit():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
-
-
-def _bwd_call(q, k, v, o, lse, do, scale, causal, g_lse=None):
-    bh, t, d = q.shape
-    bq, bk = _blocks(t), _blocks(t)
-    # delta_i = rowsum(dO ⊙ O): tiny elementwise reduce; XLA fuses it.
-    # An lse cotangent folds in here with no kernel change: the shared
-    # score gradient is ds = p·(dp − delta + g_lse), and the kernels
-    # compute ds = p·(dp − delta'), so delta' = delta − g_lse.
-    delta = jnp.sum(
-        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
-    )  # (bh, t)
-    if g_lse is not None:
-        delta = delta - g_lse.astype(jnp.float32)
-
-    wide = lambda blk: pl.BlockSpec(
-        (1, blk, d), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM
-    )
-    row = _row_spec(bq, lambda b, i, j: (b, 0, i))
-    other = lambda blk: pl.BlockSpec(
-        (1, blk, d), lambda b, i, j: (b, j, 0), memory_space=pltpu.VMEM
-    )
-    other_row = _row_spec(bq, lambda b, i, j: (b, 0, j))
-    lse, delta = lse[:, None], delta[:, None]  # (bh, 1, t) row layout
-
-    dq = pl.pallas_call(
-        partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                block_q=bq, block_k=bk),
-        grid=(bh, t // bq, t // bk),
-        in_specs=[wide(bq), other(bk), other(bk), wide(bq), row, row],
-        out_specs=wide(bq),
-        out_shape=_out_struct(q.shape, q.dtype, q),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+@partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12))
+def _bwd_call(q, k, v, o, lse, do, g_lse, scale, causal, g, d, blk, interpret):
+    n, t, cw = q.shape
+    w, c = g * d, cw // (g * d)
+    block, whole, stat = _specs(t, w, g, blk)
+    nk = t // blk
+    return pl.pallas_call(
+        partial(
+            _bwd_kernel,
+            **_statics(scale, causal, g, d, blk, t, _bwd_step(blk), interpret),
         ),
-        interpret=pallas_interpret(),
-    )(q, k, v, do, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                block_q=bq, block_k=bk),
-        grid=(bh, t // bk, t // bq),
-        in_specs=[other(bq), wide(bk), wide(bk), other(bq),
-                  other_row, other_row],
-        out_specs=(wide(bk), wide(bk)),
-        out_shape=(
-            _out_struct(k.shape, k.dtype, k),
-            _out_struct(v.shape, v.dtype, v),
-        ),
+        grid=(n, c, nk),
+        in_specs=[block, whole, whole, block, block, stat, stat],
+        out_specs=(block, whole, whole),
+        out_shape=tuple(_out_struct(x.shape, x.dtype, x) for x in (q, k, v)),
         scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((nk, w, blk), jnp.float32),  # dK, transposed
+            pltpu.VMEM((nk, w, blk), jnp.float32),  # dV, transposed
+            pltpu.VMEM((g, blk, w), jnp.float32),  # dQ, all lanes a head
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=pallas_interpret(),
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+        compiler_params=_params(t, w, blk, q.dtype.itemsize),
+        interpret=interpret,
+        name="flash_bwd",
+    )(q, k, v, o, do, lse, g_lse.astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------
-# public entry (custom_vjp over the (BH, T, D)-flattened layout)
+# custom_vjp over the kernels' (N, T, C*W) layout
 # ---------------------------------------------------------------------
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_flat_lse(q, k, v, scale, causal):
-    """``(o, lse)`` over the flattened ``(BH, T, D)`` layout.
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_lse(q, k, v, scale, causal, g, d, blk):
+    """``(o, lse)``: ``o`` as ``q``, ``lse`` ``(N, C, g, T)``.
 
     Exposing lse (per-row logsumexp of the scores) with a real VJP is
     what lets :func:`make_ring_flash_attention` combine per-hop partial
     attentions differentiably — the hop weights are ``exp(lse_h − m)``,
     so gradients flow into lse, not just into ``o``.
     """
-    return _fwd_call(q, k, v, scale, causal)
+    return _fwd_call(q, k, v, scale, causal, g, d, blk, pallas_interpret())
 
 
-def _flash_flat_fwd(q, k, v, scale, causal):
-    o, lse = _fwd_call(q, k, v, scale, causal)
+def _flash_lse_fwd(q, k, v, scale, causal, g, d, blk):
+    o, lse = _flash_lse(q, k, v, scale, causal, g, d, blk)
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_flat_bwd(scale, causal, res, g):
+def _flash_lse_bwd(scale, causal, g, d, blk, res, cot):
     q, k, v, o, lse = res
-    g_o, g_lse = g
-    dq, dk, dv = _bwd_call(
-        q, k, v, o, lse, g_o, scale, causal, g_lse=g_lse
+    g_o, g_lse = cot
+    return _bwd_call(
+        q, k, v, o, lse, g_o, g_lse, scale, causal, g, d, blk,
+        pallas_interpret(),
     )
-    return dq, dk, dv
 
 
-_flash_flat_lse.defvjp(_flash_flat_fwd, _flash_flat_bwd)
+_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def flash_attention(q, k, v, *, causal: bool = False):
+def _attend(q, k, v, *, causal: bool, block: int | None = None):
+    """``(o, lse)`` for ``(B, T, H, D)`` operands: ``o`` as ``q``,
+    ``lse`` ``(B, H, T)`` f32. Picks the layout the head width allows
+    and the block edge T allows."""
+    b, t, h, d = q.shape
+    blk = _block_for(t) if block is None else block
+    if t % blk:
+        raise ValueError(f"block {blk} does not divide seq_len {t}")
+    scale = 1.0 / (d**0.5)
+    g = _heads_per_block(h, d)
+    if g is not None:  # the projections' own layout, a free reshape
+        flat = lambda x: x.reshape(b, t, h * d)
+        o, lse = _flash_lse(flat(q), flat(k), flat(v), scale, causal, g, d, blk)
+        return o.reshape(b, t, h, d), lse.reshape(b, h, t)
+    # (B, T, H, D) -> (B*H, T, D): each (batch, head) pair is a grid row
+    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+    o, lse = _flash_lse(flat(q), flat(k), flat(v), scale, causal, 1, d, blk)
+    return o.reshape(b, h, t, d).transpose(0, 2, 1, 3), lse.reshape(b, h, t)
+
+
+def flash_attention(q, k, v, *, causal: bool = False, block: int | None = None):
     """Exact blockwise attention; drop-in for
     :func:`ops.ring_attention.dense_attention_reference`.
 
     ``q, k, v``: ``(batch, seq, heads, head_dim)``, bf16 or f32. Scores
     and the softmax never touch HBM; memory is O(T·D) instead of O(T²).
-    Sequences that are a multiple of 128 tile at the MXU edge; shorter
-    non-divisible sequences (≤ ``_MAX_WHOLE_BLOCK``) run as one
-    whole-sequence block. A LARGE non-divisible T is handled per the
-    mask structure: causal inputs are zero-padded up to the tile edge
-    and the output sliced back — exact, because the causal mask keeps
-    every real query from seeing the appended keys, and the sliced
-    rows carry zero cotangent so padded queries contribute nothing to
-    dK/dV — while non-causal inputs (where appended keys WOULD be
-    attended) raise instead of blowing VMEM at Mosaic compile time.
+    ``block`` is the query/key block edge; left out, it is the largest
+    of ``_BLOCKS`` that divides T. A T that 128 does not divide
+    is handled per the mask structure: causal inputs are zero-padded up
+    to the tile edge and the output sliced back — exact, because the
+    causal mask keeps every real query from seeing the appended keys,
+    and the sliced rows carry zero cotangent so padded queries
+    contribute nothing to dK/dV — while non-causal inputs (where
+    appended keys WOULD be attended) run as one whole-sequence block up
+    to ``_MAX_WHOLE_BLOCK`` and raise beyond it instead of blowing VMEM
+    at Mosaic compile time.
     """
-    b, t, h, d = q.shape
-    if t % _BLOCK and t > _MAX_WHOLE_BLOCK:
-        if not causal:
+    t = q.shape[1]
+    if block is None and t % _BLOCKS[-1]:
+        if causal and t > _MAX_WHOLE_BLOCK:
+            pad = -t % _BLOCKS[-1]
+            spec = ((0, 0), (0, pad), (0, 0), (0, 0))
+            return flash_attention(
+                jnp.pad(q, spec), jnp.pad(k, spec), jnp.pad(v, spec),
+                causal=True,
+            )[:, :t]
+        if t > _MAX_WHOLE_BLOCK:
             raise ValueError(
                 f"flash_attention: non-causal seq_len {t} is neither a "
-                f"multiple of {_BLOCK} nor small enough "
+                f"multiple of {_BLOCKS[-1]} nor small enough "
                 f"(<= {_MAX_WHOLE_BLOCK}) for the whole-sequence block "
-                f"path; pad the sequence to a multiple of {_BLOCK} and "
-                "mask in the caller"
+                f"path; pad the sequence to a multiple of {_BLOCKS[-1]} "
+                "and mask in the caller"
             )
-        pad = -t % _BLOCK
-        spec = ((0, 0), (0, pad), (0, 0), (0, 0))
-        return flash_attention(
-            jnp.pad(q, spec), jnp.pad(k, spec), jnp.pad(v, spec),
-            causal=True,
-        )[:, :t]
-    scale = 1.0 / (d**0.5)
-    # (B, T, H, D) -> (B*H, T, D): each (batch, head) pair is an
-    # independent attention problem and a grid row.
-    to_flat = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-    o, _ = _flash_flat_lse(to_flat(q), to_flat(k), to_flat(v), scale, causal)
-    return o.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return _attend(q, k, v, causal=causal, block=block)[0]
 
 
 def make_flash_attention(*, causal: bool = True):
     """An ``attention=`` callable for :class:`models.transformer
-    .TransformerLM` using the Pallas kernel on the chip-local sequence.
+    .TransformerLM`: :func:`flash_attention` on the chip-local
+    sequence, whatever its length. It is the code a single-chip model
+    runs by default on the TPU (``_default_causal``); injecting it asks
+    for the kernel where the default would fall back to dense (a T that
+    128 does not divide, the CPU interpreter).
 
     TP note (ADVICE r4): the math is per-head-local, but the callable
     runs as one ``pallas_call`` under ``jit`` with no partitioning
@@ -458,7 +596,7 @@ def make_flash_attention(*, causal: bool = True):
 # ---------------------------------------------------------------------
 
 
-def _ring_flash_local(q, k, v, *, axis_name, num_devices, causal, scale):
+def _ring_flash_local(q, k, v, *, axis_name, num_devices, causal):
     """Per-device body under shard_map: the full ring-flash composition.
 
     Local Q stays put; K/V blocks rotate around the ring
@@ -477,31 +615,28 @@ def _ring_flash_local(q, k, v, *, axis_name, num_devices, causal, scale):
     """
     b, t_loc, h, d = q.shape
     my = jax.lax.axis_index(axis_name)
-    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t_loc, d)
-    qf = flat(q)
 
     from multidisttorch_tpu.parallel.collectives import pvary
 
-    m0 = pvary(jnp.full((b * h, t_loc), _NEG_INF, jnp.float32), axis_name)
-    l0 = pvary(jnp.zeros((b * h, t_loc), jnp.float32), axis_name)
-    acc0 = pvary(
-        jnp.zeros((b * h, t_loc, d), jnp.float32), axis_name
-    )
+    m0 = pvary(jnp.full((b, h, t_loc), _NEG_INF, jnp.float32), axis_name)
+    l0 = pvary(jnp.zeros((b, h, t_loc), jnp.float32), axis_name)
+    acc0 = pvary(jnp.zeros((b, t_loc, h, d), jnp.float32), axis_name)
     perm = [(i, (i + 1) % num_devices) for i in range(num_devices)]
+    per_row = lambda x: x.transpose(0, 2, 1)[..., None]  # (B, H, T) on acc
 
     def body(carry, step):
-        kf, vf, m, l, acc = carry
+        k_blk, v_blk, m, l, acc = carry
 
         def full():
-            return _flash_flat_lse(qf, kf, vf, scale, False)
+            return _attend(q, k_blk, v_blk, causal=False)
 
         def diag():
-            return _flash_flat_lse(qf, kf, vf, scale, True)
+            return _attend(q, k_blk, v_blk, causal=True)
 
         def skip():
             return (
-                jnp.zeros_like(qf),
-                jnp.full((b * h, t_loc), _NEG_INF, jnp.float32),
+                jnp.zeros_like(q),
+                jnp.full((b, h, t_loc), _NEG_INF, jnp.float32),
             )
 
         if causal:
@@ -515,18 +650,15 @@ def _ring_flash_local(q, k, v, *, axis_name, num_devices, causal, scale):
         c = jnp.exp(m - m_new)
         w = jnp.exp(lse_h - m_new)
         l_new = l * c + w
-        acc_new = acc * c[..., None] + w[..., None] * o_h.astype(jnp.float32)
-        kf_next = jax.lax.ppermute(kf, axis_name, perm)
-        vf_next = jax.lax.ppermute(vf, axis_name, perm)
-        return (kf_next, vf_next, m_new, l_new, acc_new), None
+        acc_new = acc * per_row(c) + per_row(w) * o_h.astype(jnp.float32)
+        k_next = jax.lax.ppermute(k_blk, axis_name, perm)
+        v_next = jax.lax.ppermute(v_blk, axis_name, perm)
+        return (k_next, v_next, m_new, l_new, acc_new), None
 
     (_, _, _, l, acc), _ = jax.lax.scan(
-        body, (flat(k), flat(v), m0, l0, acc0), jnp.arange(num_devices)
+        body, (k, v, m0, l0, acc0), jnp.arange(num_devices)
     )
-    out = acc / jnp.where(l > 0, l, 1.0)[..., None]
-    return (
-        out.reshape(b, h, t_loc, d).transpose(0, 2, 1, 3).astype(q.dtype)
-    )
+    return (acc / per_row(jnp.where(l > 0, l, 1.0))).astype(q.dtype)
 
 
 @lru_cache(maxsize=None)
@@ -539,14 +671,12 @@ def _make_ring_flash_cached(mesh, causal: bool, head_axis=None):
     spec = P(None, DATA_AXIS, head_axis, None)
 
     def fn(q, k, v):
-        scale = 1.0 / (q.shape[-1] ** 0.5)
         return jax.shard_map(
             partial(
                 _ring_flash_local,
                 axis_name=DATA_AXIS,
                 num_devices=num_devices,
                 causal=causal,
-                scale=scale,
             ),
             mesh=mesh,
             in_specs=(spec, spec, spec),

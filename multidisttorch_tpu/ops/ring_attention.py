@@ -199,7 +199,14 @@ def make_ring_attention(
 
 
 def dense_attention_reference(q, k, v, *, causal: bool = False):
-    """O(T²) single-device reference for testing."""
+    """Plain O(T²) attention: scores, mask, softmax, a second matmul,
+    with the ``(B, H, Tq, Tk)`` scores in memory. The reference every
+    other attention of the package is tested against, and what a model
+    given no attention falls back to wherever the blockwise kernel
+    does not apply (``ops.pallas_attention.default_takes_kernel``):
+    off the TPU, over several chips (XLA partitions it over batch and
+    heads as it stands), at a length or head width the kernel does not
+    tile."""
     scale = 1.0 / (q.shape[-1] ** 0.5)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:

@@ -7,14 +7,16 @@ import numpy as np
 import pytest
 
 from multidisttorch_tpu.ops.pallas_attention import (
-    _BLOCK,
     flash_attention,
     make_flash_attention,
 )
 from multidisttorch_tpu.ops.ring_attention import dense_attention_reference
 
 
-def _qkv(b=2, t=64, h=2, d=16, seed=0, dtype=np.float32):
+_BLOCK = 128  # the smallest block edge: T = 2 * _BLOCK tiles when asked to
+
+
+def _qkv(b=2, t=64, h=2, d=16, *, seed=0, dtype=np.float32):
     rng = np.random.default_rng(seed)
     mk = lambda: jnp.asarray(rng.normal(0, 1, (b, t, h, d)).astype(dtype))
     return mk(), mk(), mk()
@@ -35,7 +37,7 @@ def test_value_parity_multi_block(causal):
     # t = 2 * _BLOCK exercises the online-softmax carry across K blocks
     # and (causal) the skipped above-diagonal block.
     q, k, v = _qkv(t=2 * _BLOCK, h=1, d=8)
-    out = flash_attention(q, k, v, causal=causal)
+    out = flash_attention(q, k, v, causal=causal, block=_BLOCK)
     ref = dense_attention_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-6
@@ -47,7 +49,9 @@ def test_gradient_parity(causal):
     q, k, v = _qkv(t=2 * _BLOCK, h=1, d=8)
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal) ** 2)
+        return jnp.sum(
+            flash_attention(q, k, v, causal=causal, block=_BLOCK) ** 2
+        )
 
     def loss_dense(q, k, v):
         return jnp.sum(
@@ -60,6 +64,64 @@ def test_gradient_parity(causal):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=5e-5, atol=5e-6
         )
+
+
+@pytest.mark.parametrize(
+    "dtype, tol", [(jnp.float32, 2e-4), (jnp.bfloat16, 3e-2)], ids=["f32", "bf16"]
+)
+@pytest.mark.parametrize(
+    "shape, block",
+    [
+        # two heads of 64 a lane block, read from the (B, T, H*D) array
+        ((2, 256, 4, 64), None),  # T is one block: 256, larger than 128
+        ((2, 256, 4, 64), 128),  # two blocks: the loop below the diagonal
+        ((1, 256, 2, 128), None),  # one head a lane block
+        ((1, 128, 2, 64), None),  # the smallest tile, one step on the diagonal
+    ],
+    ids=["d64-one-block", "d64-two-blocks", "d128", "d64-t128"],
+)
+def test_packed_heads_match_dense(shape, block, dtype, tol):
+    # The layout and the blocks the LM cells run (head width 64, blocks
+    # chosen from T), at the tolerances chip_smoke.py holds the chip to.
+    q, k, v = (a.astype(dtype) for a in _qkv(*shape, seed=5))
+    w = _qkv(*shape, seed=6)[0]
+    up = lambda a: a.astype(jnp.float32)
+
+    def out_and_grads(attn, *qkv, **kw):
+        loss = lambda q, k, v: jnp.sum(up(attn(q, k, v, causal=True, **kw)) * w)
+        return attn(*qkv, causal=True, **kw), jax.grad(loss, (0, 1, 2))(*qkv)
+
+    out, grads = out_and_grads(flash_attention, q, k, v, block=block)
+    ref, ref_grads = out_and_grads(dense_attention_reference, up(q), up(k), up(v))
+    assert out.dtype == dtype and all(g.dtype == dtype for g in grads)
+    np.testing.assert_allclose(up(out), ref, rtol=tol, atol=tol)
+    for g, r in zip(grads, ref_grads):
+        np.testing.assert_allclose(up(g), r, rtol=tol, atol=4 * tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_inside_remat_block_under_value_and_grad(dtype):
+    # What the LM step does with it: the kernel inside nn.remat(Block),
+    # differentiated, so the forward kernel runs twice and the fused
+    # backward once, against the same block over dense attention.
+    import flax.linen as nn
+
+    from multidisttorch_tpu.models.transformer import Block
+
+    x = jnp.asarray(np.random.default_rng(0).normal(0, 1, (2, 256, 128)), dtype)
+    mk = lambda cls, attn: cls(d_model=128, num_heads=2, attention=attn, dtype=dtype)
+    dense = mk(Block, lambda q, k, v: dense_attention_reference(q, k, v, causal=True))
+    flash = mk(nn.remat(Block), make_flash_attention(causal=True))
+    params = dense.init(jax.random.key(0), x)
+    loss = lambda m: lambda p, x: jnp.sum(m.apply(p, x).astype(jnp.float32) ** 2)
+    (val, (gp, gx)), (ref, (rp, rx)) = (
+        jax.jit(jax.value_and_grad(loss(m), (0, 1)))(params, x) for m in (flash, dense)
+    )
+    tol = 2e-4 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(val, ref, rtol=tol)
+    norm = lambda t: np.sqrt(sum(float(jnp.sum(a.astype(jnp.float32) ** 2)) for a in jax.tree.leaves(t)))
+    diff = jax.tree.map(lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), (gp, gx), (rp, rx))
+    assert norm(diff) < tol * norm((rp, rx))
 
 
 def test_odd_head_dim_and_seq():
@@ -297,6 +359,8 @@ def test_drives_transformer_lm():
 @pytest.mark.parametrize(
     "shape",
     [
+        (16, 1024, 16, 64),  # lm-dense: one 1,024 block a sequence
+        (64, 256, 16, 64),  # lm-short-t256
         (16, 512, 8, 64),  # the LM bench shape
         (2, 2048, 8, 64),  # T > 1024, tiled
         (2, 1100, 4, 64),  # causal pad to 1152
@@ -349,12 +413,15 @@ def test_kernels_compile_for_a_v5e_topology(monkeypatch):
     assert topo.devices[0].device_kind == "TPU v5 lite"
     on_chip = SingleDeviceSharding(topo.devices[0])
     aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=on_chip)
-    qkv = [aval((16, 512, 8, 64), jnp.bfloat16)] * 3
     attn = lambda q, k, v: flash_attention(q, k, v, causal=True)
-    jax.jit(attn).lower(*qkv).compile()
-    jax.jit(
-        jax.grad(lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(), (0, 1, 2))
-    ).lower(*qkv).compile()
+    grad = jax.grad(lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(), (0, 1, 2))
+    # the LM bench shape and the benchmark's two cells; chip_smoke.py
+    # runs them under "highest", which Mosaic takes for f32 operands only
+    for shape in [(16, 512, 8, 64), (16, 1024, 16, 64), (64, 256, 16, 64)]:
+        qkv = [aval(shape, jnp.bfloat16)] * 3
+        with jax.default_matmul_precision("highest"):
+            jax.jit(attn).lower(*qkv).compile()
+            jax.jit(grad).lower(*qkv).compile()
     elbo_args = (
         aval((4096, 784), jnp.bfloat16), aval((4096, 784), jnp.float32),
         aval((4096, 20), jnp.bfloat16), aval((4096, 20), jnp.bfloat16),
