@@ -396,15 +396,17 @@ def _kernel_elbo(batch: int, dtype) -> None:
         _close(g, r, rtol=gtol, atol=gtol, what=what + " grad")
 
 
-def _kernel_flash(b: int, t: int, h: int, d: int, dtype) -> None:
+def _kernel_flash(b: int, t: int, h: int, d: int, dtype, dv: int | None = None) -> None:
+    """``d``: the width of q and k, and of v unless ``dv`` gives v its own."""
     from multidisttorch_tpu.ops.pallas_attention import flash_attention
     from multidisttorch_tpu.ops.ring_attention import dense_attention_reference
 
+    dv = d if dv is None else dv
     q, k, v = (
-        jax.random.normal(kk, (b, t, h, d), jnp.float32).astype(dtype)
-        for kk in jax.random.split(jax.random.key(t), 3)
+        jax.random.normal(kk, (b, t, h, width), jnp.float32).astype(dtype)
+        for kk, width in zip(jax.random.split(jax.random.key(t), 3), (d, d, dv))
     )
-    w = jax.random.normal(jax.random.key(1), (b, t, h, d), jnp.float32)
+    w = jax.random.normal(jax.random.key(1), (b, t, h, dv), jnp.float32)
 
     def run(attn):
         def loss(q, k, v):
@@ -416,7 +418,7 @@ def _kernel_flash(b: int, t: int, h: int, d: int, dtype) -> None:
     with jax.default_matmul_precision("highest"):
         out, grads = run(flash_attention)(q, k, v)
         ref, ref_grads = run(dense_attention_reference)(q, k, v)
-    what = f"flash attention B={b} T={t} H={h} d={d} {jnp.dtype(dtype).name}"
+    what = f"flash attention B={b} T={t} H={h} d={d}/{dv} {jnp.dtype(dtype).name}"
     tol = 2e-4 if dtype == jnp.float32 else 3e-2
     _close(out, ref, rtol=tol, atol=tol, what=what + " forward")
     for g, r in zip(grads, ref_grads):
@@ -500,8 +502,10 @@ def phase_kernels(devices) -> None:
         _kernel_flash(2, 1100, 4, 64, dtype)  # causal pad to 1152
         _kernel_flash(2, 200, 4, 64, dtype)  # one whole-sequence block
         _kernel_flash(2, 1024, 4, 128, dtype)  # one head a lane block
+        _kernel_flash(2, 2048, 4, 192, dtype, dv=128)  # latent attention: q, k padded to 256
     _kernel_flash(16, 1024, 16, 64, jnp.bfloat16)  # the cell lm-dense
     _kernel_flash(64, 256, 16, 64, jnp.bfloat16)  # the cell lm-short-t256
+    _kernel_flash(1, 4096, 8, 192, jnp.bfloat16, dv=128)  # the cell moe-mla-t4096, 8 of its heads
     say("  fused ELBO and flash attention match their references")
     if len(devices) >= 4:
         _kernel_ring_flash(devices)

@@ -12,6 +12,10 @@ axis (2-D sequence x head attention) — trial x sequence x tensor
 parallelism in one sweep. ``--moe E`` swaps in the MoE transformer
 (E experts per block); with ``--model-parallel`` the experts claim the
 model axis instead (trial x sequence x EXPERT parallelism).
+``--latent-moe E`` swaps in the latent-attention LM instead
+(``models/latent_moe.py``: rotary low-rank attention with q and k wider
+than v, a dense layer and then dropless top-2-of-E sigmoid-routed
+experts with a shared expert), at a toy size, its context on the ring.
 
 Run (8 virtual CPU devices — two 4-device rings):
     XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
@@ -164,6 +168,13 @@ def main():
         "(expert parallelism) while the context rides the ring",
     )
     parser.add_argument(
+        "--latent-moe", type=int, default=0, metavar="E",
+        help="use the latent-attention LM (models/latent_moe.py) with a "
+        "leading dense layer, then E dropless sigmoid-routed experts a "
+        "block, 2 a token, and a shared expert; prints the assignments "
+        "each expert received in the last step",
+    )
+    parser.add_argument(
         "--pipeline", action="store_true",
         help="plan a cross-submesh MPMD pipelined LM trial "
         "(docs/PARALLEL.md): balanced 2-stage param split, the "
@@ -190,6 +201,11 @@ def main():
     if args.pipeline:
         _plan_mpmd_pipeline(args)
         return
+    if args.latent_moe and (args.moe or args.model_parallel > 1 or args.ring_flash):
+        parser.error(
+            "--latent-moe runs by itself: its experts are one chip's "
+            "(no expert exchange yet) and ring-flash hops take one head width"
+        )
     if args.model_parallel > 1:
         if args.moe:
             if args.moe % args.model_parallel:
@@ -236,7 +252,16 @@ def main():
     for g, lr in zip(groups, lrs):
         if not g.is_local_member:  # multi-host: skip remote submeshes
             continue
-        if args.moe:
+        if args.latent_moe:
+            from multidisttorch_tpu.models.latent_moe import LatentMoELM
+
+            model = LatentMoELM(
+                vocab_size=args.vocab, d_model=args.d_model,
+                num_layers=args.layers + 1, max_len=args.seq_len,
+                num_experts=args.latent_moe, top_k=2,
+                attention=make_attn(g, causal=True),
+            )
+        elif args.moe:
             from multidisttorch_tpu.models.transformer import MoETransformerLM
 
             # experts claim the model axis, so heads stay replicated
@@ -351,6 +376,13 @@ def main():
             j += interval
 
     for t in trials:
+        if args.latent_moe:
+            counts = np.asarray(t["m"]["expert_counts"])
+            mdt.log0(
+                f"assignments per expert, last step, by layer: "
+                f"{counts.reshape(-1, args.latent_moe).tolist()}",
+                trial=t["trial"],
+            )
         ev = t["eval"](t["state"], t["tokens"])
         mdt.log0(
             f"lr={t['lr']:.0e}: final loss {float(ev['loss']):.4f}, "

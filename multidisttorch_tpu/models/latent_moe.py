@@ -1,0 +1,254 @@
+"""Causal LM of latent attention and fine-grained routed experts.
+
+The block today's open expert models are made of, which
+``models/transformer.py``'s GPT-2 block cannot express: RMSNorm, no
+biases, rotary positions on a part of each head, low-rank query and
+key-value paths with a norm in the middle (multi-head latent
+attention, MLA), a gated (SwiGLU) MLP, and after ``dense_layers``
+leading dense layers an expert layer in every block
+(``ops.moe.RoutedExperts``: sigmoid scores, ``top_k`` of
+``num_experts``, a shared expert, no token dropped). A trial gets it
+exactly as it gets ``TransformerLM``: plain fields, a state from
+``create_lm_state``, a step from ``make_lm_train_step``.
+
+Per layer, with ``y = RMSNorm(x)``::
+
+    c_q = RMSNorm(y W_qa)                 q = c_q W_qb  as (H, nope + rope)
+    [c_kv | k_r] = y W_kva                c_kv = RMSNorm(c_kv)
+    [k_nope | v] per head = c_kv W_kvb    k = [k_nope | k_r for every head]
+    q_rope, k_r rotated (pairs (2i, 2i+1) of the rope part, no scaling)
+    x1 = x + W_o attention(q, k, v)       causal, scores / sqrt(nope + rope)
+    x2 = x1 + FFN(RMSNorm(x1))
+
+q and k are ``nope + rope`` wide and v ``v_head_dim``; the attention is
+injected as in ``TransformerLM``, and left out it is the blockwise
+kernel where ``ops.pallas_attention.default_takes_kernel`` takes these
+widths and the dense path elsewhere (``transformer._default_causal``).
+
+**One chip's share.** ``experts_held = (first, count)`` names the
+experts of every expert layer whose weights live here; the router keeps
+its ``num_experts`` outputs and its ``top_k`` (``ops/moe.py``). A
+sliced vocabulary is simply a smaller ``vocab_size``.
+
+The model returns ``(logits, {"expert_counts": (expert layers, count)
+int32})``: the assignments each expert held received, which
+``make_lm_train_step`` hands out with the loss.
+
+Names: a trace is split by the scope path of each operation
+(``benchmark/scope_reduce.py``), so the pieces of the two low-rank
+paths run under ``jax.named_scope``s ``q``, ``k``, ``v`` (the names a
+plain block's projections carry), the output projection is ``proj``,
+the norms ``ln_attn``, ``ln_mlp``, the dense MLP runs under ``mlp`` and
+the expert layer is the module ``moe``.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from multidisttorch_tpu.models import transformer
+from multidisttorch_tpu.ops.moe import (
+    RoutedExperts,
+    grouped_dot_takes_kernel,
+    kernel_grouped_dot,
+    ragged_grouped_dot,
+)
+from multidisttorch_tpu.utils.profiling import (
+    SCOPE_ATTN_CORE,
+    SCOPE_K,
+    SCOPE_MLP,
+    SCOPE_Q,
+    SCOPE_V,
+)
+
+
+def rope_interleaved(x, positions, theta: float):
+    """Rotate the pairs ``(2i, 2i+1)`` of ``x``'s last axis by
+    ``positions * theta**(-2i/width)``; ``x`` is ``(..., T, H, width)``,
+    the arithmetic float32. Written with lane rolls rather than a
+    ``(width/2, 2)`` reshape, which the TPU would have to relayout."""
+    width = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # (T, width/2)
+    cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)[:, None, :]
+    sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    even = jnp.arange(width) % 2 == 0
+    partner = jnp.where(even, -jnp.roll(x32, -1, axis=-1), jnp.roll(x32, 1, axis=-1))
+    return (x32 * cos + partner * sin).astype(x.dtype)
+
+
+def _default_grouped_dot(x):
+    """The grouped matrix product of the expert layer whose input is
+    ``x``: the Pallas kernel where ``ops.moe.grouped_dot_takes_kernel``
+    says it applies (a TPU, operands on one device, shapes it tiles)
+    and XLA's ragged dot everywhere else. Decided while tracing, from
+    the operands alone, as ``transformer._default_causal`` decides the
+    attention; the placement is read off the layer's input, because a
+    kernel's result no longer shows the mesh it was computed on."""
+    placed = transformer._placement(x)
+
+    def grouped_dot(lhs, rhs, sizes):
+        if placed and grouped_dot_takes_kernel(*placed, *lhs.shape, rhs.shape[-1]):
+            return kernel_grouped_dot(lhs, rhs, sizes)
+        return ragged_grouped_dot(lhs, rhs, sizes)
+
+    return grouped_dot
+
+
+class LatentMoEBlock(nn.Module):
+    """One pre-norm block: latent attention, then a dense SwiGLU MLP
+    (``num_experts`` 0) or an expert layer. Returns ``(x, counts)``,
+    ``counts`` ``(count,)`` int32 and empty for a dense block."""
+
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_head_dim: int
+    rope_theta: float
+    hidden_dim: int  # the dense MLP's width, or one expert's
+    attention: Callable  # (q, k, v) -> out; q, k (B, T, H, nope + rope), v, out (B, T, H, v)
+    num_experts: int = 0
+    experts_held: tuple[int, int] = (0, 0)
+    top_k: int = 0
+    shared_experts: int = 0
+    routed_scaling: float = 1.0
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32, name=name
+        )
+        norm = lambda name: nn.RMSNorm(
+            epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name=name
+        )
+        b, t, d = x.shape
+        h, nope, rope, dv = self.num_heads, self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim
+        positions = jnp.arange(t)
+
+        y = norm("ln_attn")(x)
+        with jax.named_scope(SCOPE_Q):
+            q = dense(h * (nope + rope), "q_b")(norm("q_norm")(dense(self.q_lora_rank, "q_a")(y)))
+            q = q.reshape(b, t, h, nope + rope)
+            q = jnp.concatenate(
+                [q[..., :nope], rope_interleaved(q[..., nope:], positions, self.rope_theta)],
+                axis=-1,
+            )
+        with jax.named_scope(SCOPE_K):
+            latent = dense(self.kv_lora_rank + rope, "kv_a")(y)
+            c_kv = norm("kv_norm")(latent[..., : self.kv_lora_rank])
+            k_rope = rope_interleaved(
+                latent[..., None, self.kv_lora_rank:], positions, self.rope_theta
+            )  # (B, T, 1, rope): one rope key for all heads
+        with jax.named_scope(SCOPE_V):
+            kv = dense(h * (nope + dv), "kv_b")(c_kv).reshape(b, t, h, nope + dv)
+            v = kv[..., nope:]
+        with jax.named_scope(SCOPE_K):
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_rope, (b, t, h, rope))], axis=-1
+            )
+        with jax.named_scope(SCOPE_ATTN_CORE):
+            attn = self.attention(q, k, v)
+        x = x + dense(d, "proj")(attn.reshape(b, t, h * dv))
+
+        y = norm("ln_mlp")(x)
+        if not self.num_experts:
+            with jax.named_scope(SCOPE_MLP):
+                y = dense(d, "down")(
+                    nn.silu(dense(self.hidden_dim, "gate")(y)) * dense(self.hidden_dim, "up")(y)
+                )
+            return x + y, jnp.zeros((0,), jnp.int32)
+        y, counts = RoutedExperts(
+            num_experts=self.num_experts,
+            experts_held=self.experts_held,
+            top_k=self.top_k,
+            hidden_dim=self.hidden_dim,
+            shared_hidden_dim=self.shared_experts * self.hidden_dim,
+            routed_scaling=self.routed_scaling,
+            dtype=self.dtype,
+            grouped_dot=_default_grouped_dot(y),
+            name="moe",
+        )(y.reshape(b * t, d))
+        return x + y.reshape(b, t, d), counts
+
+
+class LatentMoELM(nn.Module):
+    """Decoder-only LM: ``(B, T) int32 -> ((B, T, vocab) float32 logits,
+    {"expert_counts": (num_layers - dense_layers, count) int32})``.
+
+    ``experts_held`` ``None`` holds every expert. The defaults are a
+    toy for tests and examples; a configuration's file gives the
+    published sizes (``benchmark/configs/``)."""
+
+    vocab_size: int
+    d_model: int = 64
+    num_heads: int = 4
+    num_layers: int = 3
+    dense_layers: int = 1
+    q_lora_rank: int = 48
+    kv_lora_rank: int = 32
+    qk_nope_dim: int = 16
+    qk_rope_dim: int = 8
+    v_head_dim: int = 16
+    rope_theta: float = 10000.0
+    dense_hidden_dim: int = 128
+    num_experts: int = 8
+    experts_held: Optional[tuple[int, int]] = None
+    top_k: int = 2
+    expert_hidden_dim: int = 32
+    shared_experts: int = 1
+    routed_scaling: float = 1.0
+    eps: float = 1e-6
+    max_len: int = 256
+    attention: Optional[Callable] = None
+    dtype: Any = jnp.float32
+    remat: bool = False  # per-block checkpointing, as in TransformerLM
+
+    @nn.compact
+    def __call__(self, tokens):
+        _, t = tokens.shape
+        if t > self.max_len:
+            raise ValueError(f"sequence length {t} exceeds max_len={self.max_len}")
+        if not 0 <= self.dense_layers < self.num_layers:
+            raise ValueError(
+                f"dense_layers={self.dense_layers} leaves no expert layer of {self.num_layers}"
+            )
+        x = nn.Embed(
+            self.vocab_size, self.d_model, dtype=self.dtype,
+            param_dtype=jnp.float32, name="tok_embed",
+        )(tokens)
+        block_cls = nn.remat(LatentMoEBlock) if self.remat else LatentMoEBlock
+        shared = dict(
+            num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
+            kv_lora_rank=self.kv_lora_rank, qk_nope_dim=self.qk_nope_dim,
+            qk_rope_dim=self.qk_rope_dim, v_head_dim=self.v_head_dim,
+            rope_theta=self.rope_theta, attention=transformer._default_causal(self.attention),
+            eps=self.eps, dtype=self.dtype,
+        )
+        routed = dict(
+            hidden_dim=self.expert_hidden_dim, num_experts=self.num_experts,
+            experts_held=self.experts_held or (0, self.num_experts),
+            top_k=self.top_k, shared_experts=self.shared_experts,
+            routed_scaling=self.routed_scaling,
+        )
+        counts = []
+        for i in range(self.num_layers):
+            ffn = dict(hidden_dim=self.dense_hidden_dim) if i < self.dense_layers else routed
+            x, c = block_cls(**shared, **ffn, name=f"block_{i}")(x)
+            counts.append(c)
+        x = nn.RMSNorm(
+            epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name="ln_out"
+        )(x)
+        logits = nn.Dense(
+            self.vocab_size, use_bias=False, dtype=jnp.float32,
+            param_dtype=jnp.float32, name="head",
+        )(x)
+        return logits, {"expert_counts": jnp.stack(counts[self.dense_layers:])}

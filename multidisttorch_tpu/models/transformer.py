@@ -117,7 +117,7 @@ def _default_causal(attn):
 
     def causal(q, k, v):
         placed = _placement(q)
-        if placed and default_takes_kernel(*placed, *q.shape[1:]):
+        if placed and default_takes_kernel(*placed, *q.shape[1:], v.shape[-1]):
             return flash_attention(q, k, v, causal=True)
         return dense_attention_reference(q, k, v, causal=True)
 

@@ -6,9 +6,10 @@ score matrix to HBM and reads it back several times a pass; at
 is the exact online-softmax attention that never does: a forward kernel
 and ONE fused backward kernel (dQ, dK and dV from a single recomputation
 of the probabilities out of the saved per-row logsumexp), wired through
-``jax.custom_vjp``. It is what a single-chip ``TransformerLM`` runs when
-no attention is injected (``models/transformer.py::_default_causal``
-says when), what ``make_flash_attention`` hands out, and the hop of
+``jax.custom_vjp``. It is what a single-chip ``TransformerLM`` or
+``LatentMoELM`` runs when no attention is injected
+(``models/transformer.py::_default_causal`` says when), what
+``make_flash_attention`` hands out, and the hop of
 ``make_ring_flash_attention``.
 
 What runs where:
@@ -22,6 +23,13 @@ What runs where:
   128-deep contraction costs the MXU what a 64-deep one does. Other
   widths (the tests' 8, 16, 20) run the same kernels over a flattened
   ``(B*H, T, D)`` copy.
+- **Two widths.** q and k share one width and v, the output and its
+  cotangent another (latent attention: 128 + 64 beside 128). Every
+  array is read at its own width, one head a lane block; a q/k width
+  that is not whole lanes (192) is padded with zero lanes to the next
+  128 (256), which changes no score, and the scores are divided by the
+  root of the width q came with. The chip race of PR 27 (PERF.md
+  section 6) is against padding v and the output to q's width as well.
 - **Blocks.** One grid step is one query block against the whole K/V
   sequence, which stays in VMEM (256 KB each at T=1,024); a loop inside
   the kernel walks the K/V blocks below the diagonal unmasked and the
@@ -84,7 +92,7 @@ def _bwd_step(blk: int) -> int:
 
 def default_takes_kernel(
     device_kind: str, num_devices: int, seq_len: int, num_heads: int,
-    head_dim: int,
+    head_dim: int, v_head_dim: int | None = None,
 ) -> bool:
     """Whether a model that was given no attention runs this kernel
     (``models/transformer.py::_default_causal`` asks, with what tracing
@@ -100,15 +108,18 @@ def default_takes_kernel(
       16 x 1,024: 2.3 against 10.6; PERF.md section 6);
     - heads read straight from the projections' array, at the widths
       run on the chip: 128, or 64 in pairs (25 heads of 64 would take
-      the flattened layout, which was not raced).
+      the flattened layout, which was not raced), or latent
+      attention's 192 for q and k beside 128 for v, which
+      :func:`flash_attention` pads to 256 and 128 lanes a head.
     """
+    widths = (head_dim, head_dim if v_head_dim is None else v_head_dim)
     return (
         device_kind.startswith("TPU")
         and num_devices == 1
         and seq_len >= 256
         and seq_len % _BLOCKS[-1] == 0
-        and head_dim in (64, 128)
-        and _heads_per_block(num_heads, head_dim) is not None
+        and widths in ((64, 64), (128, 128), (192, 128))
+        and (widths[0] != 64 or _heads_per_block(num_heads, 64) is not None)
     )
 
 
@@ -134,7 +145,9 @@ def _out_struct(shape, dtype, like):
 
 # ---------------------------------------------------------------------
 # the kernels. Arrays are (N, T, C*W): C lane blocks of W = g*d lanes,
-# g heads of width d each. Per-row statistics (logsumexp, delta) are
+# g heads of width d each; q and k are dk wide and v, the output and its
+# cotangent dv (one head a block where the two differ). Per-row
+# statistics (logsumexp, delta) are
 # (N, C, g, T): T rides the lanes and the (g, block) tile's first dim is
 # the whole array dim, which is what the TPU's 8x128 block rule wants.
 # ---------------------------------------------------------------------
@@ -237,7 +250,7 @@ def _one_branch(body):
 
 @_one_branch
 def _fwd_kernel(i, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_t, m_sc, l_sc, v_t,
-                *, scale, fold, causal, g, d, blk, sub, nk, interpret):
+                *, scale, fold, causal, g, dk, dv, blk, sub, nk, interpret):
     """Grid (N, C, nq), nq sequential: one query block's online softmax
     over the K/V blocks it sees, each head of the lane block in turn.
 
@@ -259,7 +272,7 @@ def _fwd_kernel(i, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_t, m_sc, l_sc, v_t,
     q = q_ref[0]  # (blk, W)
     if fold:  # a power of two: exact in any float dtype
         q = q * scale
-    q_t = [_transposed(_only(q, m)) for m in _head_lanes(g, d, blk)]
+    q_t = [_transposed(_only(q, m)) for m in _head_lanes(g, dk, blk)]
     m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
     l_sc[...] = jnp.zeros_like(l_sc)
     acc_t[...] = jnp.zeros_like(acc_t)
@@ -281,7 +294,7 @@ def _fwd_kernel(i, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_t, m_sc, l_sc, v_t,
             l_sc[h, :, cols] = (
                 l_sc[h, :, cols] * corr + jnp.sum(p, axis=0, keepdims=True)
             )
-            v_h = v_t[j, h * d:(h + 1) * d, :nkeys]  # (d, keys)
+            v_h = v_t[j, h * dv:(h + 1) * dv, :nkeys]  # (dv, keys)
             acc_t[h, :, cols] = (
                 acc_t[h, :, cols] * corr + dot(v_h, p.astype(v_h.dtype))
             )
@@ -303,7 +316,7 @@ def _fwd_kernel(i, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_t, m_sc, l_sc, v_t,
 @_one_branch
 def _bwd_kernel(i, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, g_lse_ref,
                 dq_ref, dk_ref, dv_ref, dk_t, dv_t, dq_acc,
-                *, scale, fold, causal, g, d, blk, sub, nk, interpret):
+                *, scale, fold, causal, g, dk, dv, blk, sub, nk, interpret):
     """Grid (N, C, nq), nq sequential: one query block against the K/V
     blocks it sees, queries x keys. Scores, probabilities and their
     gradients are made once a tile and feed all three products. dQ
@@ -322,9 +335,9 @@ def _bwd_kernel(i, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, g_lse_ref,
     q, do = q_ref[0], do_ref[0]  # (blk, W)
     if fold:
         q = q * scale
-    lanes = _head_lanes(g, d, blk)
+    lanes = _head_lanes(g, dk, blk)
     q_h = [_only(q, m) for m in lanes]
-    do_h = [_only(do, m) for m in lanes]
+    do_h = [_only(do, m) for m in _head_lanes(g, dv, blk)]
     q_t = _transposed(q)  # (W, blk): head h is rows h*d..
     do_t = do.astype(jnp.float32).T
     # delta = rowsum(dO * O) a head, made here from the transposed
@@ -336,7 +349,7 @@ def _bwd_kernel(i, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, g_lse_ref,
     do_t = do_t.astype(do.dtype)
     lse, delta = [], []
     for h in range(g):
-        row = jnp.sum(d_o[h * d:(h + 1) * d], axis=0) - g_lse_ref[0, 0, h]
+        row = jnp.sum(d_o[h * dv:(h + 1) * dv], axis=0) - g_lse_ref[0, 0, h]
         delta.append(row[:, None])  # (blk, 1)
         lse.append(lse_ref[0, 0, h][:, None])
     dq_acc[...] = jnp.zeros_like(dq_acc)
@@ -346,7 +359,8 @@ def _bwd_kernel(i, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, g_lse_ref,
         k, v = k_ref[0, keys, :], v_ref[0, keys, :]
         rows = slice(at, at + n)
         for h in range(g):
-            head = slice(h * d, (h + 1) * d)
+            head_k = slice(h * dk, (h + 1) * dk)
+            head_v = slice(h * dv, (h + 1) * dv)
             s = dot(q_h[h][rows], k, _NT)  # (queries, keys) f32
             if not fold:
                 s = s * scale
@@ -355,11 +369,11 @@ def _bwd_kernel(i, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, g_lse_ref,
             p = jnp.exp(s - lse[h][rows])  # exact probabilities via saved lse
             dp = dot(do_h[h][rows], v, _NT)
             ds = (p * (dp - delta[h][rows])).astype(k.dtype)
-            dv_t[j, head, :nkeys] = dv_t[j, head, :nkeys] + dot(
-                do_t[head, rows], p.astype(v.dtype)
+            dv_t[j, head_v, :nkeys] = dv_t[j, head_v, :nkeys] + dot(
+                do_t[head_v, rows], p.astype(v.dtype)
             )
-            dk_t[j, head, :nkeys] = dk_t[j, head, :nkeys] + dot(
-                q_t[head, rows], ds
+            dk_t[j, head_k, :nkeys] = dk_t[j, head_k, :nkeys] + dot(
+                q_t[head_k, rows], ds
             )
             dq_acc[h, rows, :] = dq_acc[h, rows, :] + dot(ds, k)
 
@@ -379,11 +393,14 @@ def _bwd_kernel(i, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, g_lse_ref,
             dv_ref[0, rows, :] = dv_t[j].T.astype(dv_ref.dtype)
 
 
-def _specs(t, w, g, blk):
-    block = pl.BlockSpec((1, blk, w), lambda n, c, i: (n, i, c),
-                         memory_space=pltpu.VMEM)
-    whole = pl.BlockSpec((1, t, w), lambda n, c, i: (n, 0, c),
-                         memory_space=pltpu.VMEM)
+def _specs(t, g, blk):
+    """``block(w)`` and ``whole(w)``: one query block, and the whole
+    sequence, of an array whose lane blocks are ``w`` wide; ``stat``:
+    a query block of the per-row statistics."""
+    block = lambda w: pl.BlockSpec((1, blk, w), lambda n, c, i: (n, i, c),
+                                   memory_space=pltpu.VMEM)
+    whole = lambda w: pl.BlockSpec((1, t, w), lambda n, c, i: (n, 0, c),
+                                   memory_space=pltpu.VMEM)
     stat = pl.BlockSpec((1, 1, g, blk), lambda n, c, i: (n, c, 0, i),
                         memory_space=pltpu.VMEM)
     return block, whole, stat
@@ -404,67 +421,67 @@ def _params(t, w, blk, itemsize):
     )
 
 
-def _statics(scale, causal, g, d, blk, t, sub, interpret):
+def _statics(scale, causal, g, dk, dv, blk, t, sub, interpret):
     # a power-of-two scale (head widths 16, 64, 256) is folded into q
     return dict(
         scale=scale, fold=math.frexp(scale)[0] == 0.5, causal=causal,
-        g=g, d=d, blk=blk, sub=sub, nk=t // blk, interpret=interpret,
+        g=g, dk=dk, dv=dv, blk=blk, sub=sub, nk=t // blk, interpret=interpret,
     )
 
 
 # One traced and lowered function for every layer of a model: the inner
 # jit makes the 24 blocks of a step share it instead of tracing and
 # lowering some hundred kernel bodies one by one.
-@partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
-def _fwd_call(q, k, v, scale, causal, g, d, blk, interpret):
+@partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _fwd_call(q, k, v, scale, causal, g, dk, dv, blk, interpret):
     n, t, cw = q.shape
-    w, c = g * d, cw // (g * d)
-    block, whole, stat = _specs(t, w, g, blk)
+    wk, wv, c = g * dk, g * dv, cw // (g * dk)
+    block, whole, stat = _specs(t, g, blk)
     return pl.pallas_call(
         partial(
             _fwd_kernel,
-            **_statics(scale, causal, g, d, blk, t, _fwd_step(blk), interpret),
+            **_statics(scale, causal, g, dk, dv, blk, t, _fwd_step(blk), interpret),
         ),
         grid=(n, c, t // blk),
-        in_specs=[block, whole, whole],
-        out_specs=(block, stat),
+        in_specs=[block(wk), whole(wk), whole(wv)],
+        out_specs=(block(wv), stat),
         out_shape=(
-            _out_struct(q.shape, q.dtype, q),
+            _out_struct(v.shape, q.dtype, q),
             _out_struct((n, c, g, t), jnp.float32, q),
         ),
         scratch_shapes=[
-            pltpu.VMEM((g, d, blk), jnp.float32),  # output, transposed
+            pltpu.VMEM((g, dv, blk), jnp.float32),  # output, transposed
             pltpu.VMEM((g, 1, blk), jnp.float32),  # running max
             pltpu.VMEM((g, 1, blk), jnp.float32),  # running sum
-            pltpu.VMEM((t // blk, w, blk), v.dtype),  # V, transposed
+            pltpu.VMEM((t // blk, wv, blk), v.dtype),  # V, transposed
         ],
-        compiler_params=_params(t, w, blk, q.dtype.itemsize),
+        compiler_params=_params(t, wk, blk, q.dtype.itemsize),
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
 
 
-@partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12))
-def _bwd_call(q, k, v, o, lse, do, g_lse, scale, causal, g, d, blk, interpret):
+@partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12, 13))
+def _bwd_call(q, k, v, o, lse, do, g_lse, scale, causal, g, dk, dv, blk, interpret):
     n, t, cw = q.shape
-    w, c = g * d, cw // (g * d)
-    block, whole, stat = _specs(t, w, g, blk)
+    wk, wv, c = g * dk, g * dv, cw // (g * dk)
+    block, whole, stat = _specs(t, g, blk)
     nk = t // blk
     return pl.pallas_call(
         partial(
             _bwd_kernel,
-            **_statics(scale, causal, g, d, blk, t, _bwd_step(blk), interpret),
+            **_statics(scale, causal, g, dk, dv, blk, t, _bwd_step(blk), interpret),
         ),
         grid=(n, c, nk),
-        in_specs=[block, whole, whole, block, block, stat, stat],
-        out_specs=(block, whole, whole),
+        in_specs=[block(wk), whole(wk), whole(wv), block(wv), block(wv), stat, stat],
+        out_specs=(block(wk), whole(wk), whole(wv)),
         out_shape=tuple(_out_struct(x.shape, x.dtype, x) for x in (q, k, v)),
         scratch_shapes=[
-            pltpu.VMEM((nk, w, blk), jnp.float32),  # dK, transposed
-            pltpu.VMEM((nk, w, blk), jnp.float32),  # dV, transposed
-            pltpu.VMEM((g, blk, w), jnp.float32),  # dQ, all lanes a head
+            pltpu.VMEM((nk, wk, blk), jnp.float32),  # dK, transposed
+            pltpu.VMEM((nk, wv, blk), jnp.float32),  # dV, transposed
+            pltpu.VMEM((g, blk, wk), jnp.float32),  # dQ, all lanes a head
         ],
-        compiler_params=_params(t, w, blk, q.dtype.itemsize),
+        compiler_params=_params(t, wk, blk, q.dtype.itemsize),
         interpret=interpret,
         name="flash_bwd",
     )(q, k, v, o, do, lse, g_lse.astype(jnp.float32))
@@ -475,28 +492,28 @@ def _bwd_call(q, k, v, o, lse, do, g_lse, scale, causal, g, d, blk, interpret):
 # ---------------------------------------------------------------------
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_lse(q, k, v, scale, causal, g, d, blk):
-    """``(o, lse)``: ``o`` as ``q``, ``lse`` ``(N, C, g, T)``.
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_lse(q, k, v, scale, causal, g, dk, dv, blk):
+    """``(o, lse)``: ``o`` as ``v``, ``lse`` ``(N, C, g, T)``.
 
     Exposing lse (per-row logsumexp of the scores) with a real VJP is
     what lets :func:`make_ring_flash_attention` combine per-hop partial
     attentions differentiably — the hop weights are ``exp(lse_h − m)``,
     so gradients flow into lse, not just into ``o``.
     """
-    return _fwd_call(q, k, v, scale, causal, g, d, blk, pallas_interpret())
+    return _fwd_call(q, k, v, scale, causal, g, dk, dv, blk, pallas_interpret())
 
 
-def _flash_lse_fwd(q, k, v, scale, causal, g, d, blk):
-    o, lse = _flash_lse(q, k, v, scale, causal, g, d, blk)
+def _flash_lse_fwd(q, k, v, scale, causal, g, dk, dv, blk):
+    o, lse = _flash_lse(q, k, v, scale, causal, g, dk, dv, blk)
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_lse_bwd(scale, causal, g, d, blk, res, cot):
+def _flash_lse_bwd(scale, causal, g, dk, dv, blk, res, cot):
     q, k, v, o, lse = res
     g_o, g_lse = cot
     return _bwd_call(
-        q, k, v, o, lse, g_o, g_lse, scale, causal, g, d, blk,
+        q, k, v, o, lse, g_o, g_lse, scale, causal, g, dk, dv, blk,
         pallas_interpret(),
     )
 
@@ -504,24 +521,31 @@ def _flash_lse_bwd(scale, causal, g, d, blk, res, cot):
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def _attend(q, k, v, *, causal: bool, block: int | None = None):
-    """``(o, lse)`` for ``(B, T, H, D)`` operands: ``o`` as ``q``,
-    ``lse`` ``(B, H, T)`` f32. Picks the layout the head width allows
-    and the block edge T allows."""
-    b, t, h, d = q.shape
+def _attend(q, k, v, *, causal: bool, block: int | None = None,
+            scale: float | None = None):
+    """``(o, lse)`` for ``(B, T, H, D)`` operands: ``o`` as ``v``,
+    ``lse`` ``(B, H, T)`` f32. Picks the layout the head widths allow
+    (q and k of one width, v of its own) and the block edge T allows;
+    ``scale`` multiplies the scores, ``1/sqrt(D)`` of q left out."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
     blk = _block_for(t) if block is None else block
     if t % blk:
         raise ValueError(f"block {blk} does not divide seq_len {t}")
-    scale = 1.0 / (d**0.5)
-    g = _heads_per_block(h, d)
+    if scale is None:
+        scale = 1.0 / (dk**0.5)
+    if dk == dv:
+        g = _heads_per_block(h, dk)
+    else:  # one head a lane block, each array at its own width
+        g = 1 if dk % _LANES == 0 and dv % _LANES == 0 else None
     if g is not None:  # the projections' own layout, a free reshape
-        flat = lambda x: x.reshape(b, t, h * d)
-        o, lse = _flash_lse(flat(q), flat(k), flat(v), scale, causal, g, d, blk)
-        return o.reshape(b, t, h, d), lse.reshape(b, h, t)
+        flat = lambda x: x.reshape(b, t, h * x.shape[-1])
+        o, lse = _flash_lse(flat(q), flat(k), flat(v), scale, causal, g, dk, dv, blk)
+        return o.reshape(b, t, h, dv), lse.reshape(b, h, t)
     # (B, T, H, D) -> (B*H, T, D): each (batch, head) pair is a grid row
-    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-    o, lse = _flash_lse(flat(q), flat(k), flat(v), scale, causal, 1, d, blk)
-    return o.reshape(b, h, t, d).transpose(0, 2, 1, 3), lse.reshape(b, h, t)
+    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
+    o, lse = _flash_lse(flat(q), flat(k), flat(v), scale, causal, 1, dk, dv, blk)
+    return o.reshape(b, h, t, dv).transpose(0, 2, 1, 3), lse.reshape(b, h, t)
 
 
 def flash_attention(q, k, v, *, causal: bool = False, block: int | None = None):
@@ -530,6 +554,11 @@ def flash_attention(q, k, v, *, causal: bool = False, block: int | None = None):
 
     ``q, k, v``: ``(batch, seq, heads, head_dim)``, bf16 or f32. Scores
     and the softmax never touch HBM; memory is O(T·D) instead of O(T²).
+    q and k may be wider than v (latent attention's 192 beside 128):
+    they are then padded with zero lanes to a multiple of 128, which
+    leaves every score as it was, the scores are still divided by the
+    root of the width they came with, and the kernels read q and k at
+    the padded width and v, the output and its cotangent at v's.
     ``block`` is the query/key block edge; left out, it is the largest
     of ``_BLOCKS`` that divides T. A T that 128 does not divide
     is handled per the mask structure: causal inputs are zero-padded up
@@ -541,7 +570,13 @@ def flash_attention(q, k, v, *, causal: bool = False, block: int | None = None):
     to ``_MAX_WHOLE_BLOCK`` and raise beyond it instead of blowing VMEM
     at Mosaic compile time.
     """
-    t = q.shape[1]
+    t, dk = q.shape[1], q.shape[-1]
+    if dk != v.shape[-1] and dk % _LANES:
+        lanes = ((0, 0), (0, 0), (0, 0), (0, -dk % _LANES))
+        return _attend(
+            jnp.pad(q, lanes), jnp.pad(k, lanes), v, causal=causal,
+            block=block, scale=1.0 / (dk**0.5),
+        )[0]
     if block is None and t % _BLOCKS[-1]:
         if causal and t > _MAX_WHOLE_BLOCK:
             pad = -t % _BLOCKS[-1]

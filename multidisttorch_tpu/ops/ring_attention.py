@@ -77,7 +77,8 @@ def _ring_attention_local(
     axes = vary_axes if vary_axes is not None else (axis_name,)
     m0 = pvary(jnp.full((b, h, t_local), -jnp.inf, jnp.float32), axes)
     l0 = pvary(jnp.zeros((b, h, t_local), jnp.float32), axes)
-    acc0 = pvary(jnp.zeros((b, t_local, h, d), jnp.float32), axes)
+    # v may be narrower than q and k (latent attention); the output is v's
+    acc0 = pvary(jnp.zeros((b, t_local, h, v.shape[-1]), jnp.float32), axes)
 
     def body(step, carry):
         k_blk, v_blk, m, l, acc = carry

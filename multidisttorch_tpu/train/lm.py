@@ -161,9 +161,12 @@ def make_lm_train_step(
     model with ``TransformerLM(remat=True)`` — per-BLOCK checkpointing,
     the placement that actually cuts peak HBM (a whole-forward
     ``jax.checkpoint`` here would recompute everything and save
-    nothing). A model returning ``(logits, aux)`` (the MoE LM's Switch
-    load-balancing term) trains on
-    ``lm_loss + aux_loss_weight * aux``."""
+    nothing). A model returning ``(logits, aux)`` with a scalar ``aux``
+    (the MoE LM's Switch load-balancing term) trains on
+    ``lm_loss + aux_loss_weight * aux``; one returning ``(logits,
+    {name: counter})`` (``LatentMoELM``'s assignments per expert held)
+    trains on the loss alone and the counters come out beside it in
+    the step's metrics, read with the loss."""
     repl, tokens_sh, _, state_sh = _lm_shardings(
         trial, sequence_parallel, shardings
     )
@@ -186,11 +189,16 @@ def _build_lm_step_fn(model, tx, aux_loss_weight):
             out = model.apply({"params": params}, tokens)
             with jax.named_scope(SCOPE_LOSS):
                 loss = lm_loss_mean(_logits(out), tokens)
-            if isinstance(out, tuple):
+            counters = {}
+            if isinstance(out, tuple) and isinstance(out[1], dict):
+                counters = out[1]  # counted, not trained on
+            elif isinstance(out, tuple):
                 loss = loss + aux_loss_weight * out[1]
-            return loss
+            return loss, counters
 
-        loss, grads = jax.value_and_grad(loss_fn)(state.params)
+        (loss, counters), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            state.params
+        )
         with jax.named_scope(SCOPE_OPTIMIZER):
             updates, new_opt = tx.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
@@ -198,7 +206,7 @@ def _build_lm_step_fn(model, tx, aux_loss_weight):
             TrainState(
                 params=new_params, opt_state=new_opt, step=state.step + 1
             ),
-            {"loss": loss.astype(jnp.float32)},
+            {"loss": loss.astype(jnp.float32), **counters},
         )
 
     return step_fn

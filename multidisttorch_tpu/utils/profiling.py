@@ -35,6 +35,18 @@ SCOPE_ATTN_CORE = "attn_core"
 SCOPE_MLP = "mlp"
 SCOPE_LOSS = "loss"
 SCOPE_OPTIMIZER = "optimizer"
+# Inside the dropless expert layer (``ops/moe.py::RoutedExperts``, which
+# a block names ``moe``): scores, top-k and weights; sorting, gathering
+# the tokens and the weighted sum back; the grouped matrix products of
+# the experts held; the shared expert.
+SCOPE_ROUTER = "router"
+SCOPE_EXPERT_DISPATCH = "expert_dispatch"
+SCOPE_EXPERTS = "experts"
+SCOPE_SHARED_EXPERT = "shared_expert"
+# Latent attention's projections are several matrices and a norm a
+# path; these scopes give them the names a plain block's projections
+# carry as flax modules (``models/latent_moe.py``).
+SCOPE_Q, SCOPE_K, SCOPE_V = "q", "k", "v"
 
 
 @contextlib.contextmanager

@@ -61,6 +61,83 @@ def test_rule(device_kind, num_devices, seq_len, num_heads, head_dim, kernel):
     ) is kernel
 
 
+@pytest.mark.parametrize(
+    "num_devices, seq_len, head_dim, v_head_dim, kernel",
+    [
+        (1, 4096, 192, 128, True),  # latent attention: moe-mla-t4096
+        (1, 256, 192, 128, True),
+        (4, 4096, 192, 128, False),  # over several chips it is dense, as every width
+        (1, 200, 192, 128, False),
+        (1, 4096, 192, 192, False),  # pairs of widths not run on the chip
+        (1, 4096, 128, 64, False),
+        (1, 4096, 64, 128, False),
+        (1, 1024, 64, 64, True),  # v's width given and equal: the rule above
+    ],
+)
+def test_rule_with_a_width_of_its_own_for_v(num_devices, seq_len, head_dim, v_head_dim, kernel):
+    assert default_takes_kernel(V5E, num_devices, seq_len, 32, head_dim, v_head_dim) is kernel
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_one_chip_latent_attention_step_runs_the_kernel(as_tpu, remat):
+    """``LatentMoELM`` given no attention: q and k 192 wide, v 128, on
+    one chip the kernel, a forward a block (twice under remat) and one
+    fused backward; on the CPU as it is, dense."""
+    from multidisttorch_tpu.models.latent_moe import LatentMoELM
+
+    model = LatentMoELM(
+        vocab_size=64, d_model=128, num_heads=2, num_layers=LAYERS, qk_nope_dim=128,
+        qk_rope_dim=64, v_head_dim=128, max_len=T, remat=remat,
+    )
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    assert _count(_step_jaxpr(group, model), "pallas_call") == LAYERS * (3 if remat else 2)
+    (four,) = setup_groups(1, devices=jax.devices()[:4])
+    assert _count(_step_jaxpr(four, model), "pallas_call") == 0
+
+
+@pytest.mark.parametrize(
+    "device_kind, num_devices, rows, k, n, kernel",
+    [
+        (V5E, 1, 16384, 2048, 768, True),  # moe-mla-t4096: gate and up
+        (V5E, 1, 16384, 768, 2048, True),  # down
+        ("cpu", 1, 16384, 2048, 768, False),
+        (V5E, 4, 16384, 2048, 768, False),  # no partitioning rule around the kernel
+        (V5E, 1, 16000, 2048, 768, False),  # not whole tiles of rows
+        (V5E, 1, 16384, 2048, 32, False),  # not whole lanes
+    ],
+)
+def test_grouped_dot_rule(device_kind, num_devices, rows, k, n, kernel):
+    from multidisttorch_tpu.ops.moe import grouped_dot_takes_kernel
+
+    assert grouped_dot_takes_kernel(device_kind, num_devices, rows, k, n) is kernel
+
+
+def test_one_chip_expert_layer_runs_the_grouped_kernel(as_tpu):
+    """On one chip, at shapes the kernel tiles, the experts' products
+    are Pallas calls too: two forward (gate and up as one, then down)
+    and for each of them the two of its backward; over several chips,
+    as on the CPU, they are XLA's ragged dot."""
+    from multidisttorch_tpu.models.latent_moe import LatentMoELM
+
+    model = LatentMoELM(
+        vocab_size=64, d_model=128, num_heads=2, num_layers=2, max_len=T,
+        expert_hidden_dim=128, num_experts=4, top_k=2,
+    )
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    # T = 256 with widths 24/16: the attention stays dense, so every call is the experts'
+    assert _count(_step_jaxpr(group, model), "pallas_call") == 2 * 3
+    (four,) = setup_groups(1, devices=jax.devices()[:4])
+    assert _count(_step_jaxpr(four, model), "pallas_call") == 0
+
+
+def test_cpu_latent_attention_stays_dense():
+    from multidisttorch_tpu.models.latent_moe import LatentMoELM
+
+    model = LatentMoELM(vocab_size=64, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128, max_len=T)
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    assert _count(_step_jaxpr(group, model), "pallas_call") == 0
+
+
 def _count(jaxpr, primitive: str) -> int:
     """Equations of ``primitive`` in ``jaxpr``, call sites of shared
     inner jaxprs (``jit``, ``remat``, ``custom_vjp``, ``scan``)

@@ -80,6 +80,16 @@ def test_lm_hpo_example_fused_dispatch():
 
 
 @pytest.mark.examples
+def test_lm_hpo_example_latent_moe():
+    # the latent-attention LM with dropless routed experts as the
+    # third model the example offers, its context on each trial's ring
+    out = _run(["lm_hpo.py", "--ngroups", "2", "--seq-len", "64",
+                "--steps", "12", "--latent-moe", "8"])
+    assert out.count("perplexity") == 2
+    assert out.count("assignments per expert") == 2
+
+
+@pytest.mark.examples
 def test_lm_long_context_example():
     out = _run(["lm_long_context.py", "--seq-len", "64", "--steps", "8"])
     assert "greedy decode matches" in out

@@ -1,0 +1,276 @@
+"""``LatentMoELM`` (latent attention, dropless routed experts) against
+the benchmark's plain float32 reference, on the CPU at a tiny size.
+
+The reference is ``benchmark/configs/joyai-llm-flash.reference.py``,
+which imports nothing of the program; the weights reach it through
+``benchmark/entries/moe_lm_trial.py::reference_weights``, the renaming
+the chip run's comparison uses. Everything here is float32 at
+``default_matmul_precision("highest")``, seeded, and counts or compares
+numbers; nothing is timed.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from benchmark import cells
+from benchmark.entries import moe_lm_trial
+from multidisttorch_tpu.models.latent_moe import LatentMoELM, rope_interleaved
+from multidisttorch_tpu.ops.moe import RoutedExperts
+from multidisttorch_tpu.parallel.mesh import setup_groups
+from multidisttorch_tpu.train.lm import create_lm_state, make_lm_train_step
+from multidisttorch_tpu.train.steps import TrainState
+
+REFERENCE = cells.load_module("benchmark/configs/joyai-llm-flash.reference.py")
+
+# The configuration's keys at a toy size: 16 experts, 4 a token, one
+# dense layer and two expert layers.
+TINY = {
+    "vocab_size": 64, "hidden_size": 32, "num_attention_heads": 2, "num_hidden_layers": 3,
+    "first_k_dense_replace": 1, "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "rope_theta": 10000.0, "intermediate_size": 48,
+    "router_width": 16, "experts_held": [0, 16], "num_experts_per_tok": 4,
+    "moe_intermediate_size": 24, "n_shared_experts": 1, "routed_scaling_factor": 2.5,
+    "rms_norm_eps": 1e-6, "max_position_embeddings": 32,
+    "assumed": {"compute_dtype": "float32", "remat": False},
+}
+
+
+def _config(**changes):
+    return {**TINY, **changes}
+
+
+def _params(model, seed=0, t=16):
+    return model.init({"params": jax.random.key(seed)}, jnp.zeros((1, t), jnp.int32))["params"]
+
+
+def _tokens(seed=1, b=2, t=16):
+    return jax.random.randint(jax.random.key(seed), (b, t), 0, TINY["vocab_size"])
+
+
+def _rel(got, want):
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+@pytest.mark.parametrize("held", [[0, 16], [4, 8]], ids=["all", "share"])
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_model_agrees_with_the_reference(held, remat):
+    """Logits, loss, every gradient leaf, the experts chosen and the
+    counter, through ``create_lm_state`` and ``make_lm_train_step`` as a
+    trial runs them (the gradient is read back from one SGD(1.0) step)."""
+    config = _config(experts_held=held, assumed={"compute_dtype": "float32", "remat": remat})
+    model = moe_lm_trial.build_model(config)
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    sgd = optax.sgd(1.0)
+    tokens = _tokens()
+    with jax.default_matmul_precision("highest"):
+        state = create_lm_state(group, model, sgd, jax.random.key(0))
+        params = jax.tree.map(jnp.copy, state.params)
+        logits, chosen = jax.jit(
+            lambda p, t: moe_lm_trial.chosen_experts(model, p, t, config)
+        )(params, tokens)
+        after, metrics = make_lm_train_step(group, model, sgd)(state, tokens)
+        grads = jax.tree.map(jnp.subtract, params, after.params)
+        ref_logits, ref_loss, ref_grads, routing = jax.jit(
+            lambda w, t: REFERENCE.logits_loss_grads(w, t, config)
+        )(moe_lm_trial.reference_weights(params, config), tokens)
+
+    assert _rel(logits, ref_logits) < 1e-5
+    assert abs(float(metrics["loss"]) - float(ref_loss)) < 1e-5 * float(ref_loss)
+    np.testing.assert_array_equal(jnp.sort(chosen, -1), jnp.sort(routing["chosen"], -1))
+    np.testing.assert_array_equal(metrics["expert_counts"], routing["expert_counts"])
+    assert metrics["expert_counts"].shape == (2, held[1])
+    got = moe_lm_trial.reference_weights(grads, config)
+    flat_want = jax.tree_util.tree_leaves_with_path(ref_grads)
+    for (path, want), have in zip(flat_want, jax.tree.leaves(got), strict=True):
+        name = jax.tree_util.keystr(path)
+        if "score_bias" in name:  # chooses, never weighs: no gradient
+            assert not jnp.any(want) and not jnp.any(have), name
+        else:
+            assert _rel(have, want) < 2e-4, (name, _rel(have, want))
+
+
+def _layer(held, **kw):
+    return RoutedExperts(
+        num_experts=16, experts_held=held, top_k=4, hidden_dim=24, routed_scaling=2.5, **kw
+    )
+
+
+def _layer_weights(whole, first, count):
+    """The whole layer's parameters cut to one share's experts."""
+    cut = lambda a: a[first:first + count]
+    return {**whole, **{k: cut(whole[k]) for k in ("w_gate", "w_up", "w_down")}}
+
+
+def test_shares_add_up_to_the_uncut_layer():
+    """16 experts over 4 shares: the shares' routed parts, plus the
+    shared expert once, are the uncut reference's layer."""
+    x = jax.random.normal(jax.random.key(2), (40, 32))
+    with jax.default_matmul_precision("highest"):
+        whole = _layer((0, 16), shared_hidden_dim=24).init(jax.random.key(3), x)["params"]
+        config = _config()
+        ref_w = {
+            "router": whole["router"], "score_bias": whole["score_bias"],
+            "e_gate": whole["w_gate"], "e_up": whole["w_up"], "e_down": whole["w_down"],
+            "s_gate": whole["shared_gate"]["kernel"], "s_up": whole["shared_up"]["kernel"],
+            "s_down": whole["shared_down"]["kernel"],
+        }
+        want, _, want_counts = REFERENCE.experts(x, ref_w, config)
+        shared = REFERENCE.swiglu(x, ref_w["s_gate"], ref_w["s_up"], ref_w["s_down"])
+        total, counts = shared, []
+        routed_only = {k: v for k, v in whole.items() if not k.startswith("shared_")}
+        for first in range(0, 16, 4):
+            part, c = _layer((first, 4)).apply(
+                {"params": _layer_weights(routed_only, first, 4)}, x
+            )
+            total = total + part
+            counts.append(c)
+    assert _rel(total, want) < 1e-5
+    np.testing.assert_array_equal(jnp.concatenate(counts), want_counts)
+    assert int(want_counts.sum()) == 40 * 4  # every choice of every token, once
+
+
+@pytest.mark.parametrize("rows", ["usual", "worst"])
+def test_no_token_is_dropped_under_uneven_routing(rows):
+    """A selection bias that sends every token to the experts held
+    (the worst the routing allows: 8 times the mean load, 4 times the
+    usual buffer) makes the layer walk the expert order a buffer at a
+    time; a bias that keeps them away leaves the buffer empty. Either
+    way the layer and its gradients are the reference's, and the
+    counter is the reference's count."""
+    x = jax.random.normal(jax.random.key(4), (64, 32))
+    co = jax.random.normal(jax.random.key(9), (64, 32))
+    layer = _layer((4, 4))
+    config = _config(experts_held=[4, 4])
+    none = {"s_gate": jnp.zeros((32, 1)), "s_up": jnp.zeros((32, 1)), "s_down": jnp.zeros((1, 32))}
+    names = {"router": "router", "score_bias": "score_bias", "e_gate": "w_gate",
+             "e_up": "w_up", "e_down": "w_down"}
+    with jax.default_matmul_precision("highest"):
+        params = layer.init(jax.random.key(5), x)["params"]
+        push = 10.0 if rows == "worst" else -10.0
+        params["score_bias"] = params["score_bias"].at[4:8].add(push)
+
+        def program(p, x):
+            out, counts = layer.apply({"params": p}, x)
+            return jnp.sum(out * co), (out, counts)
+
+        def reference(p, x):
+            out, _, counts = REFERENCE.experts(
+                x, {ref: p[own] for ref, own in names.items()} | none, config)
+            return jnp.sum(out * co), (out, counts)
+
+        (_, (got, counts)), grads = jax.jit(
+            jax.value_and_grad(program, argnums=(0, 1), has_aux=True))(params, x)
+        (_, (want, want_counts)), want_grads = jax.jit(
+            jax.value_and_grad(reference, argnums=(0, 1), has_aux=True))(params, x)
+    np.testing.assert_array_equal(counts, want_counts)
+    if rows == "usual":
+        assert int(counts.sum()) == 0 and not jnp.any(got)
+        assert all(not jnp.any(g) for g in jax.tree.leaves(grads))
+        return
+    assert int(counts.sum()) == 64 * 4  # all four choices of every token land here
+    assert _rel(got, want) < 1e-5
+    for got_g, want_g in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads), strict=True):
+        if jnp.any(want_g):
+            assert _rel(got_g, want_g) < 1e-4
+        else:
+            assert not jnp.any(got_g)
+
+
+def test_selection_bias_moves_the_choice_and_not_the_weights():
+    x = jax.random.normal(jax.random.key(6), (32, 32))
+    layer = _layer((0, 16))
+    params = layer.init(jax.random.key(7), x)["params"]
+
+    def chosen_and_out(p):
+        (out, _), state = layer.apply({"params": p}, x, mutable=["intermediates"])
+        return state["intermediates"]["chosen"][0], out
+
+    chosen, out = chosen_and_out(params)
+    # the same shift for every expert leaves the choice, and with it
+    # the output, as it was: the bias is in no weight
+    shifted = {**params, "score_bias": params["score_bias"] + 3.0}
+    same_chosen, same_out = chosen_and_out(shifted)
+    np.testing.assert_array_equal(chosen, same_chosen)
+    np.testing.assert_array_equal(out, same_out)
+    # a large bias on one expert puts it among every token's choices
+    pushed = {**params, "score_bias": params["score_bias"].at[9].add(10.0)}
+    new_chosen, new_out = chosen_and_out(pushed)
+    assert jnp.all(jnp.any(new_chosen == 9, axis=-1))
+    assert not jnp.allclose(out, new_out)
+    grads = jax.grad(lambda p: jnp.sum(layer.apply({"params": p}, x)[0] ** 2))(params)
+    assert not jnp.any(grads["score_bias"]) and jnp.any(grads["router"])
+
+
+def test_rotary_interleaving_is_a_rotation_of_pairs():
+    x = jax.random.normal(jax.random.key(8), (2, 5, 3, 8))
+    theta = 10000.0
+    got = rope_interleaved(x, jnp.arange(5), theta)
+    want = np.zeros_like(x)
+    for pos in range(5):
+        for i in range(4):
+            angle = pos * theta ** (-2 * i / 8)
+            c, s = np.cos(angle), np.sin(angle)
+            a, b = x[:, pos, :, 2 * i], x[:, pos, :, 2 * i + 1]
+            want[:, pos, :, 2 * i] = a * c - b * s
+            want[:, pos, :, 2 * i + 1] = a * s + b * c
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    # position 0 is left alone, and a rotation keeps each pair's length
+    np.testing.assert_allclose(got[:, 0], x[:, 0], atol=1e-6)
+    pairs = lambda a: np.asarray(a).reshape(2, 5, 3, 4, 2)
+    np.testing.assert_allclose(
+        np.linalg.norm(pairs(got), axis=-1), np.linalg.norm(pairs(x), axis=-1), atol=1e-5
+    )
+
+
+def test_bf16_step_trains_and_counts():
+    """The trial path at the cell's dtypes: the loss falls, Adam leaves
+    the selection bias where it was, the counter has one row a layer."""
+    model = LatentMoELM(vocab_size=64, dtype=jnp.bfloat16, remat=True, experts_held=(2, 4))
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    tx = optax.adam(1e-2)
+    state = create_lm_state(group, model, tx, jax.random.key(0))
+    bias = jax.tree.map(jnp.copy, state.params["block_1"]["moe"]["score_bias"])
+    step = make_lm_train_step(group, model, tx)
+    tokens = group.device_put(_tokens(t=32), group.batch_sharding)
+    losses = []
+    for _ in range(8):
+        state, metrics = step(state, tokens)
+        losses.append(float(metrics["loss"]))
+    assert losses[-1] < losses[0] and np.isfinite(losses).all()
+    assert metrics["expert_counts"].shape == (2, 4) and metrics["expert_counts"].dtype == jnp.int32
+    np.testing.assert_array_equal(state.params["block_1"]["moe"]["score_bias"], bias)
+    assert isinstance(state, TrainState)
+
+
+def test_kernel_grouped_dot_matches_xla_ragged_dot():
+    """The Pallas grouped matmul a one-chip TPU model runs for its
+    experts (interpreter here) against XLA's ragged dot, forward and
+    both gradients, with rows past the groups' sum left out of the
+    comparison as the layer leaves them out."""
+    from multidisttorch_tpu.ops.moe import kernel_grouped_dot, ragged_grouped_dot
+
+    ks = jax.random.split(jax.random.key(10), 3)
+    lhs = jax.random.normal(ks[0], (1024, 128), jnp.float32).astype(jnp.bfloat16)
+    rhs = jax.random.normal(ks[1], (4, 128, 256), jnp.float32).astype(jnp.bfloat16)
+    co = jax.random.normal(ks[2], (1024, 256), jnp.float32)
+    sizes = jnp.array([300, 0, 411, 100], jnp.int32)  # 811 of 1,024 rows; one empty group
+    valid = (jnp.arange(1024) < 811)[:, None]
+
+    def run(dot):
+        def loss(lhs, rhs):
+            out = jnp.where(valid, dot(jnp.where(valid, lhs, 0), rhs, sizes), 0.0)
+            return jnp.sum(out * co), out
+
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(lhs, rhs)
+        return out, grads
+
+    out, (g_lhs, g_rhs) = run(kernel_grouped_dot)
+    want, (w_lhs, w_rhs) = run(ragged_grouped_dot)
+    assert out.dtype == jnp.float32 and out.shape == (1024, 256)
+    rel = lambda a, b: _rel(a.astype(jnp.float32), b.astype(jnp.float32))
+    assert rel(out, want) < 1e-5
+    assert rel(g_lhs, w_lhs) < 2e-2 and rel(g_rhs, w_rhs) < 2e-2  # bf16 cotangents
+    assert not jnp.any(g_rhs[1])  # the empty group's weights get no gradient

@@ -134,3 +134,81 @@ def test_scopes_are_metadata_only(monkeypatch):
     without = _lowered(TransformerLM, True)[0]
     assert SCOPE_ATTN_CORE not in without.as_text(debug_info=True)
     assert with_scopes.as_text() == without.as_text()
+
+
+# --- the latent-attention, routed-expert LM (models/latent_moe.py) ---
+
+
+def _lowered_latent(remat):
+    from multidisttorch_tpu.models.latent_moe import LatentMoELM
+
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    model = LatentMoELM(vocab_size=64, num_layers=LAYERS + 1, max_len=16, remat=remat)
+    tx = optax.adam(1e-3)
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    params = jax.eval_shape(
+        model.init, {"params": jax.random.key(0)}, jnp.zeros((2, 16), jnp.int32)
+    )["params"]
+    state = jax.eval_shape(
+        lambda p: TrainState(params=p, opt_state=tx.init(p), step=jnp.zeros((), jnp.int32)),
+        params,
+    )
+    return make_lm_train_step(group, model, tx).lower(state, tokens), params
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_expert_layer_scopes_reach_the_compiled_step(remat):
+    """The four scopes inside ``moe`` and the names the benchmark's
+    split knows, in the compiled step of the latent-attention LM: as
+    the test above counts the four of the dense LM."""
+    from benchmark import moe_scopes, scope_reduce
+    from multidisttorch_tpu.utils.profiling import (
+        SCOPE_EXPERT_DISPATCH, SCOPE_EXPERTS, SCOPE_ROUTER, SCOPE_SHARED_EXPERT,
+    )
+
+    lowered, _ = _lowered_latent(remat)
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    step = [n for n in names if n.startswith("jit(step_fn)")]
+    assert len(step) > 500
+
+    both = {"forward", "backward"} | ({"recompute"} if remat else set())
+    inner = (SCOPE_ROUTER, SCOPE_EXPERT_DISPATCH, SCOPE_EXPERTS, SCOPE_SHARED_EXPERT)
+    assert inner == moe_scopes.PARTS
+    for scope in inner:
+        under = [n for n in step if moe_scopes.classify(n) == scope]
+        assert {_pass(n) for n in under} >= both, scope
+        # the benchmark's split charges all of them to ``mlp``
+        assert {scope_reduce.classify(n)[0] for n in under} == {"mlp"}, scope
+    for i in range(1, LAYERS + 1):  # block_0 is the dense layer
+        for scope in inner:
+            assert any({f"block_{i}", "moe", scope} <= set(_components(n)) for n in step)
+    assert not any("moe" in _components(n) and "block_0" in _components(n) for n in step)
+    # nearly all of the layer is under one of the four
+    in_moe = [n for n in step if moe_scopes.classify(n) is not None]
+    other = [n for n in in_moe if moe_scopes.classify(n) == moe_scopes.OTHER]
+    assert len(other) / len(in_moe) < 0.05, sorted(set(other))[:20]
+
+    # latent attention's pieces carry the names of a plain block's
+    parts = {scope_reduce.classify(n)[0] for n in step}
+    assert {"attn_core", "attn_proj", "mlp", "norm", "embed", "head", "loss", "optimizer"} <= parts
+    for name in ("q", "k", "v", "proj"):
+        assert {_pass(n) for n in step if name in _components(n)} >= both, name
+    assert {_pass(n) for n in step if SCOPE_MLP in _components(n)} >= both  # the dense layer
+    unrecognised = [n for n in step if scope_reduce.classify(n)[0] in ("unscoped", "block_other")]
+    assert len(unrecognised) / len(step) < UNRECOGNISED_BOUND, (
+        len(unrecognised), len(step), sorted(set(unrecognised))[:20]
+    )
+
+
+def test_latent_scopes_stay_out_of_the_parameter_tree():
+    _, params = _lowered_latent(True)
+    assert set(params) == {"tok_embed", "ln_out", "head"} | {
+        f"block_{i}" for i in range(LAYERS + 1)
+    }
+    attention = {"ln_attn", "q_a", "q_norm", "q_b", "kv_a", "kv_norm", "kv_b", "proj", "ln_mlp"}
+    assert set(params["block_0"]) == attention | {"gate", "up", "down"}
+    assert set(params["block_1"]) == attention | {"moe"}
+    assert set(params["block_1"]["moe"]) == {
+        "router", "score_bias", "w_gate", "w_up", "w_down",
+        "shared_gate", "shared_up", "shared_down",
+    }

@@ -355,6 +355,59 @@ def test_drives_transformer_lm():
     assert float(m3["loss"]) < float(m1["loss"])
 
 
+@pytest.mark.parametrize(
+    "t, h, dk, dv, block, dtype, tol",
+    [
+        (256, 2, 192, 128, None, jnp.float32, 2e-5),  # latent attention's widths
+        (256, 2, 192, 128, 128, jnp.float32, 2e-5),  # two blocks a sequence
+        (256, 2, 192, 128, None, jnp.bfloat16, 3e-2),
+        (128, 2, 256, 128, None, jnp.float32, 2e-5),  # already whole lanes: no padding
+        (96, 3, 24, 16, None, jnp.float32, 2e-5),  # the flattened layout, q padded to 128
+    ],
+)
+def test_wider_q_and_k_than_v_match_dense(t, h, dk, dv, block, dtype, tol):
+    """q and k of one width, v of another (MLA: 128 + 64 beside 128):
+    the kernels read q and k padded to whole lanes and v at its own
+    width, the scores are divided by the root of the width q came with.
+    Forward and every gradient against the dense path."""
+    ks = jax.random.split(jax.random.key(7), 4)
+    q, k = (jax.random.normal(x, (2, t, h, dk), jnp.float32).astype(dtype) for x in ks[:2])
+    v = jax.random.normal(ks[2], (2, t, h, dv), jnp.float32).astype(dtype)
+    w = jax.random.normal(ks[3], (2, t, h, dv), jnp.float32)
+
+    def run(attn):
+        loss = lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+        out = attn(q, k, v)
+        return out, jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        out, grads = run(lambda q, k, v: flash_attention(q, k, v, causal=True, block=block))
+        want, want_grads = run(lambda q, k, v: dense_attention_reference(
+            *(x.astype(jnp.float32) for x in (q, k, v)), causal=True))
+    assert out.shape == (2, t, h, dv) and out.dtype == dtype
+    rel = lambda a, b: float(
+        jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
+    assert rel(out, want) < tol
+    for got, ref in zip(grads, want_grads):
+        assert got.shape == ref.shape and rel(got, ref) < tol
+
+
+def test_latent_attention_widths_lower_for_tpu(monkeypatch):
+    # the cell moe-mla-t4096's attention: 4 x 4,096, 32 heads, q and k
+    # 192 wide, v 128; forward and backward, interpret mode off
+    monkeypatch.delenv("MDT_PALLAS_INTERPRET")
+    qk = jax.ShapeDtypeStruct((4, 4096, 32, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((4, 4096, 32, 128), jnp.bfloat16)
+    fwd = lambda q, k, v: flash_attention(q, k, v, causal=True)
+    bwd = jax.grad(
+        lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+    )
+    for fn in (fwd, bwd):
+        text = jax.jit(fn).trace(qk, qk, v).lower(lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text
+        assert "32x4096x4096" not in text  # no (B, H, T, T) scores outside the kernel
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize(
     "shape",
