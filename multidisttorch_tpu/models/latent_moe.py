@@ -210,7 +210,7 @@ class LatentMoELM(nn.Module):
     max_len: int = 256
     attention: Optional[Callable] = None
     dtype: Any = jnp.float32
-    remat: bool = False  # per-block checkpointing, as in TransformerLM
+    remat: bool = False  # per-block checkpointing (transformer.remat_block)
 
     @nn.compact
     def __call__(self, tokens):
@@ -225,7 +225,7 @@ class LatentMoELM(nn.Module):
             self.vocab_size, self.d_model, dtype=self.dtype,
             param_dtype=jnp.float32, name="tok_embed",
         )(tokens)
-        block_cls = nn.remat(LatentMoEBlock) if self.remat else LatentMoEBlock
+        block_cls = transformer.remat_block(LatentMoEBlock) if self.remat else LatentMoEBlock
         shared = dict(
             num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
             kv_lora_rank=self.kv_lora_rank, qk_nope_dim=self.qk_nope_dim,

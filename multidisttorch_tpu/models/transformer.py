@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 
 from multidisttorch_tpu.ops.pallas_attention import (
+    SAVED_LSE,
+    SAVED_OUT,
     default_takes_kernel,
     flash_attention,
 )
@@ -87,6 +89,26 @@ class Block(nn.Module):
             y = nn.gelu(y)
             y = dense(d, "down")(y)
         return x + y
+
+
+# One policy object for every block: jaxprs and jit's caches compare it
+# by identity.
+_KEEP_KERNEL_RESULTS = jax.checkpoint_policies.save_only_these_names(
+    SAVED_OUT, SAVED_LSE
+)
+
+
+def remat_block(block_cls):
+    """``block_cls`` under per-block rematerialization, the one rule of
+    every model here that has a ``remat`` field: the backward pass
+    recomputes a block from its input, and of what the block made only
+    the attention kernel's output and logsumexp are kept (by name:
+    ``ops/pallas_attention.py``; bf16 ``(B, T, H*Dv)`` and f32 ``(B,
+    H, T)`` a block), so the recomputed forward does not hold the
+    kernel. Where the trace has no such names (the dense path, an
+    injected attention of another kind) nothing but the input is
+    saved."""
+    return nn.remat(block_cls, policy=_KEEP_KERNEL_RESULTS)
 
 
 def _placement(x):
@@ -186,20 +208,21 @@ class TransformerLM(nn.Module):
     max_len: int = 256
     attention: Optional[Callable] = None
     dtype: Any = jnp.float32
-    # Per-BLOCK rematerialization (flax nn.remat): only the block
-    # boundaries' residual streams are saved; each block's internal
-    # activations (qkv, attention probs, the 4x MLP) are recomputed in
-    # the backward pass. This is the placement that actually cuts peak
-    # HBM for a deep stack — checkpointing the whole forward would
-    # leave every layer's activations live during the backward and
-    # save nothing.
+    # Per-BLOCK rematerialization (remat_block): the block boundaries'
+    # residual streams are saved, and with them the attention kernel's
+    # output and logsumexp where the kernel runs; each block's other
+    # activations (qkv, dense attention's probs, the 4x MLP) are
+    # recomputed in the backward pass. This is the placement that
+    # actually cuts peak HBM for a deep stack — checkpointing the
+    # whole forward would leave every layer's activations live during
+    # the backward and save nothing.
     remat: bool = False
 
     @nn.compact
     def __call__(self, tokens):
         x = _lm_embed(self, tokens)
         attn = _default_causal(self.attention)
-        block_cls = nn.remat(Block) if self.remat else Block
+        block_cls = remat_block(Block) if self.remat else Block
         for i in range(self.num_layers):
             x = block_cls(
                 d_model=self.d_model,
@@ -343,13 +366,13 @@ class MoETransformerLM(nn.Module):
     max_len: int = 256
     attention: Optional[Callable] = None
     dtype: Any = jnp.float32
-    remat: bool = False  # per-block checkpointing, as in TransformerLM
+    remat: bool = False  # per-block checkpointing (remat_block)
 
     @nn.compact
     def __call__(self, tokens):
         x = _lm_embed(self, tokens)
         attn = _default_causal(self.attention)
-        block_cls = nn.remat(MoEBlock) if self.remat else MoEBlock
+        block_cls = remat_block(MoEBlock) if self.remat else MoEBlock
         aux_total = jnp.zeros((), jnp.float32)
         for i in range(self.num_layers):
             x, aux = block_cls(

@@ -40,6 +40,22 @@ What runs where:
   every matmul accumulates in f32, the softmax statistics and the
   logsumexp are f32, and probabilities and score gradients are cast to
   the operand dtype for the second matmuls, as XLA's dense path does.
+- **Under rematerialization.** The backward kernel needs q, k, v, the
+  forward's output and its logsumexp. A block under ``nn.remat``
+  rebuilds q, k and v from its input; the output (bf16 ``(B, T,
+  H*Dv)``) and the logsumexp (f32 ``(B, H, T)``) carry the names
+  ``SAVED_OUT`` and ``SAVED_LSE``, and the models' remat rule
+  (``models/transformer.py::remat_block``) saves what is so named, so
+  the recomputed forward of a block holds no kernel: one forward and
+  one backward call a layer and step. The names are given in the
+  ``custom_vjp``'s forward rule, to the residuals themselves:
+  differentiation replaces the call by that rule before a
+  checkpoint's policy sorts the block's values, so the policy meets
+  them there. Names on the call's outputs at the call site would be
+  copies of what the backward rule holds, and the call would be run
+  again for the residuals (the race is in CHANGES.md, PR 28).
+  Without ``jax.checkpoint``, or under one whose policy does not know
+  the names, they are inert.
 
 The kernels compile through Mosaic; the CPU test suite runs them in
 interpreter mode by asking for it (``ops/pallas_mode.py``).
@@ -52,6 +68,7 @@ from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -492,6 +509,15 @@ def _bwd_call(q, k, v, o, lse, do, g_lse, scale, causal, g, dk, dv, blk, interpr
 # ---------------------------------------------------------------------
 
 
+# What the forward kernel produced, by name: the output and the per-row
+# logsumexp are all the backward kernel needs beside q, k and v, so a
+# rematerialised block that saves these two
+# (``models/transformer.py::remat_block``) does not run the forward
+# kernel again.
+SAVED_OUT = "flash_attention_out"
+SAVED_LSE = "flash_attention_lse"
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_lse(q, k, v, scale, causal, g, dk, dv, blk):
     """``(o, lse)``: ``o`` as ``v``, ``lse`` ``(N, C, g, T)``.
@@ -506,6 +532,7 @@ def _flash_lse(q, k, v, scale, causal, g, dk, dv, blk):
 
 def _flash_lse_fwd(q, k, v, scale, causal, g, dk, dv, blk):
     o, lse = _flash_lse(q, k, v, scale, causal, g, dk, dv, blk)
+    o, lse = checkpoint_name(o, SAVED_OUT), checkpoint_name(lse, SAVED_LSE)
     return (o, lse), (q, k, v, o, lse)
 
 

@@ -161,8 +161,11 @@ def make_lm_train_step(
     model with ``TransformerLM(remat=True)`` — per-BLOCK checkpointing,
     the placement that actually cuts peak HBM (a whole-forward
     ``jax.checkpoint`` here would recompute everything and save
-    nothing). A model returning ``(logits, aux)`` with a scalar ``aux``
-    (the MoE LM's Switch load-balancing term) trains on
+    nothing); a block's input is saved, and the attention kernel's
+    output and logsumexp where the kernel runs
+    (``models/transformer.py::remat_block``). A model returning
+    ``(logits, aux)`` with a scalar ``aux`` (the MoE LM's Switch
+    load-balancing term) trains on
     ``lm_loss + aux_loss_weight * aux``; one returning ``(logits,
     {name: counter})`` (``LatentMoELM``'s assignments per expert held)
     trains on the loss alone and the counters come out beside it in

@@ -25,7 +25,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from multidisttorch_tpu.models.transformer import Block, TransformerLM
+from multidisttorch_tpu.models.transformer import Block, TransformerLM, remat_block
 from multidisttorch_tpu.parallel.mesh import TrialMesh
 from multidisttorch_tpu.parallel.pipeline import (
     pipeline_apply_stages,
@@ -106,9 +106,10 @@ def make_pipelined_lm(
     # Stages compute at the model's own dtype (params stay f32 per the
     # pipeline's packing contract; the inter-stage carry is an f32
     # buffer, so a bf16 model pays one cast per stage boundary — the
-    # within-stage math is unchanged). model.remat carries over:
-    # per-block checkpointing composes with the staged schedule.
-    block_cls = nn.remat(Block) if model.remat else Block
+    # within-stage math is unchanged). model.remat carries over, by
+    # the models' own rule: per-block checkpointing composes with the
+    # staged schedule.
+    block_cls = remat_block(Block) if model.remat else Block
     block_mod = block_cls(
         d_model=model.d_model,
         num_heads=model.num_heads,

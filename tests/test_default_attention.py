@@ -10,6 +10,10 @@ CPU devices are a TPU (the device count stays the real one). Counts in
 jaxprs only; nothing is timed.
 """
 
+import collections
+import re
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,8 +85,9 @@ def test_rule_with_a_width_of_its_own_for_v(num_devices, seq_len, head_dim, v_he
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
 def test_one_chip_latent_attention_step_runs_the_kernel(as_tpu, remat):
     """``LatentMoELM`` given no attention: q and k 192 wide, v 128, on
-    one chip the kernel, a forward a block (twice under remat) and one
-    fused backward; on the CPU as it is, dense."""
+    one chip the kernel, one forward a block (under remat too: its
+    output and logsumexp are saved) and one fused backward; on the CPU
+    as it is, dense."""
     from multidisttorch_tpu.models.latent_moe import LatentMoELM
 
     model = LatentMoELM(
@@ -90,7 +95,7 @@ def test_one_chip_latent_attention_step_runs_the_kernel(as_tpu, remat):
         qk_rope_dim=64, v_head_dim=128, max_len=T, remat=remat,
     )
     (group,) = setup_groups(1, devices=jax.devices()[:1])
-    assert _count(_step_jaxpr(group, model), "pallas_call") == LAYERS * (3 if remat else 2)
+    assert _count(_step_jaxpr(group, model), "pallas_call") == LAYERS * 2
     (four,) = setup_groups(1, devices=jax.devices()[:4])
     assert _count(_step_jaxpr(four, model), "pallas_call") == 0
 
@@ -138,19 +143,23 @@ def test_cpu_latent_attention_stays_dense():
     assert _count(_step_jaxpr(group, model), "pallas_call") == 0
 
 
-def _count(jaxpr, primitive: str) -> int:
-    """Equations of ``primitive`` in ``jaxpr``, call sites of shared
-    inner jaxprs (``jit``, ``remat``, ``custom_vjp``, ``scan``)
-    counted one by one."""
+def _counts(jaxpr) -> collections.Counter:
+    """Equations of ``jaxpr`` by primitive, call sites of shared inner
+    jaxprs (``jit``, ``remat``, ``custom_vjp``, ``scan``) counted one
+    by one."""
     jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
-    n = 0
+    n = collections.Counter()
     for eqn in jaxpr.eqns:
-        n += eqn.primitive.name == primitive
+        n[eqn.primitive.name] += 1
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else (value,):
                 if hasattr(sub, "eqns") or hasattr(getattr(sub, "jaxpr", None), "eqns"):
-                    n += _count(sub, primitive)
+                    n += _counts(sub)
     return n
+
+
+def _count(jaxpr, primitive: str) -> int:
+    return _counts(jaxpr)[primitive]
 
 
 @pytest.fixture
@@ -196,9 +205,10 @@ def test_placement_is_what_the_state_and_batch_were_put_on():
 def test_one_chip_step_runs_the_kernel(as_tpu, model_cls, remat):
     (group,) = setup_groups(1, devices=jax.devices()[:1])
     jaxpr = _step_jaxpr(group, model_cls(remat=remat, **CFG))
-    # a forward kernel a block, once more a block where remat runs the
-    # forward again, and one fused backward kernel a block
-    assert _count(jaxpr, "pallas_call") == LAYERS * (3 if remat else 2)
+    # a forward kernel and one fused backward kernel a block: where
+    # remat runs a block's forward again, the kernel's output and
+    # logsumexp were saved and the kernel is not in it
+    assert _count(jaxpr, "pallas_call") == LAYERS * 2
     forward = jax.make_jaxpr(
         lambda p, t: model_cls(**CFG).apply({"params": p}, t)
     )(*_params_and_tokens(group, model_cls(**CFG)))
@@ -252,3 +262,109 @@ def test_default_and_injected_flash_are_the_same_program(as_tpu):
         group, TransformerLM(remat=True, attention=make_flash_attention(causal=True), **CFG)
     )
     assert str(default) == str(injected)
+
+
+# --- what rematerialization keeps (transformer.remat_block) ---
+
+
+def _loss_and_grads(model):
+    """One jitted ``value_and_grad`` of a two-block LM over the kernel
+    (interpreted), parameters and inputs from fixed seeds."""
+    tokens = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, T)), jnp.int32)
+    params = TransformerLM(**CFG).init(jax.random.key(0), tokens)["params"]
+
+    def loss(p):
+        logits = model.apply({"params": p}, tokens).astype(jnp.float32)
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits[:, :-1], tokens[:, 1:]
+        ).mean()
+
+    # XLA may keep bf16 values at f32 between the operations it fuses,
+    # and fuses a recomputed block otherwise than the forward's; the
+    # interpreted kernel is such operations too. Rounding where the
+    # program says so leaves only the program to compare.
+    step = jax.jit(jax.value_and_grad(loss)).lower(params)
+    return step.compile(compiler_options={"xla_allow_excess_precision": False})(params)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_saved_kernel_results_leave_the_gradients_bit_equal(monkeypatch, dtype):
+    """What the policy saves is what the second forward call would have
+    made again: loss and every gradient leaf equal to the last bit
+    under the models' remat rule, under ``nn.remat`` with no policy,
+    and with no remat at all."""
+    make = lambda remat: TransformerLM(
+        attention=make_flash_attention(causal=True), dtype=dtype, remat=remat, **CFG
+    )
+    plain = _loss_and_grads(make(False))
+    saved = _loss_and_grads(make(True))
+    monkeypatch.setattr(transformer, "remat_block", nn.remat)  # only a block's input is saved
+    bare = _loss_and_grads(make(True))
+    assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(saved[1]))
+    for other in (bare, plain):
+        for a, b in zip(jax.tree.leaves(saved), jax.tree.leaves(other), strict=True):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_policy_is_inert_on_the_dense_path(monkeypatch):
+    """A CPU-default ``TransformerLM(remat=True)`` has no kernel and so
+    none of the names the policy saves: the step is the program
+    ``nn.remat`` with no policy gives, primitive for primitive, and
+    apart from the ``checkpoint`` equations' ``policy`` equation for
+    equation."""
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    model = TransformerLM(remat=True, **CFG)
+    with_policy = _step_jaxpr(group, model)
+    monkeypatch.setattr(transformer, "remat_block", nn.remat)
+    bare = _step_jaxpr(group, model)
+    counts = _counts(bare)
+    assert _counts(with_policy) == counts
+    assert counts["remat2"] == LAYERS and not counts["pallas_call"]
+    policy = re.compile(r"policy=[^\n\]]*")
+    assert policy.findall(str(with_policy)) != policy.findall(str(bare))
+    assert policy.sub("", str(with_policy)) == policy.sub("", str(bare))
+
+
+def test_bare_remat_runs_the_forward_kernel_twice(as_tpu, monkeypatch):
+    """The other side of ``test_one_chip_step_runs_the_kernel``'s
+    count: without the policy the recomputed block holds the forward
+    kernel again."""
+    monkeypatch.setattr(transformer, "remat_block", nn.remat)
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    jaxpr = _step_jaxpr(group, TransformerLM(remat=True, **CFG))
+    assert _count(jaxpr, "pallas_call") == LAYERS * 3
+
+
+@pytest.mark.parametrize("saved", [False, True], ids=["bare", "policy"])
+def test_hops_under_checkpoint_keep_the_logsumexp_gradient(saved):
+    # Ring-flash's hop, as _ring_flash_local combines it (weights
+    # exp(lse_h - m)), with the hop under jax.checkpoint: alone, and
+    # with the policy that saves the kernel's named results. Either way
+    # the cotangent of lse must reach the backward kernel (a dropped one
+    # leaves dQ and dK wrong against dense), and with the policy a hop's
+    # forward kernel is not run again.
+    from multidisttorch_tpu.ops.pallas_attention import _attend
+    from multidisttorch_tpu.ops.ring_attention import dense_attention_reference
+
+    hop = jax.checkpoint(
+        lambda q, k, v: _attend(q, k, v, causal=False),
+        policy=transformer._KEEP_KERNEL_RESULTS if saved else None,  # the models' own
+    )
+    per_row = lambda x: x.transpose(0, 2, 1)[..., None]  # (B, H, T) on (B, T, H, D)
+
+    def two_hops(q, k, v):
+        half = k.shape[1] // 2
+        (o1, lse1), (o2, lse2) = hop(q, k[:, :half], v[:, :half]), hop(q, k[:, half:], v[:, half:])
+        m = jnp.maximum(lse1, lse2)
+        w1, w2 = jnp.exp(lse1 - m), jnp.exp(lse2 - m)
+        return (per_row(w1) * o1 + per_row(w2) * o2) / per_row(w1 + w2)
+
+    rng = np.random.default_rng(5)
+    k, v = (jnp.asarray(rng.normal(0, 1, (1, 64, 2, 8)), jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.normal(0, 1, (1, 32, 2, 8)), jnp.float32)  # a hop is square: 32 x (32 + 32)
+    grads = lambda attn: jax.grad(lambda *qkv: jnp.sum(attn(*qkv) ** 2), argnums=(0, 1, 2))
+    g_dense = grads(lambda q, k, v: dense_attention_reference(q, k, v, causal=False))(q, k, v)
+    for a, b in zip(grads(two_hops)(q, k, v), g_dense):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5)
+    # a forward a hop, again where the checkpoint saved nothing, and a backward a hop
+    assert _count(jax.make_jaxpr(grads(two_hops))(q, k, v), "pallas_call") == 2 * (2 if saved else 3)

@@ -41,10 +41,11 @@ RECOGNISED = {
 UNRECOGNISED_BOUND = 0.06
 
 
-def _lowered(model_cls, remat):
+def _lowered(model_cls, remat, **fields):
     (group,) = setup_groups(1, devices=jax.devices()[:1])
     model = model_cls(
-        vocab_size=64, d_model=32, num_heads=4, num_layers=LAYERS, max_len=16, remat=remat
+        vocab_size=64, d_model=32, num_heads=4, num_layers=LAYERS, max_len=16, remat=remat,
+        **fields,
     )
     tx = optax.adam(1e-3)
     tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
@@ -107,6 +108,25 @@ def test_scopes_reach_the_compiled_step(model_cls, remat):
     assert len(unrecognised) / len(step) < UNRECOGNISED_BOUND, (
         len(unrecognised), len(step), sorted(set(unrecognised))[:20]
     )
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_the_attention_kernel_is_under_attn_core_once_a_pass(remat):
+    """Where the kernel runs (here interpreted: its operations carry
+    the names of the two jitted calls), ``attn_core`` holds the forward
+    kernel in the forward pass and the backward kernel in the backward
+    pass. Under remat too: the recomputed block holds no kernel, since
+    the forward's output and logsumexp were saved."""
+    from benchmark import scope_reduce
+    from multidisttorch_tpu.ops.pallas_attention import make_flash_attention
+
+    lowered, _ = _lowered(TransformerLM, remat, attention=make_flash_attention(causal=True))
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    for call, where in (("jit(_fwd_call)", "forward"), ("jit(_bwd_call)", "backward")):
+        found = {scope_reduce.classify(n) for n in names if call in n.split("/")}
+        assert found == {("attn_core", where)}, (call, found)
+        for i in range(LAYERS):
+            assert any({f"block_{i}", call} <= set(n.split("/")) for n in names)
 
 
 def test_scopes_stay_out_of_the_parameter_tree():

@@ -101,17 +101,17 @@ def test_packed_heads_match_dense(shape, block, dtype, tol):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 def test_inside_remat_block_under_value_and_grad(dtype):
-    # What the LM step does with it: the kernel inside nn.remat(Block),
-    # differentiated, so the forward kernel runs twice and the fused
-    # backward once, against the same block over dense attention.
-    import flax.linen as nn
-
-    from multidisttorch_tpu.models.transformer import Block
+    # What the LM step does with it: the kernel inside the models'
+    # rematerialised Block, differentiated, so the forward kernel runs
+    # once (its output and logsumexp are saved for the recomputed
+    # block) and the fused backward once, against the same block over
+    # dense attention.
+    from multidisttorch_tpu.models.transformer import Block, remat_block
 
     x = jnp.asarray(np.random.default_rng(0).normal(0, 1, (2, 256, 128)), dtype)
     mk = lambda cls, attn: cls(d_model=128, num_heads=2, attention=attn, dtype=dtype)
     dense = mk(Block, lambda q, k, v: dense_attention_reference(q, k, v, causal=True))
-    flash = mk(nn.remat(Block), make_flash_attention(causal=True))
+    flash = mk(remat_block(Block), make_flash_attention(causal=True))
     params = dense.init(jax.random.key(0), x)
     loss = lambda m: lambda p, x: jnp.sum(m.apply(p, x).astype(jnp.float32) ** 2)
     (val, (gp, gx)), (ref, (rp, rx)) = (
