@@ -449,8 +449,8 @@ def _kernel_ring_flash(devices) -> None:
         _close(g, r, rtol=2e-4, atol=8e-4, what="ring-flash backward")
 
 
-# The LM bench configuration (bench.py LM_*): full width, depth as
-# published there.
+# A small LM for the smoke: the width and depth the round-4 LM runs
+# used; the benchmark's cells hold the published sizes.
 LM = dict(vocab_size=32768, d_model=512, num_heads=8, num_layers=8, max_len=512)
 LM_BATCH, LM_STEPS = 16, 4
 

@@ -127,8 +127,8 @@ def _plan_mpmd_pipeline(args) -> None:
     )
     print(
         "  dry run: plan only — submit a pipeline_stages=2 VAE-family "
-        "config to the sweep service, or run bench.py --pipeline, for "
-        "an executing trial"
+        "config to the sweep service (tests/test_pipeline_mpmd.py runs "
+        "one) for an executing trial"
     )
 
 
@@ -181,7 +181,7 @@ def main():
         "all-or-nothing slice-vector placement over this world, the "
         "GPipe schedule model, and the ZeRO optimizer-memory table — "
         "then exit (the executing MPMD runner covers the VAE family; "
-        "see bench.py --pipeline)",
+        "see tests/test_pipeline_mpmd.py)",
     )
     parser.add_argument(
         "--dry-run", action="store_true",

@@ -20,8 +20,9 @@ point); the layers here work inside a process and around that cache:
   canary-execute protocol in front of jax's on-disk executable cache,
   enabling it only in processes that declared themselves sacrificial.
 - :mod:`~multidisttorch_tpu.compile.coldstart` — the **cold-start
-  books' benchmark**: ``bench.py --coldstart`` measures cold vs
-  precompiled vs cache-warm admission latency with a bit-parity gate.
+  books' drill** (``python -m multidisttorch_tpu.compile.coldstart``,
+  CPU only): cold vs precompiled vs cache-warm admission with a
+  bit-parity gate.
 
 See docs/COMPILE.md for the safety model and protocols.
 """

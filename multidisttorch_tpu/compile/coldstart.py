@@ -1,11 +1,11 @@
 """Cold-start benchmark: cold vs precompiled vs cache-warm admission.
 
 A CPU-world drill: every mode runs in a fresh child process, and a chip
-belongs to one process at a time, so ``bench.py --coldstart`` refuses
-any platform but ``cpu``. Admission cost on the chip is ROADMAP A6.
+belongs to one process at a time, so the drill (``python -m
+multidisttorch_tpu.compile.coldstart``) is for the ``cpu`` platform
+only. Admission cost on the chip is ROADMAP A2.
 
-The compile subsystem's banked evidence (``bench.py --coldstart``). One
-fixed multi-bucket sweep — ``len(COLDSTART_HIDDENS)`` shape buckets
+One fixed multi-bucket sweep — ``len(COLDSTART_HIDDENS)`` shape buckets
 (distinct hidden dims), one trial each, one submesh, so every admission
 is serialized and visible — is run to completion in FRESH child
 processes, one per mode (a child per mode is what makes "cold" honest:
@@ -349,7 +349,7 @@ def main(argv=None) -> int:
     import argparse
 
     parser = argparse.ArgumentParser(
-        description="coldstart bench child/driver (see bench.py --coldstart)"
+        description="coldstart drill: driver, or with --child one mode's child"
     )
     parser.add_argument("--child", default=None)
     parser.add_argument("--out", default=None)

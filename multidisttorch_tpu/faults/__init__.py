@@ -4,8 +4,8 @@
 :class:`FaultSpec`); ``inject`` interprets it at run time
 (:class:`FaultInjector`) through hooks the HPO driver threads through
 itself, the step dispatch, and the data iterators; ``harness`` runs the
-standard chaos protocol behind ``bench.py --chaos`` and
-``tools/chaos_run.py``. See docs/RESILIENCE.md for the failure taxonomy
+standard chaos protocol behind ``tools/chaos_run.py`` (asserted in
+``tests/test_telemetry.py`` and ``tests/test_elastic.py``). See docs/RESILIENCE.md for the failure taxonomy
 and how to write a plan.
 """
 

@@ -1,5 +1,4 @@
-"""The standard chaos protocol behind ``bench.py --chaos`` and
-``tools/chaos_run.py``.
+"""The standard chaos protocol behind ``tools/chaos_run.py``.
 
 Runs the SAME small sweep twice — once clean, once under
 :meth:`FaultPlan.standard` with full supervision (retry + ledger +
@@ -353,8 +352,8 @@ def run_chaos_mh_bench(
     world_timeout_s: float = 420.0,
     boot_grace_s: float = 120.0,
 ) -> dict:
-    """The elastic multi-host chaos drill behind ``bench.py --chaos-mh``
-    and ``tools/chaos_run.py --multihost`` (docs/RESILIENCE.md
+    """The elastic multi-host chaos drill behind
+    ``tools/chaos_run.py --multihost`` (docs/RESILIENCE.md
     "Elastic multi-host").
 
     Kill-one-of-N on CPU: an :class:`~tools.sweep_supervisor.

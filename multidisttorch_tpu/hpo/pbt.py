@@ -30,7 +30,7 @@ stream replays the ``(seed + k, epoch)`` permutations, and every
 explore draw comes from :func:`~multidisttorch_tpu.train.steps
 .pbt_perturb_factor`. That contract is what makes the two modes
 bit-identical — member states, scores, exploit decisions, and lrs —
-which the parity tests and the ``bench.py --pbt`` A/B artifact gate.
+which ``tests/test_pbt_fused.py`` asserts.
 """
 
 from __future__ import annotations
@@ -90,7 +90,7 @@ class PBTResult:
     final_lrs: list = field(default_factory=list)
     wall_s: float = 0.0
     mode: str = "submesh"
-    # Dispatch accounting for the fused-vs-submesh A/B (bench --pbt):
+    # Dispatch accounting, fused against per-submesh:
     # program_calls = compiled-program invocations, host_transfers =
     # exchange state moves through host memory.
     dispatch_book: dict = field(default_factory=dict)

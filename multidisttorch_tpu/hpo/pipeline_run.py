@@ -412,8 +412,7 @@ class _PipelineTrialRun:
 
     def write_books(self) -> Optional[str]:
         """Land the trial's pipeline books (schedule measurement,
-        optimizer memory, placement vector) as JSON in the trial dir —
-        the ``bench.py --pipeline`` artifact's source."""
+        optimizer memory, placement vector) as JSON in the trial dir."""
         books = {
             "trial_id": self.cfg.trial_id,
             "schedule": self.pipe.schedule_books(),

@@ -102,8 +102,7 @@ def fsdp_compose_shardings(
 # with per-device optimizer memory cut to ~1/n_data of replicated.
 # Selected per-TrialConfig (`zero_update=True`, hpo/driver.py); losses
 # match the replicated reference within a pinned tolerance (the grad
-# reduction reassociates across devices — regression-tested, and gated
-# by `bench.py --pipeline`).
+# reduction reassociates across devices — tests/test_pipeline_mpmd.py).
 
 
 def zero_update_shardings(
@@ -173,8 +172,8 @@ def optimizer_state_bytes(state: Any) -> dict:
     ``per_device_bytes`` (what one chip actually holds, from each opt
     leaf's concrete sharding) and ``total_bytes`` (the replicated-
     equivalent footprint — what the same state costs per device with
-    no sharding). The ratio is the ZeRO win the memory books and the
-    ``bench.py --pipeline`` gate surface; works on CPU where
+    no sharding). The ratio is the ZeRO win the memory books surface
+    (asserted in ``tests/test_pipeline_mpmd.py``); works on CPU where
     ``memory_stats()`` does not exist."""
     import math
 

@@ -482,7 +482,8 @@ def sequential_stages_reference(stage_fns, stage_params, batch):
 # anchor. Bubble fraction: each stage is busy 2M of the 2(M+S-1) ticks,
 # so the schedule's idle fraction is (S-1)/(M+S-1) — the books record
 # busy/idle per dispatch (a MEASURED schedule property, not the
-# formula), and `bench.py --pipeline` gates the two against each other.
+# formula), and tests/test_pipeline_mpmd.py holds the two against each
+# other.
 
 
 def make_vae_stage_fns(model, beta: float):
@@ -1110,8 +1111,8 @@ def make_mpmd_reference_step(
     stage chain and the SAME per-microbatch keys, composed into one
     jitted step on one submesh with scan-based gradient accumulation
     (``train.steps.accumulate_gradients`` — ascending-microbatch
-    summation, the pipeline's order). ``bench.py --pipeline`` gates the
-    pipelined trial's losses against this step's.
+    summation, the pipeline's order). ``tests/test_pipeline_mpmd.py``
+    holds the pipelined trial's losses against this step's.
 
     Returns ``step(state, batch, rng) -> (state, {"loss_sum"})`` with
     the driver's metric contract (summed loss over the batch).

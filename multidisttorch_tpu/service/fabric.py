@@ -32,7 +32,8 @@ control-plane argument, PAPERS.md arXiv 2509.07003):
   settled; ever-placed re-enter ``resume_scan`` and restore from
   their checkpoints through the existing migration machinery). A
   replica death is a scheduler event with a bounded detection +
-  replay cost, drilled by ``bench.py --fabric``.
+  replay cost, drilled by ``tools/chaos_run.py --fabric`` and
+  ``tests/test_fabric.py``.
 
 - **Elastic topology** (PR 17): routing is no longer frozen at
   ``fabric.json`` creation. ``fabric/topology.jsonl`` (service/

@@ -1,6 +1,7 @@
-"""The service-fabric acceptance drill (``bench.py --fabric``).
+"""The service-fabric acceptance drills (docs/SERVICE.md "Service
+fabric"; ``tools/chaos_run.py --fabric`` runs the chaos form).
 
-Three phases, one artifact (docs/SERVICE.md "Service fabric"):
+Three phases, one report:
 
 1. **Failover** — two REAL replica subprocesses
    (``tools/sweep_service.py --fabric``) over a 2-shard fabric, each
@@ -901,10 +902,9 @@ def _run_movable_arm(
     client = squeue.SweepClient(service_dir, tenant="mv")
     for sub in submissions:
         client.submit(dict(sub))
-    # The driver narrates retry resumes on stdout; this arm runs
-    # in-process inside `bench.py`, whose stdout contract is exactly
-    # one JSON line — route the narration to stderr with the rest of
-    # the drill diagnostics.
+    # The driver narrates retry resumes on stdout; a caller may keep
+    # stdout for one JSON line — route the narration to stderr with
+    # the rest of the drill diagnostics.
     with contextlib.redirect_stdout(sys.stderr):
         svc = SweepService(
             service_dir,

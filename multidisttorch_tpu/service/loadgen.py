@@ -8,13 +8,14 @@ is pure host logic with an injectable clock — zero jax, zero I/O
 module replays a seeded synthetic workload through the exact production
 classes (:class:`FairShareScheduler`, :class:`SlicePool`,
 :class:`PreemptionPolicy`, :func:`plan_defrag`, :func:`plan_preemption`)
-on a virtual clock, four orders of magnitude past the 18-submission
-service bench, and banks:
+on a virtual clock, four orders of magnitude past what the real-
+training service tests submit, and reports:
 
 - **p50/p95/p99 placement latency** (virtual seconds, submission →
   first placement),
 - **fairness error**: contended-share ratio-to-weight per tenant, the
-  same ±10% gate as ``bench.py --service``, now under ~10^6 decisions,
+  same ±10% gate as ``tests/test_service.py``'s fair-share test, now
+  under ~10^6 decisions,
 - **deadline hit rate** under EDF + bounded preemption,
 - **preemption/defrag churn** — evictions and moves per 1k placements
   (the anti-thrash budget's macro-level evidence).
@@ -1409,11 +1410,11 @@ def run_fabric_scenario(
 # (the two-arm dynamic-vs-static drill, promoted into the same
 # registry). ``run_scenario`` returns one self-contained artifact
 # envelope: the full report, a per-scenario SLO verdict (thresholds ON
-# the banked histogram bounds, so evaluation is exact), the
-# control-plane flight books, and a one-line headline —
-# ``bench.py --zoo`` banks one artifact per scenario and folds the
-# headline + per-phase books into ``artifacts/ctlprof_ledger.jsonl``
-# for cross-round drift tracking.
+# the histogram's bucket bounds, so evaluation is exact), the
+# control-plane flight books, and a one-line headline
+# (``tests/test_zoo.py`` replays every scenario;
+# ``ctlprof.fold_ledger_round`` folds a headline into a ledger file
+# the caller names, for drift tracking across rounds).
 # ---------------------------------------------------------------------
 
 SCENARIOS: dict[str, dict] = {
@@ -1485,8 +1486,8 @@ SCENARIOS: dict[str, dict] = {
     "split_storm": {"kind": "fabric"},
 }
 
-# Pool scenarios default to a CI-sized replay; the 1M-grade runs go
-# through ``bench.py --zoo --zoo-n``.
+# Pool scenarios default to a CI-sized replay; ``run_scenario(name,
+# n_submissions=...)`` takes a larger one.
 ZOO_POOL_DEFAULT_N = 100_000
 
 

@@ -3,8 +3,8 @@
 The ledger (``hpo/ledger.py``) is a crash LOG — this module extends the
 same JSONL machinery into an intake QUEUE with a two-stage durability
 protocol, so that *every accepted submission survives a daemon restart*
-(including ``kill -9`` mid-append — the acceptance drill in
-``bench.py --service``):
+(including ``kill -9`` mid-append —
+``tests/test_service.py::TestQueue::test_queue_survives_kill9_mid_append``):
 
 1. **Client spool** (:class:`SweepClient`): each ``submit()`` lands one
    submission as its own file under ``{service_dir}/intake/``, written

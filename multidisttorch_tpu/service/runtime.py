@@ -435,8 +435,8 @@ class SweepService:
         # snapshot-fast (default: a preemption completes at the
         # device→host snapshot, persistence lands on the victim's
         # background writer, the freed slices place the starved trial
-        # immediately) vs the legacy join-drain (MDT_SNAPSHOT_DRAIN=0,
-        # the bench's v1 comparison arm).
+        # immediately) vs the legacy join-drain (MDT_SNAPSHOT_DRAIN=0;
+        # tests/test_ckpt_v2.py holds both).
         from multidisttorch_tpu.train.checkpoint import default_format
 
         self.ckpt_format = (
@@ -1868,9 +1868,8 @@ class SweepService:
         unchanged: a SIGKILL mid-persist leaves an OPEN attempt whose
         scan-back restores the previous durable step.
 
-        ``snapshot_drain=False`` (the bench's v1 comparison arm) keeps
-        the legacy behavior: join the write inline, ledger, requeue —
-        the full-persist drain the artifact measures against.
+        ``snapshot_drain=False`` keeps the legacy behavior: join the
+        write inline, ledger, requeue.
 
         Returns the requeued entries: ONE for a classic or pipelined
         placement (a pipelined vector drains all-or-nothing through

@@ -16,7 +16,7 @@ invisible outside ad-hoc prints. This package makes them first-class:
   device-inclusive sampling; compile accounting.
 - :mod:`~multidisttorch_tpu.telemetry.export` — Chrome/Perfetto trace
   JSON (one track per trial), a Prometheus-style text dump, and a
-  run-summary JSON that ``bench.py`` embeds in its artifacts.
+  run-summary JSON.
 - ``tools/sweep_top.py`` — live console over the event JSONL.
 
 **Zero-cost-when-off contract**: telemetry is DISABLED by default.
@@ -24,7 +24,7 @@ Every hot-path seam is written as ``bus = get_bus(); if bus is not
 None: bus.emit(...)`` — with telemetry off, ``get_bus()`` returns
 ``None`` and *no event object is ever constructed* (regression-tested
 in tests/test_telemetry.py). When on, the budget is <= 2% step-time
-overhead, enforced by ``bench.py --stacked``'s telemetry A/B block.
+overhead: a budget, not yet a measurement on the chip (ROADMAP A13).
 
 Enable programmatically::
 

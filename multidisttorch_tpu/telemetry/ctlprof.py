@@ -30,8 +30,8 @@ get_ctlprof(); if prof is not None: ...``. With the profiler off, no
 object is constructed and — because every clock read goes through the
 module-level :data:`_clock` indirection — *no clock is ever read*
 (regression-tested in tests/test_ctlprof.py by patching ``_clock`` with
-a raiser). When on, the budget is the same <= 2% A/B bench.py enforces
-for the rest of telemetry.
+a raiser). When on, the budget is the same <= 2% as for the rest of
+telemetry (ROADMAP A13).
 
 A sampling fallback (``MDT_CTLPROF_SAMPLE_HZ``) covers un-instrumented
 daemon time: a daemon thread samples the armed thread's stack at the
@@ -39,7 +39,8 @@ requested rate and exports a collapsed-stack flame file
 (flamegraph.pl / speedscope "collapsed" format).
 
 Cross-round regression ledger: :func:`fold_ledger_round` appends one
-record per banked profile to ``artifacts/ctlprof_ledger.jsonl`` and
+record per profile to the ledger file the caller names
+(``ctlprof_ledger.jsonl``, its directory created on demand) and
 stamps it with ``vs_prev_rounds`` drift flags (>20% throughput move vs
 the prior median; per-phase wall-fraction shift > 0.10 absolute), so
 every future scheduler change replays the zoo and sees its
@@ -314,7 +315,7 @@ class CtlProfiler:
         """Chrome-trace events for the retained pass ring: one "ctl
         pass" track plus one track per phase, ts relative to the oldest
         retained pass. Merged into the fleet trace by
-        telemetry/fleet.py and exported standalone by bench --zoo."""
+        telemetry/fleet.py."""
         if not self.ring:
             return []
         base = self.ring[0][0]

@@ -1,7 +1,7 @@
 """Device-level performance books: XLA cost accounting, MFU, memory.
 
 PR 3 made the *host-side* sweep dynamics first-class; the device stayed
-a black box — MFU existed only as bench.py's hand-derived analytic
+a black box — MFU existed only as a hand-derived analytic
 number, and nothing recorded what a compiled step actually costs or
 what device memory a trial actually peaks at. This module keeps those
 books, per trial / per stacked bucket, inside the PR 3 registry:
@@ -17,8 +17,7 @@ books, per trial / per stacked bucket, inside the PR 3 registry:
 - **MFU + roofline** (:func:`device_books`): combine the cost gauges
   with the series' own step timings (``StepSeries`` — device-sampled
   books included) into live model-FLOPs-utilization against the chip
-  generation's peak (:func:`peak_flops_per_chip`, the one copy bench.py
-  also uses), plus a compute- vs bandwidth-bound roofline verdict from
+  generation's peak (:func:`peak_flops_per_chip`), plus a compute- vs bandwidth-bound roofline verdict from
   arithmetic intensity vs the ridge point.
 - **Memory books** (:func:`sample_memory`): ``device.memory_stats()``
   watermarks where the backend keeps them (TPU), live-buffer accounting
@@ -46,8 +45,8 @@ from multidisttorch_tpu.telemetry.metrics import MetricsRegistry, get_registry
 # documentation), keyed by the exact ``device_kind`` string jax reports
 # — the spellings are those of the installed jax's own table
 # (jax/_src/pallas/mosaic/tpu_info.py); "TPU v5 lite" is what the v5e
-# reports (chip_smoke.py prints it). The ONE copy — bench.py's MFU
-# arithmetic delegates here.
+# reports (chip_smoke.py prints it). The benchmark keeps a copy of its
+# own (benchmark/peaks.py), which no program PR may edit.
 PEAK_FLOPS_PER_CHIP = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,

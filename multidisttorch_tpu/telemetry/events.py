@@ -133,8 +133,8 @@ class Bus:
         self._sink: Optional[IO[str]] = None
         if path is not None:
             # Truncate, don't append: one bus = one run's stream. A new
-            # configure() against the same directory (a re-run banking
-            # into artifacts/, a fresh chaos drill) must never mix the
+            # configure() against the same directory (a re-run, a
+            # fresh chaos drill) must never mix the
             # previous run's events into this run's exports. Appends
             # WITHIN a run — including the chaos harness's driver
             # restarts, which share one telemetry scope — go through

@@ -50,11 +50,10 @@ a valid ledger plus a quarantined ``.partial`` directory that
 :func:`sweep_partial_bundles` reports (never half a bundle that looks
 whole).
 
-Proved by ``bench.py --incidents``: the full chaos fault plan replays
-(host / daemon / wedge / split / ckpt kinds) and every fault must
-produce EXACTLY ONE incident with the correct verdict (fault->verdict
-confusion matrix gated at 100% diagonal), while a no-fault soak must
-produce zero. See docs/INCIDENTS.md for the operator cookbook.
+Held by ``tests/test_incidents.py``: every trigger of the taxonomy
+opens EXACTLY ONE incident with the correct verdict, faults injected
+into a real sweep among them, while a no-fault soak opens none. See
+docs/INCIDENTS.md for the operator cookbook.
 """
 
 from __future__ import annotations

@@ -866,7 +866,7 @@ def build_submission_traces(
 def trace_completeness(
     traces: dict[str, dict], *, now: Optional[float] = None
 ) -> dict:
-    """The trace-completeness gate (``bench.py --fabric``): every
+    """The trace-completeness gate (``tests/test_trace.py``): every
     SETTLED/REJECTED submission must reconstruct with a closed root,
     every journal-skeleton span closed, zero orphan spans, and
     monotone span bounds. An open ATTEMPT span under a settled

@@ -413,8 +413,8 @@ def make_multi_step(
 #
 # At the flagship's size a whole train step is microseconds of MXU time,
 # so a sweep of small trials is dispatch-bound no matter how its
-# submeshes are carved (docs/DISPATCH.md; VERDICT pins flagship MFU at
-# 0.13-0.25 with dispatch as prime suspect). Scan-fusion amortizes
+# submeshes are carved (docs/DISPATCH.md; not measured in steady state
+# on the chip, ROADMAP A10). Scan-fusion amortizes
 # dispatch *in time* (more steps per call); stacking amortizes it *in
 # trials*: bucket K configs that share every array shape (architecture,
 # batch size) and differ only in scalar hypers (lr, beta, seed), stack

@@ -5,7 +5,6 @@ every trial carries ``mfu`` (float, or explicit null WITH a reason)
 and ``peak_memory_bytes``."""
 
 import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -66,26 +65,43 @@ def test_compiled_cost_analysis_graceful_on_non_lowerable():
 
 
 def test_peak_tables():
-    assert tele_device.peak_flops_per_chip("TPU v4") == 275e12
-    assert tele_device.peak_flops_per_chip("TPU v5e") == 197e12
-    assert tele_device.peak_flops_per_chip("cpu") is None
+    # FLOP/s: the parametrised cases below; bytes/s: same lookup rule.
     assert tele_device.peak_membw_per_chip("TPU v4") == pytest.approx(
         1.23e12
     )
-    # bench.py delegates to the same table — the two MFU computations
-    # cannot drift.
-    import importlib.util
+    assert tele_device.peak_membw_per_chip("cpu") is None
 
-    spec = importlib.util.spec_from_file_location(
-        "bench",
-        os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "bench.py",
-        ),
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    assert bench._peak_flops_per_chip("TPU v4") == 275e12
+
+@pytest.mark.parametrize(
+    "kind,expected",
+    [
+        ("TPU v4", 275e12),
+        ("TPU v5 lite", 197e12),
+        ("TPU v5e", 197e12),
+        ("TPU v5p", 459e12),
+        ("TPU v6e", 918e12),
+        ("cpu", None),
+    ],
+)
+def test_peak_flops_per_chip(kind, expected):
+    assert tele_device.peak_flops_per_chip(kind) == expected
+
+
+def test_peak_flops_unknown_tpu_kind_raises():
+    # A chip with no peak on record gets no neighbour's peak: "v5" in
+    # the kind must not make it a v5p, and no environment hint fills in.
+    with pytest.raises(ValueError, match="not in the peak table"):
+        tele_device.peak_flops_per_chip("TPU v5 weird")
+
+
+def test_lm_flops_formula():
+    # The copy `mfu` is computed from (benchmark/flops.py): tier-1
+    # guards the yardstick against a hand count.
+    from benchmark.flops import lm_train_flops_per_token
+
+    f = lm_train_flops_per_token(d=64, layers=2, t=128, vocab=256)
+    fwd = 2 * (24.0 * 64 * 64 + 2.0 * 128 * 64) + 2.0 * 64 * 256
+    assert f == 3.0 * fwd
 
 
 def test_roofline_classification():

@@ -15,6 +15,15 @@ import time
 import pytest
 
 from multidisttorch_tpu import telemetry
+from multidisttorch_tpu.faults import (
+    CKPT_CORRUPT,
+    CRASH,
+    DIVERGE,
+    PREEMPT,
+    FaultPlan,
+    FaultSpec,
+    HostPreemption,
+)
 from multidisttorch_tpu.telemetry import incident as tincident
 from multidisttorch_tpu.telemetry.events import get_bus
 from multidisttorch_tpu.telemetry.incident import (
@@ -520,6 +529,59 @@ def test_offline_replay_matches_live_fold(tmp_path):
         for i in detect_incidents(events).values()
     }
     assert live == offline
+
+
+# -- the production seams, through a real sweep ------------------------
+
+
+@pytest.mark.parametrize(
+    "specs,trials,epochs,verdict",
+    [
+        ((), 2, 1, None),  # the soak: no fault, no incident
+        (tuple(FaultSpec(DIVERGE, t, step=2) for t in range(3)), 3, 1,
+         DIVERGENCE_STORM),
+        # 8 steps an epoch: the only checkpoint rots, the crash's retry
+        # scans it and rejects it.
+        ((FaultSpec(CKPT_CORRUPT, 0, epoch=1), FaultSpec(CRASH, 0, step=11)),
+         1, 2, CKPT_INTEGRITY),
+        ((FaultSpec(PREEMPT, 0, step=2),), 1, 1, HOST_PREEMPTED),
+    ],
+    ids=["soak", "diverge_storm", "ckpt_corrupt", "preempt"],
+)
+def test_sweep_fault_opens_exactly_one_incident(
+    specs, trials, epochs, verdict, tmp_path
+):
+    """A fault injected into a real ``run_hpo`` sweep reaches the
+    detector through the production emits: exactly one incident of the
+    expected kind, its black-box bundle published; none without a
+    cause."""
+    from multidisttorch_tpu.data.datasets import synthetic_mnist
+    from multidisttorch_tpu.hpo.driver import TrialConfig, run_hpo
+    from multidisttorch_tpu.hpo.supervision import RetryPolicy
+
+    d = str(tmp_path)
+    cfgs = [
+        TrialConfig(trial_id=t, epochs=epochs, batch_size=16, hidden_dim=32,
+                    latent_dim=8, log_interval=10_000, seed=t)
+        for t in range(trials)
+    ]
+    with telemetry.telemetry_run(d):
+        try:
+            run_hpo(
+                cfgs, synthetic_mnist(128, seed=0), None, num_groups=1,
+                out_dir=os.path.join(d, "sweep"), verbose=False,
+                save_images=False, resilient=True,
+                retry=RetryPolicy(max_retries=2, backoff_base_s=0.01),
+                fault_plan=FaultPlan(specs=specs),
+            )
+        except HostPreemption:  # escapes even a resilient sweep
+            assert verdict == HOST_PREEMPTED
+    incs = list(load_incidents(d).values())
+    assert [i["kind"] for i in incs] == ([verdict] if verdict else [])
+    for inc in incs:
+        bundle = os.path.join(d, tincident.BUNDLE_DIRNAME, inc["id"])
+        for name in ("trigger.json", "flight_ring.json"):
+            assert os.path.isfile(os.path.join(bundle, name))
 
 
 # -- causal autopsy ---------------------------------------------------

@@ -140,7 +140,7 @@ def test_bf16_inputs_match_plain(arrays):
 
 def test_bf16_multi_block_accumulator(monkeypatch):
     # The round-4 hardware failure ("Invalid dtype for `swap`: Ref
-    # float32 vs value bfloat16", artifacts/bench_tpu_r4_flagship.json)
+    # float32 vs value bfloat16", a TPU run of 2026-07-30)
     # lived in the fwd kernel's SMEM accumulator when bf16 operands
     # crossed a multi-block grid — the one path the earlier bf16 test
     # (single block) and multi-block test (f32) each missed. Interpret

@@ -23,7 +23,7 @@ from multidisttorch_tpu.telemetry import ctlprof
 pytestmark = pytest.mark.ctlprof
 
 # Small-N: the zoo's contracts (determinism, SLO wiring, books) hold at
-# any N; CI's dedicated job replays larger N via bench --zoo.
+# any N.
 N = 1500
 
 

@@ -3,16 +3,16 @@
 driver's supervision stack and report recovery + goodput.
 
     JAX_PLATFORMS=cpu python tools/chaos_run.py \
-        --out artifacts/bench_chaos_cpu.json
+        --out results/chaos_cpu.json
 
 Runs entirely on CPU (8 virtual devices) with a CI-sized sweep: every
 infra fault in ``FaultPlan.standard`` must be recovered automatically
 (retry-with-resume, lane refill, ledger restart after the simulated
 preemption), the injected divergence must settle as a terminal
 ``diverged`` result, and goodput (useful/executed optimizer steps) is
-the recovery-overhead headline. ``bench.py --chaos`` wraps the same
-protocol (``multidisttorch_tpu/faults/harness.py``) with the bench's
-artifact conventions; this CLI is the standalone, plan-tweakable form.
+the recovery-overhead headline. The protocol is
+``multidisttorch_tpu/faults/harness.py``; ``tests/test_telemetry.py``
+and ``tests/test_elastic.py`` assert it at a smaller size.
 
 A custom plan can be drilled with ``--plan my_plan.json`` (the
 ``FaultPlan.to_json`` format) — see docs/RESILIENCE.md for how to write

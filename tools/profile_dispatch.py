@@ -7,7 +7,7 @@ trial's jit steps are enqueued from ONE Python host loop
 submeshes the host can become the serializing resource. The hardware
 half of the question needs >= 2 real chips; THIS half — where the
 per-trial host time goes as concurrency rises — is measurable on the
-8-virtual-CPU-device mesh today (VERDICT r4 item 5).
+8-virtual-CPU-device mesh today.
 
 Protocol, per concurrency level N (1, 2, 4, 8):
 
@@ -52,7 +52,7 @@ import time
 import numpy as np
 
 # Allow `python tools/profile_dispatch.py` from the repo root without
-# installation (mirrors bench.py's import situation).
+# installation.
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
