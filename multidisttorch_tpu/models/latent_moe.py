@@ -21,9 +21,21 @@ Per layer, with ``y = RMSNorm(x)``::
     x2 = x1 + FFN(RMSNorm(x1))
 
 q and k are ``nope + rope`` wide and v ``v_head_dim``; the attention is
-injected as in ``TransformerLM``, and left out it is the blockwise
-kernel where ``ops.pallas_attention.default_takes_kernel`` takes these
-widths and the dense path elsewhere (``transformer._default_causal``).
+injected as in ``TransformerLM``. **Which path runs where.** Given no
+attention, on one TPU chip, at a length and at widths
+``ops.pallas_attention.latent_takes_kernel`` takes (128 + 64 beside
+128: ``joyai-llm-flash``), the block never assembles q or k: the
+columns of ``W_qb`` and ``W_kvb`` are applied part by part, each part
+leaves its matmul as a flat ``(B, T, H * width)`` array, the one rotary
+key stays ``(B, T, rope)``, and ``ops.pallas_attention.latent_attention``
+takes the five operands (it rotates q's rotary part itself). Everywhere
+else (an injected ``attention=``, the CPU, several chips, a T or widths
+the rule refuses, the toy widths of tests and examples) q and k are
+assembled as above and go to the ``(q, k, v)`` callable: the injected
+one, or ``transformer._default_causal``'s (the blockwise kernel at the
+padded width 256 where ``default_takes_kernel`` says so, else dense).
+Decided while tracing, from the operands alone; the parameters are the
+same tree, names and initial values on both paths.
 
 **One chip's share.** ``experts_held = (first, count)`` names the
 experts of every expert layer whose weights live here; the router keeps
@@ -51,6 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from multidisttorch_tpu.models import transformer
+from multidisttorch_tpu.ops.pallas_attention import latent_attention, latent_takes_kernel
 from multidisttorch_tpu.ops.moe import (
     RoutedExperts,
     grouped_dot_takes_kernel,
@@ -66,20 +79,50 @@ from multidisttorch_tpu.utils.profiling import (
 )
 
 
+def _rope_angles(positions, theta: float, width: int):
+    """``positions * theta**(-2i/width)`` for the pairs ``i`` of a
+    ``width``-wide rotary part: ``(T, width/2)`` float32."""
+    inv_freq = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
+    return positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+
+
 def rope_interleaved(x, positions, theta: float):
     """Rotate the pairs ``(2i, 2i+1)`` of ``x``'s last axis by
     ``positions * theta**(-2i/width)``; ``x`` is ``(..., T, H, width)``,
     the arithmetic float32. Written with lane rolls rather than a
     ``(width/2, 2)`` reshape, which the TPU would have to relayout."""
     width = x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
-    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # (T, width/2)
+    angle = _rope_angles(positions, theta, width)
     cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)[:, None, :]
     sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)[:, None, :]
     x32 = x.astype(jnp.float32)
     even = jnp.arange(width) % 2 == 0
     partner = jnp.where(even, -jnp.roll(x32, -1, axis=-1), jnp.roll(x32, 1, axis=-1))
     return (x32 * cos + partner * sin).astype(x.dtype)
+
+
+class _DenseByParts(nn.Module):
+    """``nn.Dense`` without a bias (its parameter, name and initial
+    values) for a kernel whose columns are ``heads`` groups of
+    ``sum(widths)``: part ``i`` of every head, ``widths[i]`` columns of
+    each group, applied as a product of its own, so that it leaves its
+    matmul as a flat ``(..., heads * widths[i])`` array and no array of
+    whole groups is made to cut it from."""
+
+    heads: int
+    widths: tuple[int, ...]
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x, part: int):
+        group, at, width = sum(self.widths), sum(self.widths[:part]), self.widths[part]
+        kernel = self.param(
+            "kernel", nn.linear.default_kernel_init, (x.shape[-1], self.heads * group),
+            jnp.float32,
+        )
+        x, kernel = nn.dtypes.promote_dtype(x, kernel, dtype=self.dtype)
+        columns = kernel.reshape(-1, self.heads, group)[..., at:at + width]
+        return x @ columns.reshape(-1, self.heads * width)
 
 
 def _default_grouped_dot(x):
@@ -103,7 +146,13 @@ def _default_grouped_dot(x):
 class LatentMoEBlock(nn.Module):
     """One pre-norm block: latent attention, then a dense SwiGLU MLP
     (``num_experts`` 0) or an expert layer. Returns ``(x, counts)``,
-    ``counts`` ``(count,)`` int32 and empty for a dense block."""
+    ``counts`` ``(count,)`` int32 and empty for a dense block.
+
+    The attention is one of two paths, chosen while tracing (the
+    module's docstring says where each runs): :meth:`_kernel_on_parts`,
+    the kernel on q's and k's parts as ``q_b`` and ``kv_b`` make them,
+    or :meth:`_assembled`, q and k at ``nope + rope`` a head for a
+    ``(q, k, v)`` callable. Both read the same parameters."""
 
     num_heads: int
     q_lora_rank: int
@@ -113,7 +162,8 @@ class LatentMoEBlock(nn.Module):
     v_head_dim: int
     rope_theta: float
     hidden_dim: int  # the dense MLP's width, or one expert's
-    attention: Callable  # (q, k, v) -> out; q, k (B, T, H, nope + rope), v, out (B, T, H, v)
+    # (q, k, v) -> out; q, k (B, T, H, nope + rope), v, out (B, T, H, v). None: the default
+    attention: Optional[Callable] = None
     num_experts: int = 0
     experts_held: tuple[int, int] = (0, 0)
     top_k: int = 0
@@ -124,9 +174,7 @@ class LatentMoEBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        dense = lambda feats, name: nn.Dense(
-            feats, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32, name=name
-        )
+        dense = self._dense
         norm = lambda name: nn.RMSNorm(
             epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name=name
         )
@@ -135,28 +183,21 @@ class LatentMoEBlock(nn.Module):
         positions = jnp.arange(t)
 
         y = norm("ln_attn")(x)
+        placed = transformer._placement(x)
+        by_parts = bool(
+            self.attention is None and placed
+            and latent_takes_kernel(*placed, t, h, nope, rope, dv)
+        )
         with jax.named_scope(SCOPE_Q):
-            q = dense(h * (nope + rope), "q_b")(norm("q_norm")(dense(self.q_lora_rank, "q_a")(y)))
-            q = q.reshape(b, t, h, nope + rope)
-            q = jnp.concatenate(
-                [q[..., :nope], rope_interleaved(q[..., nope:], positions, self.rope_theta)],
-                axis=-1,
-            )
+            c_q = norm("q_norm")(dense(self.q_lora_rank, "q_a")(y))
         with jax.named_scope(SCOPE_K):
             latent = dense(self.kv_lora_rank + rope, "kv_a")(y)
             c_kv = norm("kv_norm")(latent[..., : self.kv_lora_rank])
             k_rope = rope_interleaved(
                 latent[..., None, self.kv_lora_rank:], positions, self.rope_theta
             )  # (B, T, 1, rope): one rope key for all heads
-        with jax.named_scope(SCOPE_V):
-            kv = dense(h * (nope + dv), "kv_b")(c_kv).reshape(b, t, h, nope + dv)
-            v = kv[..., nope:]
-        with jax.named_scope(SCOPE_K):
-            k = jnp.concatenate(
-                [kv[..., :nope], jnp.broadcast_to(k_rope, (b, t, h, rope))], axis=-1
-            )
-        with jax.named_scope(SCOPE_ATTN_CORE):
-            attn = self.attention(q, k, v)
+        attend = self._kernel_on_parts if by_parts else self._assembled
+        attn = attend(c_q, c_kv, k_rope, positions)
         x = x + dense(d, "proj")(attn.reshape(b, t, h * dv))
 
         y = norm("ln_mlp")(x)
@@ -178,6 +219,61 @@ class LatentMoEBlock(nn.Module):
             name="moe",
         )(y.reshape(b * t, d))
         return x + y.reshape(b, t, d), counts
+
+    @nn.nowrap
+    def _dense(self, feats, name):
+        return nn.Dense(
+            feats, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32, name=name
+        )
+
+    @nn.nowrap
+    def _assembled(self, c_q, c_kv, k_rope, positions):
+        """q and k assembled at ``nope + rope`` a head, the rotary key
+        copied to every head, for an attention of the ``(q, k, v)``
+        kind."""
+        b, t, _ = c_q.shape
+        h, nope, rope, dv = self.num_heads, self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim
+        with jax.named_scope(SCOPE_Q):
+            q = self._dense(h * (nope + rope), "q_b")(c_q).reshape(b, t, h, nope + rope)
+            q = jnp.concatenate(
+                [q[..., :nope], rope_interleaved(q[..., nope:], positions, self.rope_theta)],
+                axis=-1,
+            )
+        with jax.named_scope(SCOPE_V):
+            kv = self._dense(h * (nope + dv), "kv_b")(c_kv).reshape(b, t, h, nope + dv)
+            v = kv[..., nope:]
+        with jax.named_scope(SCOPE_K):
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_rope, (b, t, h, rope))], axis=-1
+            )
+        with jax.named_scope(SCOPE_ATTN_CORE):
+            return transformer._default_causal(self.attention)(q, k, v)
+
+    @nn.nowrap
+    def _kernel_on_parts(self, c_q, c_kv, k_rope, positions):
+        """The same attention with nothing assembled: ``q_b``'s and
+        ``kv_b``'s columns applied part by part, the one key as it is,
+        and ``ops.pallas_attention.latent_attention`` on the five
+        operands and the angles of q's rotation, which it makes on the
+        flat ``(B, T, H * rope)`` array a block at a time."""
+        b, t, _ = c_q.shape
+        h, nope, rope, dv = self.num_heads, self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim
+        q_b = _DenseByParts(h, (nope, rope), self.dtype, name="q_b")
+        kv_b = _DenseByParts(h, (nope, dv), self.dtype, name="kv_b")
+        heads = lambda x: x.reshape(b, t, h, -1)  # free: the kernels read the flat array
+        with jax.named_scope(SCOPE_Q):
+            q_nope, q_rope = q_b(c_q, 0), q_b(c_q, 1)
+            angle = _rope_angles(positions, self.rope_theta, rope)
+            rotation = jnp.cos(angle), jnp.sin(angle)  # of q_rope: the kernels make it
+        with jax.named_scope(SCOPE_K):
+            k_nope = kv_b(c_kv, 0)
+        with jax.named_scope(SCOPE_V):
+            v = kv_b(c_kv, 1)
+        with jax.named_scope(SCOPE_ATTN_CORE):
+            return latent_attention(
+                heads(q_nope), heads(q_rope), heads(k_nope), k_rope[:, :, 0], heads(v),
+                q_rotation=rotation, causal=True,
+            )
 
 
 class LatentMoELM(nn.Module):
@@ -230,7 +326,7 @@ class LatentMoELM(nn.Module):
             num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
             kv_lora_rank=self.kv_lora_rank, qk_nope_dim=self.qk_nope_dim,
             qk_rope_dim=self.qk_rope_dim, v_head_dim=self.v_head_dim,
-            rope_theta=self.rope_theta, attention=transformer._default_causal(self.attention),
+            rope_theta=self.rope_theta, attention=self.attention,
             eps=self.eps, dtype=self.dtype,
         )
         routed = dict(

@@ -24,12 +24,21 @@ What runs where:
   widths (the tests' 8, 16, 20) run the same kernels over a flattened
   ``(B*H, T, D)`` copy.
 - **Two widths.** q and k share one width and v, the output and its
-  cotangent another (latent attention: 128 + 64 beside 128). Every
-  array is read at its own width, one head a lane block; a q/k width
-  that is not whole lanes (192) is padded with zero lanes to the next
-  128 (256), which changes no score, and the scores are divided by the
-  root of the width q came with. The chip race of PR 27 (PERF.md
-  section 6) is against padding v and the output to q's width as well.
+  cotangent another (latent attention: 128 + 64 beside 128). Through
+  :func:`flash_attention` every array is read at its own width, one
+  head a lane block; a q/k width that is not whole lanes (192) is
+  padded with zero lanes to the next 128 (256), which changes no
+  score, and the scores are divided by the root of the width q came
+  with. Since PR 30 no benchmark cell runs that path: it is what an
+  injected ``(q, k, v)`` attention and the tests get. A one-chip
+  ``LatentMoELM`` given no attention calls :func:`latent_attention`,
+  a pair of kernels of its own (``latent_fwd``, ``latent_bwd``) over
+  the same helpers, with q and k as the two parts their projections
+  make: the 128-wide parts a head a lane block, q's rotary part two
+  heads a lane block and rotated as a block is loaded, the one rotary
+  key read once for all heads, a score the sum of two products. There
+  nothing 192 or 256 wide exists in HBM, in any pass
+  (``models/latent_moe.py::LatentMoEBlock`` says when).
 - **Blocks.** One grid step is one query block against the whole K/V
   sequence, which stays in VMEM (256 KB each at T=1,024); a loop inside
   the kernel walks the K/V blocks below the diagonal unmasked and the
@@ -127,7 +136,9 @@ def default_takes_kernel(
       run on the chip: 128, or 64 in pairs (25 heads of 64 would take
       the flattened layout, which was not raced), or latent
       attention's 192 for q and k beside 128 for v, which
-      :func:`flash_attention` pads to 256 and 128 lanes a head.
+      :func:`flash_attention` pads to 256 and 128 lanes a head and
+      :func:`latent_attention` takes as the parts 128 + 64
+      (:func:`latent_takes_kernel`).
     """
     widths = (head_dim, head_dim if v_head_dim is None else v_head_dim)
     return (
@@ -265,6 +276,47 @@ def _one_branch(body):
     return kernel
 
 
+def _start_softmax(i, v_ref, v_t, acc_t, m_sc, l_sc, blk, nk):
+    """What a forward grid step begins with: V transposed into ``v_t``
+    on a sequence's first query block, and the running maximum, sum and
+    output of this block's online softmax at their starts."""
+
+    @pl.when(i == 0)
+    def _v_transposed():
+        for j in range(nk):
+            v_t[j] = _transposed(v_ref[0, j * blk:(j + 1) * blk, :])
+
+    m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_t[...] = jnp.zeros_like(acc_t)
+
+
+def _softmax_step(s, v_h, h, cols, acc_t, m_sc, l_sc, dot):
+    """One tile of head ``h``'s online softmax: the scores ``s``, keys x
+    queries ``cols`` and masked already, and the keys' values ``v_h``
+    ``(dv, keys)`` into the running maximum, sum and output."""
+    m_prev = m_sc[h, :, cols]  # (1, n)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.exp(s - m_new)  # masked entries underflow to 0 exactly
+    corr = jnp.exp(m_prev - m_new)
+    l_sc[h, :, cols] = l_sc[h, :, cols] * corr + jnp.sum(p, axis=0, keepdims=True)
+    acc_t[h, :, cols] = acc_t[h, :, cols] * corr + dot(v_h, p.astype(v_h.dtype))
+    m_sc[h, :, cols] = m_new
+
+
+def _finish_softmax(g, acc_t, m_sc, l_sc, o_ref, lse_ref):
+    """The block's output, heads side by side, and its logsumexp."""
+    out_t = []
+    for h in range(g):
+        l = l_sc[h]
+        l = jnp.where(l > 0, l, 1.0)
+        out_t.append(acc_t[h] / l)
+        # logsumexp per row: the one residual the backward needs to
+        # rebuild p without the (Tq, Tk) matrix.
+        lse_ref[0, 0, h] = (m_sc[h] + jnp.log(l))[0]
+    o_ref[0] = jnp.concatenate(out_t, axis=0).T.astype(o_ref.dtype)
+
+
 @_one_branch
 def _fwd_kernel(i, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_t, m_sc, l_sc, v_t,
                 *, scale, fold, causal, g, dk, dv, blk, sub, nk, interpret):
@@ -281,18 +333,11 @@ def _fwd_kernel(i, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_t, m_sc, l_sc, v_t,
 
     dot = partial(_dot, interpret=interpret)
 
-    @pl.when(i == 0)
-    def _v_transposed():
-        for j in range(nk):
-            v_t[j] = _transposed(v_ref[0, j * blk:(j + 1) * blk, :])
-
+    _start_softmax(i, v_ref, v_t, acc_t, m_sc, l_sc, blk, nk)
     q = q_ref[0]  # (blk, W)
     if fold:  # a power of two: exact in any float dtype
         q = q * scale
     q_t = [_transposed(_only(q, m)) for m in _head_lanes(g, dk, blk)]
-    m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
-    l_sc[...] = jnp.zeros_like(l_sc)
-    acc_t[...] = jnp.zeros_like(acc_t)
 
     def tile(j, at, n, nkeys, own):
         k = k_ref[0, pl.ds(pl.multiple_of(j * blk, blk), nkeys), :]
@@ -304,30 +349,11 @@ def _fwd_kernel(i, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_t, m_sc, l_sc, v_t,
                 s = s * scale
             if own:
                 s = _triangle(s, n, 0)
-            m_prev = m_sc[h, :, cols]  # (1, n)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-            p = jnp.exp(s - m_new)  # masked entries underflow to 0 exactly
-            corr = jnp.exp(m_prev - m_new)
-            l_sc[h, :, cols] = (
-                l_sc[h, :, cols] * corr + jnp.sum(p, axis=0, keepdims=True)
-            )
             v_h = v_t[j, h * dv:(h + 1) * dv, :nkeys]  # (dv, keys)
-            acc_t[h, :, cols] = (
-                acc_t[h, :, cols] * corr + dot(v_h, p.astype(v_h.dtype))
-            )
-            m_sc[h, :, cols] = m_new
+            _softmax_step(s, v_h, h, cols, acc_t, m_sc, l_sc, dot)
 
     _walk(tile, i, nk, blk, sub, causal)
-
-    out_t = []
-    for h in range(g):
-        l = l_sc[h]
-        l = jnp.where(l > 0, l, 1.0)
-        out_t.append(acc_t[h] / l)
-        # logsumexp per row: the one residual the backward needs to
-        # rebuild p without the (Tq, Tk) matrix.
-        lse_ref[0, 0, h] = (m_sc[h] + jnp.log(l))[0]
-    o_ref[0] = jnp.concatenate(out_t, axis=0).T.astype(o_ref.dtype)
+    _finish_softmax(g, acc_t, m_sc, l_sc, o_ref, lse_ref)
 
 
 @_one_branch
@@ -423,7 +449,7 @@ def _specs(t, g, blk):
     return block, whole, stat
 
 
-def _params(t, w, blk, itemsize):
+def _params(t, w, blk, itemsize, lane_blocks="parallel"):
     """Double-buffered operands and results, the f32 accumulators and a
     handful of (blk, blk) f32 tiles, with room to spare: Mosaic's own
     default (16 MiB) is too small from T=4,096 on."""
@@ -431,7 +457,7 @@ def _params(t, w, blk, itemsize):
     tiles = 8 * blk * blk * 4 + 16 * blk * w * 4
     return pltpu.CompilerParams(
         # what a sequence's first query block sets up, the later use
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", lane_blocks, "arbitrary"),
         vmem_limit_bytes=int(
             min(max(32 << 20, 2 * (resident + tiles)), 100 << 20)
         ),
@@ -651,6 +677,343 @@ def make_flash_attention(*, causal: bool = True):
     # must keep q/k/v/proj replicated for this callable
     attn.carries_collectives = False  # safe inside a pipeline stage
     return attn
+
+
+# ---------------------------------------------------------------------
+# latent attention: q and k as the two parts their projections make
+# ---------------------------------------------------------------------
+
+_ROPE = 64  # the rotary part's width: two heads a lane block
+
+
+def latent_takes_kernel(
+    device_kind: str, num_devices: int, seq_len: int, num_heads: int,
+    nope_dim: int, rope_dim: int, v_head_dim: int,
+) -> bool:
+    """Whether a latent-attention block that was given no attention
+    hands :func:`latent_attention` the parts of q and k as its
+    projections make them (``models/latent_moe.py::LatentMoEBlock``
+    asks while tracing): where :func:`default_takes_kernel` takes the
+    assembled widths, and the parts are the ones the kernels tile."""
+    return default_takes_kernel(
+        device_kind, num_devices, seq_len, num_heads, nope_dim + rope_dim, v_head_dim
+    ) and _latent_widths_tile(num_heads, nope_dim, rope_dim, v_head_dim)
+
+
+def _latent_widths_tile(h: int, dn: int, dr: int, dv: int) -> bool:
+    """One head a lane block of the 128-wide parts, the heads of the
+    rotary part in pairs."""
+    return dr == _ROPE and h % (_LANES // _ROPE) == 0 and dn % _LANES == 0 and dv % _LANES == 0
+
+
+# The kernels. A grid step is the ``g = 2`` heads of one lane block of
+# q's rotary part: ``(N, T, H*dn)`` arrays are read ``g*dn`` lanes a
+# step, a head a whole lane block of them, q's rotary part ``(N, T,
+# H*64)`` 128 lanes a step as ``_fwd_kernel`` reads two 64-wide heads,
+# and the one rotary key, side by side ``g`` times ``(N, T, 128)``, is
+# the same block for every pair of heads: copy ``h`` of it is head
+# ``h``'s key. A score is the sum of two products, each accumulated in
+# f32; everything after it is the kernels' above. The heads of a step
+# are a loop, not a copy of the code a head (a head's lanes are cut
+# from the refs at a traced multiple of 128): the compiled kernels are
+# the size of one head's, and a step program holds a pair a layer.
+
+
+def _head_at(h, width):
+    """Head ``h``'s ``width`` lanes or sublanes of a ref, ``h`` traced."""
+    return pl.ds(pl.multiple_of(h * width, width), width)
+
+
+def _rope_of(qr, h):
+    """``qr``, q's rotary block, with every head's lanes but ``h``'s
+    zeroed."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, qr.shape, 1)
+    return _only(qr[...], lane // _ROPE == h)
+
+
+def _swap_pairs(x):
+    """Lanes ``2i`` and ``2i + 1`` of a ``(rows, 128)`` block exchanged:
+    two rotations of the lanes on the XLU. The exchange is its own
+    transpose."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane % 2 == 0, pltpu.roll(x, _LANES - 1, 1), pltpu.roll(x, 1, 1))
+
+
+def _rotated(x, cos_ref, sin_ref):
+    """The pairs ``(2i, 2i + 1)`` of ``x`` rotated by the block's
+    angles, float32: ``cos_ref`` holds a pair's cosine at both lanes,
+    ``sin_ref`` its sine negated at the even one."""
+    x = x.astype(jnp.float32)
+    return x * cos_ref[...] + _swap_pairs(x) * sin_ref[...]
+
+
+def _rotated_back(g, cos_ref, sin_ref):
+    """The transpose of :func:`_rotated`: the gradient of the rotated
+    block taken to the block as it came."""
+    return g * cos_ref[...] + _swap_pairs(g * sin_ref[...])
+
+
+def _latent_fwd_kernel(qn_ref, qr_ref, cos_ref, sin_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
+                       acc_t, m_sc, l_sc, v_t, qr,
+                       *, scale, causal, g, dn, dv, blk, sub, nk, interpret):
+    """Grid (N, H/g, nq), nq sequential; keys x queries as
+    ``_fwd_kernel``."""
+    i = pl.program_id(2)
+    dot = partial(_dot, interpret=interpret)
+    _start_softmax(i, v_ref, v_t, acc_t, m_sc, l_sc, blk, nk)
+    qr[...] = _rotated(qr_ref[0], cos_ref, sin_ref).astype(qr.dtype)
+
+    def head(h, carry):
+        nope = _head_at(h, dn)
+        qn_t = _transposed(qn_ref[0, :, nope])  # (dn, blk)
+        qr_t = _transposed(_rope_of(qr, h))  # (128, blk), the other head's rows zero
+
+        def tile(j, at, n, nkeys, own):
+            keys = pl.ds(pl.multiple_of(j * blk, blk), nkeys)
+            cols = slice(at, at + n)
+            s = dot(kn_ref[0, keys, nope], qn_t[:, cols]) + dot(kr_ref[0, keys, :], qr_t[:, cols])
+            s = s * scale
+            if own:
+                s = _triangle(s, n, 0)
+            v_h = v_t[j, _head_at(h, dv), :nkeys]  # (dv, keys)
+            _softmax_step(s, v_h, h, cols, acc_t, m_sc, l_sc, dot)
+
+        _walk(tile, i, nk, blk, sub, causal)
+        return carry
+
+    jax.lax.fori_loop(0, g, head, 0)
+    _finish_softmax(g, acc_t, m_sc, l_sc, o_ref, lse_ref)
+
+
+def _latent_bwd_kernel(qn_ref, qr_ref, cos_ref, sin_ref, kn_ref, kr_ref, v_ref, o_ref, do_ref,
+                       lse_ref, dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref,
+                       dkn_t, dkr_t, dv_t, dqn_acc, dqr_acc, qr, qr_t,
+                       *, scale, causal, g, dn, dv, blk, sub, nk, interpret):
+    """Grid (N, H/g, nq), both inner axes sequential; queries x keys as
+    ``_bwd_kernel``. The rotary key's gradient accumulates over the
+    query blocks and over the pairs of heads of a sequence, head ``h``
+    of every pair into rows ``h*64..`` (the gradient of its copy of the
+    key), and is written on the sequence's last grid step."""
+    c, i = pl.program_id(1), pl.program_id(2)
+    dot = partial(_dot, interpret=interpret)
+
+    @pl.when(i == 0)
+    def _init():
+        dkn_t[...] = jnp.zeros_like(dkn_t)
+        dv_t[...] = jnp.zeros_like(dv_t)
+
+    @pl.when((i == 0) & (c == 0))
+    def _init_shared():
+        dkr_t[...] = jnp.zeros_like(dkr_t)
+
+    qr[...] = _rotated(qr_ref[0], cos_ref, sin_ref).astype(qr.dtype)
+    qr_t[...] = _transposed(qr[...])  # (128, blk): head h is rows h*64..
+    dqn_acc[...] = jnp.zeros_like(dqn_acc)
+    dqr_acc[...] = jnp.zeros_like(dqr_acc)
+
+    def head(h, carry):
+        nope, rope, vals = _head_at(h, dn), _head_at(h, _ROPE), _head_at(h, dv)
+        qn, do = qn_ref[0, :, nope], do_ref[0, :, vals]  # (blk, dn), (blk, dv)
+        qr_h = _rope_of(qr, h)
+        qn_t = _transposed(qn)
+        do_t = do.astype(jnp.float32).T
+        # delta = rowsum(dO * O), from the transposed blocks as ``_bwd_kernel``'s
+        delta = jnp.sum(do_t * o_ref[0, :, vals].astype(jnp.float32).T, axis=0)[:, None]
+        do_t = do_t.astype(do.dtype)
+        lse = lse_ref[0, 0, h][:, None]  # (blk, 1)
+
+        def tile(j, at, n, nkeys, own):
+            keys = pl.ds(pl.multiple_of(j * blk, blk), nkeys)
+            kn, kr, v = kn_ref[0, keys, nope], kr_ref[0, keys, :], v_ref[0, keys, vals]
+            rows = slice(at, at + n)
+            s = (dot(qn[rows], kn, _NT) + dot(qr_h[rows], kr, _NT)) * scale  # (queries, keys) f32
+            if own:
+                s = _triangle(s, n, 1)
+            p = jnp.exp(s - lse[rows])  # exact probabilities via saved lse
+            dp = dot(do[rows], v, _NT)
+            ds = (p * (dp - delta[rows])).astype(kn.dtype)
+            dv_t[j, vals, :nkeys] = dv_t[j, vals, :nkeys] + dot(do_t[:, rows], p.astype(v.dtype))
+            dkn_t[j, nope, :nkeys] = dkn_t[j, nope, :nkeys] + dot(qn_t[:, rows], ds)
+            dkr_t[j, rope, :nkeys] = dkr_t[j, rope, :nkeys] + dot(qr_t[rope, rows], ds)
+            dqn_acc[rows, nope] = dqn_acc[rows, nope] + dot(ds, kn)
+            dqr_acc[h, rows, :] = dqr_acc[h, rows, :] + dot(ds, kr)
+
+        _walk(tile, i, nk, blk, sub, causal)
+        return carry
+
+    jax.lax.fori_loop(0, g, head, 0)
+
+    dqn_ref[0] = (dqn_acc[...] * scale).astype(dqn_ref.dtype)
+    dqr = _merge([dqr_acc[h] for h in range(g)], _head_lanes(g, _ROPE, blk)) * scale
+    dqr_ref[0] = _rotated_back(dqr, cos_ref, sin_ref).astype(dqr_ref.dtype)
+
+    @pl.when(i == nk - 1)
+    def _emit():
+        for j in range(nk):
+            rows = slice(j * blk, (j + 1) * blk)
+            dkn_ref[0, rows, :] = (dkn_t[j].T * scale).astype(dkn_ref.dtype)
+            dv_ref[0, rows, :] = dv_t[j].T.astype(dv_ref.dtype)
+
+    @pl.when((i == nk - 1) & (c == pl.num_programs(1) - 1))
+    def _emit_shared():
+        for j in range(nk):
+            rows = slice(j * blk, (j + 1) * blk)
+            dkr_ref[0, rows, :] = (dkr_t[j].T * scale).astype(dkr_ref.dtype)
+
+
+def _latent_specs(t, g, blk):
+    """``_specs``; the rotary key's: the whole sequence, the same block
+    for every pair of heads; and a query block of the rotation's
+    ``(T, 128)`` tables."""
+    shared = pl.BlockSpec((1, t, _LANES), lambda n, c, i: (n, 0, 0),
+                          memory_space=pltpu.VMEM)
+    angles = pl.BlockSpec((blk, _LANES), lambda n, c, i: (i, 0), memory_space=pltpu.VMEM)
+    return *_specs(t, g, blk), shared, angles
+
+
+def _latent_statics(qn, qr, v, scale, causal, blk, sub, interpret):
+    """The kernels' static arguments, and what a grid step reads of the
+    128-wide parts and of v: ``g`` heads' lanes."""
+    g, h = _LANES // _ROPE, qr.shape[-1] // _ROPE
+    dn, dv = qn.shape[-1] // h, v.shape[-1] // h
+    statics = dict(
+        scale=scale, causal=causal, g=g, dn=dn, dv=dv, blk=blk, sub=sub,
+        nk=qn.shape[1] // blk, interpret=interpret,
+    )
+    return statics, g, g * dn, g * dv
+
+
+@partial(jax.jit, static_argnums=(7, 8, 9, 10))
+def _latent_fwd_call(qn, qr, cos, sin, kn, kr, v, scale, causal, blk, interpret):
+    n, t, _ = qn.shape
+    st, g, wn, wv = _latent_statics(qn, qr, v, scale, causal, blk, _fwd_step(blk), interpret)
+    c = qn.shape[-1] // wn
+    block, whole, stat, shared, angles = _latent_specs(t, g, blk)
+    return pl.pallas_call(
+        partial(_latent_fwd_kernel, **st),
+        grid=(n, c, t // blk),
+        in_specs=[block(wn), block(_LANES), angles, angles, whole(wn), shared, whole(wv)],
+        out_specs=(block(wv), stat),
+        out_shape=(
+            _out_struct(v.shape, qn.dtype, qn),
+            _out_struct((n, c, g, t), jnp.float32, qn),
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((g, wv // g, blk), jnp.float32),  # output, transposed
+            pltpu.VMEM((g, 1, blk), jnp.float32),  # running max
+            pltpu.VMEM((g, 1, blk), jnp.float32),  # running sum
+            pltpu.VMEM((t // blk, wv, blk), v.dtype),  # V, transposed
+            pltpu.VMEM((blk, _LANES), qr.dtype),  # q's rotary part, rotated
+        ],
+        compiler_params=_params(t, wn, blk, qn.dtype.itemsize),
+        interpret=interpret,
+        name="latent_fwd",
+    )(qn, qr, cos, sin, kn, kr, v)
+
+
+@partial(jax.jit, static_argnums=(10, 11, 12, 13))
+def _latent_bwd_call(qn, qr, cos, sin, kn, kr, v, o, lse, do, scale, causal, blk, interpret):
+    n, t, _ = qn.shape
+    st, g, wn, wv = _latent_statics(qn, qr, v, scale, causal, blk, _bwd_step(blk), interpret)
+    nk = t // blk
+    block, whole, stat, shared, angles = _latent_specs(t, g, blk)
+    return pl.pallas_call(
+        partial(_latent_bwd_kernel, **st),
+        grid=(n, qn.shape[-1] // wn, nk),
+        in_specs=[block(wn), block(_LANES), angles, angles, whole(wn), shared, whole(wv),
+                  block(wv), block(wv), stat],
+        out_specs=(block(wn), block(_LANES), whole(wn), shared, whole(wv)),
+        out_shape=tuple(_out_struct(x.shape, x.dtype, x) for x in (qn, qr, kn, kr, v)),
+        scratch_shapes=[
+            pltpu.VMEM((nk, wn, blk), jnp.float32),  # dK's 128-wide part, transposed
+            pltpu.VMEM((nk, _LANES, blk), jnp.float32),  # the rotary key's, transposed
+            pltpu.VMEM((nk, wv, blk), jnp.float32),  # dV, transposed
+            pltpu.VMEM((blk, wn), jnp.float32),  # dQ's 128-wide part
+            pltpu.VMEM((g, blk, _LANES), jnp.float32),  # its rotary part, all lanes a head
+            pltpu.VMEM((blk, _LANES), qr.dtype),  # q's rotary part, rotated
+            pltpu.VMEM((_LANES, blk), qr.dtype),  # and transposed
+        ],
+        # the rotary key's gradient sums over the pairs of heads
+        compiler_params=_params(t, wn, blk, qn.dtype.itemsize, lane_blocks="arbitrary"),
+        interpret=interpret,
+        name="latent_bwd",
+    )(qn, qr, cos, sin, kn, kr, v, o, do, lse)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _latent(qn, qr, cos, sin, kn, kr, v, scale, causal, blk):
+    """The output, as ``v``, for the kernels' flat operands."""
+    return _latent_fwd_call(qn, qr, cos, sin, kn, kr, v, scale, causal, blk, pallas_interpret())[0]
+
+
+def _latent_fwd(qn, qr, cos, sin, kn, kr, v, scale, causal, blk):
+    o, lse = _latent_fwd_call(qn, qr, cos, sin, kn, kr, v, scale, causal, blk, pallas_interpret())
+    o, lse = checkpoint_name(o, SAVED_OUT), checkpoint_name(lse, SAVED_LSE)
+    return o, (qn, qr, cos, sin, kn, kr, v, o, lse)
+
+
+def _latent_bwd(scale, causal, blk, res, g_o):
+    dqn, dqr, dkn, dkr, dv = _latent_bwd_call(*res, g_o, scale, causal, blk, pallas_interpret())
+    cos, sin = res[2:4]  # the angles are the positions': nobody reads their gradient
+    return dqn, dqr, jnp.zeros_like(cos), jnp.zeros_like(sin), dkn, dkr, dv
+
+
+_latent.defvjp(_latent_fwd, _latent_bwd)
+
+
+def latent_attention(q_nope, q_rope, k_nope, k_rope, v, *, q_rotation=None,
+                     causal: bool = False, block: int | None = None):
+    """:func:`flash_attention` for latent attention's operands as the
+    projections make them: ``q_nope, k_nope (B, T, H, 128)``, ``q_rope
+    (B, T, H, 64)``, ``k_rope (B, T, 64)`` the one rotary key of all
+    heads, rotated already, ``v (B, T, H, 128)``. The scores are
+    ``(q_nope . k_nope + q_rope . k_rope) / sqrt(128 + 64)``, what the
+    assembled ``[q_nope | q_rope]`` and ``[k_nope | k_rope for every
+    head]`` give; no such array is made, here or in the backward pass,
+    which returns the gradient of each operand (the key's summed over
+    the heads).
+
+    ``q_rotation = (cos, sin)``, ``(T, 32)`` float32 each: ``q_rope``
+    comes unrotated, and the kernels rotate its pairs ``(2i, 2i + 1)``
+    by the angles ``i`` of every position as they load a block
+    (float32, then the operands' dtype: the arithmetic of
+    ``models/latent_moe.py::rope_interleaved``) and take the gradient
+    back through the rotation as they store it. In XLA the rotation of
+    the 32 heads' flat array cost more than the key's matmuls (PERF.md,
+    PR 30); left out, ``q_rope`` is used as it comes.
+
+    Every array is read and written in the projections' flat layout
+    (the reshapes are free): the 128-wide parts one head a lane block,
+    q's rotary part two heads a lane block, the key once for all heads
+    (side by side twice, 128 lanes, so that each head of a pair meets a
+    copy at its own lanes). Widths: a rotary part of 64, an even number
+    of heads, the other parts multiples of 128; a block edge as
+    :func:`flash_attention`'s, for a T that 128 divides or one of at
+    most ``_MAX_WHOLE_BLOCK``."""
+    b, t, h, dn = q_nope.shape
+    dr, dv = q_rope.shape[-1], v.shape[-1]
+    if not _latent_widths_tile(h, dn, dr, dv):
+        raise ValueError(
+            f"latent_attention: {h} heads of {dn} + {dr} beside {dv} do not tile: the "
+            f"rotary part is {_ROPE} wide, the heads even, the other parts multiples of {_LANES}"
+        )
+    blk = _block_for(t) if block is None else block
+    if t % blk or (block is None and blk > _MAX_WHOLE_BLOCK):
+        raise ValueError(f"latent_attention: no block edge for seq_len {t} (asked: {block})")
+    pair = _LANES // _ROPE  # heads a lane block of the rotary parts
+    if q_rotation is None:
+        cos, sin = jnp.ones((t, _LANES), jnp.float32), jnp.zeros((t, _LANES), jnp.float32)
+    else:  # a pair's angle at both its lanes, every head of a lane block alike
+        lanes = lambda x: jnp.tile(jnp.repeat(x.astype(jnp.float32), 2, axis=-1), (1, pair))
+        cos, sin = lanes(q_rotation[0]), lanes(q_rotation[1])
+        sin = jnp.where(jnp.arange(_LANES) % 2 == 0, -sin, sin)
+    flat = lambda x: x.reshape(b, t, h * x.shape[-1])
+    o = _latent(
+        flat(q_nope), flat(q_rope), cos, sin, flat(k_nope),
+        jnp.concatenate([k_rope] * pair, axis=-1), flat(v),
+        1.0 / ((dn + dr) ** 0.5), causal, blk,
+    )
+    return o.reshape(b, t, h, dv)
 
 
 # ---------------------------------------------------------------------

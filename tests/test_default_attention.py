@@ -28,6 +28,7 @@ from multidisttorch_tpu.models.transformer import (
 )
 from multidisttorch_tpu.ops.pallas_attention import (
     default_takes_kernel,
+    latent_takes_kernel,
     make_flash_attention,
 )
 from multidisttorch_tpu.parallel.mesh import MODEL_AXIS, setup_groups
@@ -82,22 +83,180 @@ def test_rule_with_a_width_of_its_own_for_v(num_devices, seq_len, head_dim, v_he
     assert default_takes_kernel(V5E, num_devices, seq_len, 32, head_dim, v_head_dim) is kernel
 
 
-@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_one_chip_latent_attention_step_runs_the_kernel(as_tpu, remat):
-    """``LatentMoELM`` given no attention: q and k 192 wide, v 128, on
-    one chip the kernel, one forward a block (under remat too: its
-    output and logsumexp are saved) and one fused backward; on the CPU
-    as it is, dense."""
+@pytest.mark.parametrize(
+    "num_devices, seq_len, num_heads, nope, rope, dv, kernel",
+    [
+        (1, 4096, 32, 128, 64, 128, True),  # moe-mla-t4096
+        (1, 256, 2, 128, 64, 128, True),
+        (4, 4096, 32, 128, 64, 128, False),  # where the assembled widths get no kernel
+        (1, 200, 32, 128, 64, 128, False),
+        (1, 4096, 31, 128, 64, 128, False),  # the rotary parts' heads do not pair up
+        (1, 4096, 32, 160, 32, 128, False),  # 192 together, but not parts the kernels tile
+        (1, 4096, 32, 16, 8, 16, False),  # the toy widths of tests and examples
+    ],
+)
+def test_rule_for_the_parts_of_latent_attention(
+    num_devices, seq_len, num_heads, nope, rope, dv, kernel
+):
+    assert latent_takes_kernel(V5E, num_devices, seq_len, num_heads, nope, rope, dv) is kernel
+    assert not latent_takes_kernel("cpu", num_devices, seq_len, num_heads, nope, rope, dv)
+
+
+def _latent_lm(**fields):
     from multidisttorch_tpu.models.latent_moe import LatentMoELM
 
-    model = LatentMoELM(
+    return LatentMoELM(
         vocab_size=64, d_model=128, num_heads=2, num_layers=LAYERS, qk_nope_dim=128,
-        qk_rope_dim=64, v_head_dim=128, max_len=T, remat=remat,
+        qk_rope_dim=64, v_head_dim=128, max_len=T, **fields,
     )
+
+
+def _assembled_heads(jaxpr, batch=4):
+    """Shapes of what ``pad`` and ``concatenate`` make as ``(B, T,
+    heads, width)`` with a head of 192 (q or k assembled) or 256
+    (padded for the kernel) lanes."""
+    return [
+        (eqn.primitive.name, var.aval.shape)
+        for eqn in _equations(jaxpr) if eqn.primitive.name in ("pad", "concatenate")
+        for var in eqn.outvars
+        if len(var.aval.shape) == 4 and var.aval.shape[:2] == (batch, T)
+        and var.aval.shape[-1] in (192, 256)
+    ]
+
+
+def _kernels(jaxpr):
+    return collections.Counter(
+        eqn.params["name"] for eqn in _equations(jaxpr) if eqn.primitive.name == "pallas_call"
+    )
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_one_chip_latent_attention_step_runs_the_kernel(as_tpu, remat):
+    """``LatentMoELM`` given no attention: q and k 128 + 64 wide, v
+    128. On one chip the kernel on the parts of q and k as the
+    projections make them, one forward a block (under remat too: its
+    output and logsumexp are saved) and one fused backward, and neither
+    q nor k assembled or padded anywhere; over four chips, as on the
+    CPU, dense on the assembled q and k."""
+    model = _latent_lm(remat=remat)
     (group,) = setup_groups(1, devices=jax.devices()[:1])
-    assert _count(_step_jaxpr(group, model), "pallas_call") == LAYERS * 2
+    jaxpr = _step_jaxpr(group, model)
+    assert _count(jaxpr, "pallas_call") == LAYERS * 2
+    assert _kernels(jaxpr) == {"latent_fwd": LAYERS, "latent_bwd": LAYERS}
+    assert not _assembled_heads(jaxpr)
     (four,) = setup_groups(1, devices=jax.devices()[:4])
     assert _count(_step_jaxpr(four, model), "pallas_call") == 0
+
+
+def test_injected_attention_gets_latent_attention_assembled(as_tpu):
+    """An ``attention=`` of the ``(q, k, v)`` kind is handed q and k at
+    192 a head, on one chip too: the kernel at the padded width."""
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    jaxpr = _step_jaxpr(group, _latent_lm(attention=make_flash_attention(causal=True)))
+    assert _kernels(jaxpr) == {"flash_fwd": LAYERS, "flash_bwd": LAYERS}
+    made = _assembled_heads(jaxpr)
+    assert ("concatenate", (4, T, 2, 192)) in made and ("pad", (4, T, 2, 256)) in made
+
+
+# The latent-attention LM's step (remat) where the parts' rule says no,
+# equation by primitive: counted at the parent of the PR that brought
+# the kernel on the parts (PR 30).
+_ASSEMBLED_STEP = {
+    "add": 225, "add_any": 50, "and": 15, "broadcast_in_dim": 244, "concatenate": 27,
+    "convert_element_type": 51, "cos": 8, "cumsum": 2, "div": 155, "dot_general": 86,
+    "dynamic_slice": 2, "eq": 18, "exp": 5, "gather": 15, "ge": 4, "integer_pow": 67, "iota": 29,
+    "jit": 125, "le": 4, "log": 1, "logistic": 8, "lt": 35, "max": 11, "min": 6, "mul": 353,
+    "ne": 23, "neg": 26, "pad": 29, "pow": 10, "ragged_dot_general": 8, "reduce_max": 5,
+    "reduce_sum": 71, "rem": 10, "remat2": 2, "reshape": 96, "reshard": 5, "rsqrt": 17,
+    "scatter-add": 6, "select_n": 66, "sign": 4, "sin": 8, "slice": 59, "sort": 4, "split": 13,
+    "sqrt": 32, "square": 17, "squeeze": 1, "stop_gradient": 7, "sub": 43, "top_k": 2,
+    "transpose": 32,
+}
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_latent_attention_step_elsewhere_is_the_assembled_one(devices, request):
+    """On the CPU, and over four chips whatever their kind, the step is
+    the program it was before the parts had a kernel, primitive for
+    primitive; plain and remat differ in the recomputation alone."""
+    (group,) = setup_groups(1, devices=jax.devices()[:devices])
+    counts = _counts(_step_jaxpr(group, _latent_lm(remat=True)))
+    assert dict(counts) == _ASSEMBLED_STEP
+    if devices == 4:
+        request.getfixturevalue("as_tpu")
+        assert _counts(_step_jaxpr(group, _latent_lm(remat=True))) == counts
+    assert sum(_counts(_step_jaxpr(group, _latent_lm())).values()) == 1668
+
+
+# The parameter tree of the latent-attention LM: what checkpoints and
+# ``benchmark/entries/moe_lm_trial.py::reference_weights`` read by name.
+_LATENT_ATTENTION_TREE = {
+    "ln_attn": {"scale": (128,)},
+    "q_a": {"kernel": (128, 48)}, "q_norm": {"scale": (48,)}, "q_b": {"kernel": (48, 2 * 192)},
+    "kv_a": {"kernel": (128, 32 + 64)}, "kv_norm": {"scale": (32,)},
+    "kv_b": {"kernel": (32, 2 * 256)},
+    "proj": {"kernel": (2 * 128, 128)},
+    "ln_mlp": {"scale": (128,)},
+}
+
+
+def test_both_paths_share_one_parameter_tree_and_one_init(monkeypatch):
+    """Names, shapes and, at one seed, the initial values to the bit:
+    whether ``model.init`` traces the assembled path (as it does on
+    every backend: nobody placed its dummy batch) or the kernel on the
+    parts."""
+    model, tokens = _latent_lm(), jnp.zeros((2, T), jnp.int32)
+    assembled = model.init(jax.random.key(3), tokens)["params"]
+    assert set(assembled) == {"tok_embed", "ln_out", "head", "block_0", "block_1"}
+    shapes = jax.tree.map(jnp.shape, assembled)
+    assert {k: v for k, v in shapes["block_0"].items() if k in _LATENT_ATTENTION_TREE} == (
+        _LATENT_ATTENTION_TREE
+    )
+    assert set(shapes["block_0"]) - set(_LATENT_ATTENTION_TREE) == {"gate", "up", "down"}
+    assert set(shapes["block_1"]) - set(_LATENT_ATTENTION_TREE) == {"moe"}
+    monkeypatch.setattr(transformer, "_placement", lambda x: (V5E, 1))
+    traced = jax.make_jaxpr(lambda: model.init(jax.random.key(3), tokens))()
+    assert _kernels(traced) == {"latent_fwd": LAYERS}
+    by_parts = model.init(jax.random.key(3), tokens)["params"]
+    assert jax.tree.structure(by_parts) == jax.tree.structure(assembled)
+    for a, b in zip(jax.tree.leaves(by_parts), jax.tree.leaves(assembled), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize(
+    "dtype, tol", [(jnp.float32, 1e-4), (jnp.bfloat16, 1e-1)], ids=["f32", "bf16"]
+)
+def test_kernel_on_the_parts_matches_the_assembled_path(monkeypatch, dtype, tol):
+    """Loss and every gradient leaf of the two-block LM: the kernel on
+    the parts (interpreted), with remat and without, against the dense
+    path on the assembled q and k."""
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    tokens = group.device_put(
+        np.random.default_rng(0).integers(0, 64, (2, T)).astype(np.int32), group.batch_sharding
+    )
+    params = group.device_put(_latent_lm().init(jax.random.key(0), tokens)["params"])
+
+    def loss_and_grads(model):
+        def loss(p):
+            logits, _ = model.apply({"params": p}, tokens)
+            return optax.softmax_cross_entropy_with_integer_labels(
+                logits[:, :-1], tokens[:, 1:]
+            ).mean()
+
+        step = jax.jit(jax.value_and_grad(loss))
+        return _kernels(jax.make_jaxpr(step)(params)), step(params)
+
+    kernels, (want, want_grads) = loss_and_grads(_latent_lm(dtype=dtype))
+    assert not kernels
+    real = transformer._placement
+    monkeypatch.setattr(transformer, "_placement", lambda x: real(x) and (V5E, real(x)[1]))
+    rel = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+    for remat in (False, True):
+        kernels, (got, grads) = loss_and_grads(_latent_lm(dtype=dtype, remat=remat))
+        assert kernels == {"latent_fwd": LAYERS, "latent_bwd": LAYERS}
+        assert abs(float(got) - float(want)) < tol * float(want)
+        worst = max(jax.tree.leaves(jax.tree.map(rel, grads, want_grads)))
+        assert worst < tol, worst
 
 
 @pytest.mark.parametrize(
@@ -143,19 +302,22 @@ def test_cpu_latent_attention_stays_dense():
     assert _count(_step_jaxpr(group, model), "pallas_call") == 0
 
 
-def _counts(jaxpr) -> collections.Counter:
-    """Equations of ``jaxpr`` by primitive, call sites of shared inner
-    jaxprs (``jit``, ``remat``, ``custom_vjp``, ``scan``) counted one
-    by one."""
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it, call
+    sites of shared inner jaxprs (``jit``, ``remat``, ``custom_vjp``,
+    ``scan``) one by one."""
     jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
-    n = collections.Counter()
     for eqn in jaxpr.eqns:
-        n[eqn.primitive.name] += 1
+        yield eqn
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else (value,):
                 if hasattr(sub, "eqns") or hasattr(getattr(sub, "jaxpr", None), "eqns"):
-                    n += _counts(sub)
-    return n
+                    yield from _equations(sub)
+
+
+def _counts(jaxpr) -> collections.Counter:
+    """Equations of ``jaxpr`` by primitive."""
+    return collections.Counter(eqn.primitive.name for eqn in _equations(jaxpr))
 
 
 def _count(jaxpr, primitive: str) -> int:

@@ -159,20 +159,30 @@ def test_scopes_are_metadata_only(monkeypatch):
 # --- the latent-attention, routed-expert LM (models/latent_moe.py) ---
 
 
-def _lowered_latent(remat):
+def _lowered_latent(remat, t=16, placed=False, **fields):
+    """The latent-attention LM's step lowered for ``(2, t)`` tokens;
+    ``placed``: state and batch carry the one-device group's shardings,
+    as a trial's do (what ``transformer._placement`` reads)."""
     from multidisttorch_tpu.models.latent_moe import LatentMoELM
 
     (group,) = setup_groups(1, devices=jax.devices()[:1])
-    model = LatentMoELM(vocab_size=64, num_layers=LAYERS + 1, max_len=16, remat=remat)
+    model = LatentMoELM(
+        **{"vocab_size": 64, "num_layers": LAYERS + 1, "max_len": t, "remat": remat, **fields}
+    )
     tx = optax.adam(1e-3)
-    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    tokens = jax.ShapeDtypeStruct((2, t), jnp.int32)
     params = jax.eval_shape(
-        model.init, {"params": jax.random.key(0)}, jnp.zeros((2, 16), jnp.int32)
+        model.init, {"params": jax.random.key(0)}, jnp.zeros((2, t), jnp.int32)
     )["params"]
     state = jax.eval_shape(
         lambda p: TrainState(params=p, opt_state=tx.init(p), step=jnp.zeros((), jnp.int32)),
         params,
     )
+    if placed:
+        on = lambda tree, sharding: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree
+        )
+        state, tokens = on(state, group.replicated_sharding), on(tokens, group.batch_sharding)
     return make_lm_train_step(group, model, tx).lower(state, tokens), params
 
 
@@ -218,6 +228,52 @@ def test_expert_layer_scopes_reach_the_compiled_step(remat):
     assert len(unrecognised) / len(step) < UNRECOGNISED_BOUND, (
         len(unrecognised), len(step), sorted(set(unrecognised))[:20]
     )
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_latent_attention_on_the_parts_keeps_the_scopes(monkeypatch, remat):
+    """Where the block hands the kernel the parts of q and k (one TPU
+    chip; here the CPU device under a v5e's name, the kernels
+    interpreted), what it runs instead of the assembly carries the
+    names the split reads: the products of ``q_b``'s and ``kv_b``'s
+    column groups under ``q``, ``k`` and ``v``, the key's rotation
+    under ``k``, the two kernels (which rotate q) under ``attn_core``
+    once a pass, and nothing new without a name."""
+    from benchmark import scope_reduce
+    from multidisttorch_tpu.models import transformer
+
+    real = transformer._placement
+    monkeypatch.setattr(
+        transformer, "_placement", lambda x: real(x) and ("TPU v5 lite", real(x)[1])
+    )
+    lowered, _ = _lowered_latent(
+        remat, t=256, placed=True,  # 256: the shortest the kernels take
+        d_model=128, num_heads=2, num_layers=LAYERS, qk_nope_dim=128, qk_rope_dim=64,
+        v_head_dim=128,
+    )
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    step = [n for n in names if n.startswith("jit(step_fn)")]
+
+    calls = (("jit(_latent_fwd_call)", "forward"), ("jit(_latent_bwd_call)", "backward"))
+    for call, where in calls:
+        found = {scope_reduce.classify(n) for n in step if call in n.split("/")}
+        assert found == {("attn_core", where)}, (call, found)
+        for i in range(LAYERS):
+            assert any({f"block_{i}", call} <= set(n.split("/")) for n in step)
+    assert not any("flash" in n for n in step)
+    every = {"forward", "backward"} | ({"recompute"} if remat else set())
+    for scope, module in (("q", "q_b"), ("k", "kv_b"), ("v", "kv_b")):
+        under = [n for n in step if {scope, module} <= set(_components(n))]
+        assert {_pass(n) for n in under} >= every, (scope, module)
+        assert {scope_reduce.classify(n)[0] for n in under} == {"attn_proj"}
+    # the one key is rotated here, q's rotary part in the kernels
+    assert any("k" in _components(n) and "jit(_roll_static)" in n for n in step)
+    assert not any("q" in _components(n) and "jit(_roll_static)" in n for n in step)
+    unrecognised = [n for n in step if scope_reduce.classify(n)[0] in ("unscoped", "block_other")]
+    assert len(unrecognised) / len(step) < UNRECOGNISED_BOUND
+    # what is left without a name is what the assembled path leaves too:
+    # the residual adds, the positions' iota and remat's own equations
+    assert {n.rsplit("/", 1)[-1] for n in unrecognised} <= {"add", "iota", "remat2"}
 
 
 def test_latent_scopes_stay_out_of_the_parameter_tree():

@@ -8,6 +8,7 @@ import pytest
 
 from multidisttorch_tpu.ops.pallas_attention import (
     flash_attention,
+    latent_attention,
     make_flash_attention,
 )
 from multidisttorch_tpu.ops.ring_attention import dense_attention_reference
@@ -392,6 +393,115 @@ def test_wider_q_and_k_than_v_match_dense(t, h, dk, dv, block, dtype, tol):
         assert got.shape == ref.shape and rel(got, ref) < tol
 
 
+def _latent_operands(b, t, h, dtype, seed=11):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    mk = lambda k, *shape: jax.random.normal(k, shape, jnp.float32).astype(dtype)
+    return (mk(ks[0], b, t, h, 128), mk(ks[1], b, t, h, 64), mk(ks[2], b, t, h, 128),
+            mk(ks[3], b, t, 64), mk(ks[4], b, t, h, 128))
+
+
+def _assembled(q_nope, q_rope, k_nope, k_rope, v):
+    """The ``(q, k, v)`` that latent attention's five operands stand
+    for: the rotary key copied to every head."""
+    every_head = jnp.broadcast_to(k_rope[:, :, None, :], q_rope.shape)
+    return (jnp.concatenate([q_nope, q_rope], -1), jnp.concatenate([k_nope, every_head], -1), v)
+
+
+@pytest.mark.parametrize(
+    "t, h, block, causal, rotated, dtype, tol",
+    [
+        (128, 2, None, True, True, jnp.float32, 2e-5),  # one block a sequence
+        (256, 2, 128, True, True, jnp.float32, 2e-5),  # two: the walk below the diagonal
+        (256, 4, 128, True, True, jnp.float32, 2e-5),  # two pairs of heads: the key's gradient
+        (256, 2, 128, True, False, jnp.float32, 2e-5),  # q's rotary part used as it comes
+        (256, 2, 128, False, True, jnp.float32, 2e-5),
+        (128, 2, None, True, True, jnp.bfloat16, 3e-2),
+        (256, 2, 128, True, True, jnp.bfloat16, 3e-2),
+    ],
+)
+def test_latent_operands_match_dense_on_the_assembled(t, h, block, causal, rotated, dtype, tol):
+    """``latent_attention`` on the parts of q and k against the dense
+    path on the assembled q and k (q's rotary part rotated as the model
+    rotates it, where the kernels are asked to): the output and all
+    five gradients, the rotary key's against the sum over the heads of
+    the assembled k's rotary part."""
+    from multidisttorch_tpu.models.latent_moe import _rope_angles, rope_interleaved
+
+    parts = _latent_operands(2, t, h, dtype)
+    w = jax.random.normal(jax.random.key(12), (2, t, h, 128), jnp.float32)
+    positions, theta = jnp.arange(t), 1e4
+    angle = _rope_angles(positions, theta, 64)
+    rotation = (jnp.cos(angle), jnp.sin(angle)) if rotated else None
+
+    def assembled(q_nope, q_rope, k_nope, k_rope, v):
+        if rotated:
+            q_rope = rope_interleaved(q_rope, positions, theta)
+        return tuple(x.astype(jnp.float32) for x in _assembled(q_nope, q_rope, k_nope, k_rope, v))
+
+    def dense(*parts):
+        return dense_attention_reference(*assembled(*parts), causal=causal)
+
+    def run(attn):
+        loss = lambda *x: jnp.sum(attn(*x).astype(jnp.float32) * w)
+        return attn(*parts), jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*parts)
+
+    with jax.default_matmul_precision("highest"):
+        out, grads = run(
+            lambda *x: latent_attention(*x, q_rotation=rotation, causal=causal, block=block)
+        )
+        want, want_grads = run(dense)
+    assert out.shape == (2, t, h, 128) and out.dtype == dtype
+    rel = lambda a, b: float(
+        jnp.linalg.norm(a.astype(jnp.float32) - b.astype(jnp.float32))
+        / jnp.linalg.norm(b.astype(jnp.float32)))
+    assert rel(out, want) < tol
+    for got, ref, part in zip(grads, want_grads, parts, strict=True):
+        assert got.shape == part.shape and got.dtype == dtype and rel(got, ref) < tol
+    # the same through the assembled k: d k_rope is the heads' sum
+    dk = jax.grad(
+        lambda q, k, v: jnp.sum(dense_attention_reference(q, k, v, causal=causal) * w), argnums=1
+    )(*assembled(*parts))
+    assert rel(grads[3], dk[..., 128:].sum(axis=2)) < tol
+
+
+@pytest.mark.parametrize(
+    "h, nope, rope, dv",
+    [(3, 128, 64, 128), (2, 128, 32, 128), (2, 96, 64, 128), (2, 128, 64, 64)],
+    ids=["odd-heads", "rope-32", "nope-96", "v-64"],
+)
+def test_latent_operands_that_do_not_tile_are_refused(h, nope, rope, dv):
+    z = lambda *shape: jnp.zeros(shape, jnp.float32)
+    with pytest.raises(ValueError, match="do not tile"):
+        latent_attention(
+            z(1, 128, h, nope), z(1, 128, h, rope), z(1, 128, h, nope), z(1, 128, rope),
+            z(1, 128, h, dv),
+        )
+
+
+def test_latent_operands_lower_for_tpu(monkeypatch):
+    # the cell moe-mla-t4096's attention as its block hands it over: 4 x
+    # 4,096, 32 heads, the parts of q and k apart, q's rotary part
+    # rotated in the kernels; forward and backward, interpret mode off.
+    # Nothing with a 192- or 256-wide head is made around the kernels,
+    # and no copy of the rotary key a head.
+    monkeypatch.delenv("MDT_PALLAS_INTERPRET")
+    part = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    parts = (part(4, 4096, 32, 128), part(4, 4096, 32, 64), part(4, 4096, 32, 128),
+             part(4, 4096, 64), part(4, 4096, 32, 128))
+    angles = jnp.zeros((4096, 32), jnp.float32)
+    rotation = jnp.cos(angles), jnp.sin(angles)
+    fwd = lambda *x: latent_attention(*x, q_rotation=rotation, causal=True)
+    bwd = jax.grad(lambda *x: fwd(*x).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))
+    for fn, calls in ((fwd, 1), (bwd, 2)):
+        text = jax.jit(fn).trace(*parts).lower(lowering_platforms=("tpu",)).as_text()
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == calls
+        assert "32x4096x4096" not in text  # no (B, H, T, T) scores outside the kernel
+        for head in ("32x192x", "32x256x", "x6144x", "x8192x"):
+            assert head not in text, head
+        # the rotary key goes in once, side by side for a pair of heads
+        assert "tensor<4x4096x128xbf16>" in text
+
+
 def test_latent_attention_widths_lower_for_tpu(monkeypatch):
     # the cell moe-mla-t4096's attention: 4 x 4,096, 32 heads, q and k
     # 192 wide, v 128; forward and backward, interpret mode off
@@ -475,6 +585,15 @@ def test_kernels_compile_for_a_v5e_topology(monkeypatch):
         with jax.default_matmul_precision("highest"):
             jax.jit(attn).lower(*qkv).compile()
             jax.jit(grad).lower(*qkv).compile()
+    # moe-mla-t4096: latent attention's five operands
+    parts = [aval((4, 4096, 32, w), jnp.bfloat16) for w in (128, 64, 128)]
+    parts += [aval((4, 4096, 64), jnp.bfloat16), aval((4, 4096, 32, 128), jnp.bfloat16)]
+    unit = jnp.ones((4096, 32), jnp.float32)
+    latent = lambda *x: latent_attention(*x, q_rotation=(unit, unit), causal=True)
+    jax.jit(latent).lower(*parts).compile()
+    jax.jit(
+        jax.grad(lambda *x: latent(*x).astype(jnp.float32).sum(), (0, 1, 2, 3, 4))
+    ).lower(*parts).compile()
     elbo_args = (
         aval((4096, 784), jnp.bfloat16), aval((4096, 784), jnp.float32),
         aval((4096, 20), jnp.bfloat16), aval((4096, 20), jnp.bfloat16),
