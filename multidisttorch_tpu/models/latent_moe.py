@@ -16,9 +16,44 @@ Per layer, with ``y = RMSNorm(x)``::
     c_q = RMSNorm(y W_qa)                 q = c_q W_qb  as (H, nope + rope)
     [c_kv | k_r] = y W_kva                c_kv = RMSNorm(c_kv)
     [k_nope | v] per head = c_kv W_kvb    k = [k_nope | k_r for every head]
-    q_rope, k_r rotated (pairs (2i, 2i+1) of the rope part, no scaling)
-    x1 = x + W_o attention(q, k, v)       causal, scores / sqrt(nope + rope)
+    q_rope, k_r rotated (pairs (2i, 2i+1) of the rope part, angle pos * inv_freq_i)
+    x1 = x + W_o attention(q, k, v)       causal, scores * s / sqrt(nope + rope)
     x2 = x1 + FFN(RMSNorm(x1))
+
+**Rotary scaling.** With ``rope_scaling`` left ``None``, ``inv_freq_i =
+theta^(-2i/rope)`` and ``s = 1``. Given a :class:`YarnScaling` (a
+configuration's ``rope_scaling`` of type ``yarn``), with ``f_i =
+theta^(-2i/rope)`` and ``L`` the original context::
+
+    turns(beta) = rope ln(L / (2 pi beta)) / (2 ln theta)
+    low = floor(turns(beta_fast)), high = ceil(turns(beta_slow)), inside [0, rope - 1]
+    m_i = 1 - clip((i - low) / (high - low), 0, 1)
+    inv_freq_i = (1 - m_i) f_i / factor + m_i f_i
+    mscale(m) = 0.1 m ln(factor) + 1
+    cos, sin times mscale(mscale) / mscale(mscale_all_dim);   s = mscale(mscale_all_dim)^2
+
+Only the angles and one number change: the kernels take ``(cos, sin)``
+and a ``scale`` they multiply the scores by anyway; on the assembled
+path q is multiplied by ``s``.
+
+**Residual streams.** With ``hc_mult`` = n > 1 the residual state of a
+token is ``X`` in ``R^{n x d}`` and each of the two sublayers ``F``
+(latent attention with ``ln_attn`` and ``proj`` inside; the FFN with
+``ln_mlp`` inside) sits behind a manifold-constrained hyper-connection
+(``ops/hyper_connection.py``, where the layout and what ``nn.remat``
+keeps are argued), parameters ``hc_attn`` and ``hc_mlp`` of a block::
+
+    x~ = RMSNorm(vec(X))                               # over all n d entries, with a scale
+    H~pre = a_pre (x~ phi_pre) + b_pre      H~post = a_post (x~ phi_post) + b_post
+    H~res = a_res mat(x~ phi_res) + b_res              # (n, n)
+    Hpre = sigmoid(H~pre)     Hpost = 2 sigmoid(H~post)
+    M = exp(clamp(H~res));  20 times: M <- M / (colsum(M) + eps), M <- M / (rowsum(M) + eps)
+    u = Hpre X  (d)        y = F(u)  (d)        X' = M X + Hpost^T y   (n x d)
+
+The streams are a tuple of n ``(B, T, d)`` arrays: the embedding n
+times, summed before ``ln_out``. With ``hc_mult`` 1 (the default) none
+of this is traced: the model, its parameter tree and its lowered step
+are the plain residual ones, character for character (test).
 
 q and k are ``nope + rope`` wide and v ``v_head_dim``; the attention is
 injected as in ``TransformerLM``. **Which path runs where.** Given no
@@ -44,25 +79,36 @@ sliced vocabulary is simply a smaller ``vocab_size``.
 
 The model returns ``(logits, {"expert_counts": (expert layers, count)
 int32})``: the assignments each expert held received, which
-``make_lm_train_step`` hands out with the loss.
+``make_lm_train_step`` hands out with the loss; with residual streams
+also ``"hc_marginal_err"``, the largest distance of a row or column
+sum of any ``M`` of the step from 1 (20 iterations need not have
+converged: a projection that stopped converging shows here).
 
 Names: a trace is split by the scope path of each operation
 (``benchmark/scope_reduce.py``), so the pieces of the two low-rank
 paths run under ``jax.named_scope``s ``q``, ``k``, ``v`` (the names a
 plain block's projections carry), the output projection is ``proj``,
 the norms ``ln_attn``, ``ln_mlp``, the dense MLP runs under ``mlp`` and
-the expert layer is the module ``moe``.
+the expert layer is the module ``moe``. The residual path runs under
+two scopes of its own, opened outside all of these: ``hc_maps`` (the
+norm over the streams, the projections, sigmoids and Sinkhorn, inside
+the modules ``hc_attn`` and ``hc_mlp``) and ``hc_mix`` (``Hpre X``,
+``M X + Hpost^T y``, the sum before ``ln_out``, and their backward).
 """
 
 from __future__ import annotations
 
+import dataclasses
+import math
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from multidisttorch_tpu.models import transformer
+from multidisttorch_tpu.ops import hyper_connection
 from multidisttorch_tpu.ops.pallas_attention import latent_attention, latent_takes_kernel
 from multidisttorch_tpu.ops.moe import (
     RoutedExperts,
@@ -79,22 +125,83 @@ from multidisttorch_tpu.utils.profiling import (
 )
 
 
-def _rope_angles(positions, theta: float, width: int):
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """A configuration's ``rope_scaling`` of type ``yarn`` (YaRN, arXiv
+    2309.00071, as the latent-attention family's public modelling code
+    applies it). A pair whose wavelength is short against the
+    ``original_max_position`` the model was trained at keeps its
+    frequency, one that is long has it divided by ``factor``, and those
+    between ``beta_fast`` and ``beta_slow`` turns over that length are
+    blended; the scores are multiplied by ``mscale(mscale_all_dim)^2``
+    and cos and sin by ``mscale(mscale) / mscale(mscale_all_dim)``,
+    ``mscale(m) = 0.1 m ln(factor) + 1``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def inv_freq(self, theta: float, width: int) -> np.ndarray:
+        """The blended frequency of each pair of a ``width``-wide
+        rotary part, float64."""
+        pairs = np.arange(width // 2)
+        freq = theta ** (-2.0 * pairs / width)
+        turns_at = lambda beta: (
+            width * math.log(self.original_max_position / (beta * 2 * math.pi))
+            / (2 * math.log(theta))
+        )
+        low = max(math.floor(turns_at(self.beta_fast)), 0)
+        high = min(math.ceil(turns_at(self.beta_slow)), width - 1)
+        keep = 1.0 - np.clip((pairs - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return (1.0 - keep) * freq / self.factor + keep * freq
+
+    def _mscale(self, m: float) -> float:
+        return 0.1 * m * math.log(self.factor) + 1.0 if self.factor > 1 else 1.0
+
+    @property
+    def score_scale(self) -> float:
+        """What multiplies ``1 / sqrt(nope + rope)``."""
+        return self._mscale(self.mscale_all_dim) ** 2
+
+    @property
+    def rotation_scale(self) -> float:
+        """What multiplies cos and sin."""
+        return self._mscale(self.mscale) / self._mscale(self.mscale_all_dim)
+
+
+def _rope_angles(positions, theta: float, width: int, scaling: Optional[YarnScaling] = None):
     """``positions * theta**(-2i/width)`` for the pairs ``i`` of a
-    ``width``-wide rotary part: ``(T, width/2)`` float32."""
-    inv_freq = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
+    ``width``-wide rotary part, or ``positions`` times ``scaling``'s
+    blended frequencies: ``(T, width/2)`` float32."""
+    if scaling is None:
+        inv_freq = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
+    else:
+        inv_freq = jnp.asarray(scaling.inv_freq(theta, width), jnp.float32)
     return positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
 
 
-def rope_interleaved(x, positions, theta: float):
+def _rotation_scaled(cos, sin, scaling: Optional[YarnScaling]):
+    """``cos`` and ``sin`` times ``scaling``'s ``rotation_scale``, where
+    that is not 1."""
+    if scaling is None or scaling.rotation_scale == 1.0:
+        return cos, sin
+    return cos * scaling.rotation_scale, sin * scaling.rotation_scale
+
+
+def rope_interleaved(x, positions, theta: float, scaling: Optional[YarnScaling] = None):
     """Rotate the pairs ``(2i, 2i+1)`` of ``x``'s last axis by
-    ``positions * theta**(-2i/width)``; ``x`` is ``(..., T, H, width)``,
+    ``positions * theta**(-2i/width)`` (``scaling``: by its blended
+    frequencies); ``x`` is ``(..., T, H, width)``,
     the arithmetic float32. Written with lane rolls rather than a
     ``(width/2, 2)`` reshape, which the TPU would have to relayout."""
     width = x.shape[-1]
-    angle = _rope_angles(positions, theta, width)
+    angle = _rope_angles(positions, theta, width, scaling)
     cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)[:, None, :]
     sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)[:, None, :]
+    cos, sin = _rotation_scaled(cos, sin, scaling)
     x32 = x.astype(jnp.float32)
     even = jnp.arange(width) % 2 == 0
     partner = jnp.where(even, -jnp.roll(x32, -1, axis=-1), jnp.roll(x32, 1, axis=-1))
@@ -146,7 +253,10 @@ def _default_grouped_dot(x):
 class LatentMoEBlock(nn.Module):
     """One pre-norm block: latent attention, then a dense SwiGLU MLP
     (``num_experts`` 0) or an expert layer. Returns ``(x, counts)``,
-    ``counts`` ``(count,)`` int32 and empty for a dense block.
+    ``counts`` ``(count,)`` int32 and empty for a dense block. With
+    ``hc_mult`` > 1 it takes and returns the ``hc_mult`` streams (a
+    tuple of ``(B, T, d)`` arrays), each sublayer behind its
+    hyper-connection, and returns ``(streams, counts, marginal_err)``.
 
     The attention is one of two paths, chosen while tracing (the
     module's docstring says where each runs): :meth:`_kernel_on_parts`,
@@ -171,13 +281,41 @@ class LatentMoEBlock(nn.Module):
     routed_scaling: float = 1.0
     eps: float = 1e-6
     dtype: Any = jnp.float32
+    rope_scaling: Optional[YarnScaling] = None
+    hc_mult: int = 1  # residual streams; 1: the plain residual add
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: tuple[float, float] = (-30.0, 30.0)
 
     @nn.compact
     def __call__(self, x):
-        dense = self._dense
-        norm = lambda name: nn.RMSNorm(
+        if self.hc_mult == 1:
+            x = x + self._attention(x)
+            y, counts = self._ffn(x)
+            return x + y, counts
+        connection = lambda name: hyper_connection.HyperConnection(
+            sinkhorn_iters=self.hc_sinkhorn_iters, eps=self.hc_eps, clamp=self.hc_clamp,
+            norm_eps=self.eps, name=name,
+        )
+        streams = x
+        around_attn = connection("hc_attn")(streams)
+        y = self._attention(hyper_connection.read(around_attn, streams))
+        streams = hyper_connection.write(around_attn, streams, y)
+        around_ffn = connection("hc_mlp")(streams)
+        y, counts = self._ffn(hyper_connection.read(around_ffn, streams))
+        streams = hyper_connection.write(around_ffn, streams, y)
+        return streams, counts, jnp.maximum(around_attn.marginal_err, around_ffn.marginal_err)
+
+    @nn.nowrap
+    def _norm(self, name):
+        return nn.RMSNorm(
             epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name=name
         )
+
+    @nn.nowrap
+    def _attention(self, x):
+        """Latent attention of ``ln_attn(x)``, through ``proj``."""
+        dense, norm = self._dense, self._norm
         b, t, d = x.shape
         h, nope, rope, dv = self.num_heads, self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim
         positions = jnp.arange(t)
@@ -194,19 +332,26 @@ class LatentMoEBlock(nn.Module):
             latent = dense(self.kv_lora_rank + rope, "kv_a")(y)
             c_kv = norm("kv_norm")(latent[..., : self.kv_lora_rank])
             k_rope = rope_interleaved(
-                latent[..., None, self.kv_lora_rank:], positions, self.rope_theta
+                latent[..., None, self.kv_lora_rank:], positions, self.rope_theta,
+                self.rope_scaling,
             )  # (B, T, 1, rope): one rope key for all heads
         attend = self._kernel_on_parts if by_parts else self._assembled
         attn = attend(c_q, c_kv, k_rope, positions)
-        x = x + dense(d, "proj")(attn.reshape(b, t, h * dv))
+        return dense(d, "proj")(attn.reshape(b, t, h * dv))
 
-        y = norm("ln_mlp")(x)
+    @nn.nowrap
+    def _ffn(self, x):
+        """``(y, counts)``: the dense MLP or the expert layer of
+        ``ln_mlp(x)``."""
+        dense = self._dense
+        b, t, d = x.shape
+        y = self._norm("ln_mlp")(x)
         if not self.num_experts:
             with jax.named_scope(SCOPE_MLP):
                 y = dense(d, "down")(
                     nn.silu(dense(self.hidden_dim, "gate")(y)) * dense(self.hidden_dim, "up")(y)
                 )
-            return x + y, jnp.zeros((0,), jnp.int32)
+            return y, jnp.zeros((0,), jnp.int32)
         y, counts = RoutedExperts(
             num_experts=self.num_experts,
             experts_held=self.experts_held,
@@ -218,7 +363,7 @@ class LatentMoEBlock(nn.Module):
             grouped_dot=_default_grouped_dot(y),
             name="moe",
         )(y.reshape(b * t, d))
-        return x + y.reshape(b, t, d), counts
+        return y.reshape(b, t, d), counts
 
     @nn.nowrap
     def _dense(self, feats, name):
@@ -236,9 +381,14 @@ class LatentMoEBlock(nn.Module):
         with jax.named_scope(SCOPE_Q):
             q = self._dense(h * (nope + rope), "q_b")(c_q).reshape(b, t, h, nope + rope)
             q = jnp.concatenate(
-                [q[..., :nope], rope_interleaved(q[..., nope:], positions, self.rope_theta)],
+                [
+                    q[..., :nope],
+                    rope_interleaved(q[..., nope:], positions, self.rope_theta, self.rope_scaling),
+                ],
                 axis=-1,
             )
+            if self.rope_scaling is not None and self.rope_scaling.score_scale != 1.0:
+                q = q * self.rope_scaling.score_scale  # the callable divides by sqrt(width)
         with jax.named_scope(SCOPE_V):
             kv = self._dense(h * (nope + dv), "kv_b")(c_kv).reshape(b, t, h, nope + dv)
             v = kv[..., nope:]
@@ -263,8 +413,9 @@ class LatentMoEBlock(nn.Module):
         heads = lambda x: x.reshape(b, t, h, -1)  # free: the kernels read the flat array
         with jax.named_scope(SCOPE_Q):
             q_nope, q_rope = q_b(c_q, 0), q_b(c_q, 1)
-            angle = _rope_angles(positions, self.rope_theta, rope)
-            rotation = jnp.cos(angle), jnp.sin(angle)  # of q_rope: the kernels make it
+            angle = _rope_angles(positions, self.rope_theta, rope, self.rope_scaling)
+            # of q_rope: the kernels make it
+            rotation = _rotation_scaled(jnp.cos(angle), jnp.sin(angle), self.rope_scaling)
         with jax.named_scope(SCOPE_K):
             k_nope = kv_b(c_kv, 0)
         with jax.named_scope(SCOPE_V):
@@ -273,6 +424,8 @@ class LatentMoEBlock(nn.Module):
             return latent_attention(
                 heads(q_nope), heads(q_rope), heads(k_nope), k_rope[:, :, 0], heads(v),
                 q_rotation=rotation, causal=True,
+                scale=None if self.rope_scaling is None
+                else self.rope_scaling.score_scale / math.sqrt(nope + rope),
             )
 
 
@@ -307,6 +460,12 @@ class LatentMoELM(nn.Module):
     attention: Optional[Callable] = None
     dtype: Any = jnp.float32
     remat: bool = False  # per-block checkpointing (transformer.remat_block)
+    rope_scaling: Optional[YarnScaling] = None
+    # residual streams mixed by hyper-connections (ops/hyper_connection.py); 1: plain residuals
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: tuple[float, float] = (-30.0, 30.0)
 
     @nn.compact
     def __call__(self, tokens):
@@ -327,19 +486,28 @@ class LatentMoELM(nn.Module):
             kv_lora_rank=self.kv_lora_rank, qk_nope_dim=self.qk_nope_dim,
             qk_rope_dim=self.qk_rope_dim, v_head_dim=self.v_head_dim,
             rope_theta=self.rope_theta, attention=self.attention,
-            eps=self.eps, dtype=self.dtype,
+            eps=self.eps, dtype=self.dtype, rope_scaling=self.rope_scaling,
+            hc_mult=self.hc_mult, hc_sinkhorn_iters=self.hc_sinkhorn_iters,
+            hc_eps=self.hc_eps, hc_clamp=self.hc_clamp,
         )
+        if self.hc_mult != 1:
+            # the embedding copied into the streams (the hyper-connections
+            # paper's rule for the first layer): the same array n times
+            x = (x,) * self.hc_mult
         routed = dict(
             hidden_dim=self.expert_hidden_dim, num_experts=self.num_experts,
             experts_held=self.experts_held or (0, self.num_experts),
             top_k=self.top_k, shared_experts=self.shared_experts,
             routed_scaling=self.routed_scaling,
         )
-        counts = []
+        counts, errs = [], []
         for i in range(self.num_layers):
             ffn = dict(hidden_dim=self.dense_hidden_dim) if i < self.dense_layers else routed
-            x, c = block_cls(**shared, **ffn, name=f"block_{i}")(x)
+            x, c, *err = block_cls(**shared, **ffn, name=f"block_{i}")(x)
             counts.append(c)
+            errs += err
+        if self.hc_mult != 1:
+            x = hyper_connection.merge(x)
         x = nn.RMSNorm(
             epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name="ln_out"
         )(x)
@@ -347,4 +515,7 @@ class LatentMoELM(nn.Module):
             self.vocab_size, use_bias=False, dtype=jnp.float32,
             param_dtype=jnp.float32, name="head",
         )(x)
-        return logits, {"expert_counts": jnp.stack(counts[self.dense_layers:])}
+        counters = {"expert_counts": jnp.stack(counts[self.dense_layers:])}
+        if errs:
+            counters["hc_marginal_err"] = jnp.max(jnp.stack(errs))
+        return logits, counters

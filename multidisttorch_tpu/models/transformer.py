@@ -26,6 +26,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from multidisttorch_tpu.ops.hyper_connection import SAVED_MAPS, SAVED_Y
 from multidisttorch_tpu.ops.pallas_attention import (
     SAVED_LSE,
     SAVED_OUT,
@@ -94,7 +95,7 @@ class Block(nn.Module):
 # One policy object for every block: jaxprs and jit's caches compare it
 # by identity.
 _KEEP_KERNEL_RESULTS = jax.checkpoint_policies.save_only_these_names(
-    SAVED_OUT, SAVED_LSE
+    SAVED_OUT, SAVED_LSE, SAVED_MAPS, SAVED_Y
 )
 
 

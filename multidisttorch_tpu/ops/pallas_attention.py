@@ -962,12 +962,16 @@ _latent.defvjp(_latent_fwd, _latent_bwd)
 
 
 def latent_attention(q_nope, q_rope, k_nope, k_rope, v, *, q_rotation=None,
-                     causal: bool = False, block: int | None = None):
+                     causal: bool = False, block: int | None = None,
+                     scale: float | None = None):
     """:func:`flash_attention` for latent attention's operands as the
     projections make them: ``q_nope, k_nope (B, T, H, 128)``, ``q_rope
     (B, T, H, 64)``, ``k_rope (B, T, 64)`` the one rotary key of all
     heads, rotated already, ``v (B, T, H, 128)``. The scores are
-    ``(q_nope . k_nope + q_rope . k_rope) / sqrt(128 + 64)``, what the
+    ``(q_nope . k_nope + q_rope . k_rope) * scale``, ``scale`` ``1 /
+    sqrt(128 + 64)`` unless given (a configuration whose rotary scaling
+    changes it hands it in: the kernels multiply the scores by it
+    anyway, so it costs no pass over q), what the
     assembled ``[q_nope | q_rope]`` and ``[k_nope | k_rope for every
     head]`` give; no such array is made, here or in the backward pass,
     which returns the gradient of each operand (the key's summed over
@@ -1011,7 +1015,7 @@ def latent_attention(q_nope, q_rope, k_nope, k_rope, v, *, q_rotation=None,
     o = _latent(
         flat(q_nope), flat(q_rope), cos, sin, flat(k_nope),
         jnp.concatenate([k_rope] * pair, axis=-1), flat(v),
-        1.0 / ((dn + dr) ** 0.5), causal, blk,
+        1.0 / ((dn + dr) ** 0.5) if scale is None else scale, causal, blk,
     )
     return o.reshape(b, t, h, dv)
 

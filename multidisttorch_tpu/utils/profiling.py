@@ -47,6 +47,12 @@ SCOPE_SHARED_EXPERT = "shared_expert"
 # path; these scopes give them the names a plain block's projections
 # carry as flax modules (``models/latent_moe.py``).
 SCOPE_Q, SCOPE_K, SCOPE_V = "q", "k", "v"
+# Hyper-connections (``ops/hyper_connection.py``), opened outside every
+# scope above: making a sublayer's three maps (the norm over all the
+# streams, the projections, the sigmoids, Sinkhorn), and the mixes of
+# the streams (what the sublayer reads, what is written back).
+SCOPE_HC_MAPS = "hc_maps"
+SCOPE_HC_MIX = "hc_mix"
 
 
 @contextlib.contextmanager

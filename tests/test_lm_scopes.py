@@ -288,3 +288,67 @@ def test_latent_scopes_stay_out_of_the_parameter_tree():
         "router", "score_bias", "w_gate", "w_up", "w_down",
         "shared_gate", "shared_up", "shared_down",
     }
+
+
+# --- hyper-connections around the latent-attention LM's sublayers ---
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_hyper_connection_scopes_reach_the_compiled_step(remat):
+    """``hc_maps`` and ``hc_mix`` (``ops/hyper_connection.py``) in the
+    compiled step of the LM with four streams: in every block, under
+    both connections' flax names for the maps, in every pass; opened
+    outside the names the benchmark's split knows, so that those parts
+    are charged as before and the new time is the blocks' own
+    (``block_other``), not ``unscoped``."""
+    from benchmark import hc_scopes, scope_reduce
+    from multidisttorch_tpu.utils.profiling import SCOPE_HC_MAPS, SCOPE_HC_MIX
+
+    assert hc_scopes.PARTS == (SCOPE_HC_MAPS, SCOPE_HC_MIX)
+    lowered, _ = _lowered_latent(remat, hc_mult=4)
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    step = [n for n in names if n.startswith("jit(step_fn)")]
+    every = {"forward", "backward"} | ({"recompute"} if remat else set())
+    for scope in hc_scopes.PARTS:
+        under = [n for n in step if hc_scopes.classify(n) == scope]
+        assert {_pass(n) for n in under} >= every, scope
+        for i in range(LAYERS + 1):  # once a sublayer: both connections of every block
+            in_block = [n for n in under if f"block_{i}" in _components(n)]
+            assert in_block, (scope, i)
+            if scope == SCOPE_HC_MAPS:
+                for connection in ("hc_attn", "hc_mlp"):
+                    assert any(connection in _components(n) for n in in_block), (i, connection)
+        # nothing the split knows moved under them; inside a block they are block_other
+        moved = {"attn_core", "q", "k", "v", "proj", "mlp", "moe", "ln_attn", "ln_mlp"}
+        assert not any(moved & set(_components(n)) for n in under), scope
+        parts = {scope_reduce.classify(n)[0] for n in under}
+        assert parts <= {"block_other", "unscoped"} and "block_other" in parts, (scope, parts)
+        # what is under a scope outside every block is the sum of the streams before ln_out
+        outside = [n for n in under if scope_reduce.classify(n)[0] == "unscoped"]
+        assert scope == SCOPE_HC_MIX or not outside, outside[:5]
+    # the Sinkhorn iterations are one loop, not 20 copies of its body
+    assert any("while" in n for n in step if hc_scopes.classify(n) == SCOPE_HC_MAPS)
+    # the parts the split knows are all still there, in every pass
+    parts = {scope_reduce.classify(n) for n in step}
+    for part in ("attn_core", "attn_proj", "mlp", "norm"):
+        assert {which for p, which in parts if p == part} >= every, part
+    # and what has no name at all is no more than it was
+    unscoped = [n for n in step if scope_reduce.classify(n)[0] == "unscoped"
+                and hc_scopes.classify(n) is None]
+    assert len(unscoped) / len(step) < UNRECOGNISED_BOUND
+
+
+def test_hyper_connection_scopes_stay_out_of_the_parameter_tree():
+    _, params = _lowered_latent(True, hc_mult=4)
+    attention = {"ln_attn", "q_a", "q_norm", "q_b", "kv_a", "kv_norm", "kv_b", "proj", "ln_mlp"}
+    connections = {"hc_attn", "hc_mlp"}
+    assert set(params["block_0"]) == attention | connections | {"gate", "up", "down"}
+    assert set(params["block_1"]) == attention | connections | {"moe"}
+    for name in connections:
+        assert set(params["block_1"][name]) == {
+            "norm", "phi_pre", "phi_post", "phi_res", "b_pre", "b_post", "b_res",
+            "a_pre", "a_post", "a_res",
+        }
+    # without streams the tree is the one the test above lists
+    _, plain = _lowered_latent(True)
+    assert not connections & set(plain["block_1"])
