@@ -111,6 +111,7 @@ from multidisttorch_tpu.models import transformer
 from multidisttorch_tpu.ops import hyper_connection
 from multidisttorch_tpu.ops.pallas_attention import latent_attention, latent_takes_kernel
 from multidisttorch_tpu.ops.moe import (
+    GroupedDot,
     RoutedExperts,
     grouped_dot_takes_kernel,
     kernel_grouped_dot,
@@ -233,21 +234,27 @@ class _DenseByParts(nn.Module):
 
 
 def _default_grouped_dot(x):
-    """The grouped matrix product of the expert layer whose input is
-    ``x``: the Pallas kernel where ``ops.moe.grouped_dot_takes_kernel``
-    says it applies (a TPU, operands on one device, shapes it tiles)
-    and XLA's ragged dot everywhere else. Decided while tracing, from
-    the operands alone, as ``transformer._default_causal`` decides the
+    """The two grouped products of the expert layer whose input is
+    ``x`` (the experts' and the sums of the rows by token): each the
+    Pallas kernel where ``ops.moe.grouped_dot_takes_kernel`` says it
+    applies (a TPU, operands on one device, shapes it tiles) and XLA's
+    ragged dot everywhere else. Decided while tracing, from the
+    operands alone, as ``transformer._default_causal`` decides the
     attention; the placement is read off the layer's input, because a
     kernel's result no longer shows the mesh it was computed on."""
     placed = transformer._placement(x)
 
-    def grouped_dot(lhs, rhs, sizes):
-        if placed and grouped_dot_takes_kernel(*placed, *lhs.shape, rhs.shape[-1]):
-            return kernel_grouped_dot(lhs, rhs, sizes)
-        return ragged_grouped_dot(lhs, rhs, sizes)
+    def chosen(rows: int, k: int, n: int) -> GroupedDot:
+        kernel = placed and grouped_dot_takes_kernel(*placed, rows, k, n)
+        return kernel_grouped_dot if kernel else ragged_grouped_dot
 
-    return grouped_dot
+    def experts(lhs, rhs, sizes):
+        return chosen(*lhs.shape, rhs.shape[-1]).experts(lhs, rhs, sizes)
+
+    def token_sums(rows, weight, key, n):
+        return chosen(rows.shape[0], n, rows.shape[1]).token_sums(rows, weight, key, n)
+
+    return GroupedDot(experts, token_sums)
 
 
 class LatentMoEBlock(nn.Module):

@@ -84,7 +84,7 @@ class Maps(NamedTuple):
     marginal_err: jax.Array
 
 
-def _bf16_parts(a, count: int = 3):
+def bf16_parts(a, count: int = 3):
     """``a`` (float32) as ``count`` bf16 arrays that sum to it, each
     holding the next 8 bits of the mantissa: three make a float32."""
     parts = []
@@ -111,7 +111,7 @@ def project_bf16(x, w):
 
 def _project_fwd(x, w):
     m = w.shape[-1]
-    wide = jnp.dot(x, jnp.concatenate(_bf16_parts(w), axis=-1),
+    wide = jnp.dot(x, jnp.concatenate(bf16_parts(w), axis=-1),
                    preferred_element_type=jnp.float32)  # (N, 3M)
     return wide[:, :m] + wide[:, m:2 * m] + wide[:, 2 * m:], (x, w)
 
@@ -119,8 +119,8 @@ def _project_fwd(x, w):
 def _project_bwd(res, g):
     x, w = res
     m = w.shape[-1]
-    g0, g1, g2 = _bf16_parts(g)
-    w0, w1, _ = _bf16_parts(w)
+    g0, g1, g2 = bf16_parts(g)
+    w0, w1, _ = bf16_parts(w)
     wide = jnp.dot(x.T, jnp.concatenate([g0, g1, g2], axis=-1),
                    preferred_element_type=jnp.float32)  # (K, 3M)
     dw = wide[:, :m] + wide[:, m:2 * m] + wide[:, 2 * m:]

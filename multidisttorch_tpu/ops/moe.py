@@ -26,9 +26,15 @@ expert computed whole; what the absent experts would add is left out,
 and nothing stands in for the exchange that would fetch it. The
 (token, expert) assignments that land here are sorted by expert, their
 tokens gathered into one buffer, run through grouped matrix products
-(``grouped_dot``: XLA's ``jax.lax.ragged_dot``, or on one TPU chip
-jax's Pallas grouped-matmul kernel, chosen by the model from the
-operands' placement) and summed back by weight. Shapes are static: the buffer holds twice the mean
+and summed back by weight, token by token. Every gather and every sum
+of that exchange, in the backward pass too, runs over the buffer's M
+rows and never over the ``N * top_k`` slots, most of which went to
+experts held elsewhere. Who makes the grouped products and the sums by
+token is the layer's ``grouped_dot`` (a :class:`GroupedDot`): XLA
+(``jax.lax.ragged_dot``, a scatter-add) or, on one TPU chip, kernels
+(jax's Pallas grouped matmul; ``token_sums`` of this file, the sums as
+0/1 matrices times the rows on the MXU), chosen by the model from the
+operands' placement. Shapes are static: the buffer holds twice the mean
 load, and a step whose routing sends more than that here (up to every
 token with all it can send) walks the expert order one buffer at a
 time, chosen by ``lax.cond`` on the step's own count.
@@ -36,13 +42,17 @@ time, chosen by ``lax.cond`` on the step's own count.
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from multidisttorch_tpu.ops.hyper_connection import bf16_parts
 from multidisttorch_tpu.ops.pallas_mode import pallas_interpret
 from multidisttorch_tpu.utils.profiling import (
     SCOPE_EXPERT_DISPATCH,
@@ -150,102 +160,243 @@ def moe_ep_shardings(trial, params: Any) -> Any:
 # ---------------------------------------------------------------------
 
 
-def _gather_tokens(x, tok):
+def _take(x, at):
+    """``x[at]`` along the first axis for indices known to be in range
+    (``jnp.take`` would clamp them and select the fill value)."""
+    return x.at[at].get(mode="promise_in_bounds")
+
+
+# The exchange between token order and expert order. Row r of the
+# buffer holds one (token, slot) pair and each pair has one row, so the
+# transpose of "gather the tokens" is "sum each token's rows" and the
+# reverse. Every gather fetches the buffer's M rows and every sum runs
+# over them (the layer's ``grouped_dot.token_sums``): of the N*k pairs
+# only those that landed here have a row, one in sixteen in
+# ``moe-mla-t4096``, and nothing is gathered to ``(N, k, d)``. ``tok``
+# is a row's token and ``pair`` its pair as one index, ``token * k +
+# slot``; a row past the step's count has the ``key`` N and the
+# ``pair`` N*k, one past the last, and is in no sum. Left to autodiff
+# both transposes would be XLA's scatter-add of (M, d) rows, slower on
+# the TPU than the ``(N, k, d)`` sums were (PERF.md section 6, PR 32's
+# race).
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(grouped_dot, x, tok, key):
     """Row ``r`` of the buffer is token ``tok[r]``: ``(N, d) -> (M, d)``."""
-    return jnp.take(x, tok, axis=0)
+    return _take(x, tok)
 
 
-def _sum_slots(ys, row, w):
-    """``out[t] = sum_j w[t, j] * ys[row[t, j]]`` in float32: a token's
-    ``k`` slots read back from the buffer. A slot of weight 0 adds
-    exactly 0 whatever its row holds (rows past the step's count are
-    never written)."""
-    picked = jnp.take(ys, row, axis=0).astype(jnp.float32)  # (N, k, d)
-    w = w[..., None]
-    return jnp.sum(jnp.where(w != 0, picked * w, 0.0), axis=1).astype(ys.dtype)
+def _dispatch_fwd(grouped_dot, x, tok, key):
+    # the zero-size slice keeps N, the one static thing the rule needs
+    return _take(x, tok), (key, x[:, :0])
 
 
-# Both directions of the exchange between token order and expert order
-# are gathers: row r holds one (token, slot) pair and each pair has one
-# row, so the transpose of "gather the tokens" is "sum each token's
-# slots" and the reverse. Autodiff would write both transposes as
-# scatter-adds of (M, d) rows.
-
-
-@jax.custom_vjp
-def _dispatch(x, tok, row, held):
-    return _gather_tokens(x, tok)
-
-
-def _dispatch_fwd(x, tok, row, held):
-    return _gather_tokens(x, tok), (tok, row, held)
-
-
-def _dispatch_bwd(res, g):
-    tok, row, held = res
-    return _sum_slots(g, row, held), None, None, None
+def _dispatch_bwd(grouped_dot, res, g):
+    key, x = res
+    return grouped_dot.token_sums(g, None, key, x.shape[0]), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def _combine(ys, tok, row, w, w_of_row):
-    return _sum_slots(ys, row, w)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combine(grouped_dot, ys, tok, key, pair, w):
+    """``out[t] = sum_j w[t, j] * ys[row of (t, j)]`` for ``w`` ``(N,
+    k)``, as the sum over the rows, each by the weight of its pair."""
+    return _combine_fwd(grouped_dot, ys, tok, key, pair, w)[0]
 
 
-def _combine_fwd(ys, tok, row, w, w_of_row):
-    return _sum_slots(ys, row, w), (ys, tok, row, w, w_of_row)
+def _combine_fwd(grouped_dot, ys, tok, key, pair, w):
+    w_of_row = w.reshape(-1).at[pair].get(mode="fill", fill_value=0)
+    return grouped_dot.token_sums(ys, w_of_row, key, w.shape[0]), (ys, tok, pair, w_of_row, w)
 
 
-def _combine_bwd(res, g):
-    ys, tok, row, w, w_of_row = res
-    g_ys = (_gather_tokens(g, tok).astype(jnp.float32) * w_of_row[:, None]).astype(ys.dtype)
-    picked = jnp.take(ys, row, axis=0).astype(jnp.float32)
-    g_w = jnp.einsum("nkd,nd->nk", picked, g.astype(jnp.float32))
-    g_w = jnp.where(w != 0, g_w, 0.0)
-    return g_ys, None, None, g_w, jnp.zeros_like(w_of_row)
+def _combine_bwd(grouped_dot, res, g):
+    ys, tok, pair, w_of_row, w = res
+    g_rows = _take(g, tok).astype(jnp.float32)
+    g_ys = (g_rows * w_of_row[:, None]).astype(ys.dtype)
+    # a pair's weight gets <its row, its token's cotangent>: M inner
+    # products, each put where its pair is; the pairs without a row get 0
+    g_w_of_row = jnp.sum(ys.astype(jnp.float32) * g_rows, axis=-1)
+    g_w = jnp.zeros((w.size,), w.dtype).at[pair].set(g_w_of_row, mode="drop", unique_indices=True)
+    return g_ys, None, None, None, g_w.reshape(w.shape)
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-# The experts' matrix products: rows ``sum(sizes[:g]) .. sum(sizes[:g+1])``
-# of ``lhs`` times ``rhs[g]``, float32 accumulated and out; rows past
-# ``sum(sizes)`` hold whatever (the layer masks them).
+# The layer's two products over ragged groups of rows, as one backend
+# makes them (``GroupedDot``).
+#
+# ``experts(lhs, rhs, sizes)``: rows ``sum(sizes[:g]) .. sum(sizes[:g+1])``
+# of ``lhs`` ``(M, K)`` times ``rhs[g]``, float32 accumulated and out;
+# rows past ``sum(sizes)`` hold whatever (the layer masks them).
+#
+# ``token_sums(rows, weight, key, n)``: ``out[t] = sum of weight[r] *
+# rows[r] over the r with key[r] == t``, ``(M, d) -> (n, d)``; ``weight``
+# ``None`` is 1. Each term and the sum are float32 and the sum is
+# rounded to the rows' dtype once; a row of key ``n`` is in no sum,
+# whatever it holds (NaN too).
 
-_TILE_ROWS = 512  # rows a tile of the kernel; mean load an expert in moe-mla-t4096
+
+class GroupedDot(NamedTuple):
+    experts: Callable  # (lhs (M, K), rhs (G, K, N), sizes (G,)) -> (M, N) float32
+    token_sums: Callable  # (rows (M, d), weight (M,) or None, key (M,), n) -> (n, d)
 
 
-def ragged_grouped_dot(lhs, rhs, sizes):
-    """XLA's own (``jax.lax.ragged_dot``): every backend, any shape."""
+_TILE_ROWS = 512  # rows a tile of the experts' kernel; mean load an expert in moe-mla-t4096
+_TOKEN_BLOCK = 256  # tokens a block of the sums' kernel
+_SUM_TILE_ROWS = 128  # rows a step of the sums' kernel; about what a block of tokens has
+
+
+def _widest_tile(width: int) -> int:
+    """The widest tile up to 1,024 that divides ``width`` into whole tiles."""
+    return next(t for t in range(1024, 0, -128) if width % t == 0)
+
+
+def _visits(key, n: int, block: int, tile: int):
+    """``(block_of, tile_of, count)``: the (token block, tile of rows)
+    pairs the sums' kernel visits, in order: each block with every tile
+    its rows reach into, an empty block once (it is written too), and
+    then, up to the static ``m // tile + n // block``, the last pair
+    again. ``count`` says how many of them are to be computed: none
+    where no row has a key under ``n``."""
+    blocks, tiles = n // block, key.shape[0] // tile
+    firsts = jnp.arange(blocks + 1, dtype=jnp.int32) * block
+    edge = jnp.sum(key[:, None] < firsts[None, :], axis=0, dtype=jnp.int32)  # rows before a block
+    lo, hi = edge[:-1], edge[1:]
+    first = jnp.minimum(lo // tile, tiles - 1)
+    reach = jnp.where(hi > lo, (hi - 1) // tile - first + 1, 1)
+    end = jnp.cumsum(reach)
+    visit = jnp.arange(tiles + blocks, dtype=jnp.int32)
+    block_of = jnp.minimum(
+        jnp.sum(end[None, :] <= visit[:, None], axis=1, dtype=jnp.int32), blocks - 1
+    )
+    within = jnp.minimum(visit - (end - reach)[block_of], reach[block_of] - 1)
+    return block_of, first[block_of] + within, jnp.where(edge[-1] > 0, end[-1], 0)[None]
+
+
+def _token_sums_kernel(block_of, tile_of, count, key, *refs, block: int):
+    *weight, rows, out, acc = refs  # the weights' column only where the rows have weights
+    visit, last = pl.program_id(1), pl.num_programs(1) - 1
+    here = block_of[visit]
+
+    @pl.when((visit == 0) | (block_of[jnp.maximum(visit - 1, 0)] != here))
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+
+    @pl.when(visit < count[0])
+    def _():
+        # 1 where the row's token is that position of this block; the
+        # rows of another block's tokens match none
+        at = key[...] - here * block  # (1, tile)
+        select = jax.lax.broadcasted_iota(jnp.int32, (block, at.shape[1]), 0) == at
+        select = select.astype(jnp.bfloat16)
+        # the matrix is exact in bf16, and so has to be each term: a
+        # bf16 row of weight 1 is, a float32 term goes as the three
+        # bf16 parts that add up to it
+        terms = rows[...]
+        if weight or terms.dtype != jnp.bfloat16:
+            terms = terms.astype(jnp.float32)
+            terms = bf16_parts(terms * weight[0][...] if weight else terms)
+        else:
+            terms = [terms]
+        for part in terms:
+            acc[...] += jnp.dot(select, part, preferred_element_type=jnp.float32)
+
+    @pl.when((visit == last) | (block_of[jnp.minimum(visit + 1, last)] != here))
+    def _():
+        out[...] = acc[...].astype(out.dtype)
+
+
+@functools.partial(jax.jit, static_argnames="n")  # one lowering of the kernel a shape, not one a call
+def _kernel_token_sums(rows, weight, key, n: int):
+    """The sums as products on the MXU. The rows are brought into token
+    order (a sort of M keys, one M-row gather) and the tokens cut into
+    blocks; a block's rows are then a contiguous, ragged range, and its
+    sums are a 0/1 matrix ``(block, rows)`` times those rows, a tile of
+    rows a step. The matrix is made from the keys and the terms from
+    the rows in VMEM; neither is ever in HBM."""
+    m, d = rows.shape
+    block, tile, columns = math.gcd(n, _TOKEN_BLOCK), _SUM_TILE_ROWS, _widest_tile(d)
+    by_token = jnp.argsort(key)
+    key = _take(key, by_token)
+    # a place past the rows that count fetches the first row: what the
+    # kernel multiplies by 0 was then written by the step (unless no
+    # row counts, and then it computes nothing)
+    by_token = jnp.where(key < n, by_token, by_token[0])
+    index = lambda j, v, block_of, tile_of, count: (tile_of[v], j)
+    operands, specs = [_take(rows, by_token)], [pl.BlockSpec((tile, columns), index)]
+    if weight is not None:  # a column, to scale the rows by
+        operands.insert(0, _take(weight, by_token)[:, None])
+        specs.insert(0, pl.BlockSpec((tile, 1), lambda j, v, *visits: index(0, v, *visits)))
+    return pl.pallas_call(
+        functools.partial(_token_sums_kernel, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(d // columns, m // tile + n // block),
+            in_specs=[
+                pl.BlockSpec((1, tile), lambda j, v, block_of, tile_of, count: (0, tile_of[v])),
+                *specs,
+            ],
+            out_specs=pl.BlockSpec(
+                (block, columns), lambda j, v, block_of, tile_of, count: (block_of[v], j)
+            ),
+            scratch_shapes=[pltpu.VMEM((block, columns), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, d), rows.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=pallas_interpret(),
+        name="token_sums",
+    )(*_visits(key, n, block, tile), key[None, :], *operands)
+
+
+def _segment_token_sums(rows, weight, key, n: int):
+    terms = rows.astype(jnp.float32)
+    if weight is not None:
+        terms = terms * weight[:, None]
+    # a key of n is out of range, and what is out of range is dropped
+    return jax.ops.segment_sum(terms, key, num_segments=n).astype(rows.dtype)
+
+
+def _ragged_experts(lhs, rhs, sizes):
     return jax.lax.ragged_dot(lhs, rhs, sizes, preferred_element_type=jnp.float32)
 
 
-def kernel_grouped_dot(lhs, rhs, sizes):
-    """jax's Pallas grouped matmul for the TPU (megablox ``gmm``, with
-    its own backward kernels): only the tiles that hold rows of a group
-    are visited. XLA's ragged dot, expanded by the TPU compiler, ran
-    the cell's experts no faster and its operations carry no scope
-    path, so a trace could not say whose they were (PERF.md section 6)."""
+def _kernel_experts(lhs, rhs, sizes):
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    # the widest tile up to 1,024 that divides the width into whole tiles
-    tile = lambda width: next(t for t in range(1024, 0, -128) if width % t == 0)
     return gmm(
-        lhs, rhs, sizes, jnp.float32, (_TILE_ROWS, *map(tile, rhs.shape[1:])),
+        lhs, rhs, sizes, jnp.float32, (_TILE_ROWS, *map(_widest_tile, rhs.shape[1:])),
         interpret=pallas_interpret(),
     )
+
+
+# XLA's own (``jax.lax.ragged_dot``, and a scatter-add of the rows):
+# every backend, any shape.
+ragged_grouped_dot = GroupedDot(_ragged_experts, _segment_token_sums)
+
+# The TPU's kernels: for the experts jax's Pallas grouped matmul
+# (megablox ``gmm``, with its own backward kernels), which visits only
+# the tiles that hold rows of a group (XLA's ragged dot, expanded by
+# the TPU compiler, ran the cell's experts no faster and its operations
+# carry no scope path, so a trace could not say whose they were:
+# PERF.md section 6); for the sums a kernel of this file.
+kernel_grouped_dot = GroupedDot(_kernel_experts, _kernel_token_sums)
 
 
 def grouped_dot_takes_kernel(
     device_kind: str, num_devices: int, rows: int, k: int, n: int
 ) -> bool:
     """Whether an expert layer that was given no ``grouped_dot`` runs
-    the Pallas kernel (``models/latent_moe.py`` asks, with what tracing
-    shows of the operands' placement, as ``default_takes_kernel`` is
-    asked for the attention) or XLA's ragged dot: a TPU, operands on
-    one device, whole tiles of rows and whole lanes of both widths."""
+    a product as the Pallas kernel (``models/latent_moe.py`` asks, with
+    what tracing shows of the operands' placement, as
+    ``default_takes_kernel`` is asked for the attention) or as XLA's
+    form: a TPU, operands on one device, whole tiles of rows and whole
+    lanes of both widths. The experts' product is ``(rows, k)`` by
+    ``(k, n)``; the sums by token take ``rows`` rows ``n`` wide to
+    ``k`` tokens."""
     return (
         device_kind.startswith("TPU")
         and num_devices == 1
@@ -287,7 +438,7 @@ class RoutedExperts(nn.Module):
     shared_hidden_dim: int = 0
     routed_scaling: float = 1.0
     dtype: Any = jnp.float32
-    grouped_dot: Callable = ragged_grouped_dot  # (lhs, rhs, sizes) -> float32
+    grouped_dot: GroupedDot = ragged_grouped_dot  # who makes the layer's two products
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -326,13 +477,11 @@ class RoutedExperts(nn.Module):
             held = (local >= 0) & (local < count)
             # expert order, the assignments to absent experts last
             group = jnp.where(held, local, count).reshape(n * k)
-            order = jnp.argsort(group, stable=True)  # row -> assignment
-            row_of = jnp.argsort(order).reshape(n, k)  # assignment -> row
+            order = jnp.argsort(group, stable=True)  # row -> pair, as token * k + slot
             counts = jnp.sum(
                 group[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32
             )
             total = jnp.sum(counts)
-            weights = jnp.where(held, weights, 0.0)
 
         with jax.named_scope(SCOPE_EXPERTS):
             w_gate_up = jnp.concatenate([w_gate, w_up], axis=-1).astype(self.dtype)
@@ -340,43 +489,47 @@ class RoutedExperts(nn.Module):
         def routed(start, rows: int, grouped_dot=self.grouped_dot):
             """The held experts' part of rows ``start .. start + rows``
             of the expert order, through a buffer of ``rows`` rows."""
-            assignment = jax.lax.dynamic_slice_in_dim(order, start, rows)
-            tok = assignment // k
-            inside = (row_of >= start) & (row_of < start + rows)
-            row = jnp.clip(row_of - start, 0, rows - 1)
-            w = jnp.where(inside, weights, 0.0)  # weight 0 outside the buffer
-            xs = _dispatch(x, tok, row, (held & inside).astype(jnp.float32))
+            pair = jax.lax.dynamic_slice_in_dim(order, start, rows)
+            tok = pair // k
+            valid = start + jnp.arange(rows) < total  # rows the step's count reaches
+            pair = jnp.where(valid, pair, n * k)
+            key = pair // k
+            xs = _dispatch(grouped_dot, x, tok, key)
             ends = jnp.cumsum(counts)
             in_buffer = lambda at: jnp.clip(at - start, 0, rows)
             sizes = in_buffer(ends) - in_buffer(ends - counts)
             with jax.named_scope(SCOPE_EXPERTS):
                 # operands as they come (bf16), float32 accumulated and
                 # out; gate and up as one product, so xs is read once
-                gate_up = grouped_dot(xs, w_gate_up, sizes)
+                gate_up = grouped_dot.experts(xs, w_gate_up, sizes)
                 act = (nn.silu(gate_up[:, :h]) * gate_up[:, h:]).astype(self.dtype)
-                ys = grouped_dot(act, w_down.astype(self.dtype), sizes).astype(self.dtype)
-            ys = jnp.where((start + jnp.arange(rows) < total)[:, None], ys, 0)
-            w_of_row = jnp.take(w.reshape(n * k), assignment)
-            return _combine(ys, tok, row, w, w_of_row)
+                ys = grouped_dot.experts(act, w_down.astype(self.dtype), sizes)
+                ys = ys.astype(self.dtype)
+            ys = jnp.where(valid[:, None], ys, 0)
+            return _combine(grouped_dot, ys, tok, key, pair, weights)
 
         # A step that sends more than the usual buffer holds (nothing
         # is dropped) walks the expert order a buffer at a time, each
         # recomputed in the backward pass, so that the worst case
         # sizes no temporary. The walk multiplies with XLA's ragged
-        # dot whatever the layer was given: a second set of kernels in
-        # the seldom-taken branch would add 8 MB to a step's cached
-        # executables, which are near the chip machine's cache limit
-        # (PERF.md section 6). All of it is the exchange's scope but
-        # the experts' products, which name their own inside it.
+        # dot whatever the layer was given: a second set of those
+        # kernels in the seldom-taken branch would add 8 MB to a
+        # step's cached executables, which are near the chip machine's
+        # cache limit (PERF.md section 6); the sums by token stay the
+        # layer's, a tenth of that. All of it is the exchange's scope
+        # but the experts' products, which name their own inside it.
         usual, worst = _buffer_rows(n, k, count, e)
         with jax.named_scope(SCOPE_EXPERT_DISPATCH):
             if usual < worst:
                 order = jnp.pad(order, (0, -worst % usual))
 
                 def walk():
-                    one = jax.checkpoint(
-                        lambda at: routed(at, usual, ragged_grouped_dot).astype(jnp.float32)
-                    )
+                    dots = self.grouped_dot._replace(experts=ragged_grouped_dot.experts)
+                    nothing = lambda: jnp.zeros((n, d), jnp.float32)
+                    # a buffer past the step's count holds no row and is not run
+                    one = jax.checkpoint(lambda at: jax.lax.cond(
+                        at < total, lambda: routed(at, usual, dots).astype(jnp.float32), nothing
+                    ))
                     out, _ = jax.lax.scan(
                         lambda acc, at: (acc + one(at), None),
                         jnp.zeros((n, d), jnp.float32),

@@ -141,8 +141,8 @@ def test_one_chip_latent_attention_step_runs_the_kernel(as_tpu, remat):
     model = _latent_lm(remat=remat)
     (group,) = setup_groups(1, devices=jax.devices()[:1])
     jaxpr = _step_jaxpr(group, model)
-    assert _count(jaxpr, "pallas_call") == LAYERS * 2
-    assert _kernels(jaxpr) == {"latent_fwd": LAYERS, "latent_bwd": LAYERS}
+    # beside them the expert layer's sums by token (d_model is whole lanes)
+    assert _kernels(jaxpr) == {"latent_fwd": LAYERS, "latent_bwd": LAYERS, "token_sums": 2}
     assert not _assembled_heads(jaxpr)
     (four,) = setup_groups(1, devices=jax.devices()[:4])
     assert _count(_step_jaxpr(four, model), "pallas_call") == 0
@@ -153,24 +153,27 @@ def test_injected_attention_gets_latent_attention_assembled(as_tpu):
     192 a head, on one chip too: the kernel at the padded width."""
     (group,) = setup_groups(1, devices=jax.devices()[:1])
     jaxpr = _step_jaxpr(group, _latent_lm(attention=make_flash_attention(causal=True)))
-    assert _kernels(jaxpr) == {"flash_fwd": LAYERS, "flash_bwd": LAYERS}
+    assert _kernels(jaxpr) == {"flash_fwd": LAYERS, "flash_bwd": LAYERS, "token_sums": 2}
     made = _assembled_heads(jaxpr)
     assert ("concatenate", (4, T, 2, 192)) in made and ("pad", (4, T, 2, 256)) in made
 
 
 # The latent-attention LM's step (remat) where the parts' rule says no,
 # equation by primitive: counted at the parent of the PR that brought
-# the kernel on the parts (PR 30).
+# the kernel on the parts (PR 30), and again at PR 32, whose expert
+# layer sums over the buffer's rows (a sort of every slot, a gather
+# and a product over them less; a scatter of the weights' gradient
+# and one scatter-add more).
 _ASSEMBLED_STEP = {
-    "add": 225, "add_any": 50, "and": 15, "broadcast_in_dim": 244, "concatenate": 27,
-    "convert_element_type": 51, "cos": 8, "cumsum": 2, "div": 155, "dot_general": 86,
-    "dynamic_slice": 2, "eq": 18, "exp": 5, "gather": 15, "ge": 4, "integer_pow": 67, "iota": 29,
-    "jit": 125, "le": 4, "log": 1, "logistic": 8, "lt": 35, "max": 11, "min": 6, "mul": 353,
-    "ne": 23, "neg": 26, "pad": 29, "pow": 10, "ragged_dot_general": 8, "reduce_max": 5,
-    "reduce_sum": 71, "rem": 10, "remat2": 2, "reshape": 96, "reshard": 5, "rsqrt": 17,
-    "scatter-add": 6, "select_n": 66, "sign": 4, "sin": 8, "slice": 59, "sort": 4, "split": 13,
-    "sqrt": 32, "square": 17, "squeeze": 1, "stop_gradient": 7, "sub": 43, "top_k": 2,
-    "transpose": 32,
+    "add": 225, "add_any": 49, "and": 14, "broadcast_in_dim": 234, "concatenate": 27,
+    "convert_element_type": 43, "cos": 8, "cumsum": 2, "div": 157, "dot_general": 85,
+    "dynamic_slice": 2, "eq": 18, "exp": 5, "gather": 13, "ge": 2, "integer_pow": 67, "iota": 27,
+    "jit": 103, "le": 4, "log": 1, "logistic": 8, "lt": 32, "max": 9, "min": 4, "mul": 353,
+    "ne": 24, "neg": 26, "pad": 29, "pow": 10, "ragged_dot_general": 8, "reduce_max": 5,
+    "reduce_sum": 70, "rem": 12, "remat2": 2, "reshape": 95, "reshard": 7, "rsqrt": 17,
+    "scatter": 1, "scatter-add": 7, "select_n": 60, "sign": 8, "sin": 8, "slice": 60, "sort": 2,
+    "split": 13, "sqrt": 32, "square": 17, "squeeze": 1, "stop_gradient": 7, "sub": 43,
+    "top_k": 2, "transpose": 32,
 }
 
 
@@ -185,7 +188,7 @@ def test_latent_attention_step_elsewhere_is_the_assembled_one(devices, request):
     if devices == 4:
         request.getfixturevalue("as_tpu")
         assert _counts(_step_jaxpr(group, _latent_lm(remat=True))) == counts
-    assert sum(_counts(_step_jaxpr(group, _latent_lm())).values()) == 1668
+    assert sum(_counts(_step_jaxpr(group, _latent_lm())).values()) == 1616
 
 
 # The parameter tree of the latent-attention LM: what checkpoints and
@@ -216,7 +219,7 @@ def test_both_paths_share_one_parameter_tree_and_one_init(monkeypatch):
     assert set(shapes["block_1"]) - set(_LATENT_ATTENTION_TREE) == {"moe"}
     monkeypatch.setattr(transformer, "_placement", lambda x: (V5E, 1))
     traced = jax.make_jaxpr(lambda: model.init(jax.random.key(3), tokens))()
-    assert _kernels(traced) == {"latent_fwd": LAYERS}
+    assert _kernels(traced) == {"latent_fwd": LAYERS, "token_sums": 1}  # the expert layer's output
     by_parts = model.init(jax.random.key(3), tokens)["params"]
     assert jax.tree.structure(by_parts) == jax.tree.structure(assembled)
     for a, b in zip(jax.tree.leaves(by_parts), jax.tree.leaves(assembled), strict=True):
@@ -253,7 +256,7 @@ def test_kernel_on_the_parts_matches_the_assembled_path(monkeypatch, dtype, tol)
     rel = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
     for remat in (False, True):
         kernels, (got, grads) = loss_and_grads(_latent_lm(dtype=dtype, remat=remat))
-        assert kernels == {"latent_fwd": LAYERS, "latent_bwd": LAYERS}
+        assert kernels == {"latent_fwd": LAYERS, "latent_bwd": LAYERS, "token_sums": 2}
         assert abs(float(got) - float(want)) < tol * float(want)
         worst = max(jax.tree.leaves(jax.tree.map(rel, grads, want_grads)))
         assert worst < tol, worst
@@ -279,8 +282,10 @@ def test_grouped_dot_rule(device_kind, num_devices, rows, k, n, kernel):
 def test_one_chip_expert_layer_runs_the_grouped_kernel(as_tpu):
     """On one chip, at shapes the kernel tiles, the experts' products
     are Pallas calls too: two forward (gate and up as one, then down)
-    and for each of them the two of its backward; over several chips,
-    as on the CPU, they are XLA's ragged dot."""
+    and for each of them the two of its backward, and so are the two
+    sums of the buffer's rows by token (the layer's output, and the
+    gradient of its input); over several chips, as on the CPU, they are
+    XLA's ragged dot and scatter-add."""
     from multidisttorch_tpu.models.latent_moe import LatentMoELM
 
     model = LatentMoELM(
@@ -289,7 +294,9 @@ def test_one_chip_expert_layer_runs_the_grouped_kernel(as_tpu):
     )
     (group,) = setup_groups(1, devices=jax.devices()[:1])
     # T = 256 with widths 24/16: the attention stays dense, so every call is the experts'
-    assert _count(_step_jaxpr(group, model), "pallas_call") == 2 * 3
+    jaxpr = _step_jaxpr(group, model)
+    assert _kernels(jaxpr)["token_sums"] == 2
+    assert _count(jaxpr, "pallas_call") == 2 * 3 + 2
     (four,) = setup_groups(1, devices=jax.devices()[:4])
     assert _count(_step_jaxpr(four, model), "pallas_call") == 0
 

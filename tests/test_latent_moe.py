@@ -179,6 +179,39 @@ def test_no_token_is_dropped_under_uneven_routing(rows):
             assert not jnp.any(got_g)
 
 
+def test_a_walk_skips_the_buffers_past_the_count():
+    """A bias on two of the four experts held sends them two choices of
+    every token: more than the usual buffer of 128 rows and fewer than
+    the worst case's 256, so the walk's last buffers hold no row and
+    are not run. Output, gradients and counter are the reference's."""
+    x = jax.random.normal(jax.random.key(4), (64, 32))
+    co = jax.random.normal(jax.random.key(9), (64, 32))
+    layer, config = _layer((4, 4)), _config(experts_held=[4, 4])
+    names = {"router": "router", "score_bias": "score_bias", "e_gate": "w_gate",
+             "e_up": "w_up", "e_down": "w_down"}
+    none = {"s_gate": jnp.zeros((32, 1)), "s_up": jnp.zeros((32, 1)), "s_down": jnp.zeros((1, 32))}
+    with jax.default_matmul_precision("highest"):
+        params = layer.init(jax.random.key(5), x)["params"]
+        params["score_bias"] = params["score_bias"].at[4:6].add(10.0)
+
+        def program(p, x):
+            out, counts = layer.apply({"params": p}, x)
+            return jnp.sum(out * co), counts
+
+        def reference(p, x):
+            out, _, counts = REFERENCE.experts(
+                x, {ref: p[own] for ref, own in names.items()} | none, config)
+            return jnp.sum(out * co), counts
+
+        grad = lambda f: jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(params, x)
+        ((got, counts), grads), ((want, want_counts), want_grads) = grad(program), grad(reference)
+    np.testing.assert_array_equal(counts, want_counts)
+    assert 128 < int(counts.sum()) <= 192  # two or three of the four buffers hold rows
+    assert abs(float(got) - float(want)) < 1e-5 * abs(float(want))
+    for got_g, want_g in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads), strict=True):
+        assert _rel(got_g, want_g) < 1e-4 if jnp.any(want_g) else not jnp.any(got_g)
+
+
 def test_selection_bias_moves_the_choice_and_not_the_weights():
     x = jax.random.normal(jax.random.key(6), (32, 32))
     layer = _layer((0, 16))
@@ -267,10 +300,155 @@ def test_kernel_grouped_dot_matches_xla_ragged_dot():
         (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(lhs, rhs)
         return out, grads
 
-    out, (g_lhs, g_rhs) = run(kernel_grouped_dot)
-    want, (w_lhs, w_rhs) = run(ragged_grouped_dot)
+    out, (g_lhs, g_rhs) = run(kernel_grouped_dot.experts)
+    want, (w_lhs, w_rhs) = run(ragged_grouped_dot.experts)
     assert out.dtype == jnp.float32 and out.shape == (1024, 256)
     rel = lambda a, b: _rel(a.astype(jnp.float32), b.astype(jnp.float32))
     assert rel(out, want) < 1e-5
     assert rel(g_lhs, w_lhs) < 2e-2 and rel(g_rhs, w_rhs) < 2e-2  # bf16 cotangents
     assert not jnp.any(g_rhs[1])  # the empty group's weights get no gradient
+
+
+# --- the exchange on the buffer's rows (ops/moe.py: _dispatch, _combine) ---
+
+_EX = {"n": 768, "k": 4, "m": 1024, "d": 128}  # three blocks of 256 tokens, eight tiles of 128 rows
+
+
+def _exchange_case(case, dtype, seed=11):
+    """A buffer of ``m`` rows for ``n`` tokens of ``k`` slots: which
+    slots have a row (``total`` of them, rows ``0 .. total`` in random
+    order, as the expert order leaves them), their weights, and rows
+    past ``total`` holding NaN and any token. Token 300 has all its
+    slots held in every case with a row at all."""
+    n, k, m, d = (_EX[name] for name in "nkmd")
+    rng = np.random.default_rng(seed)
+    tokens = np.arange(256, 512) if case == "empty_blocks" else np.arange(n)
+    slots = [(t, j) for t in tokens for j in range(k)]
+    total = {"none": 0, "full": m, "some": 600, "empty_blocks": 500}[case]
+    rest = [s for s in slots if s[0] != 300]
+    picked = [(300, j) for j in range(k)] + [rest[i] for i in rng.permutation(len(rest))]
+    picked = [picked[i] for i in rng.permutation(total)] if total else []
+    row = np.zeros((n, k), np.int32)
+    held = np.zeros((n, k), bool)
+    tok = rng.integers(0, n, m).astype(np.int32)
+    for r, (t, j) in enumerate(picked):
+        row[t, j], held[t, j], tok[r] = r, True, t
+    w = np.where(held, rng.uniform(0.05, 1.0, (n, k)), 0.0).astype(np.float32)
+    valid = np.arange(m) < total
+    w_of_row = np.zeros(m, np.float32)
+    w_of_row[row[held]] = w[held]
+    pair = np.full(m, n * k, np.int32)  # a row's (token, slot) as one index; none: one past the last
+    pair[row[held]] = np.flatnonzero(held.reshape(-1))
+    ys = rng.standard_normal((m, d)).astype(np.float32)
+    ys = jnp.asarray(ys).astype(dtype)
+    return dict(
+        row=jnp.asarray(row), held=jnp.asarray(held), tok=jnp.asarray(tok), w=jnp.asarray(w),
+        valid=jnp.asarray(valid), key=jnp.asarray(np.where(valid, tok, n)), pair=jnp.asarray(pair),
+        w_of_row=jnp.asarray(w_of_row), ys=ys, ys_nan=jnp.where(valid[:, None], ys, jnp.nan),
+        x=jnp.asarray(rng.standard_normal((n, d)).astype(np.float32)).astype(dtype),
+        g_out=jnp.asarray(rng.standard_normal((n, d)).astype(np.float32)).astype(dtype),
+    )
+
+
+def _half_ulp_bf16(a):
+    """Half a unit in the last of bf16's 8 significant bits, at ``a``."""
+    return 2.0 ** (np.floor(np.log2(np.maximum(np.abs(a), 1e-30))) - 8)
+
+
+def _assert_rounded_once(got, want, dtype):
+    """``got`` is the float32 ``want`` within float32's own rounding
+    (1e-6), or rounded to bf16 once (half a unit in the last of 8
+    bits, and the float32 noise under it)."""
+    assert got.dtype == dtype
+    got, want = np.asarray(got.astype(jnp.float32)), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    scale = np.abs(want).max() if want.size and np.abs(want).max() > 0 else 1.0
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6 * scale)
+    else:
+        assert (np.abs(got - want) <= _half_ulp_bf16(want) * (1 + 1e-3) + 1e-6 * scale).all()
+
+
+@pytest.mark.parametrize("case", ["some", "none", "full", "empty_blocks"])
+@pytest.mark.parametrize("path", ["plain", "kernel"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_exchange_sums_over_the_rows_that_landed(dtype, path, case):
+    """``_combine``, its two transposes and ``_dispatch``'s against the
+    plain float32 statement of them over a token's slots: ``out[t] =
+    sum_j w[t, j] * ys[row[t, j]]``. Both forms of the sums (XLA's
+    scatter-add; the kernel, interpreted), a buffer with no row, a full
+    one, token blocks with no row, a token with every slot held; the
+    rows past the step's count hold NaN and are never read."""
+    from multidisttorch_tpu.ops import moe
+
+    dot = {"plain": moe.ragged_grouped_dot, "kernel": moe.kernel_grouped_dot}[path]
+    c = _exchange_case(case, dtype)
+    f32 = lambda a: a.astype(jnp.float32)
+    slots = lambda rows: jnp.where(c["held"][..., None], f32(rows)[c["row"]], 0.0)  # (n, k, d)
+
+    # forward: the weighted sum of a token's slots
+    out, back = jax.vjp(
+        lambda ys, w: moe._combine(dot, ys, c["tok"], c["key"], c["pair"], w), c["ys_nan"], c["w"]
+    )
+    _assert_rounded_once(out, jnp.sum(c["w"][..., None] * slots(c["ys"]), axis=1), dtype)
+    # its transposes: a row gets its token's cotangent by its weight, a
+    # slot's weight the inner product of its row and that cotangent
+    g_ys, g_w = back(c["g_out"])
+    want_g_ys = jnp.where(c["valid"][:, None], c["w_of_row"][:, None] * f32(c["g_out"])[c["tok"]], 0.0)
+    _assert_rounded_once(g_ys, want_g_ys, dtype)
+    want_g_w = jnp.sum(slots(c["ys"]) * f32(c["g_out"])[:, None, :], axis=-1)
+    np.testing.assert_allclose(g_w, want_g_w, rtol=1e-5, atol=1e-5)
+
+    # the gather's transpose: every held slot's row, weight 1
+    xs, back = jax.vjp(lambda x: moe._dispatch(dot, x, c["tok"], c["key"]), c["x"])
+    np.testing.assert_array_equal(f32(xs), f32(c["x"])[c["tok"]])
+    g_rows = jnp.where(c["valid"][:, None], c["ys"], jnp.nan)  # NaN past the count here too
+    (g_x,) = back(g_rows)
+    _assert_rounded_once(g_x, jnp.sum(slots(c["ys"]), axis=1), dtype)
+
+
+def _all_equations(jaxpr):
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                if hasattr(sub, "eqns") or hasattr(getattr(sub, "jaxpr", None), "eqns"):
+                    yield from _all_equations(sub)
+
+
+@pytest.mark.parametrize("path", ["plain", "kernel"])
+def test_nothing_is_gathered_to_every_slot(path):
+    """The traced layer, forward and backward, the usual buffer and
+    the walk: no value of ``n * k * d`` elements with rows ``d`` wide
+    (what a gather of every token's ``k`` slots would make); the sums
+    by token are the kernel's calls or XLA's scatter-adds
+    (``tests/test_lm_scopes.py`` finds them under the exchange's
+    scope in the compiled step)."""
+    from multidisttorch_tpu.ops import moe
+
+    n, k, d = 512, 4, 256
+    dot = {"plain": moe.ragged_grouped_dot, "kernel": moe.kernel_grouped_dot}[path]
+    layer = RoutedExperts(
+        num_experts=16, experts_held=(4, 4), top_k=k, hidden_dim=128, shared_hidden_dim=128,
+        dtype=jnp.bfloat16, grouped_dot=dot, name="moe",
+    )
+    x = jax.ShapeDtypeStruct((n, d), jnp.bfloat16)
+    params = jax.eval_shape(lambda: layer.init(jax.random.key(0), jnp.zeros((n, d), jnp.bfloat16)))
+    assert moe._buffer_rows(n, k, 4, 16) == (1024, 2048)  # the walk is in the trace
+
+    def loss(p, x):
+        return jnp.sum(layer.apply(p, x)[0].astype(jnp.float32))
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+    kernels = scatters = 0
+    for eqn in _all_equations(jaxpr):
+        for var in eqn.outvars:
+            shape = var.aval.shape
+            assert not (shape and shape[-1] == d and var.aval.size >= n * k * d), (eqn, shape)
+        kernels += eqn.primitive.name == "pallas_call" and eqn.params["name"] == "token_sums"
+        scatters += eqn.primitive.name == "scatter-add"
+    # the layer's output and its input's gradient, in the usual buffer and in the
+    # walk: kernel calls, or scatter-adds
+    assert kernels == (4 if path == "kernel" else 0)
+    assert scatters >= (0 if path == "kernel" else 4)

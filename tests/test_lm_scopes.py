@@ -213,6 +213,11 @@ def test_expert_layer_scopes_reach_the_compiled_step(remat):
         for scope in inner:
             assert any({f"block_{i}", "moe", scope} <= set(_components(n)) for n in step)
     assert not any("moe" in _components(n) and "block_0" in _components(n) for n in step)
+    # the sums of the buffer's rows by token (here XLA's scatter-adds), the layer's
+    # output and, from a custom_vjp's backward rule, its input's gradient: the exchange's
+    sums = [n for n in step if n.endswith(f"{SCOPE_EXPERT_DISPATCH}/scatter-add")]
+    assert {moe_scopes.classify(n) for n in sums} == {SCOPE_EXPERT_DISPATCH}
+    assert {_pass(n) for n in sums} >= {"forward", "backward"}
     # nearly all of the layer is under one of the four
     in_moe = [n for n in step if moe_scopes.classify(n) is not None]
     other = [n for n in in_moe if moe_scopes.classify(n) == moe_scopes.OTHER]
@@ -239,7 +244,7 @@ def test_latent_attention_on_the_parts_keeps_the_scopes(monkeypatch, remat):
     column groups under ``q``, ``k`` and ``v``, the key's rotation
     under ``k``, the two kernels (which rotate q) under ``attn_core``
     once a pass, and nothing new without a name."""
-    from benchmark import scope_reduce
+    from benchmark import moe_scopes, scope_reduce
     from multidisttorch_tpu.models import transformer
 
     real = transformer._placement
@@ -261,6 +266,10 @@ def test_latent_attention_on_the_parts_keeps_the_scopes(monkeypatch, remat):
         for i in range(LAYERS):
             assert any({f"block_{i}", call} <= set(n.split("/")) for n in step)
     assert not any("flash" in n for n in step)
+    # the expert layer's sums by token are a kernel here too, the exchange's in both passes
+    sums = [n for n in step if "token_sums" in n.split("/")]
+    assert {moe_scopes.classify(n) for n in sums} == {"expert_dispatch"}
+    assert {_pass(n) for n in sums} >= {"forward", "backward"}
     every = {"forward", "backward"} | ({"recompute"} if remat else set())
     for scope, module in (("q", "q_b"), ("k", "kv_b"), ("v", "kv_b")):
         under = [n for n in step if {scope, module} <= set(_components(n))]
