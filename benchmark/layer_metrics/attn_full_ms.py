@@ -1,0 +1,13 @@
+"""Device time of one optimizer step under the ``attn_full`` scope,
+every pass: the attention core of the layers that see the whole past
+(``swa_scopes.py``). Part of what ``attn_core_ms`` reads."""
+
+from benchmark import swa_scopes
+
+LAYER = "step programs"
+UNIT = "ms"
+MOVES = "tokens_per_s_per_chip"
+
+
+def read(record: dict):
+    return swa_scopes.ms_per_step(record, "attn_full")

@@ -1,0 +1,252 @@
+"""Causal LM of grouped-head attention in a layer pattern and routed
+experts whose router reads the block's input.
+
+The block of the window/full hybrid expert models, which neither
+``models/transformer.py`` nor ``models/latent_moe.py`` expresses: fewer
+KV heads than query heads, a pattern by layer index of which layers see
+the whole past and which a sliding window, and of which layers carry
+rotary positions and which none at all, and in every layer an expert
+layer whose router scores what attention reads, before attention
+(``ops.moe.RoutedExperts`` with ``scoring="softmax"``, ReGLU experts,
+no shared expert). A trial gets it as it gets ``LatentMoELM``: plain
+fields, a state from ``create_lm_state``, a step from
+``make_lm_train_step``.
+
+Per layer, with ``H`` query heads over ``Hkv`` KV heads of width
+``head_dim``::
+
+    y = RMSNorm(x)
+    r = y W_r  (float32);  chosen = top_k(r);  w = softmax(r[chosen])
+    q = y W_q as (H, head_dim);  k = y W_k, v = y W_v as (Hkv, head_dim)   # head h reads KV head h // (H / Hkv)
+    rope_layout[layer] = 1: q and k rotated over the whole head, element i with i + head_dim/2,
+        angle pos * theta**(-2i/head_dim);  0: not rotated, no positions at all
+    s_ij = q_i . k_j / sqrt(head_dim), kept where j <= i, and where window_layout[layer] = 1 also i - j < window
+    x1 = x + softmax(s) v W_o
+    z = RMSNorm(x1)
+    x2 = x1 + sum over e in chosen, held here:  w_e W_down,e (relu(W_gate,e z) * (W_up,e z))
+
+then the final RMSNorm and an untied head; no biases.
+
+**Which attention runs where.** Given no ``attention``, on one TPU chip,
+at a T that 128 divides and heads 128 wide
+(``ops.pallas_attention.grouped_takes_kernel``), the core is
+``ops.pallas_attention.grouped_attention``: kernels that read q, k and v
+flat as the projections leave them, fetch a group's K/V block once for
+its query heads, visit only the blocks the mask keeps and rotate q as
+they load it; k is rotated here (``Hkv`` heads). Everywhere else (the
+CPU, several chips, toy widths) q is rotated here too and the core is
+``blocked_window_attention``, XLA's masked softmax in query blocks.
+Decided while tracing, from the operands alone. An injected
+``attention`` has ``grouped_attention``'s signature.
+
+**One chip's share.** ``experts_held = (first, count)`` as in
+``LatentMoELM``; a sliced vocabulary is a smaller ``vocab_size``. A
+share that is trained alone, with no shared expert to answer the tokens
+whose experts are all elsewhere, teaches its routers that only the
+experts held answer, and within tens of steps every token chooses them;
+``absent_share_grad=False`` (``RoutedExperts``' field) keeps that from
+the backward pass and changes nothing forward.
+
+**The embedding's deviation.** ``embed_stddev`` ``None`` draws the rows
+as ``nn.Embed`` does (``1 / sqrt(d_model)``). At random weights every
+sublayer's output has entries of unit size (lecun-normal matrices after
+a norm), so rows that small drown in the first of them, and from the
+third layer on every token reads alike to the routers: a caller that
+wants seeded weights to route as a trained model's do (tokens told
+apart, an even load) gives a deviation of its own, as a benchmark
+configuration's file does.
+
+The model returns ``(logits, {"expert_counts": (layers, count)
+int32})``.
+
+Names: ``ln_attn``, ``q``, ``k``, ``v``, ``proj``, ``ln_mlp`` and
+``moe`` are flax modules (the rotations run under the scopes ``q`` and
+``k``); the core runs under ``attn_core`` and inside it under
+``attn_full`` or ``attn_window``; the router's operations are
+``moe/router`` like the rest of the expert layer's, wherever XLA
+schedules them.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from multidisttorch_tpu.models import transformer
+from multidisttorch_tpu.models.latent_moe import _default_grouped_dot, _rope_angles
+from multidisttorch_tpu.ops.moe import RoutedExperts
+from multidisttorch_tpu.ops.pallas_attention import (
+    blocked_window_attention,
+    grouped_attention,
+    grouped_takes_kernel,
+)
+from multidisttorch_tpu.utils.profiling import (
+    SCOPE_ATTN_CORE,
+    SCOPE_ATTN_FULL,
+    SCOPE_ATTN_WINDOW,
+    SCOPE_K,
+    SCOPE_Q,
+)
+
+
+def rope_halves(x, cos, sin):
+    """``x`` ``(B, T, H, width)`` rotated in the halves convention:
+    element ``i`` with ``i + width/2`` by the angle ``i`` of every
+    position, ``cos``, ``sin`` ``(T, width/2)``; the arithmetic
+    float32."""
+    half = x.shape[-1] // 2
+    x32, c, s = x.astype(jnp.float32), cos[:, None, :], sin[:, None, :]
+    lo, hi = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([lo * c - hi * s, hi * c + lo * s], axis=-1).astype(x.dtype)
+
+
+class GroupedWindowMoEBlock(nn.Module):
+    """One pre-norm block: grouped-head attention (``window`` ``None``:
+    the whole past; ``rotary`` ``False``: no positions), then the
+    expert layer, routed from the block's normed input. Returns ``(x,
+    counts)``."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: Optional[int]
+    rotary: bool
+    rope_theta: float
+    hidden_dim: int  # one expert's width
+    num_experts: int
+    experts_held: tuple[int, int]
+    top_k: int
+    absent_share_grad: bool = True  # as RoutedExperts'
+    # (q, k, v, *, window, q_rotation) -> out, as ops.pallas_attention.grouped_attention. None: the default
+    attention: Optional[Callable] = None
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, d = x.shape
+        h, hkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32, name=name
+        )
+        norm = lambda name: nn.RMSNorm(
+            epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name=name
+        )
+        y = norm("ln_attn")(x)
+        q = dense(h * hd, "q")(y).reshape(b, t, h, hd)
+        k = dense(hkv * hd, "k")(y).reshape(b, t, hkv, hd)
+        v = dense(hkv * hd, "v")(y).reshape(b, t, hkv, hd)
+        rotation = None
+        if self.rotary:
+            angle = _rope_angles(jnp.arange(t), self.rope_theta, hd)
+            rotation = jnp.cos(angle), jnp.sin(angle)
+            with jax.named_scope(SCOPE_K):
+                k = rope_halves(k, *rotation)
+        placed = transformer._placement(x)
+        attend = self.attention
+        if attend is None and placed and grouped_takes_kernel(*placed, t, h, hkv, hd):
+            attend = grouped_attention
+        if attend is None and self.rotary:  # the plain path takes q as it is multiplied
+            with jax.named_scope(SCOPE_Q):
+                q = rope_halves(q, *rotation)
+        kind = SCOPE_ATTN_FULL if self.window is None else SCOPE_ATTN_WINDOW
+        with jax.named_scope(SCOPE_ATTN_CORE), jax.named_scope(kind):
+            if attend is None:
+                attn = blocked_window_attention(q, k, v, window=self.window)
+            else:
+                attn = attend(q, k, v, window=self.window, q_rotation=rotation)
+        x = x + dense(d, "proj")(attn.reshape(b, t, h * hd))
+
+        z = norm("ln_mlp")(x)
+        out, counts = RoutedExperts(
+            num_experts=self.num_experts,
+            experts_held=self.experts_held,
+            top_k=self.top_k,
+            hidden_dim=self.hidden_dim,
+            dtype=self.dtype,
+            grouped_dot=_default_grouped_dot(z),
+            scoring="softmax",
+            activation="relu",
+            absent_share_grad=self.absent_share_grad,
+            name="moe",
+        )(z.reshape(b * t, d), router_input=y.reshape(b * t, d))
+        return x + out.reshape(b, t, d), counts
+
+
+class GroupedWindowMoELM(nn.Module):
+    """Decoder-only LM: ``(B, T) int32 -> ((B, T, vocab) float32 logits,
+    {"expert_counts": (num_layers, count) int32})``.
+
+    ``window_layout`` and ``rope_layout`` say by layer index (1 or 0)
+    whether the layer's attention is held to ``window`` keys and whether
+    its q and k are rotated. ``experts_held`` ``None`` holds every
+    expert. The defaults are a toy for tests and examples; a
+    configuration's file gives the published sizes
+    (``benchmark/configs/``)."""
+
+    vocab_size: int
+    d_model: int = 64
+    num_heads: int = 4
+    num_kv_heads: int = 2
+    head_dim: int = 16
+    num_layers: int = 4
+    window_layout: tuple[int, ...] = (0, 1, 1, 1)
+    rope_layout: tuple[int, ...] = (0, 1, 1, 1)
+    window: int = 8
+    rope_theta: float = 10000.0
+    num_experts: int = 8
+    experts_held: Optional[tuple[int, int]] = None
+    top_k: int = 2
+    expert_hidden_dim: int = 32
+    eps: float = 1e-6
+    max_len: int = 256
+    embed_stddev: Optional[float] = None  # None: nn.Embed's own 1 / sqrt(d_model)
+    absent_share_grad: bool = True  # as RoutedExperts'; False for a chip's share trained alone
+    attention: Optional[Callable] = None
+    dtype: Any = jnp.float32
+    remat: bool = False  # per-block checkpointing (transformer.remat_block)
+
+    @nn.compact
+    def __call__(self, tokens):
+        _, t = tokens.shape
+        if t > self.max_len:
+            raise ValueError(f"sequence length {t} exceeds max_len={self.max_len}")
+        if not len(self.window_layout) == len(self.rope_layout) == self.num_layers:
+            raise ValueError(
+                f"window_layout and rope_layout name {len(self.window_layout)} and "
+                f"{len(self.rope_layout)} layers of {self.num_layers}"
+            )
+        drawn = {} if self.embed_stddev is None else {
+            "embedding_init": nn.initializers.normal(self.embed_stddev)
+        }
+        x = nn.Embed(
+            self.vocab_size, self.d_model, dtype=self.dtype, param_dtype=jnp.float32,
+            name="tok_embed", **drawn,
+        )(tokens)
+        block_cls = (
+            transformer.remat_block(GroupedWindowMoEBlock) if self.remat else GroupedWindowMoEBlock
+        )
+        counts = []
+        for i, (windowed, rotary) in enumerate(zip(self.window_layout, self.rope_layout)):
+            x, c = block_cls(
+                num_heads=self.num_heads, num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
+                window=self.window if windowed else None, rotary=bool(rotary),
+                rope_theta=self.rope_theta, hidden_dim=self.expert_hidden_dim,
+                num_experts=self.num_experts,
+                experts_held=self.experts_held or (0, self.num_experts),
+                top_k=self.top_k, absent_share_grad=self.absent_share_grad,
+                attention=self.attention, eps=self.eps, dtype=self.dtype,
+                name=f"block_{i}",
+            )(x)
+            counts.append(c)
+        x = nn.RMSNorm(
+            epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name="ln_out"
+        )(x)
+        logits = nn.Dense(
+            self.vocab_size, use_bias=False, dtype=jnp.float32,
+            param_dtype=jnp.float32, name="head",
+        )(x)
+        return logits, {"expert_counts": jnp.stack(counts)}

@@ -17,9 +17,11 @@ the all-to-all-equivalent collectives itself. The Switch auxiliary
 load-balancing loss (eq. 4) is returned alongside the output.
 
 :class:`RoutedExperts` (what today's fine-grained expert models run,
-``models/latent_moe.py``): sigmoid scores over ALL the experts of the
-layer, the ``top_k`` largest of score + selection bias a token, weights
-normalised over the chosen, no capacity and no dropped token. The layer
+``models/latent_moe.py``, ``models/grouped_window_moe.py``): scores
+over ALL the experts of the layer (sigmoid, the ``top_k`` largest of
+score + selection bias a token, weights normalised over the chosen; or
+the ``top_k`` largest logits and a softmax over them), SwiGLU or ReGLU
+experts, no capacity and no dropped token. The layer
 is told which experts it holds (``experts_held``: one chip's share of
 an expert-parallel group) and adds only their terms, plus a shared
 expert computed whole; what the absent experts would add is left out,
@@ -417,18 +419,42 @@ def _buffer_rows(n: int, k: int, count: int, e: int) -> tuple[int, int]:
 
 
 class RoutedExperts(nn.Module):
-    """Dropless sigmoid-routed experts, one chip's share of them:
+    """Dropless routed experts, one chip's share of them:
     ``(N, d) -> ((N, d), (count,) int32)``, the second the assignments
     to each expert held.
 
     ``num_experts`` is the router's width, ``experts_held = (first,
     count)`` the experts whose weights live here. Parameters: ``router``
-    ``(d, E)`` and the selection bias ``score_bias`` ``(E,)`` (it moves
+    ``(d, E)`` and, under ``scoring="sigmoid"``, the selection bias
+    ``score_bias`` ``(E,)`` (it moves
     which experts are chosen and never their weights, so its gradient
     is zero), ``w_gate``, ``w_up`` ``(count, d, h)`` and ``w_down``
-    ``(count, h, d)``, each expert a SwiGLU, and the shared expert's
+    ``(count, h, d)``, each expert ``W_down(act(W_gate x) * (W_up x))``,
+    and the shared expert's
     ``shared_gate``, ``shared_up``, ``shared_down`` when
     ``shared_hidden_dim`` is not 0.
+
+    ``scoring``: ``"sigmoid"``: sigmoid scores, the ``top_k`` largest
+    of score + bias chosen, the chosen scores normalised to sum 1;
+    ``"softmax"``: the ``top_k`` largest logits chosen and a softmax
+    over them (which is the softmax over all the experts, its top k,
+    normalised), no bias parameter. ``activation``: ``"silu"`` (SwiGLU)
+    or ``"relu"`` (ReGLU). ``router_input``, where given, is what the
+    router reads in place of ``x``: a block whose router reads the
+    block's input while the experts read what attention made of it
+    hands in both.
+
+    ``absent_share_grad`` ``False``
+    keeps from the backward pass what the share of a token's weight that
+    its experts held here have, ``S``, would tell it: the weights are
+    ``stop_gradient(S) * (w / S)`` over the experts held, the same
+    numbers forward. Run alone, a chip's share answers only for its own
+    experts, so the router's gradient says that weight moved onto them
+    always helps, and within tens of steps every token chooses them;
+    with the share held still the gradient is the whole group's under
+    the one assumption a chip alone can make, that the absent experts'
+    answers are as useful to a token, weight for weight, as the held
+    ones'.
     """
 
     num_experts: int
@@ -439,9 +465,12 @@ class RoutedExperts(nn.Module):
     routed_scaling: float = 1.0
     dtype: Any = jnp.float32
     grouped_dot: GroupedDot = ragged_grouped_dot  # who makes the layer's two products
+    scoring: str = "sigmoid"
+    activation: str = "silu"
+    absent_share_grad: bool = True
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    def __call__(self, x: jnp.ndarray, router_input=None) -> tuple[jnp.ndarray, jnp.ndarray]:
         n, d = x.shape
         e, k, h = self.num_experts, self.top_k, self.hidden_dim
         first, count = self.experts_held
@@ -449,10 +478,15 @@ class RoutedExperts(nn.Module):
             raise ValueError(
                 f"experts_held={self.experts_held} top_k={k} do not fit {e} experts"
             )
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring={self.scoring!r}: sigmoid or softmax")
+        act_fn = {"silu": nn.silu, "relu": nn.relu}[self.activation]
         x = x.astype(self.dtype)
+        routed_from = x if router_input is None else router_input
         per_expert = nn.initializers.lecun_normal(batch_axis=(0,))
         router = self.param("router", nn.initializers.lecun_normal(), (d, e), jnp.float32)
-        bias = self.param("score_bias", nn.initializers.normal(0.01), (e,), jnp.float32)
+        if self.scoring == "sigmoid":
+            bias = self.param("score_bias", nn.initializers.normal(0.01), (e,), jnp.float32)
         w_gate = self.param("w_gate", per_expert, (count, d, h), jnp.float32)
         w_up = self.param("w_up", per_expert, (count, d, h), jnp.float32)
         w_down = self.param("w_down", per_expert, (count, h, d), jnp.float32)
@@ -461,20 +495,31 @@ class RoutedExperts(nn.Module):
             # float32 in earnest: on the TPU a float32 product otherwise
             # runs as one bf16 pass, and a choice among 256 close scores
             # turns on less than that rounds away
-            scores = jax.nn.sigmoid(
-                jnp.dot(x.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST)
+            scores = jnp.dot(
+                routed_from.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST
             )  # (N, E)
-            _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), k)
+            if self.scoring == "sigmoid":
+                scores = jax.nn.sigmoid(scores)
+                _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), k)
+            else:  # the chosen logits come with the choice: no (N, k) gather
+                picked, chosen = jax.lax.top_k(scores, k)
             # for whoever asks (``mutable=["intermediates"]``): a test, the
             # benchmark's comparison of choices with its reference
             self.sow("intermediates", "chosen", chosen)
-            picked = jnp.take_along_axis(scores, chosen, axis=-1)  # (N, k)
-            weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+            if self.scoring == "sigmoid":
+                picked = jnp.take_along_axis(scores, chosen, axis=-1)  # (N, k)
+                weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+            else:
+                weights = jax.nn.softmax(picked, axis=-1)
             weights = weights * self.routed_scaling
 
         with jax.named_scope(SCOPE_EXPERT_DISPATCH):
             local = chosen - first
             held = (local >= 0) & (local < count)
+            if not self.absent_share_grad:
+                here = jnp.where(held, weights, 0)
+                share = jnp.sum(here, axis=-1, keepdims=True)
+                weights = jax.lax.stop_gradient(share) * (here / jnp.maximum(share, 1e-20))
             # expert order, the assignments to absent experts last
             group = jnp.where(held, local, count).reshape(n * k)
             order = jnp.argsort(group, stable=True)  # row -> pair, as token * k + slot
@@ -502,7 +547,7 @@ class RoutedExperts(nn.Module):
                 # operands as they come (bf16), float32 accumulated and
                 # out; gate and up as one product, so xs is read once
                 gate_up = grouped_dot.experts(xs, w_gate_up, sizes)
-                act = (nn.silu(gate_up[:, :h]) * gate_up[:, h:]).astype(self.dtype)
+                act = (act_fn(gate_up[:, :h]) * gate_up[:, h:]).astype(self.dtype)
                 ys = grouped_dot.experts(act, w_down.astype(self.dtype), sizes)
                 ys = ys.astype(self.dtype)
             ys = jnp.where(valid[:, None], ys, 0)
@@ -549,6 +594,6 @@ class RoutedExperts(nn.Module):
                 )
                 hs = self.shared_hidden_dim
                 y = y + dense(d, "shared_down")(
-                    nn.silu(dense(hs, "shared_gate")(x)) * dense(hs, "shared_up")(x)
+                    act_fn(dense(hs, "shared_gate")(x)) * dense(hs, "shared_up")(x)
                 )
         return y, counts

@@ -6,9 +6,10 @@ score matrix to HBM and reads it back several times a pass; at
 is the exact online-softmax attention that never does: a forward kernel
 and ONE fused backward kernel (dQ, dK and dV from a single recomputation
 of the probabilities out of the saved per-row logsumexp), wired through
-``jax.custom_vjp``. It is what a single-chip ``TransformerLM`` or
-``LatentMoELM`` runs when no attention is injected
-(``models/transformer.py::_default_causal`` says when), what
+``jax.custom_vjp``. It is what a single-chip ``TransformerLM``,
+``LatentMoELM`` or ``GroupedWindowMoELM`` runs when no attention is
+injected (``models/transformer.py::_default_causal`` and the two rules
+beside ``default_takes_kernel`` say when), what
 ``make_flash_attention`` hands out, and the hop of
 ``make_ring_flash_attention``.
 
@@ -39,12 +40,30 @@ What runs where:
   key read once for all heads, a score the sum of two products. There
   nothing 192 or 256 wide exists in HBM, in any pass
   (``models/latent_moe.py::LatentMoEBlock`` says when).
-- **Blocks.** One grid step is one query block against the whole K/V
-  sequence, which stays in VMEM (256 KB each at T=1,024); a loop inside
-  the kernel walks the K/V blocks below the diagonal unmasked and the
-  diagonal block masked, so nothing above the diagonal is fetched or
-  computed. The block edge follows T: the largest of ``_BLOCKS`` that
-  divides it, else the whole sequence.
+- **Blocks.** In the ``(q, k, v)`` and the latent kernels one grid step
+  is one query block against the whole K/V sequence, which stays in
+  VMEM (256 KB each at T=1,024; so they stop near T = 4,096); a loop
+  inside the kernel walks the K/V blocks below the diagonal unmasked
+  and the diagonal block masked, so nothing above the diagonal is
+  fetched or computed. The block edge follows T: the largest of
+  ``_BLOCKS`` that divides it, else the whole sequence.
+- **Grouped KV heads, a window, K and V by the block.** A third pair,
+  ``grouped_fwd`` and ``grouped_bwd`` (:func:`grouped_attention`; a
+  one-chip ``GroupedWindowMoELM``'s, :func:`grouped_takes_kernel` says
+  when), takes fewer KV heads than query heads at head width 128, ``(B,
+  T, H*128)`` and ``(B, T, Hkv*128)`` flat from the projections, and an
+  optional sliding window. A grid step is one (query block, K/V block)
+  tile for all the query heads of a group against the group's one K/V
+  block, fetched once for them; the tiles are listed at trace time
+  (:func:`_visits`): only those that hold a pair with ``j <= i`` and
+  ``i - j < window``, the ones wholly inside unmasked, the diagonal
+  and the window's far edge masked, nothing outside fetched or
+  multiplied, in the one fused backward kernel as well. K and V come
+  by the block, so T = 16,384 compiles (the forward's VMEM does not
+  grow with T; the backward keeps one KV head's float32 dK and dV, 1.5
+  KB a token with their outgoing copies). q of a window layer is
+  rotated as a block is loaded (the halves convention), as the latent
+  kernels rotate theirs.
 - **Dtypes.** Operands go into the MXU as they come (bf16 stays bf16),
   every matmul accumulates in f32, the softmax statistics and the
   logsumexp are f32, and probabilities and score gradients are cast to
@@ -77,6 +96,7 @@ from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -120,9 +140,22 @@ def default_takes_kernel(
     device_kind: str, num_devices: int, seq_len: int, num_heads: int,
     head_dim: int, v_head_dim: int | None = None,
 ) -> bool:
-    """Whether a model that was given no attention runs this kernel
-    (``models/transformer.py::_default_causal`` asks, with what tracing
-    shows of the operands' placement) or XLA's dense path.
+    """Whether a model that was given no attention runs the ``(q, k,
+    v)`` kernel (``models/transformer.py::_default_causal`` asks, with
+    what tracing shows of the operands' placement) or XLA's dense path.
+    :func:`latent_takes_kernel` and :func:`grouped_takes_kernel` ask
+    the same of latent attention's parts and of grouped KV heads, each
+    with its own widths on top: what runs where after them is
+
+    - ``TransformerLM``: ``flash_fwd``, ``flash_bwd``; a sequence's
+      whole K and V in VMEM;
+    - ``LatentMoELM``: ``latent_fwd``, ``latent_bwd``; likewise;
+    - ``GroupedWindowMoELM``: ``grouped_fwd``, ``grouped_bwd``; one
+      K/V block a grid step, the blocks outside a window skipped;
+
+    and everywhere else (the CPU, several chips, a T or widths a rule
+    refuses) XLA's dense path, for grouped heads in query blocks
+    (:func:`blocked_window_attention`). The rule of this function:
 
     - a TPU: Mosaic compiles for nothing else, and the CPU suite's
       interpreter is for tests that inject the kernel;
@@ -452,7 +485,12 @@ def _specs(t, g, blk):
 def _params(t, w, blk, itemsize, lane_blocks="parallel"):
     """Double-buffered operands and results, the f32 accumulators and a
     handful of (blk, blk) f32 tiles, with room to spare: Mosaic's own
-    default (16 MiB) is too small from T=4,096 on."""
+    default (16 MiB) is too small from T=4,096 on. ``resident`` grows
+    with T for the ``(q, k, v)`` and the latent kernels, which keep a
+    sequence's whole K and V (and dK, dV) in VMEM and so stop where the
+    100 MiB cap does, a little past T = 4,096 at these widths; the
+    grouped kernels fetch K and V by the block and size their own
+    (``_grouped_params``)."""
     resident = 8 * t * w * itemsize + 2 * t * w * 4
     tiles = 8 * blk * blk * 4 + 16 * blk * w * 4
     return pltpu.CompilerParams(
@@ -1018,6 +1056,418 @@ def latent_attention(q_nope, q_rope, k_nope, k_rope, v, *, q_rotation=None,
         1.0 / ((dn + dr) ** 0.5) if scale is None else scale, causal, blk,
     )
     return o.reshape(b, t, h, dv)
+
+
+# ---------------------------------------------------------------------
+# grouped KV heads, an optional window, K and V by the block
+# ---------------------------------------------------------------------
+
+# Block edges of the grouped kernels, best first (the chip race at 1 x
+# 16,384, 28 heads over 4, forward + backward: a full layer 46.2 ms at
+# 1,024, 49.2 at 512, 88.3 at 256; a window layer of 4,096 25.4, 25.6,
+# 43.4: PERF.md section 6, PR 33).
+_GROUPED_BLOCKS = (1024, 512, 256, 128)
+_FIRST, _LAST, _MASKED = 1, 2, 4  # what a visit is to its query block, and its tile's kind
+
+
+def grouped_takes_kernel(
+    device_kind: str, num_devices: int, seq_len: int, num_heads: int,
+    num_kv_heads: int, head_dim: int,
+) -> bool:
+    """Whether a block of grouped-head attention that was given no
+    attention runs :func:`grouped_attention`'s kernels
+    (``models/grouped_window_moe.py`` asks while tracing, with what
+    tracing shows of the operands' placement) or the plain masked path
+    in query blocks (:func:`blocked_window_attention`): where
+    :func:`default_takes_kernel` takes a head width of 128 (a TPU,
+    operands on one device, a T from 256 that 128 divides), for whole
+    groups of query heads a KV head. No upper bound on T: K and V come
+    by the block."""
+    return (
+        default_takes_kernel(device_kind, num_devices, seq_len, num_heads, head_dim)
+        and head_dim == _LANES
+        and num_heads % num_kv_heads == 0
+    )
+
+
+def _visits(t: int, blk: int, window: int | None):
+    """The (query block, K/V block) tiles that hold a pair the mask
+    keeps (``j <= i``, and ``i - j < window``), as three int32 tables a
+    grid step reads: query block by query block, each from its diagonal
+    tile down to the farthest it reaches (the diagonal first: there
+    every query sees a key, its own, so the running maximum is a score
+    from then on and a masked score's ``exp`` is 0 exactly), with a
+    flag word: first and last visit of the query block, and whether the
+    tile needs the mask (the diagonal, and the window's far edge).
+    Static: numpy at trace time. Nothing outside these tiles is fetched
+    or multiplied, in either pass."""
+    q_of, k_of, flags = [], [], []
+    for i in range(t // blk):
+        lo = 0 if window is None else max(0, (i * blk - window + 1) // blk)
+        for j in range(i, lo - 1, -1):
+            edge = window is not None and (i + 1) * blk - 1 - j * blk >= window
+            q_of.append(i)
+            k_of.append(j)
+            flags.append(_FIRST * (j == i) | _LAST * (j == lo) | _MASKED * (j == i or edge))
+    return tuple(np.asarray(x, np.int32) for x in (q_of, k_of, flags))
+
+
+def _kept(i, j, blk: int, window: int | None, keys_axis: int):
+    """The ``(blk, blk)`` mask of query block ``i`` against K/V block
+    ``j``: key <= query, and query - key < ``window``."""
+    query = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1 - keys_axis)
+    key = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), keys_axis)
+    ahead = (i - j) * blk + query - key
+    return ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
+
+
+def _rotated_halves(x, cos_ref, sin_ref):
+    """A head's ``(rows, 128)`` block rotated by the block's angles in
+    the halves convention (element ``i`` with ``i + 64``), float32:
+    ``cos_ref`` holds an angle's cosine at both lanes, ``sin_ref`` its
+    sine, negated at the first half's."""
+    x = x.astype(jnp.float32)
+    return x * cos_ref[...] + pltpu.roll(x, _LANES // 2, 1) * sin_ref[...]
+
+
+def _rotated_halves_back(g, cos_ref, sin_ref):
+    """The transpose of :func:`_rotated_halves` (the exchange of the
+    halves is its own)."""
+    return g * cos_ref[...] + pltpu.roll(g * sin_ref[...], _LANES // 2, 1)
+
+
+def _grouped_fwd_kernel(q_of, k_of, flags_of, *refs, scale, window, g, blk, rotate, interpret):
+    """Grid (N, KV heads, visits), the visits sequential: one tile of
+    one query block's online softmax, the ``g`` query heads of the
+    group in turn against the one K/V block fetched for them all; keys
+    x queries as ``_fwd_kernel``. q is rotated (window layers) and
+    transposed once a query block, V transposed once a visit."""
+    q_ref, *refs = refs
+    if rotate:
+        cos_ref, sin_ref, *refs = refs
+    k_ref, v_ref, o_ref, lse_ref, acc_t, m_sc, l_sc, q_t = refs
+    visit = pl.program_id(2)
+    i, j, flags = q_of[visit], k_of[visit], flags_of[visit]
+    dot = partial(_dot, interpret=interpret)
+
+    @pl.when((flags & _FIRST) != 0)
+    def _start():
+        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_t[...] = jnp.zeros_like(acc_t)
+
+        def head(h, carry):
+            q = q_ref[0, :, _head_at(h, _LANES)]
+            if rotate:
+                q = _rotated_halves(q, cos_ref, sin_ref).astype(q_ref.dtype)
+            q_t[h] = _transposed(q)  # (128, blk)
+            return carry
+
+        jax.lax.fori_loop(0, g, head, 0)
+
+    def tile(masked: bool):
+        k, v_t = k_ref[0], _transposed(v_ref[0])  # (blk, 128), (128, blk)
+        keep = _kept(i, j, blk, window, 0) if masked else None
+
+        def head(h, carry):
+            s = dot(k, q_t[h]) * scale  # (keys, queries) f32
+            if masked:
+                s = jnp.where(keep, s, _NEG_INF)
+            _softmax_step(s, v_t, h, slice(None), acc_t, m_sc, l_sc, dot)
+            return carry
+
+        jax.lax.fori_loop(0, g, head, 0)
+
+    pl.when((flags & _MASKED) != 0)(lambda: tile(True))
+    pl.when((flags & _MASKED) == 0)(lambda: tile(False))
+    pl.when((flags & _LAST) != 0)(lambda: _finish_softmax(g, acc_t, m_sc, l_sc, o_ref, lse_ref))
+
+
+def _grouped_bwd_kernel(q_of, k_of, flags_of, *refs, scale, window, g, blk, rotate, interpret):
+    """Grid (N, KV heads, visits), the visits sequential and in the
+    forward's order; queries x keys as ``_bwd_kernel``. dQ of a query
+    block accumulates over its visits and leaves with the last (rotated
+    back where q came unrotated); dK and dV of the group's one KV head
+    accumulate transposed, ``(128, blk)`` a K/V block, over the query
+    blocks within reach and the ``g`` heads, and are written on the KV
+    head's last visit. What is made once a query block (q rotated, q
+    and dO transposed, delta, the logsumexp as a column) waits in VMEM
+    for the block's other visits."""
+    q_ref, *refs = refs
+    if rotate:
+        cos_ref, sin_ref, *refs = refs
+    (k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
+     dk_t, dv_t, dq_acc, q_sc, q_t, do_t, lse_sc, delta_sc) = refs
+    visit, last_visit = pl.program_id(2), pl.num_programs(2) - 1
+    i, j, flags = q_of[visit], k_of[visit], flags_of[visit]
+    dot = partial(_dot, interpret=interpret)
+
+    @pl.when(visit == 0)
+    def _init():
+        dk_t[...] = jnp.zeros_like(dk_t)
+        dv_t[...] = jnp.zeros_like(dv_t)
+
+    @pl.when((flags & _FIRST) != 0)
+    def _start():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+        def head(h, carry):
+            lanes = _head_at(h, _LANES)
+            q, do = q_ref[0, :, lanes], do_ref[0, :, lanes]
+            if rotate:
+                q = _rotated_halves(q, cos_ref, sin_ref).astype(q_ref.dtype)
+            q_sc[:, lanes] = q
+            q_t[h] = _transposed(q)
+            do_t[h] = _transposed(do)
+            # delta = rowsum(dO * O), the one use of the forward's output
+            delta_sc[h] = jnp.sum(
+                do.astype(jnp.float32) * o_ref[0, :, lanes].astype(jnp.float32),
+                axis=1, keepdims=True,
+            )
+            lse_sc[h] = lse_ref[0, 0, h][:, None]
+            return carry
+
+        jax.lax.fori_loop(0, g, head, 0)
+
+    def tile(masked: bool):
+        k, v = k_ref[0], v_ref[0]  # (blk, 128)
+        keep = _kept(i, j, blk, window, 1) if masked else None
+
+        def head(h, carry):
+            lanes = _head_at(h, _LANES)
+            s = dot(q_sc[:, lanes], k, _NT) * scale  # (queries, keys) f32
+            if masked:
+                s = jnp.where(keep, s, _NEG_INF)
+            p = jnp.exp(s - lse_sc[h])  # exact probabilities via saved lse
+            dp = dot(do_ref[0, :, lanes], v, _NT)
+            ds = (p * (dp - delta_sc[h])).astype(k.dtype)
+            dv_t[j] = dv_t[j] + dot(do_t[h], p.astype(v.dtype))
+            dk_t[j] = dk_t[j] + dot(q_t[h], ds)
+            dq_acc[:, lanes] = dq_acc[:, lanes] + dot(ds, k)
+            return carry
+
+        jax.lax.fori_loop(0, g, head, 0)
+
+    pl.when((flags & _MASKED) != 0)(lambda: tile(True))
+    pl.when((flags & _MASKED) == 0)(lambda: tile(False))
+
+    @pl.when((flags & _LAST) != 0)
+    def _emit_dq():
+        def head(h, carry):
+            lanes = _head_at(h, _LANES)
+            dq = dq_acc[:, lanes] * scale
+            if rotate:
+                dq = _rotated_halves_back(dq, cos_ref, sin_ref)
+            dq_ref[0, :, lanes] = dq.astype(dq_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, g, head, 0)
+
+    @pl.when(visit == last_visit)
+    def _emit_dkv():
+        def block(n, carry):
+            rows = pl.ds(pl.multiple_of(n * blk, blk), blk)
+            dk_ref[0, rows, :] = (dk_t[n].T * scale).astype(dk_ref.dtype)
+            dv_ref[0, rows, :] = dv_t[n].T.astype(dv_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, dk_t.shape[0], block, 0)
+
+
+def _grouped_layout(q, k, rotation, window, blk: int):
+    """What both calls are laid out by: the visits' three tables, the
+    grid ``(N, KV heads, visits)``, ``g`` query heads a KV head, and
+    the block specs under the prefetched tables: a query block of a
+    group's heads, the visit's K/V block of the group's one KV head, a
+    query block of the statistics, and of the rotation's two tables
+    where q comes unrotated."""
+    n, t, _ = q.shape
+    g = q.shape[-1] // k.shape[-1]
+    tables = _visits(t, blk, window)
+    group = pl.BlockSpec((1, blk, g * _LANES), lambda n, c, v, q_of, k_of, f: (n, q_of[v], c))
+    keys = pl.BlockSpec((1, blk, _LANES), lambda n, c, v, q_of, k_of, f: (n, k_of[v], c))
+    stat = pl.BlockSpec((1, 1, g, blk), lambda n, c, v, q_of, k_of, f: (n, c, 0, q_of[v]))
+    angles = pl.BlockSpec((blk, _LANES), lambda n, c, v, q_of, k_of, f: (q_of[v], 0))
+    grid = (n, k.shape[-1] // _LANES, len(tables[0]))
+    return tables, grid, g, group, keys, stat, [angles, angles] if rotation else []
+
+
+def _grouped_params(resident: int, g: int, blk: int):
+    """As ``_params``: ``resident`` is what stays in VMEM beside a
+    visit's blocks. The forward has none; the backward keeps a KV
+    head's dK and dV, float32 and as they leave, 1.5 KB a token."""
+    blocks = 16 * blk * g * _LANES * 4 + 8 * blk * blk * 4
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=int(min(max(32 << 20, 2 * (resident + blocks)), 100 << 20)),
+    )
+
+
+@partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _grouped_fwd_call(q, k, v, rotation, scale, window, blk, interpret):
+    tables, grid, g, group, keys, stat, angles = _grouped_layout(q, k, rotation, window, blk)
+    return pl.pallas_call(
+        partial(_grouped_fwd_kernel, scale=scale, window=window, g=g, blk=blk,
+                rotate=bool(rotation), interpret=interpret),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=grid,
+            in_specs=[group, *angles, keys, keys],
+            out_specs=(group, stat),
+            scratch_shapes=[
+                pltpu.VMEM((g, _LANES, blk), jnp.float32),  # output, transposed
+                pltpu.VMEM((g, 1, blk), jnp.float32),  # running max
+                pltpu.VMEM((g, 1, blk), jnp.float32),  # running sum
+                pltpu.VMEM((g, _LANES, blk), q.dtype),  # q, rotated and transposed
+            ],
+        ),
+        out_shape=(
+            _out_struct(q.shape, q.dtype, q),
+            _out_struct((*grid[:2], g, q.shape[1]), jnp.float32, q),
+        ),
+        compiler_params=_grouped_params(0, g, blk),
+        interpret=interpret,
+        name="grouped_fwd",
+    )(*tables, q, *(rotation or ()), k, v)
+
+
+@partial(jax.jit, static_argnums=(7, 8, 9, 10))
+def _grouped_bwd_call(q, k, v, rotation, o, lse, do, scale, window, blk, interpret):
+    t = q.shape[1]
+    tables, grid, g, group, keys, stat, angles = _grouped_layout(q, k, rotation, window, blk)
+    whole = pl.BlockSpec((1, t, _LANES), lambda n, c, v, q_of, k_of, f: (n, 0, c))
+    column = pltpu.VMEM((g, blk, 1), jnp.float32)
+    return pl.pallas_call(
+        partial(_grouped_bwd_kernel, scale=scale, window=window, g=g, blk=blk,
+                rotate=bool(rotation), interpret=interpret),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=grid,
+            in_specs=[group, *angles, keys, keys, group, group, stat],
+            out_specs=(group, whole, whole),
+            scratch_shapes=[
+                pltpu.VMEM((t // blk, _LANES, blk), jnp.float32),  # dK, transposed
+                pltpu.VMEM((t // blk, _LANES, blk), jnp.float32),  # dV, transposed
+                pltpu.VMEM((blk, g * _LANES), jnp.float32),  # dQ
+                pltpu.VMEM((blk, g * _LANES), q.dtype),  # q, rotated
+                pltpu.VMEM((g, _LANES, blk), q.dtype),  # and transposed
+                pltpu.VMEM((g, _LANES, blk), q.dtype),  # dO, transposed
+                column, column,  # the logsumexp and delta of a head's queries
+            ],
+        ),
+        out_shape=tuple(_out_struct(x.shape, x.dtype, x) for x in (q, k, v)),
+        compiler_params=_grouped_params(
+            2 * t * _LANES * (4 + 2 * k.dtype.itemsize), g, blk
+        ),
+        interpret=interpret,
+        name="grouped_bwd",
+    )(*tables, q, *(rotation or ()), k, v, o, do, lse)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _grouped(q, k, v, rotation, scale, window, blk):
+    """The output, as ``q``, for the kernels' flat operands."""
+    return _grouped_fwd_call(q, k, v, rotation, scale, window, blk, pallas_interpret())[0]
+
+
+def _grouped_fwd(q, k, v, rotation, scale, window, blk):
+    o, lse = _grouped_fwd_call(q, k, v, rotation, scale, window, blk, pallas_interpret())
+    o, lse = checkpoint_name(o, SAVED_OUT), checkpoint_name(lse, SAVED_LSE)
+    return o, (q, k, v, rotation, o, lse)
+
+
+def _grouped_bwd(scale, window, blk, res, g_o):
+    *operands, o, lse = res
+    dq, dk, dv = _grouped_bwd_call(*operands, o, lse, g_o, scale, window, blk, pallas_interpret())
+    # the angles are the positions': nobody reads their gradient
+    return dq, dk, dv, jax.tree.map(jnp.zeros_like, operands[3])
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def _halves_tables(cos, sin):
+    """``(T, 64)`` cosines and sines of the halves convention as the
+    ``(T, 128)`` float32 tables the grouped kernels multiply a head by:
+    an angle's cosine at both its lanes, its sine negated at the first
+    half's."""
+    cos, sin = cos.astype(jnp.float32), sin.astype(jnp.float32)
+    return jnp.concatenate([cos, cos], axis=-1), jnp.concatenate([-sin, sin], axis=-1)
+
+
+def grouped_attention(q, k, v, *, window: int | None = None, q_rotation=None,
+                      block: int | None = None):
+    """Causal attention over grouped KV heads with an optional window:
+    ``q (B, T, H, 128)``, ``k, v (B, T, Hkv, 128)``, query head ``h``
+    reading KV head ``h // (H / Hkv)``; query ``i`` sees the keys ``j
+    <= i`` and, given a ``window``, only those with ``i - j < window``.
+    Exact, as :func:`flash_attention`; scores are divided by
+    ``sqrt(128)``.
+
+    Every array is read and written in the projections' flat layout.
+    A grid step is one (query block, K/V block) tile for the ``H /
+    Hkv`` query heads of a group: their ``(block, H / Hkv * 128)``
+    lanes of q against the group's one ``(block, 128)`` K and V block,
+    fetched once for all of them. Only tiles that hold a kept pair are
+    visited (:func:`_visits`): the ones wholly inside unmasked, the
+    diagonal and the window's far edge masked, the same list in the one
+    fused backward kernel, where dK and dV of a K/V block gather from
+    the query blocks within reach. K and V come by the block, so the
+    forward's VMEM does not grow with T; the backward keeps a KV
+    head's float32 dK and dV and their outgoing copies, 1.5 KB a
+    token (24 MiB at T = 16,384).
+
+    ``q_rotation = (cos, sin)``, ``(T, 64)`` float32 each: q comes
+    unrotated and the kernels rotate each head's block as they load it,
+    element ``i`` with ``i + 64`` by the angle ``i`` of every position
+    (float32, then the operands' dtype), and take the gradient back
+    through the rotation; k comes rotated (it is ``Hkv`` heads, an
+    eighth of q's bytes or less). Left out, q is used as it comes."""
+    b, t, h, d = q.shape
+    hkv = k.shape[2]
+    if d != _LANES or v.shape[-1] != _LANES or h % hkv:
+        raise ValueError(
+            f"grouped_attention: {h} query heads over {hkv} KV heads of width {d}: the "
+            f"kernels take whole groups of heads {_LANES} wide"
+        )
+    blk = next((x for x in _GROUPED_BLOCKS if t % x == 0), None) if block is None else block
+    if blk is None or t % blk or blk % _LANES:
+        raise ValueError(f"grouped_attention: no block edge for seq_len {t} (asked: {block})")
+    if window is not None and window >= t:
+        window = None  # every key a query may see is inside: plain causal
+    rotation = None if q_rotation is None else _halves_tables(*q_rotation)
+    flat = lambda x: x.reshape(b, t, -1)
+    o = _grouped(flat(q), flat(k), flat(v), rotation, 1.0 / math.sqrt(d), window, blk)
+    return o.reshape(b, t, h, d)
+
+
+def blocked_window_attention(q, k, v, *, window: int | None = None, block: int = 512):
+    """The plain form of :func:`grouped_attention` (q and k as they are
+    to be multiplied: rotated already), what runs off one TPU chip:
+    XLA's masked softmax, one block of ``block`` queries at a time
+    against all the keys, each block recomputed in the backward pass,
+    so that the ``(H, block, T)`` scores are all that is ever alive;
+    a T that ``block`` does not divide runs whole. The KV heads are
+    not repeated: a group's query heads meet their one KV head in the
+    product."""
+    b, t, h, d = q.shape
+    hkv = k.shape[2]
+    g = h // hkv
+    block = block if t % block == 0 else t
+    q = q.reshape(b, t, hkv, g, d)
+    at = jnp.arange(t)
+
+    @jax.checkpoint
+    def one_block(start):
+        qb = jax.lax.dynamic_slice_in_dim(q, start, block, axis=1)
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qb, k, preferred_element_type=jnp.float32)
+        ahead = (start + jnp.arange(block))[:, None] - at[None, :]
+        keep = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
+        p = jax.nn.softmax(jnp.where(keep, s / math.sqrt(d), -jnp.inf), axis=-1)
+        return jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), v)
+
+    out = jax.lax.map(one_block, jnp.arange(0, t, block))  # (blocks, B, block, Hkv, g, d)
+    return out.transpose(1, 0, 2, 3, 4, 5).reshape(b, t, h, d)
 
 
 # ---------------------------------------------------------------------

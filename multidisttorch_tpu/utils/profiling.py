@@ -53,6 +53,11 @@ SCOPE_Q, SCOPE_K, SCOPE_V = "q", "k", "v"
 # the streams (what the sublayer reads, what is written back).
 SCOPE_HC_MAPS = "hc_maps"
 SCOPE_HC_MIX = "hc_mix"
+# A model whose layers alternate between full causal attention and a
+# sliding window (``models/grouped_window_moe.py``) names, inside
+# ``attn_core``, which of the two a layer's core is.
+SCOPE_ATTN_FULL = "attn_full"
+SCOPE_ATTN_WINDOW = "attn_window"
 
 
 @contextlib.contextmanager

@@ -1,0 +1,131 @@
+"""The grouped attention kernels of ``ops/pallas_attention.py`` (grouped
+KV heads, an optional window, K and V by the block), interpreted,
+against the masked dense form: forward and gradients, float32 at
+``default_matmul_precision("highest")``, seeded. Nothing is timed."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from multidisttorch_tpu.models.grouped_window_moe import rope_halves
+from multidisttorch_tpu.models.latent_moe import _rope_angles
+from multidisttorch_tpu.ops.pallas_attention import (
+    blocked_window_attention,
+    grouped_attention,
+    grouped_takes_kernel,
+)
+
+
+def _rel(got, want):
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+def _dense(q, k, v, window):
+    """The masked dense form: KV heads repeated, the whole score matrix."""
+    t, d, g = q.shape[1], q.shape[-1], q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(d)
+    ahead = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    keep = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(jnp.where(keep, s, -jnp.inf), -1), v)
+
+
+def _operands(t, h, hkv, seed=0):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    shape = lambda heads: (2, t, heads, 128)
+    return (jax.random.normal(keys[0], shape(h)), jax.random.normal(keys[1], shape(hkv)),
+            jax.random.normal(keys[2], shape(hkv)), jax.random.normal(keys[3], shape(h)))
+
+
+# window: none, shorter than a block, several blocks and a part, >= T (plain causal)
+@pytest.mark.parametrize("window", [None, 50, 300, 512], ids=lambda w: f"w{w}")
+@pytest.mark.parametrize("group", [1, 2, 4], ids=lambda g: f"g{g}")
+def test_grouped_kernels_match_the_masked_dense_form(group, window):
+    """Forward and the gradients of q, k and v, T = 512 as four K/V
+    blocks of 128, q rotated in the kernels where a window is set (as
+    the model's window layers are)."""
+    q, k, v, co = _operands(512, 2 * group, 2)
+    angle = _rope_angles(jnp.arange(512), 10000.0, 128)
+    rotation = (jnp.cos(angle), jnp.sin(angle)) if window else None
+
+    def kernel(q, k, v):
+        out = grouped_attention(q, k, v, window=window, q_rotation=rotation, block=128)
+        return jnp.sum(out * co), out
+
+    def dense(q, k, v):
+        out = _dense(rope_halves(q, *rotation) if rotation else q, k, v, window)
+        return jnp.sum(out * co), out
+
+    with jax.default_matmul_precision("highest"):
+        (_, got), got_grads = jax.value_and_grad(kernel, (0, 1, 2), has_aux=True)(q, k, v)
+        (_, want), want_grads = jax.value_and_grad(dense, (0, 1, 2), has_aux=True)(q, k, v)
+    assert _rel(got, want) < 1e-5
+    for have, need in zip(got_grads, want_grads, strict=True):
+        assert _rel(have, need) < 1e-5
+
+
+def test_group_of_one_gives_the_flash_kernel_s_answers():
+    """One KV head a query head and no window is what
+    ``flash_attention`` computes."""
+    from multidisttorch_tpu.ops.pallas_attention import flash_attention
+
+    q, k, v, _ = _operands(256, 2, 2, seed=3)
+    with jax.default_matmul_precision("highest"):
+        got = grouped_attention(q, k, v, block=128)
+        want = flash_attention(q, k, v, causal=True, block=128)
+    assert _rel(got, want) < 1e-6
+
+
+@pytest.mark.parametrize("window", [None, 100], ids=["full", "window"])
+def test_the_plain_blocked_path_is_the_dense_form(window):
+    q, k, v, co = _operands(256, 4, 2, seed=5)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * co)
+
+    with jax.default_matmul_precision("highest"):
+        blocked = lambda q, k, v: blocked_window_attention(q, k, v, window=window, block=64)
+        got = jax.value_and_grad(loss(blocked), (0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(loss(lambda q, k, v: _dense(q, k, v, window)), (0, 1, 2))(q, k, v)
+    for have, need in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert _rel(have, need) < 1e-5
+
+
+def test_refuses_what_does_not_tile():
+    q, k, v, _ = _operands(256, 3, 2)
+    with pytest.raises(ValueError, match="whole groups"):
+        grouped_attention(q, k, v)
+    with pytest.raises(ValueError, match="no block edge"):
+        grouped_attention(q[:, :200, :2], k[:, :200], v[:, :200])
+
+
+def test_rule_takes_one_tpu_chip_at_width_128():
+    assert grouped_takes_kernel("TPU v5 lite", 1, 16384, 28, 4, 128)
+    assert grouped_takes_kernel("TPU v5 lite", 1, 256, 2, 2, 128)
+    assert not grouped_takes_kernel("cpu", 1, 16384, 28, 4, 128)
+    assert not grouped_takes_kernel("TPU v5 lite", 4, 16384, 28, 4, 128)
+    assert not grouped_takes_kernel("TPU v5 lite", 1, 16384, 28, 4, 64)
+    assert not grouped_takes_kernel("TPU v5 lite", 1, 200, 28, 4, 128)
+    assert not grouped_takes_kernel("TPU v5 lite", 1, 16384, 28, 8 - 3, 128)
+
+
+def test_grouped_operands_lower_for_tpu(monkeypatch):
+    # the cell moe-swa-t16384's attention as its block hands it over: 1 x
+    # 16,384, 28 query heads over 4 KV heads; forward and backward,
+    # interpret mode off, a full layer and a window layer whose q the
+    # kernels rotate. No score matrix and no repeated KV head is made
+    # around the kernels.
+    monkeypatch.delenv("MDT_PALLAS_INTERPRET")
+    part = lambda heads: jax.ShapeDtypeStruct((1, 16384, heads, 128), jnp.bfloat16)
+    angles = jnp.zeros((16384, 64), jnp.float32)
+    for window, rotation in ((None, None), (4096, (jnp.cos(angles), jnp.sin(angles)))):
+        fwd = lambda q, k, v: grouped_attention(q, k, v, window=window, q_rotation=rotation)
+        bwd = jax.grad(lambda *x: fwd(*x).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+        for fn, calls in ((fwd, 1), (bwd, 2)):
+            text = jax.jit(fn).trace(part(28), part(4), part(4)).lower(
+                lowering_platforms=("tpu",)).as_text()
+            assert text.count("stablehlo.custom_call @tpu_custom_call") == calls
+            assert "16384x16384" not in text
+            assert "tensor<1x16384x28x128xbf16>" not in text.split("custom_call")[1]

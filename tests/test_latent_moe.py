@@ -104,32 +104,96 @@ def _layer_weights(whole, first, count):
     return {**whole, **{k: cut(whole[k]) for k in ("w_gate", "w_up", "w_down")}}
 
 
-def test_shares_add_up_to_the_uncut_layer():
-    """16 experts over 4 shares: the shares' routed parts, plus the
-    shared expert once, are the uncut reference's layer."""
+def _sigmoid_whole(x):
+    """The uncut sigmoid-routed layer with its shared expert: ``(the
+    layer's parameters without the shared expert's, what the reference
+    gives for the whole layer, its counts, what every share computes
+    alike)``."""
+    whole = _layer((0, 16), shared_hidden_dim=24).init(jax.random.key(3), x)["params"]
+    ref_w = {
+        "router": whole["router"], "score_bias": whole["score_bias"],
+        "e_gate": whole["w_gate"], "e_up": whole["w_up"], "e_down": whole["w_down"],
+        "s_gate": whole["shared_gate"]["kernel"], "s_up": whole["shared_up"]["kernel"],
+        "s_down": whole["shared_down"]["kernel"],
+    }
+    want, _, want_counts = REFERENCE.experts(x, ref_w, _config())
+    shared = REFERENCE.swiglu(x, ref_w["s_gate"], ref_w["s_up"], ref_w["s_down"])
+    routed_only = {k: v for k, v in whole.items() if not k.startswith("shared_")}
+    return routed_only, want, want_counts, shared
+
+
+def _softmax_whole(x, router_input):
+    """The same for the scoring of the window/full hybrid models (top-k
+    of the logits, a softmax over the chosen, ReGLU, no shared expert,
+    the router reading ``router_input``), against that configuration's
+    own reference."""
+    reference = cells.load_module("benchmark/configs/smallthinker-21b-a3b.reference.py")
+    config = {"moe_num_active_primary_experts": 4, "experts_held": [0, 16]}
+    whole = _layer((0, 16), scoring="softmax", activation="relu").init(
+        jax.random.key(3), x)["params"]
+    ref_w = {"router": whole["router"], "e_gate": whole["w_gate"], "e_up": whole["w_up"],
+             "e_down": whole["w_down"]}
+    chosen, weights = reference.route(router_input, ref_w, config)
+    want, want_counts = reference.experts(x, chosen, weights * 2.5, ref_w, config)
+    return whole, want, want_counts, jnp.zeros_like(x)
+
+
+@pytest.mark.parametrize(
+    "scoring, shares, kw",
+    [("sigmoid", 4, {}), ("softmax", 8, {}), ("softmax", 8, {"absent_share_grad": False})],
+    ids=["sigmoid-4-shares", "softmax-8-shares", "softmax-8-shares-held-still"],
+)
+def test_shares_add_up_to_the_uncut_layer(scoring, shares, kw):
+    """16 experts over 4 or 8 shares: the shares' routed parts, plus
+    what every share computes alike (the shared expert) once, are the
+    uncut reference's layer, under either scoring (and whatever the
+    backward pass is told of a share: forward it is the same layer)."""
     x = jax.random.normal(jax.random.key(2), (40, 32))
+    router_input = jax.random.normal(jax.random.key(7), (40, 32))
+    held = 16 // shares
     with jax.default_matmul_precision("highest"):
-        whole = _layer((0, 16), shared_hidden_dim=24).init(jax.random.key(3), x)["params"]
-        config = _config()
-        ref_w = {
-            "router": whole["router"], "score_bias": whole["score_bias"],
-            "e_gate": whole["w_gate"], "e_up": whole["w_up"], "e_down": whole["w_down"],
-            "s_gate": whole["shared_gate"]["kernel"], "s_up": whole["shared_up"]["kernel"],
-            "s_down": whole["shared_down"]["kernel"],
-        }
-        want, _, want_counts = REFERENCE.experts(x, ref_w, config)
-        shared = REFERENCE.swiglu(x, ref_w["s_gate"], ref_w["s_up"], ref_w["s_down"])
-        total, counts = shared, []
-        routed_only = {k: v for k, v in whole.items() if not k.startswith("shared_")}
-        for first in range(0, 16, 4):
-            part, c = _layer((first, 4)).apply(
-                {"params": _layer_weights(routed_only, first, 4)}, x
+        if scoring == "sigmoid":
+            whole, want, want_counts, total = _sigmoid_whole(x)
+            call = {}
+        else:
+            whole, want, want_counts, total = _softmax_whole(x, router_input)
+            kw, call = {"scoring": "softmax", "activation": "relu", **kw}, {"router_input": router_input}
+        counts = []
+        for first in range(0, 16, held):
+            part, c = _layer((first, held), **kw).apply(
+                {"params": _layer_weights(whole, first, held)}, x, **call
             )
             total = total + part
             counts.append(c)
     assert _rel(total, want) < 1e-5
     np.testing.assert_array_equal(jnp.concatenate(counts), want_counts)
     assert int(want_counts.sum()) == 40 * 4  # every choice of every token, once
+
+
+@pytest.mark.parametrize("absent_share_grad", [True, False], ids=["cut-alone", "held-still"])
+def test_share_held_still_tells_the_router_nothing_of_the_cut(absent_share_grad):
+    """One share of 8 with ``absent_share_grad=False``: the same output,
+    and a router's gradient in which the absent experts' columns are nothing
+    and the held experts' columns add up to nothing (a token's weight
+    moves among its experts held, never onto them); the cut alone
+    gives neither."""
+    x = jax.random.normal(jax.random.key(2), (40, 32))
+    co = jax.random.normal(jax.random.key(9), (40, 32))
+    kw = {"scoring": "softmax", "activation": "relu"}
+    layer = _layer((4, 2), **kw, absent_share_grad=absent_share_grad)
+    with jax.default_matmul_precision("highest"):
+        params = layer.init(jax.random.key(5), x)["params"]
+        loss = lambda p, layer: jnp.sum(layer.apply({"params": p}, x)[0] * co)
+        out = layer.apply({"params": params}, x)[0]
+        alone = _layer((4, 2), **kw).apply({"params": params}, x)[0]
+        router = jax.grad(loss)(params, layer)["router"]  # (32, 16)
+    assert _rel(out, alone) < 1e-6
+    held, absent = router[:, 4:6], jnp.delete(router, jnp.arange(4, 6), axis=1)
+    scale = float(jnp.linalg.norm(held))
+    assert scale > 0
+    nothing = lambda a: float(jnp.linalg.norm(a)) < 1e-5 * scale
+    still = nothing(absent) and nothing(held.sum(-1))
+    assert still is not absent_share_grad
 
 
 @pytest.mark.parametrize("rows", ["usual", "worst"])

@@ -361,3 +361,112 @@ def test_hyper_connection_scopes_stay_out_of_the_parameter_tree():
     # without streams the tree is the one the test above lists
     _, plain = _lowered_latent(True)
     assert not connections & set(plain["block_1"])
+
+
+def _lowered_grouped(remat, t=16, placed=False, **fields):
+    """The grouped-head window/full LM's step lowered for ``(1, t)``
+    tokens; ``placed`` as :func:`_lowered_latent`."""
+    from multidisttorch_tpu.models.grouped_window_moe import GroupedWindowMoELM
+
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    model = GroupedWindowMoELM(**{"vocab_size": 64, "max_len": t, "remat": remat, **fields})
+    tx = optax.adam(1e-3)
+    tokens = jax.ShapeDtypeStruct((1, t), jnp.int32)
+    params = jax.eval_shape(
+        model.init, {"params": jax.random.key(0)}, jnp.zeros((1, t), jnp.int32)
+    )["params"]
+    state = jax.eval_shape(
+        lambda p: TrainState(params=p, opt_state=tx.init(p), step=jnp.zeros((), jnp.int32)),
+        params,
+    )
+    if placed:
+        on = lambda tree, sharding: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree
+        )
+        state, tokens = on(state, group.replicated_sharding), on(tokens, group.batch_sharding)
+    return make_lm_train_step(group, model, tx).lower(state, tokens), params
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_window_and_full_scopes_reach_the_compiled_step(remat):
+    """``attn_full`` and ``attn_window`` inside ``attn_core``, by the
+    layer pattern, and ``moe/router`` for the router that reads the
+    block's input, in the compiled tiny step (the plain path: the CPU);
+    the accepted splits read the step unedited."""
+    from benchmark import moe_scopes, scope_reduce, swa_scopes
+    from multidisttorch_tpu.utils.profiling import (
+        SCOPE_ATTN_FULL, SCOPE_ATTN_WINDOW, SCOPE_EXPERT_DISPATCH, SCOPE_EXPERTS, SCOPE_ROUTER,
+    )
+
+    lowered, _ = _lowered_grouped(remat)  # the default pattern: full, window, window, window
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    step = [n for n in names if n.startswith("jit(step_fn)")]
+    assert len(step) > 500
+    every = {"forward", "backward"} | ({"recompute"} if remat else set())
+    assert swa_scopes.PARTS == (SCOPE_ATTN_FULL, SCOPE_ATTN_WINDOW)
+    for kind, blocks in ((SCOPE_ATTN_FULL, {"block_0"}), (SCOPE_ATTN_WINDOW,
+                                                          {"block_1", "block_2", "block_3"})):
+        under = [n for n in step if swa_scopes.classify(n) == kind]
+        assert {_pass(n) for n in under} >= every, kind
+        assert {scope_reduce.classify(n)[0] for n in under} == {"attn_core"}, kind
+        assert {c for n in under for c in _components(n) if c.startswith("block_")} == blocks
+    core = [n for n in step if scope_reduce.classify(n)[0] == "attn_core"]
+    assert all(swa_scopes.classify(n) is not None for n in core)  # attn_core_ms is the two's sum
+    for scope in (SCOPE_ROUTER, SCOPE_EXPERT_DISPATCH, SCOPE_EXPERTS):
+        under = [n for n in step if moe_scopes.classify(n) == scope]
+        assert {_pass(n) for n in under} >= every, scope
+        assert {scope_reduce.classify(n)[0] for n in under} == {"mlp"}, scope
+    for i in range(4):
+        assert any({f"block_{i}", "moe", SCOPE_ROUTER} <= set(_components(n)) for n in step)
+    parts = {scope_reduce.classify(n)[0] for n in step}
+    assert {"attn_core", "attn_proj", "mlp", "norm", "embed", "head", "loss", "optimizer"} <= parts
+    # the rotations of q and k (window layers only) are the projections'
+    for name in ("q", "k", "v", "proj"):
+        assert {_pass(n) for n in step if name in _components(n)} >= every, name
+    unrecognised = [n for n in step if scope_reduce.classify(n)[0] in ("unscoped", "block_other")]
+    assert len(unrecognised) / len(step) < UNRECOGNISED_BOUND, (
+        len(unrecognised), len(step), sorted(set(unrecognised))[:20]
+    )
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_grouped_kernels_are_under_their_layer_s_scope_once_a_pass(monkeypatch, remat):
+    """Where the block takes the kernels (one TPU chip; here the CPU
+    device under a v5e's name, the kernels interpreted): one forward and
+    one backward call a layer under ``attn_core`` and the layer's kind,
+    none in the recomputed block; k is rotated outside them and q is
+    not."""
+    from benchmark import scope_reduce, swa_scopes
+    from multidisttorch_tpu.models import transformer
+
+    real = transformer._placement
+    monkeypatch.setattr(
+        transformer, "_placement", lambda x: real(x) and ("TPU v5 lite", real(x)[1])
+    )
+    lowered, _ = _lowered_grouped(
+        remat, t=256, placed=True, d_model=128, num_heads=2, num_kv_heads=1, head_dim=128,
+        num_layers=2, window_layout=(0, 1), rope_layout=(0, 1), window=128, num_experts=4,
+    )
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    step = [n for n in names if n.startswith("jit(step_fn)")]
+    calls = (("jit(_grouped_fwd_call)", "forward"), ("jit(_grouped_bwd_call)", "backward"))
+    for call, where in calls:
+        found = {scope_reduce.classify(n) for n in step if call in n.split("/")}
+        assert found == {("attn_core", where)}, (call, found)
+        kinds = {(swa_scopes.classify(n), c) for n in step if call in n.split("/")
+                 for c in _components(n) if c.startswith("block_")}
+        assert kinds == {("attn_full", "block_0"), ("attn_window", "block_1")}
+    rotated = lambda name: any(
+        {name, "block_1"} <= set(_components(n)) and n.endswith("/concatenate") for n in step
+    )
+    assert rotated("k") and not rotated("q")
+    unrecognised = [n for n in step if scope_reduce.classify(n)[0] in ("unscoped", "block_other")]
+    assert len(unrecognised) / len(step) < UNRECOGNISED_BOUND
+
+
+def test_grouped_scopes_stay_out_of_the_parameter_tree():
+    _, params = _lowered_grouped(True)
+    assert set(params) == {"tok_embed", "ln_out", "head"} | {f"block_{i}" for i in range(4)}
+    for i in range(4):
+        assert set(params[f"block_{i}"]) == {"ln_attn", "q", "k", "v", "proj", "ln_mlp", "moe"}
+        assert set(params[f"block_{i}"]["moe"]) == {"router", "w_gate", "w_up", "w_down"}
