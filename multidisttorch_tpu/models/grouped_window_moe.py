@@ -74,6 +74,7 @@ from typing import Any, Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from multidisttorch_tpu.models import transformer
 from multidisttorch_tpu.models.latent_moe import _default_grouped_dot, _rope_angles
@@ -158,7 +159,9 @@ class GroupedWindowMoEBlock(nn.Module):
                 attn = blocked_window_attention(q, k, v, window=self.window)
             else:
                 attn = attend(q, k, v, window=self.window, q_rotation=rotation)
-        x = x + dense(d, "proj")(attn.reshape(b, t, h * hd))
+        x = checkpoint_name(
+            x + dense(d, "proj")(attn.reshape(b, t, h * hd)), transformer.SAVED_RESIDUAL
+        )
 
         z = norm("ln_mlp")(x)
         out, counts = RoutedExperts(

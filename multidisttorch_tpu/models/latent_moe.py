@@ -106,6 +106,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from multidisttorch_tpu.models import transformer
 from multidisttorch_tpu.ops import hyper_connection
@@ -297,7 +298,7 @@ class LatentMoEBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         if self.hc_mult == 1:
-            x = x + self._attention(x)
+            x = checkpoint_name(x + self._attention(x), transformer.SAVED_RESIDUAL)
             y, counts = self._ffn(x)
             return x + y, counts
         connection = lambda name: hyper_connection.HyperConnection(

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from multidisttorch_tpu.ops.hyper_connection import SAVED_MAPS, SAVED_Y
+from multidisttorch_tpu.ops.moe import SAVED_ROUTING
 from multidisttorch_tpu.ops.pallas_attention import (
     SAVED_LSE,
     SAVED_OUT,
@@ -35,6 +36,17 @@ from multidisttorch_tpu.ops.pallas_attention import (
 )
 from multidisttorch_tpu.ops.ring_attention import dense_attention_reference
 from multidisttorch_tpu.utils.profiling import SCOPE_ATTN_CORE, SCOPE_MLP
+
+# The residual stream after a block's attention, ``x + proj(o)``, by the
+# name :func:`remat_block` keeps: ``proj``'s backward reads ``o`` and the
+# weights, never its output, so with the sum kept the recomputed block
+# does not multiply by ``proj`` again. What that is worth a byte kept
+# grows with the width ``proj`` reads: the blocks of
+# ``models/latent_moe.py`` and ``models/grouped_window_moe.py`` (4,096
+# and 3,584 wide in their cells) give the name; :class:`Block` (1,024 in
+# its cells) does not, where the same name made the step slower on the
+# chip than the product it spared (PERF.md section 6, PR 34).
+SAVED_RESIDUAL = "residual_after_attention"
 
 
 def _layer_ctors(mod):
@@ -94,22 +106,42 @@ class Block(nn.Module):
 
 # One policy object for every block: jaxprs and jit's caches compare it
 # by identity.
-_KEEP_KERNEL_RESULTS = jax.checkpoint_policies.save_only_these_names(
-    SAVED_OUT, SAVED_LSE, SAVED_MAPS, SAVED_Y
+_KEEP_ACROSS_REMAT = jax.checkpoint_policies.save_only_these_names(
+    SAVED_OUT, SAVED_LSE, SAVED_MAPS, SAVED_Y, SAVED_RESIDUAL, SAVED_ROUTING
 )
 
 
 def remat_block(block_cls):
     """``block_cls`` under per-block rematerialization, the one rule of
     every model here that has a ``remat`` field: the backward pass
-    recomputes a block from its input, and of what the block made only
-    the attention kernel's output and logsumexp are kept (by name:
-    ``ops/pallas_attention.py``; bf16 ``(B, T, H*Dv)`` and f32 ``(B,
-    H, T)`` a block), so the recomputed forward does not hold the
-    kernel. Where the trace has no such names (the dense path, an
-    injected attention of another kind) nothing but the input is
-    saved."""
-    return nn.remat(block_cls, policy=_KEEP_KERNEL_RESULTS)
+    recomputes a block from its input, and of what the block made it
+    keeps, by name, what costs most to remake a byte. Six names, each
+    given where the value is made, and a block keeps those its trace
+    holds:
+
+    - ``SAVED_OUT``, ``SAVED_LSE`` (``ops/pallas_attention.py``): an
+      attention kernel's output and logsumexp, bf16 ``(B, T, H*Dv)``
+      and f32 ``(B, H, T)``, so the recomputed forward holds no kernel;
+    - ``SAVED_RESIDUAL`` (this file; given by ``LatentMoEBlock``
+      without streams and ``GroupedWindowMoEBlock``, on every attention
+      path, the dense one too): the residual stream after attention,
+      ``x + proj(o)``, one ``(B, T, d)`` array at the compute dtype, so
+      ``proj`` is not multiplied again;
+    - ``SAVED_ROUTING`` (``ops/moe.py::RoutedExperts``): the router's
+      float32 logits ``(N, E)``, with sigmoid scoring its choices and
+      their scores ``(N, k)``, the order into expert order ``(N*k,)``
+      and the counts, so neither the float32 product nor the gathers
+      and sorts after it run again;
+    - ``SAVED_MAPS``, ``SAVED_Y`` (``ops/hyper_connection.py``): a
+      connection's projections and norm factor, and its sublayer's
+      output, which does there what ``SAVED_RESIDUAL`` does around a
+      plain residual add.
+
+    Everything else (q, k, v, the MLP's or the experts' hidden
+    activations, the norms) is made again from the block's input;
+    where the trace holds none of the names (a ``TransformerLM`` on the
+    dense path) nothing but the input is saved."""
+    return nn.remat(block_cls, policy=_KEEP_ACROSS_REMAT)
 
 
 def _placement(x):

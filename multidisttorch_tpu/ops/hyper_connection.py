@@ -43,13 +43,18 @@ accumulate in float32 and round once, to the streams' dtype. The norm's factor i
 the streams as they are and is scaled after: ``x~ phi = r (X (g phi))``.
 
 **Under ``nn.remat``** (``models/transformer.py::remat_block`` keeps
-these names): the projections' products and the norm's factor, 25
-float32 a token and sublayer (:data:`SAVED_MAPS`), so the recomputed forward
-reads the streams for the mixes alone, and the sublayer's output
-(:data:`SAVED_Y`), which the backward of ``Hpost^T y`` needs where a
-plain residual add needed nothing: without it the recomputed block
-would run ``F`` to its end. The 20 iterations run again in the
-backward pass, for their own residuals.
+six names: the attention kernels' ``SAVED_OUT`` and ``SAVED_LSE``, an
+expert block's ``SAVED_RESIDUAL``, the router's ``SAVED_ROUTING`` and
+the two given here): the projections' products and the norm's factor,
+25 float32 a token and sublayer (:data:`SAVED_MAPS`), so the recomputed
+forward reads the streams for the mixes alone, and the sublayer's
+output (:data:`SAVED_Y`), which the backward of ``Hpost^T y`` needs
+where a plain residual add needed nothing: without it the recomputed
+block would run ``F`` to its end. It is also what spares the
+recomputed block ``proj``, as the stream after attention
+(``SAVED_RESIDUAL``) does around a plain residual add, which is why a
+block behind connections does not name that stream. The 20 iterations
+run again in the backward pass, for their own residuals.
 
 Scopes (``utils/profiling.py``): ``hc_maps`` (norm, projections,
 sigmoids, Sinkhorn, the counter) and ``hc_mix`` (the three mixes).

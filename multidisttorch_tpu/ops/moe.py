@@ -51,6 +51,7 @@ from typing import Any, Callable, NamedTuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -62,6 +63,12 @@ from multidisttorch_tpu.utils.profiling import (
     SCOPE_ROUTER,
     SCOPE_SHARED_EXPERT,
 )
+
+# What ``RoutedExperts``' router makes and the backward pass reads, by
+# the name the models' remat rule (``models/transformer.py::remat_block``)
+# keeps: the recomputed block then runs neither the float32 product nor
+# the gathers and sorts after it, to remake arrays of a few MB.
+SAVED_ROUTING = "router_results"
 
 
 class MoEMLP(nn.Module):
@@ -491,6 +498,11 @@ class RoutedExperts(nn.Module):
         w_up = self.param("w_up", per_expert, (count, d, h), jnp.float32)
         w_down = self.param("w_down", per_expert, (count, h, d), jnp.float32)
 
+        # Under ``nn.remat`` a name keeps a value only for those who read the
+        # named copy, and a primitive's own derivative rule reads the
+        # primitive's output: so the logits are named before the sigmoid
+        # (which runs again, one elementwise pass) and the choices before
+        # the gather, and everyone after takes the named value.
         with jax.named_scope(SCOPE_ROUTER):
             # float32 in earnest: on the TPU a float32 product otherwise
             # runs as one bf16 pass, and a choice among 256 close scores
@@ -498,16 +510,23 @@ class RoutedExperts(nn.Module):
             scores = jnp.dot(
                 routed_from.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST
             )  # (N, E)
+            scores = checkpoint_name(scores, SAVED_ROUTING)
             if self.scoring == "sigmoid":
                 scores = jax.nn.sigmoid(scores)
                 _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), k)
-            else:  # the chosen logits come with the choice: no (N, k) gather
+                chosen = checkpoint_name(chosen, SAVED_ROUTING)
+            else:
+                # the chosen logits come with the choice: no (N, k) gather.
+                # ``top_k``'s derivative rule gathers by its own indices, so
+                # it runs again on the saved logits and a name would keep nothing
                 picked, chosen = jax.lax.top_k(scores, k)
             # for whoever asks (``mutable=["intermediates"]``): a test, the
             # benchmark's comparison of choices with its reference
             self.sow("intermediates", "chosen", chosen)
             if self.scoring == "sigmoid":
                 picked = jnp.take_along_axis(scores, chosen, axis=-1)  # (N, k)
+                # the normalisation's backward reads them
+                picked = checkpoint_name(picked, SAVED_ROUTING)
                 weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
             else:
                 weights = jax.nn.softmax(picked, axis=-1)
@@ -522,10 +541,12 @@ class RoutedExperts(nn.Module):
                 weights = jax.lax.stop_gradient(share) * (here / jnp.maximum(share, 1e-20))
             # expert order, the assignments to absent experts last
             group = jnp.where(held, local, count).reshape(n * k)
-            order = jnp.argsort(group, stable=True)  # row -> pair, as token * k + slot
+            # row -> pair, as token * k + slot
+            order = checkpoint_name(jnp.argsort(group, stable=True), SAVED_ROUTING)
             counts = jnp.sum(
                 group[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32
             )
+            counts = checkpoint_name(counts, SAVED_ROUTING)
             total = jnp.sum(counts)
 
         with jax.named_scope(SCOPE_EXPERTS):

@@ -161,9 +161,9 @@ def make_lm_train_step(
     model with ``TransformerLM(remat=True)`` — per-BLOCK checkpointing,
     the placement that actually cuts peak HBM (a whole-forward
     ``jax.checkpoint`` here would recompute everything and save
-    nothing); a block's input is saved, and the attention kernel's
-    output and logsumexp where the kernel runs
-    (``models/transformer.py::remat_block``). A model returning
+    nothing); a block's input is saved, and of what the block made the
+    few values that cost most to remake a byte
+    (``models/transformer.py::remat_block`` lists them). A model returning
     ``(logits, aux)`` with a scalar ``aux`` (the MoE LM's Switch
     load-balancing term) trains on
     ``lm_loss + aux_loss_weight * aux``; one returning ``(logits,

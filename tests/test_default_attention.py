@@ -163,17 +163,21 @@ def test_injected_attention_gets_latent_attention_assembled(as_tpu):
 # the kernel on the parts (PR 30), and again at PR 32, whose expert
 # layer sums over the buffer's rows (a sort of every slot, a gather
 # and a product over them less; a scatter of the weights' gradient
-# and one scatter-add more).
+# and one scatter-add more), and at PR 34, whose remat rule keeps the
+# stream after attention and the router's results (seven names, four of
+# them floats that jax rounds where they are kept; out of the recomputed
+# blocks went ``proj`` twice and the expert layer's router product,
+# top-k, gather of the picked scores, sort into expert order and count).
 _ASSEMBLED_STEP = {
-    "add": 225, "add_any": 49, "and": 14, "broadcast_in_dim": 234, "concatenate": 27,
-    "convert_element_type": 43, "cos": 8, "cumsum": 2, "div": 157, "dot_general": 85,
-    "dynamic_slice": 2, "eq": 18, "exp": 5, "gather": 13, "ge": 2, "integer_pow": 67, "iota": 27,
-    "jit": 103, "le": 4, "log": 1, "logistic": 8, "lt": 32, "max": 9, "min": 4, "mul": 353,
-    "ne": 24, "neg": 26, "pad": 29, "pow": 10, "ragged_dot_general": 8, "reduce_max": 5,
-    "reduce_sum": 70, "rem": 12, "remat2": 2, "reshape": 95, "reshard": 7, "rsqrt": 17,
-    "scatter": 1, "scatter-add": 7, "select_n": 60, "sign": 8, "sin": 8, "slice": 60, "sort": 2,
-    "split": 13, "sqrt": 32, "square": 17, "squeeze": 1, "stop_gradient": 7, "sub": 43,
-    "top_k": 2, "transpose": 32,
+    "add": 222, "add_any": 49, "and": 13, "broadcast_in_dim": 230, "concatenate": 27,
+    "convert_element_type": 41, "cos": 8, "cumsum": 2, "div": 157, "dot_general": 82,
+    "dynamic_slice": 2, "eq": 17, "exp": 5, "gather": 12, "ge": 1, "integer_pow": 67, "iota": 25,
+    "jit": 101, "le": 4, "log": 1, "logistic": 8, "lt": 31, "max": 9, "min": 4, "mul": 353,
+    "name": 7, "ne": 24, "neg": 26, "pad": 29, "pow": 10, "ragged_dot_general": 8,
+    "reduce_max": 5, "reduce_precision": 4, "reduce_sum": 69, "rem": 12, "remat2": 2,
+    "reshape": 94, "reshard": 7, "rsqrt": 17, "scatter": 1, "scatter-add": 7, "select_n": 59,
+    "sign": 8, "sin": 8, "slice": 60, "sort": 1, "split": 13, "sqrt": 32, "square": 17,
+    "squeeze": 1, "stop_gradient": 6, "sub": 42, "top_k": 1, "transpose": 32,
 }
 
 
@@ -188,7 +192,7 @@ def test_latent_attention_step_elsewhere_is_the_assembled_one(devices, request):
     if devices == 4:
         request.getfixturevalue("as_tpu")
         assert _counts(_step_jaxpr(group, _latent_lm(remat=True))) == counts
-    assert sum(_counts(_step_jaxpr(group, _latent_lm())).values()) == 1616
+    assert sum(_counts(_step_jaxpr(group, _latent_lm())).values()) == 1616 + 7  # the names
 
 
 # The parameter tree of the latent-attention LM: what checkpoints and
@@ -436,14 +440,16 @@ def test_default_and_injected_flash_are_the_same_program(as_tpu):
 # --- what rematerialization keeps (transformer.remat_block) ---
 
 
-def _loss_and_grads(model):
-    """One jitted ``value_and_grad`` of a two-block LM over the kernel
-    (interpreted), parameters and inputs from fixed seeds."""
-    tokens = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, T)), jnp.int32)
-    params = TransformerLM(**CFG).init(jax.random.key(0), tokens)["params"]
+def _loss_and_grads(model, init=TransformerLM(**CFG), t=T):
+    """One jitted ``value_and_grad`` of an LM (by default a two-block
+    one over the kernel, interpreted), parameters and inputs from fixed
+    seeds."""
+    tokens = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, t)), jnp.int32)
+    params = init.init(jax.random.key(0), tokens)["params"]
 
     def loss(p):
-        logits = model.apply({"params": p}, tokens).astype(jnp.float32)
+        out = model.apply({"params": p}, tokens)
+        logits = (out[0] if isinstance(out, tuple) else out).astype(jnp.float32)
         return optax.softmax_cross_entropy_with_integer_labels(
             logits[:, :-1], tokens[:, 1:]
         ).mean()
@@ -494,6 +500,63 @@ def test_the_policy_is_inert_on_the_dense_path(monkeypatch):
     assert policy.sub("", str(with_policy)) == policy.sub("", str(bare))
 
 
+@pytest.mark.parametrize(
+    "kind, fields",
+    [
+        ("latent", {}),  # sigmoid scoring, every expert held
+        ("latent", {"experts_held": (2, 3)}),  # one chip's share: most choices land elsewhere
+        ("grouped", {}),  # softmax scoring, the router on the block's input
+        ("grouped", {"experts_held": (2, 3), "absent_share_grad": False}),
+    ],
+    ids=["sigmoid-whole", "sigmoid-cut", "softmax-whole", "softmax-cut"],
+)
+def test_the_kept_residual_and_routing_leave_the_gradients_bit_equal(monkeypatch, kind, fields):
+    """The residual after attention and the router's results, kept by
+    name (on the dense path too: this is the CPU), are what the
+    recomputed block would have made again: in float32, rounded where
+    the program says so, loss and every gradient leaf under the models'
+    remat rule equal those under ``nn.remat`` with no policy to the
+    last bit, and the rule does keep them (a block's ``proj`` and an
+    expert layer's router product, sort into expert order and, with
+    sigmoid scoring, ``top_k`` are not in the recomputed blocks)."""
+    from multidisttorch_tpu.models.grouped_window_moe import GroupedWindowMoELM
+    from multidisttorch_tpu.models.latent_moe import LatentMoELM
+    from multidisttorch_tpu.ops.moe import SAVED_ROUTING
+
+    make = {"latent": LatentMoELM, "grouped": GroupedWindowMoELM}[kind]
+    model = make(vocab_size=64, max_len=16, remat=True, **fields)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.key(0), tokens)["params"]
+
+    def gradient():  # traced anew each time: make_jaxpr remembers a function's trace
+        logits = lambda p: model.apply({"params": p}, tokens)[0]
+        return jax.make_jaxpr(jax.grad(lambda p: logits(p).sum()))(params)
+
+    saved, kept = _loss_and_grads(model, init=model, t=16), gradient()
+    monkeypatch.setattr(transformer, "remat_block", nn.remat)  # only a block's input is saved
+    bare, again = _loss_and_grads(model, init=model, t=16), _recomputed(gradient())
+    names = {e.params["name"] for e in _equations(kept) if e.primitive.name == "name"}
+    assert names == {transformer.SAVED_RESIDUAL, SAVED_ROUTING}
+    kept = _recomputed(kept)
+    routers = model.num_layers - getattr(model, "dense_layers", 0)
+    assert again["dot_general"] - kept["dot_general"] == model.num_layers + routers
+    assert again["sort"] - kept["sort"] == routers
+    assert (again["top_k"], kept["top_k"]) == (routers, 0 if kind == "latent" else routers)
+    still = [g for g in jax.tree.leaves(saved[1]) if not float(jnp.abs(g).max())]
+    assert len(still) <= routers  # the selection biases: they choose and never weigh
+    for a, b in zip(jax.tree.leaves(saved), jax.tree.leaves(bare), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _recomputed(jaxpr) -> collections.Counter:
+    """Equations inside the gradient's recomputed blocks, by primitive."""
+    inside = collections.Counter()
+    for eqn in _equations(jaxpr):
+        if eqn.primitive.name == "remat2":
+            inside.update(_counts(eqn.params["jaxpr"]))
+    return inside
+
+
 def test_bare_remat_runs_the_forward_kernel_twice(as_tpu, monkeypatch):
     """The other side of ``test_one_chip_step_runs_the_kernel``'s
     count: without the policy the recomputed block holds the forward
@@ -517,7 +580,7 @@ def test_hops_under_checkpoint_keep_the_logsumexp_gradient(saved):
 
     hop = jax.checkpoint(
         lambda q, k, v: _attend(q, k, v, causal=False),
-        policy=transformer._KEEP_KERNEL_RESULTS if saved else None,  # the models' own
+        policy=transformer._KEEP_ACROSS_REMAT if saved else None,  # the models' own
     )
     per_row = lambda x: x.transpose(0, 2, 1)[..., None]  # (B, H, T) on (B, T, H, D)
 

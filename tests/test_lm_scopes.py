@@ -226,8 +226,10 @@ def test_expert_layer_scopes_reach_the_compiled_step(remat):
     # latent attention's pieces carry the names of a plain block's
     parts = {scope_reduce.classify(n)[0] for n in step}
     assert {"attn_core", "attn_proj", "mlp", "norm", "embed", "head", "loss", "optimizer"} <= parts
-    for name in ("q", "k", "v", "proj"):
+    for name in ("q", "k", "v"):
         assert {_pass(n) for n in step if name in _components(n)} >= both, name
+    # the stream after attention is kept across remat: ``proj`` is not multiplied again
+    assert {_pass(n) for n in step if "proj" in _components(n)} == {"forward", "backward"}
     assert {_pass(n) for n in step if SCOPE_MLP in _components(n)} >= both  # the dense layer
     unrecognised = [n for n in step if scope_reduce.classify(n)[0] in ("unscoped", "block_other")]
     assert len(unrecognised) / len(step) < UNRECOGNISED_BOUND, (
@@ -281,8 +283,11 @@ def test_latent_attention_on_the_parts_keeps_the_scopes(monkeypatch, remat):
     unrecognised = [n for n in step if scope_reduce.classify(n)[0] in ("unscoped", "block_other")]
     assert len(unrecognised) / len(step) < UNRECOGNISED_BOUND
     # what is left without a name is what the assembled path leaves too:
-    # the residual adds, the positions' iota and remat's own equations
-    assert {n.rsplit("/", 1)[-1] for n in unrecognised} <= {"add", "iota", "remat2"}
+    # the residual adds (the one after attention rounded where remat keeps
+    # it), the positions' iota and remat's own equations
+    assert {n.rsplit("/", 1)[-1] for n in unrecognised} <= {
+        "add", "reduce_precision", "iota", "remat2"
+    }
 
 
 def test_latent_scopes_stay_out_of_the_parameter_tree():
@@ -421,8 +426,9 @@ def test_window_and_full_scopes_reach_the_compiled_step(remat):
     parts = {scope_reduce.classify(n)[0] for n in step}
     assert {"attn_core", "attn_proj", "mlp", "norm", "embed", "head", "loss", "optimizer"} <= parts
     # the rotations of q and k (window layers only) are the projections'
-    for name in ("q", "k", "v", "proj"):
+    for name in ("q", "k", "v"):
         assert {_pass(n) for n in step if name in _components(n)} >= every, name
+    assert {_pass(n) for n in step if "proj" in _components(n)} == {"forward", "backward"}
     unrecognised = [n for n in step if scope_reduce.classify(n)[0] in ("unscoped", "block_other")]
     assert len(unrecognised) / len(step) < UNRECOGNISED_BOUND, (
         len(unrecognised), len(step), sorted(set(unrecognised))[:20]
@@ -462,6 +468,48 @@ def test_grouped_kernels_are_under_their_layer_s_scope_once_a_pass(monkeypatch, 
     assert rotated("k") and not rotated("q")
     unrecognised = [n for n in step if scope_reduce.classify(n)[0] in ("unscoped", "block_other")]
     assert len(unrecognised) / len(step) < UNRECOGNISED_BOUND
+
+
+# --- what remat keeps of a block (transformer.remat_block) ---
+
+_REMAT_STEPS = {
+    "dense": lambda: _lowered(TransformerLM, True),  # the control: its block names nothing
+    "latent": lambda: _lowered_latent(True),  # sigmoid scoring
+    "latent-cut": lambda: _lowered_latent(True, experts_held=(2, 3)),
+    "grouped": lambda: _lowered_grouped(True),  # softmax scoring
+}
+
+
+@pytest.mark.parametrize("model", list(_REMAT_STEPS))
+def test_the_recomputed_block_leaves_out_what_remat_keeps(model):
+    """In the compiled tiny steps under remat the recomputed blocks of
+    the two expert models hold q, k and v and no operation of ``proj``
+    (the stream after attention is kept; ``TransformerLM``'s block does
+    not name it and multiplies by ``proj`` again); of the router they
+    hold no product and, with sigmoid scoring, no top-k and no gather
+    either (logits, choices and picked scores are kept; the sigmoid and
+    the normalisation are made again); with softmax scoring ``top_k``
+    runs again on the kept logits, its derivative rule reading its own
+    indices; and the exchange holds no sort (the order into expert
+    order is kept; the sums by token are XLA's scatter-adds here and
+    sort nothing)."""
+    from benchmark import moe_scopes
+    from multidisttorch_tpu.utils.profiling import SCOPE_EXPERT_DISPATCH, SCOPE_ROUTER
+
+    names = re.findall(r'op_name="([^"]*)"', _REMAT_STEPS[model]()[0].compile().as_text())
+    again = [n for n in names if n.startswith("jit(step_fn)") and _pass(n) == "recompute"]
+    for name in ("q", "k", "v", "ln_attn", "ln_mlp"):
+        assert any(name in _components(n) for n in again), name
+    assert any("proj" in _components(n) for n in again) == (model == "dense")
+    made = lambda scope: {n.rsplit("/", 1)[-1] for n in again if moe_scopes.classify(n) == scope}
+    router = made(SCOPE_ROUTER)
+    if model == "dense":
+        assert not router
+        return
+    assert router and not router & {"dot_general", "sort", "gather"}
+    assert ("top_k" in router) == (model == "grouped")
+    assert ("exp" in router) and "div" in router  # the scoring itself is cheap, and made again
+    assert "sort" not in made(SCOPE_EXPERT_DISPATCH)
 
 
 def test_grouped_scopes_stay_out_of_the_parameter_tree():
