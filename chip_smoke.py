@@ -43,7 +43,10 @@ from multidisttorch_tpu.hpo import TrialConfig, run_hpo  # noqa: E402
 from multidisttorch_tpu.models.vae import VAE  # noqa: E402
 from multidisttorch_tpu.parallel.mesh import setup_groups  # noqa: E402
 from multidisttorch_tpu.train import ckpt_store  # noqa: E402
-from multidisttorch_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
+from multidisttorch_tpu.utils.compile_cache import (  # noqa: E402
+    compile_log,
+    enable_compile_cache,
+)
 
 
 @dataclass(frozen=True)
@@ -99,51 +102,29 @@ def check(cond, msg: str) -> None:
         raise SmokeFailure(msg)
 
 
-class CompileBook:
-    """Counts this process's XLA compiles through jax.monitoring: the
-    persistent cache's hits and misses (a miss is an entry written) and
-    the seconds spent in backend compile or cache retrieval."""
-
-    def __init__(self):
-        self.hits = self.misses = 0
-        self.compile_s = 0.0
-        jax.monitoring.register_event_listener(self._on_event)
-        jax.monitoring.register_event_duration_secs_listener(self._on_secs)
-
-    def _on_event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def _on_secs(self, event: str, secs: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-
-    def snapshot(self) -> tuple[int, int, float]:
-        return self.hits, self.misses, self.compile_s
-
-
 @contextlib.contextmanager
-def phase(name: str, devices, book: CompileBook):
+def phase(name: str, devices):
     """Announce a phase with the device it runs on; report its wall
-    time (and compile share) when it returns. An exception passes
-    through: a failed phase fails the run."""
+    time (and, from the program's compile log, its compile share) when
+    it returns. An exception passes through: a failed phase fails the
+    run."""
     d0 = devices[0]
     where = (
         f"platform={d0.platform} device_kind={d0.device_kind!r} "
         f"devices={len(devices)}"
     )
     say(f"{name}: start {where}")
-    before = book.snapshot()
+    log = compile_log()  # installed by enable_compile_cache()
+    before = log.snapshot()
     t0 = time.perf_counter()
     yield
     wall = time.perf_counter() - t0
-    after = book.snapshot()
+    after = log.snapshot()
     say(
         f"{name}: ok wall_s={wall:.2f} compile_s="
-        f"{after[2] - before[2]:.2f} cache_hits={after[0] - before[0]} "
-        f"cache_misses={after[1] - before[1]} {where}",
+        f"{after['backend_s'] - before['backend_s']:.2f} cache_hits="
+        f"{after['hits'] - before['hits']} cache_misses="
+        f"{after['misses'] - before['misses']} {where}",
     )
 
 
@@ -569,8 +550,7 @@ def main() -> int:
     cache_dir = enable_compile_cache()
     entries_before = cache_entries(cache_dir)
     say(f"compile cache: {cache_dir} ({entries_before} entries)")
-    book = CompileBook()
-    with phase("1 backend", jax.devices(), book):
+    with phase("1 backend", jax.devices()):
         devices = phase_backend()
     size = Size()
     train = synthetic_mnist(size.train_rows, seed=0)
@@ -578,24 +558,25 @@ def main() -> int:
     # Trial directories, checkpoints and the service's state: some
     # hundred MB nobody reads after the checks, so they go with the run.
     with tempfile.TemporaryDirectory(prefix="chip_smoke_") as out_dir:
-        with phase("2 run_hpo classic", devices, book):
+        with phase("2 run_hpo classic", devices):
             phase_classic(devices, out_dir, size, train, test)
-        with phase("3 run_hpo stacked", devices, book):
+        with phase("3 run_hpo stacked", devices):
             phase_stacked(devices, out_dir, size, train, test)
         if len(devices) >= 4:
-            with phase("4 multi-chip trials", devices, book):
+            with phase("4 multi-chip trials", devices):
                 phase_multichip(devices, out_dir, size, train, test)
         else:
             say(f"4 multi-chip trials: needs 4 devices, have {len(devices)}")
-        with phase("5 sweep service", devices, book):
+        with phase("5 sweep service", devices):
             phase_service(devices, out_dir, size, train, test)
-    with phase("6 kernels", devices, book):
+    with phase("6 kernels", devices):
         phase_kernels(devices)
     entries_after = cache_entries(cache_dir)
+    total = compile_log().snapshot()
     say(
         f"7 cache: {cache_dir} entries {entries_before} -> "
-        f"{entries_after}; this run hits={book.hits} misses={book.misses} "
-        f"compile_s={book.compile_s:.2f} still_on="
+        f"{entries_after}; this run hits={total['hits']} misses="
+        f"{total['misses']} compile_s={total['backend_s']:.2f} still_on="
         f"{jax.config.jax_enable_compilation_cache} total_wall_s="
         f"{time.perf_counter() - t_start:.1f}",
     )

@@ -47,6 +47,11 @@ from multidisttorch_tpu.train.lm import (  # noqa: E402
     make_lm_multi_step,
     make_lm_train_step,
 )
+from multidisttorch_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
+from multidisttorch_tpu.utils.profiling import (  # noqa: E402
+    admission_line,
+    admission_split,
+)
 
 
 def _plan_mpmd_pipeline(args) -> None:
@@ -221,6 +226,7 @@ def main():
                 f"--model-parallel {args.model_parallel} must divide "
                 f"the model's 4 attention heads"
             )
+    enable_compile_cache()  # the persistent cache, and the compile log
     groups = mdt.setup_groups(args.ngroups, model_parallel=args.model_parallel)
     if args.seq_len % groups[0].data_size:
         parser.error(
@@ -252,6 +258,7 @@ def main():
     for g, lr in zip(groups, lrs):
         if not g.is_local_member:  # multi-host: skip remote submeshes
             continue
+        t_admit = time.perf_counter()
         if args.latent_moe:
             from multidisttorch_tpu.models.latent_moe import LatentMoELM
 
@@ -333,6 +340,7 @@ def main():
                 g, model, tx, sequence_parallel=True, shardings=sh
             )
             entry["input"] = entry["tokens"]
+        entry["admit"] = (t_admit, time.perf_counter())
         trials.append(entry)
 
     kind = "ring-flash" if args.ring_flash else "ring"
@@ -357,7 +365,26 @@ def main():
     interval = 10
     for i in range(args.steps // K):
         for t in trials:
+            t_call = time.perf_counter()
             t["state"], t["m"] = t["step"](t["state"], t["input"])
+            if i == 0:
+                # Why the trial took this long to start, from the
+                # program's compile log (docs/OBSERVABILITY.md,
+                # "Admission"): its state's programs, then the step's
+                # trace, lowering and load or compile. Waiting for the
+                # first loss holds the next trial's first call back,
+                # this once.
+                jax.block_until_ready(t["m"]["loss"])
+                t_ready = time.perf_counter()
+                program = t["step"].__name__  # train.lm.STEP_PROGRAM when K == 1
+                mdt.log0(
+                    admission_line(
+                        admission_split(program, *t["admit"]),
+                        admission_split(program, t_call, t_ready),
+                        t["admit"][1] - t["admit"][0] + t_ready - t_call,
+                    ),
+                    trial=t["trial"],
+                )
         # Log the loss of EVERY step a per-step loop would have logged
         # in this chunk, labeled with that step (the fused metrics come
         # back (K,), so each cadence point is indexable — same contract

@@ -256,7 +256,28 @@ def setup_groups(
       neighbors are ``m`` device positions apart — adjacent when
       ``m == 1`` — so GPipe's stage-to-stage ppermute hops stay on
       short ICI paths.
+
+    Timed into the process's compile log as the span
+    ``admit:setup_groups`` (``utils/profiling.span``); with
+    ``devices=None`` in a process that has not asked for its devices
+    yet, that is the backend's start too.
     """
+    # utils/__init__ imports this module (through utils/logging).
+    from multidisttorch_tpu.utils.profiling import SPAN_SETUP_GROUPS, span
+
+    with span(SPAN_SETUP_GROUPS):
+        return _carve_groups(
+            num_groups,
+            devices,
+            allow_uneven=allow_uneven,
+            model_parallel=model_parallel,
+            pipeline_parallel=pipeline_parallel,
+        )
+
+
+def _carve_groups(
+    num_groups, devices, *, allow_uneven, model_parallel, pipeline_parallel
+) -> list[TrialMesh]:
     devs = list(jax.devices()) if devices is None else list(devices)
     world = len(devs)
     if num_groups < 1:

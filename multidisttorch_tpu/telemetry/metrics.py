@@ -1,17 +1,15 @@
 """Metrics registry: counters, gauges, fixed-bucket histograms, and the
 sweep's step-time accounting.
 
-Absorbs and extends ``utils/profiling.StepTimer``: where the StepTimer
-collects one trial's raw mark-to-mark latencies, the registry holds the
-whole sweep's timing state keyed by series name + labels, understands
-**stacked buckets** (a mark that advances K lanes is one dispatch but K
-lane-steps — ``StepSeries`` keeps both books, so per-lane effective
-step rate falls out of the totals), separates **dispatch time** (what a
+The registry holds the whole sweep's timing state keyed by series name
++ labels, understands **stacked buckets** (a mark that advances K lanes
+is one dispatch but K lane-steps — ``StepSeries`` keeps both books, so
+per-lane effective step rate falls out of the totals), separates **dispatch time** (what a
 mark measures in an async-dispatch loop) from **device-inclusive time**
 (sampled sparsely via ``jax.block_until_ready`` every
 ``device_sample_every`` marks — cheap enough for the <= 2% overhead
 budget, honest enough to catch a device-bound step), and counts
-compiles (best-effort ``jax.monitoring`` listener).
+compiles (from the process's compile log, ``utils/compile_cache.py``).
 
 Histograms use FIXED log-spaced bucket bounds, so percentiles are
 bucket-upper-bound estimates computed in O(buckets) with zero per-
@@ -187,8 +185,7 @@ class StepSeries:
     ``mark(steps=s, lanes=k)`` closes the interval since the previous
     mark: one *dispatch* advancing ``s`` optimizer steps on each of
     ``k`` live lanes (classic trials are the k=1, s=1-or-fused case).
-    This is the stacked-mode fix for the old ``StepTimer`` semantics,
-    where a K-lane mark silently read as ONE trial's step time: the
+    A K-lane mark must not read as ONE trial's step time: the
     bucket's dispatch latency and its lane-step count are kept apart,
     and the per-lane effective step rate is derived from the totals
     (``lane_steps / total_s``), never from misattributing the bucket's
@@ -433,34 +430,29 @@ def disable() -> None:
 
 
 def install_compile_listener() -> bool:
-    """Best-effort compile accounting via ``jax.monitoring``: every
-    compile-flavored duration event increments ``compile_count`` and
-    accumulates ``compile_seconds``. Installed once per process (JAX
-    offers no unregister); the listener reads the CURRENT registry, so
-    after :func:`disable` it is a cheap no-op."""
+    """Compile accounting from the process's compile log
+    (``utils/compile_cache.CompileLog``, which alone listens to jax's
+    monitoring events): every ``backend`` entry, one a program compiled
+    or loaded from the persistent cache, increments ``compile_count``
+    and adds its seconds to ``compile_seconds``. A program's traces and
+    lowering are in the log and not in these series. Subscribed once
+    per process; the sink reads the CURRENT registry, so after
+    :func:`disable` it is a cheap no-op."""
     global _compile_listener_installed
     if _compile_listener_installed:
         return True
-    try:
-        from jax import monitoring
-    except ImportError:
-        return False
-    hook = getattr(
-        monitoring, "register_event_duration_secs_listener", None
+    from multidisttorch_tpu.utils.compile_cache import (
+        STAGE_BACKEND,
+        install_compile_log,
     )
-    if hook is None:
-        return False
 
-    def on_event(name: str, secs: float, **kw) -> None:
+    def on_entry(entry) -> None:
         reg = _registry
-        if reg is None or "compile" not in name:
+        if reg is None or entry.stage != STAGE_BACKEND:
             return
         reg.counter("compile_count").inc()
-        reg.counter("compile_seconds").inc(secs)
+        reg.counter("compile_seconds").inc(entry.secs)
 
-    try:
-        hook(on_event)
-    except Exception:  # noqa: BLE001 — observability never raises
-        return False
+    install_compile_log().subscribe(on_entry)
     _compile_listener_installed = True
     return True

@@ -25,7 +25,22 @@ import optax
 
 from multidisttorch_tpu.parallel.mesh import DATA_AXIS, TrialMesh
 from multidisttorch_tpu.train.steps import TrainState
-from multidisttorch_tpu.utils.profiling import SCOPE_LOSS, SCOPE_OPTIMIZER
+from multidisttorch_tpu.utils.profiling import (
+    SCOPE_LOSS,
+    SCOPE_OPTIMIZER,
+    SPAN_INIT_OPT,
+    SPAN_INIT_PARAMS,
+    SPAN_INIT_STATE,
+    SPAN_PLACE_STATE,
+    span,
+)
+
+# The name jax gives the train step's program, in its compile events and
+# so in the compile log (``utils/compile_cache.py``): ``step_fn``'s own.
+# :func:`make_lm_train_step` returns the ``jax.jit`` object itself
+# (callers use ``.lower``), so the step's trace, lowering and load are
+# read from the log by this name and not from a wrapper on the hot path.
+STEP_PROGRAM = "step_fn"
 
 
 def _logits(out):
@@ -302,23 +317,38 @@ def create_lm_state(
     (e.g. ``parallel.fsdp.fsdp_param_shardings``) via the shared
     ``train.steps.place_sharded_state`` recipe — same contract as the
     VAE and classifier state creators.
+
+    Timed where it happens, into the process's compile log
+    (``utils/profiling.span``): ``admit:init_state`` around the whole,
+    and inside it ``admit:init_params`` (``model.init``, eager: one
+    small program an initializer and shape, each traced, lowered and
+    loaded or compiled by itself), ``admit:init_opt`` (``tx.init``) and
+    ``admit:place_state``. The spans are host time: dispatch and
+    transfers are asynchronous, so the last programs and copies may
+    still be running when ``admit:place_state`` closes.
     """
     from multidisttorch_tpu.train.steps import place_sharded_state
 
     if example_len is None:
         example_len = 8 * trial.data_size
-    params = model.init(
-        {"params": rng}, jnp.zeros((1, example_len), jnp.int32)
-    )["params"]
-    if param_shardings is not None:
-        return place_sharded_state(trial, params, tx, param_shardings)
-    return trial.device_put(
-        TrainState(
-            params=params,
-            opt_state=tx.init(params),
-            step=jnp.zeros((), jnp.int32),
-        )
-    )
+    with span(SPAN_INIT_STATE):
+        with span(SPAN_INIT_PARAMS):
+            params = model.init(
+                {"params": rng}, jnp.zeros((1, example_len), jnp.int32)
+            )["params"]
+        if param_shardings is not None:
+            with span(SPAN_PLACE_STATE):  # tx.init runs on the placed weights
+                return place_sharded_state(trial, params, tx, param_shardings)
+        with span(SPAN_INIT_OPT):
+            opt_state = tx.init(params)
+        with span(SPAN_PLACE_STATE):
+            return trial.device_put(
+                TrainState(
+                    params=params,
+                    opt_state=opt_state,
+                    step=jnp.zeros((), jnp.int32),
+                )
+            )
 
 
 def make_lm_sample(

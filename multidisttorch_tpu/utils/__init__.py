@@ -1,3 +1,3 @@
 from multidisttorch_tpu.utils.imaging import save_image_grid
 from multidisttorch_tpu.utils.logging import log0
-from multidisttorch_tpu.utils.profiling import StepTimer, profile_trace, trial_timer
+from multidisttorch_tpu.utils.profiling import profile_trace, trial_timer

@@ -3,19 +3,29 @@
 The reference's only instrumentation is one wall-clock print per trial
 (``/root/reference/vae-hpo.py:159,172-174``). Parity requires exactly
 that (:func:`trial_timer`); :func:`profile_trace` adds the nearly-free
-JAX profiler (TensorBoard-loadable traces incl. TPU device timelines),
-and :class:`StepTimer` gives per-step latency stats for finding host-
-side dispatch bottlenecks in multi-trial runs (SURVEY.md §7 "hard
-parts": contention is host-side).
+JAX profiler (TensorBoard-loadable traces incl. TPU device timelines).
+:func:`span` times the host's part of a trial's admission where it
+happens, into the process's compile log
+(``utils/compile_cache.CompileLog``) and, under a profiler session, onto
+the trace's own timeline; :func:`admission_split` reads the two
+together. Per-step latency books are ``telemetry.metrics.StepSeries``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import time
-from dataclasses import dataclass, field
+from typing import Optional
 
-import numpy as np
+from multidisttorch_tpu.utils.compile_cache import (
+    STAGE_BACKEND,
+    STAGE_LOWER,
+    STAGE_RETRIEVAL,
+    STAGE_SPAN,
+    STAGE_TRACE,
+    Sum,
+    compile_log,
+)
 
 
 @contextlib.contextmanager
@@ -58,6 +68,105 @@ SCOPE_HC_MIX = "hc_mix"
 # ``attn_core``, which of the two a layer's core is.
 SCOPE_ATTN_FULL = "attn_full"
 SCOPE_ATTN_WINDOW = "attn_window"
+
+# Host spans of a trial's admission (:func:`span`), each opened where
+# the work is done: ``parallel/mesh.py::setup_groups``; the whole of
+# ``train/lm.py::create_lm_state`` and, inside it, ``model.init``,
+# ``tx.init`` and the placement on the submesh. The step's own trace,
+# lowering and load are in the compile log under the step's name
+# (``train/lm.py::STEP_PROGRAM``), not under a span.
+SPAN_SETUP_GROUPS = "admit:setup_groups"
+SPAN_INIT_STATE = "admit:init_state"
+SPAN_INIT_PARAMS = "admit:init_params"
+SPAN_INIT_OPT = "admit:init_opt"
+SPAN_PLACE_STATE = "admit:place_state"
+
+
+@contextlib.contextmanager
+def span(name: str):
+    """Time a block of host work into the compile log, as the entry
+    ``(span, name, end, secs)`` on ``time.perf_counter()``, under a
+    ``jax.profiler.TraceAnnotation`` of the same name: in any profiler
+    session (``run_hpo(profile_dir=)``'s or an operator's) the span lies
+    on the trace's timeline, above the device operations it caused.
+    Where no log is installed (``enable_compile_cache`` not called) the
+    annotation is all there is."""
+    import jax
+
+    log = compile_log()
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(name):
+            yield
+    finally:
+        if log is not None:
+            log.add_span(name, t0, time.perf_counter())
+
+
+def admission_split(
+    program: str, since: Optional[float], until: Optional[float]
+) -> Optional[dict]:
+    """Where the admissions between ``since`` and ``until`` spent their
+    seconds, from the compile log; ``None`` where none is installed.
+
+    Of the step ``program`` (``train.lm.STEP_PROGRAM``), summed over the
+    trials that first called it in the interval: ``step_trace_s``
+    (outermost), ``step_lower_s``, ``step_load_s`` (the backend's part:
+    the cache key, the read and the deserialisation when the cache hit,
+    the compile when it did not), ``step_retrieval_s`` (the read alone;
+    0 on a miss) and ``step_programs``. Of the ``admit:init_state``
+    spans that ended in it: ``init_s`` (their wall time),
+    ``init_programs`` (``backend`` entries inside them: the programs
+    ``model.init`` and ``tx.init`` dispatch one by one) and
+    ``init_trace_s``, ``init_lower_s``, ``init_load_s``. What is left of
+    ``init_s`` is those programs' own run time and the host's."""
+    log = compile_log()
+    if log is None:
+        return None
+    zero = Sum(0, 0.0)
+    step = log.by_program(since, until).get(program, {})
+    out = {
+        "step_trace_s": step.get(STAGE_TRACE, zero).secs,
+        "step_lower_s": step.get(STAGE_LOWER, zero).secs,
+        "step_load_s": step.get(STAGE_BACKEND, zero).secs,
+        "step_retrieval_s": step.get(STAGE_RETRIEVAL, zero).secs,
+        "step_programs": step.get(STAGE_BACKEND, zero).n,
+        "init_s": 0.0,
+        "init_programs": 0,
+        "init_trace_s": 0.0,
+        "init_lower_s": 0.0,
+        "init_load_s": 0.0,
+    }
+    key = {
+        STAGE_TRACE: "init_trace_s",
+        STAGE_LOWER: "init_lower_s",
+        STAGE_BACKEND: "init_load_s",
+    }
+    for s in log.entries(since, until):
+        if s.stage != STAGE_SPAN or s.program != SPAN_INIT_STATE:
+            continue
+        out["init_s"] += s.secs
+        for e in log.entries(s.start, s.end):
+            if e.stage in key and e.thread == s.thread:
+                out[key[e.stage]] += e.secs
+                if e.stage == STAGE_BACKEND:
+                    out["init_programs"] += 1
+    return out
+
+
+def admission_line(init: dict, step: dict, admitted_s: float) -> str:
+    """One line for an operator, when a trial's first step returns:
+    ``init`` and ``step`` are :func:`admission_split` over the trial's
+    state creation and over its first call of the step."""
+    cached = "hit" if step["step_retrieval_s"] > 0 else "miss"
+    return (
+        f"admitted in {admitted_s:.1f} s: init {init['init_s']:.1f} s "
+        f"({init['init_programs']} programs, trace+lower "
+        f"{init['init_trace_s'] + init['init_lower_s']:.1f} s, load "
+        f"{init['init_load_s']:.1f} s); step trace "
+        f"{step['step_trace_s']:.1f} s, lower {step['step_lower_s']:.1f} s, "
+        f"load {step['step_load_s']:.1f} s ({cached})"
+    )
 
 
 @contextlib.contextmanager
@@ -142,74 +251,3 @@ def profile_window(log_dir: str, *, steps: int = 25) -> ProfileWindow:
     w = ProfileWindow(log_dir, steps=steps)
     w.start()
     return w
-
-
-@dataclass
-class StepTimer:
-    """Rolling per-step latency collector.
-
-    Note: in an async-dispatch loop, per-step host time measures
-    *dispatch* cost; call ``mark(sync=True)`` (blocks on ``value``) at
-    sparse intervals to sample true device-inclusive step time.
-
-    **Stacked-mode semantics**: a mark that closes a K-lane stacked
-    dispatch (docs/STACKING.md) is ONE dispatch but K lane-steps of
-    training progress — pass ``lanes=K`` so the timing is attributed to
-    the *bucket* and :meth:`stats` can report the per-lane effective
-    step rate (``lane_steps / total_s``) instead of silently reading
-    the bucket's latency as a single trial's step time. The sweep-wide
-    generalization of this collector (per-key series, dispatch vs
-    device-sampled books, fixed-bucket percentiles) lives in
-    ``telemetry.metrics.StepSeries``, which absorbs these semantics.
-    """
-
-    times: list = field(default_factory=list)
-    lanes: list = field(default_factory=list)
-    synced: list = field(default_factory=list)
-    _last: float = field(default_factory=time.perf_counter)
-
-    def mark(self, value=None, sync: bool = False, lanes: int = 1):
-        if sync and value is not None:
-            import jax
-
-            jax.block_until_ready(value)
-        now = time.perf_counter()
-        self.times.append(now - self._last)
-        self.lanes.append(lanes)
-        self.synced.append(bool(sync and value is not None))
-        self._last = now
-
-    def stats(self) -> dict:
-        if not self.times:
-            return {}
-        arr = np.asarray(self.times)
-        # Two populations, never mixed (StepSeries' two-books rule): a
-        # sync=True mark includes the device drain a dispatch-only mark
-        # doesn't, so pooling them let a handful of sparse synced
-        # samples contaminate the dispatch p95. Headline percentiles
-        # come from the dispatch-only marks; the synced samples get
-        # their own block below.
-        synced = np.asarray(self.synced, dtype=bool)
-        disp = arr[~synced]
-        pop = disp if disp.size else arr
-        out = {
-            "steps": len(arr),
-            "mean_s": float(pop.mean()),
-            "p50_s": float(np.percentile(pop, 50)),
-            "p95_s": float(np.percentile(pop, 95)),
-            "total_s": float(arr.sum()),
-        }
-        if synced.any() and disp.size:
-            dev = arr[synced]
-            out["device_sampled"] = {
-                "count": int(dev.size),
-                "mean_s": float(dev.mean()),
-                "p50_s": float(np.percentile(dev, 50)),
-                "p95_s": float(np.percentile(dev, 95)),
-            }
-        lane_steps = int(sum(self.lanes))
-        if lane_steps != len(arr):  # at least one stacked mark
-            out["lane_steps"] = lane_steps
-            if out["total_s"] > 0:
-                out["per_lane_steps_per_s"] = lane_steps / out["total_s"]
-        return out

@@ -12,6 +12,10 @@ import pytest
 
 import chip_smoke
 from multidisttorch_tpu.data.datasets import synthetic_mnist
+from multidisttorch_tpu.utils.compile_cache import (
+    compile_log,
+    enable_compile_cache,
+)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = chip_smoke.Size(
@@ -31,23 +35,27 @@ def data():
 def test_phases_classic_stacked_service_tiny(tmp_path, data):
     train, test = data
     devices = jax.devices()[:2]
-    book = chip_smoke.CompileBook()
-    with chip_smoke.phase("2 run_hpo classic", devices, book):
+    enable_compile_cache()  # as main() does: installs the compile log
+    before = compile_log().snapshot()
+    with chip_smoke.phase("2 run_hpo classic", devices):
         first = chip_smoke.phase_classic(
             devices, str(tmp_path), TINY, train, test
         )
     assert [r.group_id for r in first] == [0, 1]
-    with chip_smoke.phase("3 run_hpo stacked", devices, book):
+    with chip_smoke.phase("3 run_hpo stacked", devices):
         stacked = chip_smoke.phase_stacked(
             devices, str(tmp_path), TINY, train, test
         )
     assert len(stacked) == TINY.stacked_lanes
-    with chip_smoke.phase("5 sweep service", devices, book):
+    with chip_smoke.phase("5 sweep service", devices):
         settled = chip_smoke.phase_service(
             devices, str(tmp_path), TINY, train, test
         )
     assert len(settled) == TINY.submissions
-    assert book.hits + book.misses > 0  # the persistent cache is on
+    after = compile_log().snapshot()
+    # the persistent cache is on
+    assert after["hits"] + after["misses"] > before["hits"] + before["misses"]
+    assert after["backend_s"] > before["backend_s"]
 
 
 def test_a_failed_check_raises():

@@ -23,7 +23,6 @@ from multidisttorch_tpu.telemetry import device as tele_device
 from multidisttorch_tpu.telemetry import events as tele_events
 from multidisttorch_tpu.telemetry import export as tele_export
 from multidisttorch_tpu.telemetry import metrics as tele_metrics
-from multidisttorch_tpu.utils.profiling import StepTimer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -303,49 +302,49 @@ def test_telemetry_off_constructs_no_events(tmp_path, monkeypatch):
     assert telemetry.get_bus() is None
 
 
-# -- step-time semantics (StepTimer satellite + StepSeries) ------------
+# -- compile accounting (from the program's compile log) ---------------
 
 
-def test_steptimer_stacked_attribution():
-    t = StepTimer()
-    for _ in range(4):
-        t.mark(lanes=4)  # K=4 stacked bucket dispatches
-    s = t.stats()
-    assert s["steps"] == 4  # dispatches, as before
-    assert s["lane_steps"] == 16  # but 16 lane-steps of progress
-    assert s["per_lane_steps_per_s"] == pytest.approx(
-        16 / s["total_s"]
-    )
-    # Unstacked marks keep the exact legacy stats shape (no new keys).
-    t2 = StepTimer()
-    t2.mark()
-    t2.mark()
-    assert "lane_steps" not in t2.stats()
+def test_compile_count_is_programs_not_traces(tmp_path):
+    """``compile_count`` counts programs compiled or loaded, one a
+    ``backend`` entry of the compile log: a function with an inner
+    ``jit`` is traced several times over and compiled once, and the
+    cache's ``compile_time_saved_sec`` is no compile time."""
+    import jax
+    import jax.numpy as jnp
+
+    with telemetry.telemetry_run(str(tmp_path / "tel")):
+        reg = tele_metrics.get_registry()
+
+        @jax.jit
+        def inner(x):
+            return jnp.sin(x) * 2.0
+
+        def outer(x):
+            return inner(x) + jnp.where(x > 0, x, 0.0)
+
+        x = jnp.arange(5, dtype=jnp.float32) - 2.0  # made before counting
+        traces = []
+
+        def on_secs(event, secs, **kw):
+            if event.endswith("jaxpr_trace_duration"):
+                traces.append(kw["fun_name"])
+
+        before = reg.counter("compile_count").value
+        before_s = reg.counter("compile_seconds").value
+        jax.monitoring.register_event_duration_secs_listener(on_secs)
+        try:
+            jax.jit(outer)(x).block_until_ready()
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_secs)
+        assert len(traces) > 1 and "inner" in traces  # outer, inner, sin, ...
+        assert reg.counter("compile_count").value - before == 1
+        assert reg.counter("compile_seconds").value > before_s
+        jax.jit(outer)(x).block_until_ready()  # cached in memory: nothing
+        assert reg.counter("compile_count").value - before == 1
 
 
-def test_steptimer_separates_sync_population():
-    """The p95 satellite of ISSUE 4: sparse sync=True marks (device-
-    inclusive, systematically longer) must not contaminate the
-    dispatch-only percentiles — the two populations report separately,
-    mirroring StepSeries' dispatch/device books."""
-    t = StepTimer()
-    # Hand-build the two populations (no sleeps): 20 fast dispatch
-    # marks and 2 slow synced ones.
-    t.times = [0.001] * 20 + [0.5, 0.6]
-    t.lanes = [1] * 22
-    t.synced = [False] * 20 + [True, True]
-    s = t.stats()
-    assert s["steps"] == 22
-    assert s["p95_s"] == pytest.approx(0.001)  # uncontaminated
-    assert s["mean_s"] == pytest.approx(0.001)
-    assert s["total_s"] == pytest.approx(20 * 0.001 + 1.1)
-    dev = s["device_sampled"]
-    assert dev["count"] == 2
-    assert dev["p50_s"] == pytest.approx(0.55)
-    # No sync marks -> exact legacy shape, no new keys.
-    t2 = StepTimer()
-    t2.times, t2.lanes, t2.synced = [0.001] * 3, [1] * 3, [False] * 3
-    assert "device_sampled" not in t2.stats()
+# -- step-time semantics (StepSeries) ---------------------------------
 
 
 def test_step_series_open_interval():

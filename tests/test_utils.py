@@ -12,7 +12,7 @@ from multidisttorch_tpu.parallel.mesh import setup_groups
 from multidisttorch_tpu.train.checkpoint import restore_state, save_state
 from multidisttorch_tpu.train.steps import create_train_state, make_train_step
 from multidisttorch_tpu.utils.imaging import save_image_grid
-from multidisttorch_tpu.utils.profiling import StepTimer, trial_timer
+from multidisttorch_tpu.utils.profiling import trial_timer
 
 
 class TestImaging:
@@ -125,15 +125,3 @@ class TestProfiling:
             pass
         out = capsys.readouterr().out
         assert "trial 3 Done. time:" in out
-
-    def test_step_timer_stats(self):
-        t = StepTimer()
-        for _ in range(5):
-            t.mark()
-        s = t.stats()
-        assert s["steps"] == 5
-        assert s["total_s"] >= 0
-        assert s["p95_s"] >= s["p50_s"] or s["steps"] < 3
-
-    def test_empty_stats(self):
-        assert StepTimer().stats() == {}
