@@ -90,6 +90,7 @@ from multidisttorch_tpu.utils.profiling import (
     SCOPE_ATTN_WINDOW,
     SCOPE_K,
     SCOPE_Q,
+    SCOPE_V,
 )
 
 
@@ -108,7 +109,11 @@ class GroupedWindowMoEBlock(nn.Module):
     """One pre-norm block: grouped-head attention (``window`` ``None``:
     the whole past; ``rotary`` ``False``: no positions), then the
     expert layer, routed from the block's normed input. Returns ``(x,
-    counts)``."""
+    counts)``. Under ``transformer.remat_block`` it keeps, beside the
+    core's output and logsumexp and the router's results, the stream
+    after attention and q, k and v as the core reads them: the
+    recomputed block holds the two norms, the experts' first halves and
+    the exchange's gathers."""
 
     num_heads: int
     num_kv_heads: int
@@ -137,22 +142,34 @@ class GroupedWindowMoEBlock(nn.Module):
             epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name=name
         )
         y = norm("ln_attn")(x)
-        q = dense(h * hd, "q")(y).reshape(b, t, h, hd)
-        k = dense(hkv * hd, "k")(y).reshape(b, t, hkv, hd)
-        v = dense(hkv * hd, "v")(y).reshape(b, t, hkv, hd)
+        # q, k and v stay flat, as the projections write them and the kernels
+        # read them, and are heads only where something reads heads: the TPU
+        # compiler lays a kept (B, T, H, 128) array out with T innermost and
+        # copies it back for the kernels in both passes (PERF.md section 6,
+        # PR 36).
+        q, k, v = dense(h * hd, "q")(y), dense(hkv * hd, "k")(y), dense(hkv * hd, "v")(y)
+        heads = lambda a: a.reshape(b, t, -1, hd)
         rotation = None
         if self.rotary:
             angle = _rope_angles(jnp.arange(t), self.rope_theta, hd)
             rotation = jnp.cos(angle), jnp.sin(angle)
             with jax.named_scope(SCOPE_K):
-                k = rope_halves(k, *rotation)
+                k = rope_halves(heads(k), *rotation).reshape(k.shape)
         placed = transformer._placement(x)
         attend = self.attention
         if attend is None and placed and grouped_takes_kernel(*placed, t, h, hkv, hd):
             attend = grouped_attention
         if attend is None and self.rotary:  # the plain path takes q as it is multiplied
             with jax.named_scope(SCOPE_Q):
-                q = rope_halves(q, *rotation)
+                q = rope_halves(heads(q), *rotation).reshape(q.shape)
+        # What the attention reads, kept across remat by name: the call and
+        # so the kernels' backward take the named copies, and the recomputed
+        # block makes none of the three products and no rotation again.
+        def kept(a, scope):  # jax rounds a kept float where it is named: the projection's work
+            with jax.named_scope(scope):
+                return checkpoint_name(a, transformer.SAVED_QKV)
+
+        q, k, v = heads(kept(q, SCOPE_Q)), heads(kept(k, SCOPE_K)), heads(kept(v, SCOPE_V))
         kind = SCOPE_ATTN_FULL if self.window is None else SCOPE_ATTN_WINDOW
         with jax.named_scope(SCOPE_ATTN_CORE), jax.named_scope(kind):
             if attend is None:

@@ -48,6 +48,19 @@ from multidisttorch_tpu.utils.profiling import SCOPE_ATTN_CORE, SCOPE_MLP
 # chip than the product it spared (PERF.md section 6, PR 34).
 SAVED_RESIDUAL = "residual_after_attention"
 
+# The operands an attention reads, q, k and v as its call receives them
+# (k rotated already; flat, as the projections write them and the
+# kernels read them), by the name :func:`remat_block` keeps: the
+# kernels' backward reads them, so with the three kept the recomputed
+# block multiplies its normed input by none of the three matrices and
+# rotates nothing. ``GroupedWindowMoEBlock`` alone gives the name (its
+# q is 3,584 wide from a 2,560-wide input); what the same operands are
+# worth a byte in the other blocks is less, and in :class:`Block` there
+# is no room for them (PERF.md section 6, PR 36). Defined here and not
+# beside the kernels: a line moved in ``ops/pallas_attention.py`` or
+# ``ops/moe.py`` changes every kernel's serialized module.
+SAVED_QKV = "attention_operands"
+
 
 def _layer_ctors(mod):
     """The dense/layernorm constructors every block variant shares
@@ -107,7 +120,7 @@ class Block(nn.Module):
 # One policy object for every block: jaxprs and jit's caches compare it
 # by identity.
 _KEEP_ACROSS_REMAT = jax.checkpoint_policies.save_only_these_names(
-    SAVED_OUT, SAVED_LSE, SAVED_MAPS, SAVED_Y, SAVED_RESIDUAL, SAVED_ROUTING
+    SAVED_OUT, SAVED_LSE, SAVED_MAPS, SAVED_Y, SAVED_RESIDUAL, SAVED_ROUTING, SAVED_QKV
 )
 
 
@@ -115,7 +128,7 @@ def remat_block(block_cls):
     """``block_cls`` under per-block rematerialization, the one rule of
     every model here that has a ``remat`` field: the backward pass
     recomputes a block from its input, and of what the block made it
-    keeps, by name, what costs most to remake a byte. Six names, each
+    keeps, by name, what costs most to remake a byte. Seven names, each
     given where the value is made, and a block keeps those its trace
     holds:
 
@@ -135,12 +148,21 @@ def remat_block(block_cls):
     - ``SAVED_MAPS``, ``SAVED_Y`` (``ops/hyper_connection.py``): a
       connection's projections and norm factor, and its sublayer's
       output, which does there what ``SAVED_RESIDUAL`` does around a
-      plain residual add.
+      plain residual add;
+    - ``SAVED_QKV`` (this file; given by ``GroupedWindowMoEBlock``
+      alone, on either attention path): q, k and v as the attention
+      call receives them, k rotated, ``(B, T, (H + 2 Hkv) * head_dim)``
+      at the compute dtype, so the three products and the rotation are
+      not made again. ``remat`` is there to make a step fit, and this
+      is the largest of the seven: 9.2 KB a token and layer in
+      ``smallthinker-21b-a3b`` (bf16, 28 + 4 + 4 heads of 128), 151 MB
+      a layer at T = 16,384.
 
-    Everything else (q, k, v, the MLP's or the experts' hidden
-    activations, the norms) is made again from the block's input;
-    where the trace holds none of the names (a ``TransformerLM`` on the
-    dense path) nothing but the input is saved."""
+    Everything else (the other blocks' q, k and v, the MLP's or the
+    experts' hidden activations, the norms) is made again from the
+    block's input; where the trace holds none of the names (a
+    ``TransformerLM`` on the dense path) nothing but the input is
+    saved."""
     return nn.remat(block_cls, policy=_KEEP_ACROSS_REMAT)
 
 

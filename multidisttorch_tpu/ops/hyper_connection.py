@@ -43,9 +43,9 @@ accumulate in float32 and round once, to the streams' dtype. The norm's factor i
 the streams as they are and is scaled after: ``x~ phi = r (X (g phi))``.
 
 **Under ``nn.remat``** (``models/transformer.py::remat_block`` keeps
-six names: the attention kernels' ``SAVED_OUT`` and ``SAVED_LSE``, an
-expert block's ``SAVED_RESIDUAL``, the router's ``SAVED_ROUTING`` and
-the two given here): the projections' products and the norm's factor,
+seven names: the kernels' ``SAVED_OUT`` and ``SAVED_LSE``, an expert
+block's ``SAVED_RESIDUAL`` and ``SAVED_QKV``, the router's ``SAVED_ROUTING``
+and the two given here): the projections' products and the norm's factor,
 25 float32 a token and sublayer (:data:`SAVED_MAPS`), so the recomputed
 forward reads the streams for the mixes alone, and the sublayer's
 output (:data:`SAVED_Y`), which the backward of ``Hpost^T y`` needs

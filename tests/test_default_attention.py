@@ -11,6 +11,8 @@ jaxprs only; nothing is timed.
 """
 
 import collections
+import hashlib
+import os
 import re
 
 import flax.linen as nn
@@ -27,7 +29,10 @@ from multidisttorch_tpu.models.transformer import (
     transformer_tp_shardings,
 )
 from multidisttorch_tpu.ops.pallas_attention import (
+    SAVED_LSE,
+    SAVED_OUT,
     default_takes_kernel,
+    grouped_attention,
     latent_takes_kernel,
     make_flash_attention,
 )
@@ -313,17 +318,20 @@ def test_cpu_latent_attention_stays_dense():
     assert _count(_step_jaxpr(group, model), "pallas_call") == 0
 
 
-def _equations(jaxpr):
+def _equations(jaxpr, kernels=True):
     """Every equation of ``jaxpr`` and of the jaxprs inside it, call
     sites of shared inner jaxprs (``jit``, ``remat``, ``custom_vjp``,
-    ``scan``) one by one."""
+    ``scan``) one by one; ``kernels=False`` stays out of a
+    ``pallas_call``'s body."""
     jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
     for eqn in jaxpr.eqns:
         yield eqn
+        if eqn.primitive.name == "pallas_call" and not kernels:
+            continue
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else (value,):
                 if hasattr(sub, "eqns") or hasattr(getattr(sub, "jaxpr", None), "eqns"):
-                    yield from _equations(sub)
+                    yield from _equations(sub, kernels)
 
 
 def _counts(jaxpr) -> collections.Counter:
@@ -500,6 +508,14 @@ def test_the_policy_is_inert_on_the_dense_path(monkeypatch):
     assert policy.sub("", str(with_policy)) == policy.sub("", str(bare))
 
 
+# A GroupedWindowMoELM over the grouped kernels (an injected attention of
+# their signature; widths they tile): a full layer and a rotary window layer.
+_GROUPED_KERNELS = dict(
+    attention=grouped_attention, d_model=128, num_heads=2, num_kv_heads=1, head_dim=128,
+    num_layers=2, window_layout=(0, 1), rope_layout=(0, 1), window=128, num_experts=4, max_len=256,
+)
+
+
 @pytest.mark.parametrize(
     "kind, fields",
     [
@@ -507,8 +523,11 @@ def test_the_policy_is_inert_on_the_dense_path(monkeypatch):
         ("latent", {"experts_held": (2, 3)}),  # one chip's share: most choices land elsewhere
         ("grouped", {}),  # softmax scoring, the router on the block's input
         ("grouped", {"experts_held": (2, 3), "absent_share_grad": False}),
+        ("grouped", _GROUPED_KERNELS),  # the kernels, interpreted: q comes to them unrotated
+        ("grouped", {**_GROUPED_KERNELS, "experts_held": (1, 2), "absent_share_grad": False}),
     ],
-    ids=["sigmoid-whole", "sigmoid-cut", "softmax-whole", "softmax-cut"],
+    ids=["sigmoid-whole", "sigmoid-cut", "softmax-whole", "softmax-cut",
+         "softmax-kernels", "softmax-kernels-cut"],
 )
 def test_the_kept_residual_and_routing_leave_the_gradients_bit_equal(monkeypatch, kind, fields):
     """The residual after attention and the router's results, kept by
@@ -518,28 +537,43 @@ def test_the_kept_residual_and_routing_leave_the_gradients_bit_equal(monkeypatch
     remat rule equal those under ``nn.remat`` with no policy to the
     last bit, and the rule does keep them (a block's ``proj`` and an
     expert layer's router product, sort into expert order and, with
-    sigmoid scoring, ``top_k`` are not in the recomputed blocks)."""
+    sigmoid scoring, ``top_k`` are not in the recomputed blocks). The
+    grouped block also keeps q, k and v as its attention reads them, on
+    the plain path (both rotated) and on the kernels' (k alone): the
+    three products are not in its recomputed blocks, nor a rotation;
+    the angles are made again, for the rotations' backward."""
     from multidisttorch_tpu.models.grouped_window_moe import GroupedWindowMoELM
     from multidisttorch_tpu.models.latent_moe import LatentMoELM
     from multidisttorch_tpu.ops.moe import SAVED_ROUTING
 
     make = {"latent": LatentMoELM, "grouped": GroupedWindowMoELM}[kind]
-    model = make(vocab_size=64, max_len=16, remat=True, **fields)
-    tokens = jnp.zeros((2, 16), jnp.int32)
+    model = make(**{"vocab_size": 64, "max_len": 16, "remat": True, **fields})
+    t = model.max_len
+    tokens = jnp.zeros((2, t), jnp.int32)
     params = jax.eval_shape(model.init, jax.random.key(0), tokens)["params"]
 
     def gradient():  # traced anew each time: make_jaxpr remembers a function's trace
         logits = lambda p: model.apply({"params": p}, tokens)[0]
         return jax.make_jaxpr(jax.grad(lambda p: logits(p).sum()))(params)
 
-    saved, kept = _loss_and_grads(model, init=model, t=16), gradient()
+    saved, kept = _loss_and_grads(model, init=model, t=t), gradient()
     monkeypatch.setattr(transformer, "remat_block", nn.remat)  # only a block's input is saved
-    bare, again = _loss_and_grads(model, init=model, t=16), _recomputed(gradient())
+    bare, again = _loss_and_grads(model, init=model, t=t), _recomputed(gradient())
     names = {e.params["name"] for e in _equations(kept) if e.primitive.name == "name"}
-    assert names == {transformer.SAVED_RESIDUAL, SAVED_ROUTING}
+    operands = {transformer.SAVED_QKV} if kind == "grouped" else set()
+    kernels = {SAVED_OUT, SAVED_LSE} if model.attention else set()
+    assert names == {transformer.SAVED_RESIDUAL, SAVED_ROUTING} | operands | kernels
     kept = _recomputed(kept)
     routers = model.num_layers - getattr(model, "dense_layers", 0)
-    assert again["dot_general"] - kept["dot_general"] == model.num_layers + routers
+    products = model.num_layers + routers + (3 * model.num_layers if operands else 0)
+    assert again["dot_general"] - kept["dot_general"] == products
+    if operands:
+        rotary = sum(model.rope_layout)
+        # rope_halves joins its halves once a call; the kernels' tables are two joins a layer
+        assert again["concatenate"] - kept["concatenate"] == (1 if kernels else 2) * rotary
+        assert again["cos"] == kept["cos"] == rotary  # the angles: the rotations' backward reads them
+        calls = len(kernels) and model.num_layers  # the backward kernel is in the block's transpose
+        assert (again["pallas_call"], kept["pallas_call"]) == (2 * calls, calls)
     assert again["sort"] - kept["sort"] == routers
     assert (again["top_k"], kept["top_k"]) == (routers, 0 if kind == "latent" else routers)
     still = [g for g in jax.tree.leaves(saved[1]) if not float(jnp.abs(g).max())]
@@ -548,12 +582,65 @@ def test_the_kept_residual_and_routing_leave_the_gradients_bit_equal(monkeypatch
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _tiny_latent(**fields):
+    from multidisttorch_tpu.models.latent_moe import LatentMoELM
+
+    return LatentMoELM(vocab_size=64, remat=True, max_len=16, **fields)
+
+
+# The blocks that give no name for q, k and v: their tiny remat steps as
+# lowered at the parent of the PR that brought ``SAVED_QKV`` (PR 36).
+_OTHER_BLOCKS = {
+    "dense": lambda: TransformerLM(remat=True, **{**CFG, "max_len": 16}),
+    "latent": _tiny_latent,
+    "latent-streams": lambda: _tiny_latent(hc_mult=4),
+}
+_OTHER_BLOCKS_DIGESTS = os.path.join(
+    os.path.dirname(__file__), "fixtures", "steps_without_operands.sha256"
+)
+
+
+def _lowered_digest(model) -> str:
+    """SHA-256 of a model's lowered step on shapes alone (2 x 16
+    tokens), less the numbers jax appends to its private functions'
+    names (they count the trace's equations)."""
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    tx = optax.adam(1e-3)
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    state = jax.eval_shape(lambda k: create_lm_state(group, model, tx, k), jax.random.key(0))
+    text = make_lm_train_step(group, model, tx).lower(state, tokens).as_text()
+    return hashlib.sha256(re.sub(r"@(\w+?)_\d+\b", r"@\1", text).encode()).hexdigest()
+
+
+def record_other_blocks_digests():
+    """``python -c "import sys; sys.path.insert(0, 'tests'); import
+    test_default_attention as t; t.record_other_blocks_digests()"``,
+    for a PR that changes one of the three steps on purpose."""
+    with open(_OTHER_BLOCKS_DIGESTS, "w") as f:
+        for name, make in _OTHER_BLOCKS.items():
+            f.write(f"{name} {_lowered_digest(make())}\n")
+
+
+@pytest.mark.parametrize("name", list(_OTHER_BLOCKS))
+def test_a_name_no_trace_holds_changes_no_step(name):
+    """``SAVED_QKV`` is in the one policy of every model, and
+    ``GroupedWindowMoEBlock`` alone gives it: the lowered tiny steps of
+    ``TransformerLM`` and of ``LatentMoELM`` with one stream and with
+    four are, character for character, what they lowered to before the
+    policy had the name (``tests/fixtures/steps_without_operands.sha256``,
+    taken from that parent's checkout)."""
+    with open(_OTHER_BLOCKS_DIGESTS) as f:
+        recorded = dict(line.split() for line in f if line.strip())
+    assert _lowered_digest(_OTHER_BLOCKS[name]()) == recorded[name]
+
+
 def _recomputed(jaxpr) -> collections.Counter:
-    """Equations inside the gradient's recomputed blocks, by primitive."""
+    """Equations inside the gradient's recomputed blocks, by primitive;
+    a kernel is one equation."""
     inside = collections.Counter()
     for eqn in _equations(jaxpr):
         if eqn.primitive.name == "remat2":
-            inside.update(_counts(eqn.params["jaxpr"]))
+            inside.update(e.primitive.name for e in _equations(eqn.params["jaxpr"], kernels=False))
     return inside
 
 

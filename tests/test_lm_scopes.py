@@ -425,10 +425,10 @@ def test_window_and_full_scopes_reach_the_compiled_step(remat):
         assert any({f"block_{i}", "moe", SCOPE_ROUTER} <= set(_components(n)) for n in step)
     parts = {scope_reduce.classify(n)[0] for n in step}
     assert {"attn_core", "attn_proj", "mlp", "norm", "embed", "head", "loss", "optimizer"} <= parts
-    # the rotations of q and k (window layers only) are the projections'
-    for name in ("q", "k", "v"):
-        assert {_pass(n) for n in step if name in _components(n)} >= every, name
-    assert {_pass(n) for n in step if "proj" in _components(n)} == {"forward", "backward"}
+    # the rotations of q and k (window layers only) are the projections';
+    # under remat the block keeps all four's results: none is made again
+    for name in ("q", "k", "v", "proj"):
+        assert {_pass(n) for n in step if name in _components(n)} == {"forward", "backward"}, name
     unrecognised = [n for n in step if scope_reduce.classify(n)[0] in ("unscoped", "block_other")]
     assert len(unrecognised) / len(step) < UNRECOGNISED_BOUND, (
         len(unrecognised), len(step), sorted(set(unrecognised))[:20]
@@ -483,9 +483,13 @@ _REMAT_STEPS = {
 @pytest.mark.parametrize("model", list(_REMAT_STEPS))
 def test_the_recomputed_block_leaves_out_what_remat_keeps(model):
     """In the compiled tiny steps under remat the recomputed blocks of
-    the two expert models hold q, k and v and no operation of ``proj``
-    (the stream after attention is kept; ``TransformerLM``'s block does
-    not name it and multiplies by ``proj`` again); of the router they
+    the two expert models hold no operation of ``proj`` (the stream
+    after attention is kept; ``TransformerLM``'s block does not name it
+    and multiplies by ``proj`` again); q, k and v are made again in
+    ``TransformerLM`` and ``LatentMoELM`` and not in
+    ``GroupedWindowMoELM``, whose block keeps what its attention reads:
+    no operation under ``q``, ``k`` or ``v`` there, product or
+    rotation, while both norms still run again; of the router they
     hold no product and, with sigmoid scoring, no top-k and no gather
     either (logits, choices and picked scores are kept; the sigmoid and
     the normalisation are made again); with softmax scoring ``top_k``
@@ -498,8 +502,11 @@ def test_the_recomputed_block_leaves_out_what_remat_keeps(model):
 
     names = re.findall(r'op_name="([^"]*)"', _REMAT_STEPS[model]()[0].compile().as_text())
     again = [n for n in names if n.startswith("jit(step_fn)") and _pass(n) == "recompute"]
-    for name in ("q", "k", "v", "ln_attn", "ln_mlp"):
+    for name in ("ln_attn", "ln_mlp"):
         assert any(name in _components(n) for n in again), name
+    for name in ("q", "k", "v"):
+        under = [n for n in again if name in _components(n)]
+        assert bool(under) == (model != "grouped"), (name, under[:5])
     assert any("proj" in _components(n) for n in again) == (model == "dense")
     made = lambda scope: {n.rsplit("/", 1)[-1] for n in again if moe_scopes.classify(n) == scope}
     router = made(SCOPE_ROUTER)
