@@ -28,8 +28,10 @@ Per layer, with ``H`` query heads over ``Hkv`` KV heads of width
 then the final RMSNorm and an untied head; no biases.
 
 **Which attention runs where.** Given no ``attention``, on one TPU chip,
-at a T that 128 divides and heads 128 wide
-(``ops.pallas_attention.grouped_takes_kernel``), the core is
+at a T that 128 divides and heads 128 wide, or 64 wide in a layer
+without positions (``ops.pallas_attention.grouped_takes_kernel``; the
+kernels at 64 rotate nothing, so a rotary layer of such heads keeps the
+plain path), the core is
 ``ops.pallas_attention.grouped_attention``: kernels that read q, k and v
 flat as the projections leave them, fetch a group's K/V block once for
 its query heads, visit only the blocks the mask keeps and rotate q as
@@ -157,7 +159,9 @@ class GroupedWindowMoEBlock(nn.Module):
                 k = rope_halves(heads(k), *rotation).reshape(k.shape)
         placed = transformer._placement(x)
         attend = self.attention
-        if attend is None and placed and grouped_takes_kernel(*placed, t, h, hkv, hd):
+        if attend is None and placed and grouped_takes_kernel(
+            *placed, t, h, hkv, hd, rotates_q=self.rotary
+        ):
             attend = grouped_attention
         if attend is None and self.rotary:  # the plain path takes q as it is multiplied
             with jax.named_scope(SCOPE_Q):

@@ -35,6 +35,7 @@ from multidisttorch_tpu.ops.pallas_attention import (
     flash_attention,
 )
 from multidisttorch_tpu.ops.ring_attention import dense_attention_reference
+from multidisttorch_tpu.ops.selective_scan import SAVED_SCAN_OUT, SAVED_SCAN_STATES
 from multidisttorch_tpu.utils.profiling import SCOPE_ATTN_CORE, SCOPE_MLP
 
 # The residual stream after a block's attention, ``x + proj(o)``, by the
@@ -120,7 +121,8 @@ class Block(nn.Module):
 # One policy object for every block: jaxprs and jit's caches compare it
 # by identity.
 _KEEP_ACROSS_REMAT = jax.checkpoint_policies.save_only_these_names(
-    SAVED_OUT, SAVED_LSE, SAVED_MAPS, SAVED_Y, SAVED_RESIDUAL, SAVED_ROUTING, SAVED_QKV
+    SAVED_OUT, SAVED_LSE, SAVED_MAPS, SAVED_Y, SAVED_RESIDUAL, SAVED_ROUTING, SAVED_QKV,
+    SAVED_SCAN_OUT, SAVED_SCAN_STATES,
 )
 
 
@@ -128,7 +130,7 @@ def remat_block(block_cls):
     """``block_cls`` under per-block rematerialization, the one rule of
     every model here that has a ``remat`` field: the backward pass
     recomputes a block from its input, and of what the block made it
-    keeps, by name, what costs most to remake a byte. Seven names, each
+    keeps, by name, what costs most to remake a byte. Nine names, each
     given where the value is made, and a block keeps those its trace
     holds:
 
@@ -156,7 +158,14 @@ def remat_block(block_cls):
       not made again. ``remat`` is there to make a step fit, and this
       is the largest of the seven: 9.2 KB a token and layer in
       ``smallthinker-21b-a3b`` (bf16, 28 + 4 + 4 heads of 128), 151 MB
-      a layer at T = 16,384.
+      a layer at T = 16,384;
+    - ``SAVED_SCAN_OUT``, ``SAVED_SCAN_STATES``
+      (``ops/selective_scan.py``; a Mamba layer of
+      ``models/ssm_hybrid.py``): the selective scan's output ``(B, T,
+      E)`` at the compute dtype and the state at each chunk's end,
+      float32 ``(B, T / 256, N, E)``, which the scan's backward walks
+      from, so the recomputed forward holds no scan: 10.3 KB and 1.3 KB
+      a token and layer in ``phi-4-mini-flash`` (E = 5,120, N = 16).
 
     Everything else (the other blocks' q, k and v, the MLP's or the
     experts' hidden activations, the norms) is made again from the

@@ -1072,20 +1072,24 @@ _FIRST, _LAST, _MASKED = 1, 2, 4  # what a visit is to its query block, and its 
 
 def grouped_takes_kernel(
     device_kind: str, num_devices: int, seq_len: int, num_heads: int,
-    num_kv_heads: int, head_dim: int,
+    num_kv_heads: int, head_dim: int, rotates_q: bool = False,
 ) -> bool:
     """Whether a block of grouped-head attention that was given no
-    attention runs :func:`grouped_attention`'s kernels
-    (``models/grouped_window_moe.py`` asks while tracing, with what
-    tracing shows of the operands' placement) or the plain masked path
-    in query blocks (:func:`blocked_window_attention`): where
-    :func:`default_takes_kernel` takes a head width of 128 (a TPU,
-    operands on one device, a T from 256 that 128 divides), for whole
-    groups of query heads a KV head. No upper bound on T: K and V come
-    by the block."""
+    attention runs :func:`grouped_attention`'s kernels (the models ask
+    while tracing, with what tracing shows of the operands' placement)
+    or the plain masked path in query blocks
+    (:func:`blocked_window_attention`): where
+    :func:`default_takes_kernel` takes the head width (a TPU, operands
+    on one device, a T from 256 that 128 divides), at 128, or at 64 with
+    the KV heads in pairs, for whole groups of query heads a KV head.
+    ``rotates_q``: the caller will hand the kernels a ``q_rotation``,
+    which only those at 128 apply; a rotary layer of heads 64 wide
+    keeps the plain path, which takes q rotated. No upper bound on T:
+    K and V come by the block."""
+    paired = head_dim == 64 and num_kv_heads % 2 == 0 and not rotates_q
     return (
         default_takes_kernel(device_kind, num_devices, seq_len, num_heads, head_dim)
-        and head_dim == _LANES
+        and (head_dim == _LANES or paired)
         and num_heads % num_kv_heads == 0
     )
 
@@ -1422,21 +1426,40 @@ def grouped_attention(q, k, v, *, window: int | None = None, q_rotation=None,
     element ``i`` with ``i + 64`` by the angle ``i`` of every position
     (float32, then the operands' dtype), and take the gradient back
     through the rotation; k comes rotated (it is ``Hkv`` heads, an
-    eighth of q's bytes or less). Left out, q is used as it comes."""
+    eighth of q's bytes or less). Left out, q is used as it comes.
+
+    **Heads 64 wide** (``q (B, T, H, 64)``, ``k, v (B, T, Hkv, 64)``,
+    ``Hkv`` even; scores divided by 8) run a pair of kernels of their
+    own over the same tiles, ``grouped64_fwd`` and ``grouped64_bwd`` at
+    the end of this file: a grid step is two KV heads' 128 lanes of K
+    and V against their ``2 H / Hkv`` query heads' lanes of q. They take
+    no ``q_rotation``. k and v are arguments in both widths, so a layer
+    may attend with its own q over another layer's k and v, and the
+    cotangents add up where the arrays are made."""
     b, t, h, d = q.shape
     hkv = k.shape[2]
-    if d != _LANES or v.shape[-1] != _LANES or h % hkv:
+    paired = d == 64 and hkv % 2 == 0  # two KV heads a lane block: the kernels at the file's end
+    if (d != _LANES and not paired) or v.shape[-1] != d or h % hkv:
         raise ValueError(
             f"grouped_attention: {h} query heads over {hkv} KV heads of width {d}: the "
-            f"kernels take whole groups of heads {_LANES} wide"
+            f"kernels take whole groups of heads {_LANES} wide, or 64 wide over KV heads in pairs"
         )
-    blk = next((x for x in _GROUPED_BLOCKS if t % x == 0), None) if block is None else block
+    if block is None:  # the largest edge that divides T; heads of 64 by their own race (below)
+        edges = _GROUPED_BLOCKS if d == _LANES else _grouped64_blocks(t, window)
+        blk = next((x for x in edges if t % x == 0), None)
+    else:
+        blk = block
     if blk is None or t % blk or blk % _LANES:
         raise ValueError(f"grouped_attention: no block edge for seq_len {t} (asked: {block})")
     if window is not None and window >= t:
         window = None  # every key a query may see is inside: plain causal
-    rotation = None if q_rotation is None else _halves_tables(*q_rotation)
     flat = lambda x: x.reshape(b, t, -1)
+    if paired:
+        if q_rotation is not None:
+            raise ValueError("grouped_attention: heads 64 wide come rotated, or with no positions")
+        o = _grouped64(flat(q), flat(k), flat(v), 1.0 / math.sqrt(d), window, blk)
+        return o.reshape(b, t, h, d)
+    rotation = None if q_rotation is None else _halves_tables(*q_rotation)
     o = _grouped(flat(q), flat(k), flat(v), rotation, 1.0 / math.sqrt(d), window, blk)
     return o.reshape(b, t, h, d)
 
@@ -1445,26 +1468,32 @@ def blocked_window_attention(q, k, v, *, window: int | None = None, block: int =
     """The plain form of :func:`grouped_attention` (q and k as they are
     to be multiplied: rotated already), what runs off one TPU chip:
     XLA's masked softmax, one block of ``block`` queries at a time
-    against all the keys, each block recomputed in the backward pass,
-    so that the ``(H, block, T)`` scores are all that is ever alive;
-    a T that ``block`` does not divide runs whole. The KV heads are
-    not repeated: a group's query heads meet their one KV head in the
-    product."""
+    against the keys the block can see, each block recomputed in the
+    backward pass: all the keys without a window (the ``(H, block, T)``
+    scores are all that is ever alive), and under a window the ``block
+    + window - 1`` keys a block's window reaches, sliced from K and V
+    (``(H, block, block + window)`` scores, and a window layer does not
+    do a full layer's work); a T that ``block`` does not divide runs
+    whole. The KV heads are not repeated: a group's query heads meet
+    their one KV head in the product."""
     b, t, h, d = q.shape
     hkv = k.shape[2]
     g = h // hkv
     block = block if t % block == 0 else t
     q = q.reshape(b, t, hkv, g, d)
-    at = jnp.arange(t)
+    # the keys a query block reads: the block's own and the window's reach before it
+    span = t if window is None else min(t, block + window - 1)
 
     @jax.checkpoint
     def one_block(start):
         qb = jax.lax.dynamic_slice_in_dim(q, start, block, axis=1)
-        s = jnp.einsum("bqkgd,bskd->bkgqs", qb, k, preferred_element_type=jnp.float32)
-        ahead = (start + jnp.arange(block))[:, None] - at[None, :]
+        first = jnp.clip(start + block - span, 0, t - span)  # of the keys read
+        kb, vb = (jax.lax.dynamic_slice_in_dim(a, first, span, axis=1) for a in (k, v))
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qb, kb, preferred_element_type=jnp.float32)
+        ahead = (start + jnp.arange(block))[:, None] - (first + jnp.arange(span))[None, :]
         keep = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
         p = jax.nn.softmax(jnp.where(keep, s / math.sqrt(d), -jnp.inf), axis=-1)
-        return jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), v)
+        return jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), vb)
 
     out = jax.lax.map(one_block, jnp.arange(0, t, block))  # (blocks, B, block, Hkv, g, d)
     return out.transpose(1, 0, 2, 3, 4, 5).reshape(b, t, h, d)
@@ -1598,3 +1627,248 @@ def make_ring_flash_attention(trial, *, causal: bool = False,
     return _wrap_head_check(
         _make_ring_flash_cached(mesh, causal, head_axis), mesh, head_axis
     )
+
+
+# ---------------------------------------------------------------------
+# grouped KV heads 64 wide: two KV heads a 128-lane K/V block
+# (at the end of the file: a line moved above changes the serialized
+# module of every kernel there, and with it every cell's cache key)
+# ---------------------------------------------------------------------
+#
+# ``grouped64_fwd`` and ``grouped64_bwd`` are the grouped kernels'
+# walks (the same ``_visits``, ``_kept``, flags and softmax steps) over
+# heads of 64. A grid step is one tile of two KV heads' 128 lanes of K
+# and V against their ``2 r`` query heads' ``r * 128`` lanes of q (``r``
+# = query heads a KV head; 2 in ``phi-4-mini-flash``: 256 lanes). Once a
+# query block, each query head is laid out over its KV head's 64 lanes
+# of a 128-lane block, zeros in the other 64, so that every product
+# against the K or V block is the one head's: a 128-deep contraction
+# costs the MXU what a 64-deep one does. p v is made for the KV head's
+# 64 rows alone; dQ leaves through the inverse of the layout.
+
+
+# Block edges at head width 64, best first (the chip race at 1 x 16,384,
+# 40 heads over 20, forward + backward: a full layer 62.0 ms at 512 and
+# 88.0 at 1,024, whose backward holds four heads' 1,024 x 1,024 tiles at
+# once; a window layer of 512 10.5 at 512, 11.9 at 256, 21.9 at 1,024,
+# which multiplies twice the tiles the window needs: PERF.md section 6,
+# PR 37).
+_GROUPED64_BLOCKS = (512, 256, 128)
+
+
+def _grouped64_blocks(t: int, window: int | None):
+    """The edges a layer of heads 64 wide may take: none beyond its
+    window's reach."""
+    reach = t if window is None else max(window, _GROUPED64_BLOCKS[-1])
+    return tuple(x for x in _GROUPED64_BLOCKS if x <= reach)
+
+
+def _over_its_kv_head(x, half: int, at: int):
+    """``x`` ``(rows, 128)`` float32, two query heads side by side: the
+    head in lanes ``half * 64 ..`` moved to lanes ``at * 64 ..``, zeros
+    in the other 64."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    x = jnp.where(lane // 64 == half, x, 0.0)
+    return x if half == at else pltpu.roll(x, 64, 1)
+
+
+def _grouped64_fwd_kernel(q_of, k_of, flags_of, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                          acc_t, m_sc, l_sc, q_t, *, scale, window, r, blk, interpret):
+    """Grid (N, pairs of KV heads, visits), as ``_grouped_fwd_kernel``;
+    keys x queries. ``q_t[p]`` is query head ``p`` of the group,
+    transposed, in the rows of its KV head ``p // r`` of the pair."""
+    visit = pl.program_id(2)
+    i, j, flags = q_of[visit], k_of[visit], flags_of[visit]
+    dot = partial(_dot, interpret=interpret)
+    heads = range(2 * r)
+
+    @pl.when((flags & _FIRST) != 0)
+    def _start():
+        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_t[...] = jnp.zeros_like(acc_t)
+        for p in heads:
+            two = q_ref[0, :, (p // 2) * _LANES:(p // 2 + 1) * _LANES].astype(jnp.float32)
+            q_t[p] = _over_its_kv_head(two, p % 2, p // r).T.astype(q_t.dtype)  # (128, blk)
+
+    def tile(masked: bool):
+        k, v_t = k_ref[0], _transposed(v_ref[0])  # (blk, 128), (128, blk)
+        keep = _kept(i, j, blk, window, 0) if masked else None
+        for p in heads:
+            s = dot(k, q_t[p]) * scale  # (keys, queries) f32
+            if masked:
+                s = jnp.where(keep, s, _NEG_INF)
+            rows = slice((p // r) * 64, (p // r + 1) * 64)
+            _softmax_step(s, v_t[rows], p, slice(None), acc_t, m_sc, l_sc, dot)
+
+    pl.when((flags & _MASKED) != 0)(lambda: tile(True))
+    pl.when((flags & _MASKED) == 0)(lambda: tile(False))
+    pl.when((flags & _LAST) != 0)(
+        lambda: _finish_softmax(2 * r, acc_t, m_sc, l_sc, o_ref, lse_ref)
+    )
+
+
+def _grouped64_bwd_kernel(q_of, k_of, flags_of, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                          dq_ref, dk_ref, dv_ref, dk_t, dv_t, dq_acc, q_sc, do_sc, q_t, do_t,
+                          lse_sc, delta_sc, *, scale, window, r, blk, interpret):
+    """As ``_grouped_bwd_kernel``, queries x keys, on q and dO laid out
+    a query head a 128-lane block over its KV head's lanes (``q_sc``,
+    ``do_sc`` and transposed ``q_t``, ``do_t``): dK and dV of the pair
+    gather in their own rows, and dQ's 64 lanes a head go back where q
+    came from."""
+    visit, last_visit = pl.program_id(2), pl.num_programs(2) - 1
+    i, j, flags = q_of[visit], k_of[visit], flags_of[visit]
+    dot = partial(_dot, interpret=interpret)
+    heads = range(2 * r)
+    block_of = lambda n: slice(n * _LANES, (n + 1) * _LANES)
+
+    @pl.when(visit == 0)
+    def _init():
+        dk_t[...] = jnp.zeros_like(dk_t)
+        dv_t[...] = jnp.zeros_like(dv_t)
+
+    @pl.when((flags & _FIRST) != 0)
+    def _start():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        for p in heads:
+            two = block_of(p // 2)
+            q = _over_its_kv_head(q_ref[0, :, two].astype(jnp.float32), p % 2, p // r)
+            do = do_ref[0, :, two].astype(jnp.float32)
+            lane = jax.lax.broadcasted_iota(jnp.int32, do.shape, 1)
+            # delta = rowsum(dO * O) over the head's own lanes
+            delta_sc[p] = jnp.sum(
+                jnp.where(lane // 64 == p % 2, do * o_ref[0, :, two].astype(jnp.float32), 0.0),
+                axis=1, keepdims=True,
+            )
+            do = _over_its_kv_head(do, p % 2, p // r)
+            q_sc[:, block_of(p)] = q.astype(q_sc.dtype)
+            do_sc[:, block_of(p)] = do.astype(do_sc.dtype)
+            q_t[p] = q.T.astype(q_t.dtype)
+            do_t[p] = do.T.astype(do_t.dtype)
+            lse_sc[p] = lse_ref[0, 0, p][:, None]
+
+    def tile(masked: bool):
+        k, v = k_ref[0], v_ref[0]  # (blk, 128)
+        keep = _kept(i, j, blk, window, 1) if masked else None
+        for p in heads:
+            s = dot(q_sc[:, block_of(p)], k, _NT) * scale  # (queries, keys) f32
+            if masked:
+                s = jnp.where(keep, s, _NEG_INF)
+            prob = jnp.exp(s - lse_sc[p])
+            dp = dot(do_sc[:, block_of(p)], v, _NT)
+            ds = (prob * (dp - delta_sc[p])).astype(k.dtype)
+            dv_t[j] = dv_t[j] + dot(do_t[p], prob.astype(v.dtype))
+            dk_t[j] = dk_t[j] + dot(q_t[p], ds)
+            dq_acc[:, block_of(p)] = dq_acc[:, block_of(p)] + dot(ds, k)
+
+    pl.when((flags & _MASKED) != 0)(lambda: tile(True))
+    pl.when((flags & _MASKED) == 0)(lambda: tile(False))
+
+    @pl.when((flags & _LAST) != 0)
+    def _emit_dq():
+        for n in range(r):  # two query heads a 128-lane block of dQ
+            lo, hi = 2 * n, 2 * n + 1
+            a, b = dq_acc[:, block_of(lo)], dq_acc[:, block_of(hi)]
+            a = a if lo // r == 0 else pltpu.roll(a, 64, 1)  # from its KV head's lanes to lanes 0..63
+            b = b if hi // r == 1 else pltpu.roll(b, 64, 1)  # to lanes 64..127
+            lane = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+            dq_ref[0, :, block_of(n)] = (jnp.where(lane < 64, a, b) * scale).astype(dq_ref.dtype)
+
+    @pl.when(visit == last_visit)
+    def _emit_dkv():
+        def block(n, carry):
+            rows = pl.ds(pl.multiple_of(n * blk, blk), blk)
+            dk_ref[0, rows, :] = (dk_t[n].T * scale).astype(dk_ref.dtype)
+            dv_ref[0, rows, :] = dv_t[n].T.astype(dv_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, dk_t.shape[0], block, 0)
+
+
+def _grouped64_layout(q, k, window, blk: int):
+    """``_grouped_layout``'s tables, grid and specs (``r`` query heads
+    a KV head: ``r * 128`` lanes of q a pair of KV heads), with a
+    statistic a query head."""
+    tables, grid, r, group, keys, _, _ = _grouped_layout(q, k, None, window, blk)
+    stat = pl.BlockSpec((1, 1, 2 * r, blk), lambda n, c, v, q_of, k_of, f: (n, c, 0, q_of[v]))
+    return tables, grid, r, group, keys, stat
+
+
+@partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _grouped64_fwd_call(q, k, v, scale, window, blk, interpret):
+    tables, grid, r, group, keys, stat = _grouped64_layout(q, k, window, blk)
+    return pl.pallas_call(
+        partial(_grouped64_fwd_kernel, scale=scale, window=window, r=r, blk=blk,
+                interpret=interpret),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=grid,
+            in_specs=[group, keys, keys],
+            out_specs=(group, stat),
+            scratch_shapes=[
+                pltpu.VMEM((2 * r, 64, blk), jnp.float32),  # output, transposed
+                pltpu.VMEM((2 * r, 1, blk), jnp.float32),  # running max
+                pltpu.VMEM((2 * r, 1, blk), jnp.float32),  # running sum
+                pltpu.VMEM((2 * r, _LANES, blk), q.dtype),  # q over its KV head, transposed
+            ],
+        ),
+        out_shape=(
+            _out_struct(q.shape, q.dtype, q),
+            _out_struct((*grid[:2], 2 * r, q.shape[1]), jnp.float32, q),
+        ),
+        compiler_params=_grouped_params(0, 2 * r, blk),
+        interpret=interpret,
+        name="grouped64_fwd",
+    )(*tables, q, k, v)
+
+
+@partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _grouped64_bwd_call(q, k, v, o, lse, do, scale, window, blk, interpret):
+    t = q.shape[1]
+    tables, grid, r, group, keys, stat = _grouped64_layout(q, k, window, blk)
+    whole = pl.BlockSpec((1, t, _LANES), lambda n, c, v, q_of, k_of, f: (n, 0, c))
+    column = pltpu.VMEM((2 * r, blk, 1), jnp.float32)
+    laid_out = pltpu.VMEM((blk, 2 * r * _LANES), q.dtype)
+    transposed = pltpu.VMEM((2 * r, _LANES, blk), q.dtype)
+    return pl.pallas_call(
+        partial(_grouped64_bwd_kernel, scale=scale, window=window, r=r, blk=blk,
+                interpret=interpret),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=grid,
+            in_specs=[group, keys, keys, group, group, stat],
+            out_specs=(group, whole, whole),
+            scratch_shapes=[
+                pltpu.VMEM((t // blk, _LANES, blk), jnp.float32),  # dK, transposed
+                pltpu.VMEM((t // blk, _LANES, blk), jnp.float32),  # dV, transposed
+                pltpu.VMEM((blk, 2 * r * _LANES), jnp.float32),  # dQ, a head a lane block
+                laid_out, laid_out, transposed, transposed,  # q and dO, and transposed
+                column, column,  # the logsumexp and delta of a head's queries
+            ],
+        ),
+        out_shape=tuple(_out_struct(x.shape, x.dtype, x) for x in (q, k, v)),
+        compiler_params=_grouped_params(
+            2 * t * _LANES * (4 + 2 * k.dtype.itemsize), 2 * r, blk
+        ),
+        interpret=interpret,
+        name="grouped64_bwd",
+    )(*tables, q, k, v, o, do, lse)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _grouped64(q, k, v, scale, window, blk):
+    """The output, as ``q``, for the kernels' flat operands."""
+    return _grouped64_fwd_call(q, k, v, scale, window, blk, pallas_interpret())[0]
+
+
+def _grouped64_fwd(q, k, v, scale, window, blk):
+    o, lse = _grouped64_fwd_call(q, k, v, scale, window, blk, pallas_interpret())
+    o, lse = checkpoint_name(o, SAVED_OUT), checkpoint_name(lse, SAVED_LSE)
+    return o, (q, k, v, o, lse)
+
+
+def _grouped64_bwd(scale, window, blk, res, g_o):
+    return _grouped64_bwd_call(*res, g_o, scale, window, blk, pallas_interpret())
+
+
+_grouped64.defvjp(_grouped64_fwd, _grouped64_bwd)

@@ -68,6 +68,19 @@ SCOPE_HC_MIX = "hc_mix"
 # ``attn_core``, which of the two a layer's core is.
 SCOPE_ATTN_FULL = "attn_full"
 SCOPE_ATTN_WINDOW = "attn_window"
+# The decoder-hybrid-decoder (``models/ssm_hybrid.py``): inside
+# ``attn_core`` a third kind, the layers that attend with their own q
+# over another layer's k and v; a Mamba layer's four projections and its
+# gate, its causal convolution and its selective scan
+# (``ops/selective_scan.py``, whatever implements it); all of a gated
+# memory unit's mixer; a head that is the embedding's transpose and so
+# no flax module of its own.
+SCOPE_ATTN_CROSS = "attn_cross"
+SCOPE_SSM_PROJ = "ssm_proj"
+SCOPE_SSM_CONV = "ssm_conv"
+SCOPE_SSM_SCAN = "ssm_scan"
+SCOPE_GMU = "gmu"
+SCOPE_HEAD = "head"
 
 # Host spans of a trial's admission (:func:`span`), each opened where
 # the work is done: ``parallel/mesh.py::setup_groups``; the whole of

@@ -97,6 +97,8 @@ def test_refuses_what_does_not_tile():
     q, k, v, _ = _operands(256, 3, 2)
     with pytest.raises(ValueError, match="whole groups"):
         grouped_attention(q, k, v)
+    with pytest.raises(ValueError, match="whole groups"):  # 64 wide, an odd KV head left over
+        grouped_attention(q[..., :64], k[:, :, :1, :64].repeat(3, 2), v[:, :, :1, :64].repeat(3, 2))
     with pytest.raises(ValueError, match="no block edge"):
         grouped_attention(q[:, :200, :2], k[:, :200], v[:, :200])
 
@@ -106,9 +108,108 @@ def test_rule_takes_one_tpu_chip_at_width_128():
     assert grouped_takes_kernel("TPU v5 lite", 1, 256, 2, 2, 128)
     assert not grouped_takes_kernel("cpu", 1, 16384, 28, 4, 128)
     assert not grouped_takes_kernel("TPU v5 lite", 4, 16384, 28, 4, 128)
-    assert not grouped_takes_kernel("TPU v5 lite", 1, 16384, 28, 4, 64)
+    assert not grouped_takes_kernel("TPU v5 lite", 1, 16384, 28, 4, 32)
     assert not grouped_takes_kernel("TPU v5 lite", 1, 200, 28, 4, 128)
     assert not grouped_takes_kernel("TPU v5 lite", 1, 16384, 28, 8 - 3, 128)
+
+
+def test_rule_takes_width_64_where_the_kv_heads_pair_up():
+    assert grouped_takes_kernel("TPU v5 lite", 1, 16384, 40, 20, 64)
+    assert grouped_takes_kernel("TPU v5 lite", 1, 256, 8, 2, 64)
+    assert not grouped_takes_kernel("TPU v5 lite", 1, 16384, 40, 5, 64)  # an odd KV head is left over
+    assert not grouped_takes_kernel("TPU v5 lite", 1, 16384, 40, 16, 64)  # no whole groups
+    assert not grouped_takes_kernel("cpu", 1, 16384, 40, 20, 64)
+    assert not grouped_takes_kernel("TPU v5 lite", 4, 16384, 40, 20, 64)
+    # the kernels at 64 rotate nothing: a rotary layer (28 heads over 4 of 64 trained on the
+    # plain path before them) keeps it, and at 128 the kernels rotate q as they load it
+    assert grouped_takes_kernel("TPU v5 lite", 1, 16384, 28, 4, 64)
+    assert not grouped_takes_kernel("TPU v5 lite", 1, 16384, 28, 4, 64, rotates_q=True)
+    assert grouped_takes_kernel("TPU v5 lite", 1, 16384, 28, 4, 128, rotates_q=True)
+
+
+def _operands_64(t, h, hkv, seed=0):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    shape = lambda heads: (2, t, heads, 64)
+    return (jax.random.normal(keys[0], shape(h)), jax.random.normal(keys[1], shape(hkv)),
+            jax.random.normal(keys[2], shape(hkv)), jax.random.normal(keys[3], shape(h)))
+
+
+# (query heads, KV heads): two, four and one query heads a KV head; four KV heads: two lane blocks
+@pytest.mark.parametrize("heads", [(4, 2), (8, 2), (2, 2), (8, 4)], ids=lambda h: f"h{h[0]}kv{h[1]}")
+@pytest.mark.parametrize("window", [None, 100, 128, 300], ids=lambda w: f"w{w}")
+def test_kernels_at_width_64_match_the_masked_dense_form_and_the_plain_path(heads, window):
+    """Forward and the gradients of q, k and v at heads 64 wide, two KV
+    heads a lane block, T = 512 as four K/V blocks of 128 (a window
+    shorter than a block, a block, several and a part), against the
+    masked softmax and ``blocked_window_attention``."""
+    q, k, v, co = _operands_64(512, *heads, seed=heads[0])
+    loss = lambda fn: jax.value_and_grad(lambda q, k, v: jnp.sum(fn(q, k, v) * co), (0, 1, 2))
+    with jax.default_matmul_precision("highest"):
+        got = loss(lambda q, k, v: grouped_attention(q, k, v, window=window, block=128))(q, k, v)
+        want = loss(lambda q, k, v: _dense(q, k, v, window))(q, k, v)
+        plain = loss(lambda q, k, v: blocked_window_attention(q, k, v, window=window, block=64))(
+            q, k, v)
+    for have, need, other in zip(*(jax.tree.leaves(x) for x in (got, want, plain)), strict=True):
+        assert _rel(have, need) < 1e-5
+        assert _rel(other, need) < 1e-5
+
+
+def test_kernels_at_width_64_take_k_and_v_from_elsewhere():
+    """A layer's own q over another's k and v (the cross-attention
+    layer's call): the cotangents of k and v from two such calls and
+    from the layer that made them add up where the arrays are made."""
+    q, k, v, co = _operands_64(256, 4, 2, seed=7)
+    q2 = jax.random.normal(jax.random.key(8), q.shape)
+
+    def three_readers(attend):
+        def loss(q, q2, k, v):
+            own = attend(q, k, v, window=None)
+            return jnp.sum((own + attend(q2, k, v, window=None) + attend(own, k, v, window=None)) * co)
+
+        return jax.grad(loss, (0, 1, 2, 3))
+
+    with jax.default_matmul_precision("highest"):
+        got = three_readers(lambda *a, **kw: grouped_attention(*a, block=128, **kw))(q, q2, k, v)
+        want = three_readers(_dense)(q, q2, k, v)
+    for have, need in zip(got, want, strict=True):
+        assert _rel(have, need) < 1e-5
+
+
+def test_block_edge_at_width_64_follows_the_window():
+    """Given no block, the edge at width 64 is 512 at most (the chip's
+    race) and under a window the largest that does not pass it (a
+    window of 512 under a block of 1,024 would multiply twice the tiles
+    it needs); heads 128 wide keep the edge they were raced at."""
+    from multidisttorch_tpu.ops import pallas_attention
+
+    seen = []
+    real = pallas_attention._grouped64
+    q, k, v, _ = _operands_64(1024, 4, 2)
+    try:
+        pallas_attention._grouped64 = lambda q, k, v, scale, window, blk: seen.append(blk) or q
+        grouped_attention(q, k, v)
+        grouped_attention(q, k, v, window=512)
+        grouped_attention(q, k, v, window=100)
+        grouped_attention(q, k, v, window=512, block=1024)
+    finally:
+        pallas_attention._grouped64 = real
+    assert seen == [512, 512, 128, 1024]
+    with pytest.raises(ValueError, match="come rotated"):
+        grouped_attention(q, k, v, q_rotation=(jnp.zeros((1024, 32)), jnp.zeros((1024, 32))))
+
+
+def test_the_plain_path_reads_only_the_keys_a_window_reaches():
+    """No ``(H, block, T)`` array for a window layer off the chip: a
+    query block meets ``block + window - 1`` keys, in both passes."""
+    part = lambda heads: jax.ShapeDtypeStruct((1, 4096, heads, 64), jnp.float32)
+    fn = lambda q, k, v: blocked_window_attention(q, k, v, window=512, block=512)
+    grad = jax.grad(lambda *x: fn(*x).sum(), argnums=(0, 1, 2))
+    for traced in (fn, grad):
+        text = str(jax.make_jaxpr(traced)(part(4), part(2), part(2)))
+        assert "512,1023]" in text and "512,4096]" not in text
+    full = str(jax.make_jaxpr(lambda q, k, v: blocked_window_attention(q, k, v, block=512))(
+        part(4), part(2), part(2)))
+    assert "512,4096]" in full
 
 
 def test_grouped_operands_lower_for_tpu(monkeypatch):
@@ -129,3 +230,23 @@ def test_grouped_operands_lower_for_tpu(monkeypatch):
             assert text.count("stablehlo.custom_call @tpu_custom_call") == calls
             assert "16384x16384" not in text
             assert "tensor<1x16384x28x128xbf16>" not in text.split("custom_call")[1]
+
+
+def test_operands_at_width_64_lower_for_tpu(monkeypatch):
+    # the cell ssm-yoco-t16384's attention as its blocks hand it over: 1
+    # x 16,384, 40 query heads over 20 KV heads of 64; forward and
+    # backward, interpret mode off, a full layer and a window layer. No
+    # score matrix, no repeated KV head and no head padded to 128 lanes
+    # is made around the kernels.
+    monkeypatch.delenv("MDT_PALLAS_INTERPRET")
+    part = lambda heads: jax.ShapeDtypeStruct((1, 16384, heads, 64), jnp.bfloat16)
+    for window in (None, 512):
+        fwd = lambda q, k, v: grouped_attention(q, k, v, window=window)
+        bwd = jax.grad(lambda *x: fwd(*x).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+        for fn, calls in ((fwd, 1), (bwd, 2)):
+            text = jax.jit(fn).trace(part(40), part(20), part(20)).lower(
+                lowering_platforms=("tpu",)).as_text()
+            assert text.count("stablehlo.custom_call @tpu_custom_call") == calls
+            assert "grouped64_" in text and "16384x16384" not in text
+            assert "tensor<1x16384x40x128xbf16>" not in text
+            assert "tensor<1x16384x40x64xbf16>" not in text.split("custom_call")[1]

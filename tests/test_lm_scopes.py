@@ -525,3 +525,145 @@ def test_grouped_scopes_stay_out_of_the_parameter_tree():
     for i in range(4):
         assert set(params[f"block_{i}"]) == {"ln_attn", "q", "k", "v", "proj", "ln_mlp", "moe"}
         assert set(params[f"block_{i}"]["moe"]) == {"router", "w_gate", "w_up", "w_down"}
+
+
+# --- the decoder-hybrid-decoder (models/ssm_hybrid.py) ---
+
+
+def _lowered_hybrid(remat, t=16, placed=False, **fields):
+    """``SambaYLM``'s step lowered for ``(1, t)`` tokens; ``placed`` as
+    :func:`_lowered_latent`."""
+    from multidisttorch_tpu.models.ssm_hybrid import SambaYLM
+
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    model = SambaYLM(**{"vocab_size": 64, "max_len": t, "remat": remat, **fields})
+    tx = optax.adam(1e-3)
+    tokens = jax.ShapeDtypeStruct((1, t), jnp.int32)
+    params = jax.eval_shape(
+        model.init, {"params": jax.random.key(0)}, jnp.zeros((1, t), jnp.int32)
+    )["params"]
+    state = jax.eval_shape(
+        lambda p: TrainState(params=p, opt_state=tx.init(p), step=jnp.zeros((), jnp.int32)),
+        params,
+    )
+    if placed:
+        on = lambda tree, sharding: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree
+        )
+        state, tokens = on(state, group.replicated_sharding), on(tokens, group.batch_sharding)
+    return make_lm_train_step(group, model, tx).lower(state, tokens), params
+
+
+# the default 8 layers: which blocks hold which scope
+_HYBRID_BLOCKS = {
+    "ssm_scan": {"block_0", "block_2", "block_4"}, "ssm_proj": {"block_0", "block_2", "block_4"},
+    "ssm_conv": {"block_0", "block_2", "block_4"}, "gmu": {"block_6"}, "attn_cross": {"block_7"},
+    "attn_window": {"block_1", "block_3"}, "attn_full": {"block_5"},
+}
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_hybrid_scopes_reach_the_compiled_step(remat):
+    """``ssm_proj``, ``ssm_conv``, ``ssm_scan``, ``gmu`` and, inside
+    ``attn_core``, ``attn_window``, ``attn_full`` and ``attn_cross`` in
+    the compiled tiny step (the plain path: the CPU), each in the blocks
+    of its kind and in every pass it has; the tied head under ``head``;
+    the accepted splits read the step unedited: the state-space and
+    memory scopes are the blocks' own (``block_other``), not
+    ``unscoped``."""
+    from benchmark import scope_reduce, ssm_scopes, swa_scopes
+    from multidisttorch_tpu.utils import profiling
+
+    assert ssm_scopes.PARTS == (
+        profiling.SCOPE_SSM_SCAN, profiling.SCOPE_SSM_PROJ, profiling.SCOPE_SSM_CONV,
+        profiling.SCOPE_GMU, profiling.SCOPE_ATTN_CROSS,
+    )
+    lowered, _ = _lowered_hybrid(remat)
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    step = [n for n in names if n.startswith("jit(step_fn)")]
+    assert len(step) > 500
+    both = {"forward", "backward"}
+    every = both | ({"recompute"} if remat else set())
+    blocks_of = lambda under: {c for n in under for c in _components(n) if c.startswith("block_")}
+    for scope in ssm_scopes.PARTS:
+        under = [n for n in step if ssm_scopes.classify(n) == scope]
+        # under remat the scan's output and chunk states are kept: no scan is made again
+        assert {_pass(n) for n in under} >= (both if scope == "ssm_scan" else every), scope
+        assert blocks_of(under) == _HYBRID_BLOCKS[scope], scope
+        # (a reshape fused across the scope's edge carries two paths, the projection's first)
+        parts = {"attn_core", "attn_proj"} if scope == "attn_cross" else {"block_other"}
+        found = {scope_reduce.classify(n)[0] for n in under}
+        assert found <= parts and parts - {"attn_proj"} <= found, (scope, found)
+    if remat:  # of the scan the recomputed block holds its operands' slices and -exp(A_log) alone
+        again = {n.rsplit("/", 1)[-1] for n in step
+                 if ssm_scopes.classify(n) == "ssm_scan" and _pass(n) == "recompute"}
+        assert again <= {"exp", "neg", "slice"}, again
+    for scope in swa_scopes.PARTS:
+        under = [n for n in step if swa_scopes.classify(n) == scope]
+        assert {_pass(n) for n in under} >= every and blocks_of(under) == _HYBRID_BLOCKS[scope]
+    core = [n for n in step if scope_reduce.classify(n)[0] == "attn_core"]
+    assert all((swa_scopes.classify(n) or ssm_scopes.classify(n)) for n in core)  # the three's sum
+    head = [n for n in step if profiling.SCOPE_HEAD in _components(n)]
+    assert {_pass(n) for n in head} >= both
+    assert {scope_reduce.classify(n)[0] for n in head} == {"head"}
+    parts = {scope_reduce.classify(n)[0] for n in step}
+    assert {"attn_core", "attn_proj", "mlp", "norm", "embed", "head", "loss", "optimizer",
+            "block_other"} <= parts
+    unscoped = [n for n in step if scope_reduce.classify(n)[0] == "unscoped"]
+    assert len(unscoped) / len(step) < UNRECOGNISED_BOUND, sorted(set(unscoped))[:20]
+    # what a block leaves without one of its own names is its residual adds and what
+    # remat wraps them in, a small part
+    bare = [n for n in step if scope_reduce.classify(n)[0] == "block_other"
+            and ssm_scopes.classify(n) is None]
+    assert len(bare) / len(step) < UNRECOGNISED_BOUND, sorted(set(bare))[:20]
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_hybrid_kernels_are_under_their_layer_s_scope_once_a_pass(monkeypatch, remat):
+    """Where the block takes the kernels (one TPU chip; here the CPU
+    device under a v5e's name, the kernels interpreted): one forward and
+    one backward scan a Mamba layer under ``ssm_scan`` and one forward
+    and one backward 64-wide grouped kernel an attention layer under
+    ``attn_core`` and the layer's kind, none of either in the recomputed
+    block."""
+    from benchmark import scope_reduce, ssm_scopes, swa_scopes
+    from multidisttorch_tpu.models import transformer
+    from multidisttorch_tpu.ops import selective_scan
+
+    for module in (transformer, selective_scan):  # the attention's rules and the scan's own
+        monkeypatch.setattr(
+            module, "_placement",
+            lambda x, real=module._placement: real(x) and ("TPU v5 lite", real(x)[1]),
+        )
+    lowered, _ = _lowered_hybrid(
+        remat, t=256, placed=True, d_model=256, num_heads=4, num_kv_heads=2, head_dim=64,
+        mlp_width=64, window=128,
+        layer_kinds=("mamba", "window", "mamba_memory", "full_kv", "gmu", "cross"),
+    )
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    step = [n for n in names if n.startswith("jit(step_fn)")]
+    kind_of = lambda n: swa_scopes.classify(n) or ssm_scopes.classify(n)
+    for call, where in (("jit(_kernel_fwd)", "forward"), ("jit(_kernel_bwd)", "backward")):
+        found = {(kind_of(n), _pass(n), c) for n in step if call in n.split("/")
+                 for c in _components(n) if c.startswith("block_")}
+        assert found == {("ssm_scan", where, "block_0"), ("ssm_scan", where, "block_2")}, found
+    for call, where in (("jit(_grouped64_fwd_call)", "forward"),
+                        ("jit(_grouped64_bwd_call)", "backward")):
+        found = {scope_reduce.classify(n) for n in step if call in n.split("/")}
+        assert found == {("attn_core", where)}, (call, found)
+        kinds = {(kind_of(n), c) for n in step if call in n.split("/")
+                 for c in _components(n) if c.startswith("block_")}
+        assert kinds == {("attn_window", "block_1"), ("attn_full", "block_3"),
+                         ("attn_cross", "block_5")}
+    assert not any("_grouped_fwd_call" in n or "flash" in n for n in step)
+
+
+def test_hybrid_scopes_stay_out_of_the_parameter_tree():
+    _, params = _lowered_hybrid(True)
+    assert set(params) == {"tok_embed", "ln_out"} | {f"block_{i}" for i in range(8)}  # no head
+    shared = {"ln_attn", "ln_mlp", "gate", "up", "down"}
+    mamba = {"in_proj", "conv_w", "conv_b", "x_proj", "dt_proj", "dt_bias", "A_log", "D", "out_proj"}
+    assert set(params["block_0"]) == set(params["block_4"]) == shared | mamba
+    assert set(params["block_1"]) == set(params["block_5"]) == shared | {"qkv", "proj"}
+    assert set(params["block_6"]) == shared | {"in_proj", "out_proj"}
+    assert set(params["block_7"]) == shared | {"q", "proj"}
