@@ -234,7 +234,7 @@ class GroupedWindowMoELM(nn.Module):
     remat: bool = False  # per-block checkpointing (transformer.remat_block)
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, head=True):
         _, t = tokens.shape
         if t > self.max_len:
             raise ValueError(f"sequence length {t} exceeds max_len={self.max_len}")
@@ -272,5 +272,8 @@ class GroupedWindowMoELM(nn.Module):
         logits = nn.Dense(
             self.vocab_size, use_bias=False, dtype=jnp.float32,
             param_dtype=jnp.float32, name="head",
-        )(x)
+        )(x) if head else x  # the normed state: transformer.head_weights
         return logits, {"expert_counts": jnp.stack(counts)}
+
+    def head_weights(self, params):
+        return transformer.head_weights(params)

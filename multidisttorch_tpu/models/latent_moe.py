@@ -476,7 +476,7 @@ class LatentMoELM(nn.Module):
     hc_clamp: tuple[float, float] = (-30.0, 30.0)
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, head=True):
         _, t = tokens.shape
         if t > self.max_len:
             raise ValueError(f"sequence length {t} exceeds max_len={self.max_len}")
@@ -522,8 +522,11 @@ class LatentMoELM(nn.Module):
         logits = nn.Dense(
             self.vocab_size, use_bias=False, dtype=jnp.float32,
             param_dtype=jnp.float32, name="head",
-        )(x)
+        )(x) if head else x  # the normed state: transformer.head_weights
         counters = {"expert_counts": jnp.stack(counts[self.dense_layers:])}
         if errs:
             counters["hc_marginal_err"] = jnp.max(jnp.stack(errs))
         return logits, counters
+
+    def head_weights(self, params):
+        return transformer.head_weights(params)

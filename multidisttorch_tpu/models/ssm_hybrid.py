@@ -312,7 +312,7 @@ class SambaYLM(nn.Module):
         return tuple(self.layer_kinds)
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, head=True):
         _, t = tokens.shape
         if t > self.max_len:
             raise ValueError(f"sequence length {t} exceeds max_len={self.max_len}")
@@ -343,7 +343,9 @@ class SambaYLM(nn.Module):
         x = nn.LayerNorm(
             epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name="ln_out"
         )(x)
-        if self.tie_embeddings:
+        if not head:
+            logits = x  # the normed state: transformer.head_weights
+        elif self.tie_embeddings:
             with jax.named_scope(SCOPE_HEAD):  # float32, as the other models' heads
                 logits = jnp.einsum(
                     "btd,vd->btv", x.astype(jnp.float32), embed.embedding.astype(jnp.float32)
@@ -354,3 +356,6 @@ class SambaYLM(nn.Module):
                 name="head",
             )(x)
         return logits, {"ssm_state_rms": jnp.stack(state_rms)}
+
+    def head_weights(self, params):
+        return transformer.head_weights(params, tied=self.tie_embeddings)

@@ -228,15 +228,34 @@ def _lm_embed(mod, tokens):
     return x + pos
 
 
-def _lm_head(mod, x):
-    """Final norm + f32 vocab head, shared by both LM variants."""
+def _lm_head(mod, x, head=True):
+    """Final norm + f32 vocab head, shared by both LM variants; with
+    ``head`` false the normed state itself (:func:`head_weights`)."""
     x = nn.LayerNorm(
         dtype=mod.dtype, param_dtype=jnp.float32, name="ln_out"
     )(x)
+    if not head:
+        return x
     return nn.Dense(
         mod.vocab_size, dtype=jnp.float32, param_dtype=jnp.float32,
         name="head",
     )(x)
+
+
+def head_weights(params, tied=False):
+    """``(weights, bias, tied)`` of an LM's vocabulary head as its
+    parameter tree holds them: ``head/kernel`` ``(d, V)`` and
+    ``head/bias`` (``None`` where the head has none), or with ``tied``
+    the embedding table ``tok_embed/embedding`` ``(V, d)``, which the
+    head reads transposed. Every LM here answers ``head_weights(params)``
+    with this and takes ``head=False`` in its call to hand back the
+    state after ``ln_out`` where the logits would be: the two halves of
+    what ``train/lm.py``'s step asks of a model to run the head and the
+    loss as one walk (``ops/head_loss.py``) and never hold the logits.
+    A model without the method is asked for its logits, as ever."""
+    if tied:
+        return params["tok_embed"]["embedding"], None, True
+    return params["head"]["kernel"], params["head"].get("bias"), False
 
 
 def _lm_param_shapes(trial, model):
@@ -283,7 +302,7 @@ class TransformerLM(nn.Module):
     remat: bool = False
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, head=True):
         x = _lm_embed(self, tokens)
         attn = _default_causal(self.attention)
         block_cls = remat_block(Block) if self.remat else Block
@@ -295,7 +314,10 @@ class TransformerLM(nn.Module):
                 dtype=self.dtype,
                 name=f"block_{i}",
             )(x)
-        return _lm_head(self, x)
+        return _lm_head(self, x, head)
+
+    def head_weights(self, params):
+        return head_weights(params)
 
 
 def transformer_tp_shardings(
@@ -433,7 +455,7 @@ class MoETransformerLM(nn.Module):
     remat: bool = False  # per-block checkpointing (remat_block)
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, head=True):
         x = _lm_embed(self, tokens)
         attn = _default_causal(self.attention)
         block_cls = remat_block(MoEBlock) if self.remat else MoEBlock
@@ -449,8 +471,11 @@ class MoETransformerLM(nn.Module):
                 name=f"block_{i}",
             )(x)
             aux_total = aux_total + aux
-        logits = _lm_head(self, x)
+        logits = _lm_head(self, x, head)
         return logits, aux_total / self.num_layers
+
+    def head_weights(self, params):
+        return head_weights(params)
 
 
 def moe_lm_ep_shardings(trial, model: MoETransformerLM):

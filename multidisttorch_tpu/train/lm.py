@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from multidisttorch_tpu.models.transformer import _placement
+from multidisttorch_tpu.ops.head_loss import lm_head_loss
 from multidisttorch_tpu.parallel.mesh import DATA_AXIS, TrialMesh
 from multidisttorch_tpu.train.steps import TrainState
 from multidisttorch_tpu.utils.profiling import (
@@ -121,7 +123,15 @@ def _sample_token(logits, rng, temperature, top_k, top_p):
 
 def lm_loss_mean(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     """Mean next-token cross-entropy; the last position is masked (its
-    target would wrap around the roll)."""
+    target would wrap around the roll).
+
+    The definition of the LM objective: the eval step computes it, and
+    so does the train step of a trial whose operands lie on more than
+    one device or whose model offers only logits. A one-device trial of
+    the LMs here trains on ``ops/head_loss.py::lm_head_loss``, the same
+    loss and gradients made without the ``(B, T, V)`` logits, which
+    ``tests/test_head_loss.py`` holds to this function differentiated
+    through a float32 head."""
     targets = jnp.roll(tokens, -1, axis=1)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
@@ -185,7 +195,20 @@ def make_lm_train_step(
     ``lm_loss + aux_loss_weight * aux``; one returning ``(logits,
     {name: counter})`` (``LatentMoELM``'s assignments per expert held)
     trains on the loss alone and the counters come out beside it in
-    the step's metrics, read with the loss."""
+    the step's metrics, read with the loss.
+
+    Head and loss take one of two paths, chosen while tracing from what
+    the step can see (:func:`_build_lm_step_fn`): a model that hands
+    back its state after the last norm and names its head's weights
+    (``__call__(tokens, head=False)`` and ``head_weights(params)``:
+    every LM of ``models/``), on operands that lie on one device, is
+    asked for that state and ``ops/head_loss.py::lm_head_loss`` walks
+    it in blocks of positions, loss and gradients at once, the float32
+    logits never whole; any other model (a user's module, a pipeline
+    stage), and any trial over several devices (a walk over blocks of
+    rows of a batch- or sequence-sharded array would make GSPMD gather
+    it), is asked for ``(B, T, V)`` logits and :func:`lm_loss_mean` is
+    differentiated through them."""
     repl, tokens_sh, _, state_sh = _lm_shardings(
         trial, sequence_parallel, shardings
     )
@@ -201,13 +224,22 @@ def make_lm_train_step(
 def _build_lm_step_fn(model, tx, aux_loss_weight):
     """The un-jitted LM optimizer step shared by the single-dispatch and
     scan-fused factories (one copy of the loss/update math, so the two
-    cannot drift)."""
+    cannot drift; :func:`make_lm_train_step` says which of the head's
+    and loss's two paths a trial takes)."""
 
     def step_fn(state: TrainState, tokens: jax.Array):
+        placed = _placement(tokens)  # None: no mesh to see, so one device
+        walk = hasattr(model, "head_weights") and (placed is None or placed[1] == 1)
+
         def loss_fn(params):
-            out = model.apply({"params": params}, tokens)
-            with jax.named_scope(SCOPE_LOSS):
-                loss = lm_loss_mean(_logits(out), tokens)
+            if walk:
+                out = model.apply({"params": params}, tokens, head=False)
+                weights, bias, tied = model.head_weights(params)
+                loss = lm_head_loss(_logits(out), weights, bias, tokens, model.dtype, tied)
+            else:
+                out = model.apply({"params": params}, tokens)
+                with jax.named_scope(SCOPE_LOSS):
+                    loss = lm_loss_mean(_logits(out), tokens)
             counters = {}
             if isinstance(out, tuple) and isinstance(out[1], dict):
                 counters = out[1]  # counted, not trained on

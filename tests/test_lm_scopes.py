@@ -92,7 +92,9 @@ def test_scopes_reach_the_compiled_step(model_cls, remat):
 
     both = {"forward", "backward"}
     assert passes(SCOPE_ATTN_CORE) == both | ({"recompute"} if remat else set())
-    assert passes(SCOPE_LOSS) == both
+    # the head and the loss are one walk that makes its gradients as it goes
+    # (ops/head_loss.py): both read as the forward pass, the two backward products too
+    assert passes(SCOPE_LOSS) == passes("head") == {"forward"}
     assert passes(SCOPE_OPTIMIZER) == {"none"}
     for i in range(LAYERS):
         assert any({f"block_{i}", SCOPE_ATTN_CORE} <= set(_components(n)) for n in step)
@@ -604,7 +606,7 @@ def test_hybrid_scopes_reach_the_compiled_step(remat):
     core = [n for n in step if scope_reduce.classify(n)[0] == "attn_core"]
     assert all((swa_scopes.classify(n) or ssm_scopes.classify(n)) for n in core)  # the three's sum
     head = [n for n in step if profiling.SCOPE_HEAD in _components(n)]
-    assert {_pass(n) for n in head} >= both
+    assert {_pass(n) for n in head} == {"forward"}  # the walk's: its three products are one pass
     assert {scope_reduce.classify(n)[0] for n in head} == {"head"}
     parts = {scope_reduce.classify(n)[0] for n in step}
     assert {"attn_core", "attn_proj", "mlp", "norm", "embed", "head", "loss", "optimizer",
@@ -667,3 +669,56 @@ def test_hybrid_scopes_stay_out_of_the_parameter_tree():
     assert set(params["block_1"]) == set(params["block_5"]) == shared | {"qkv", "proj"}
     assert set(params["block_6"]) == shared | {"in_proj", "out_proj"}
     assert set(params["block_7"]) == shared | {"q", "proj"}
+
+
+# --- the head and the loss as one walk (ops/head_loss.py), in every LM ---
+
+
+def _walk_lms():
+    from multidisttorch_tpu.models.grouped_window_moe import GroupedWindowMoELM
+    from multidisttorch_tpu.models.latent_moe import LatentMoELM
+    from multidisttorch_tpu.models.ssm_hybrid import SambaYLM
+
+    small = dict(d_model=32, num_heads=4, num_layers=LAYERS)
+    return {
+        "dense": lambda **kw: TransformerLM(**small, **kw),
+        "moe": lambda **kw: MoETransformerLM(**small, **kw),
+        "latent": LatentMoELM, "grouped": GroupedWindowMoELM, "hybrid": SambaYLM,
+    }
+
+
+@pytest.mark.parametrize("blocks", [1, 2], ids=["one-block", "loop"])
+@pytest.mark.parametrize("name", ["dense", "moe", "latent", "grouped", "hybrid"])
+def test_head_and_loss_of_the_walk_reach_the_compiled_step(monkeypatch, name, blocks):
+    """``head`` and ``loss`` in the compiled tiny step of every LM, as
+    ``benchmark/scope_reduce.classify`` files them: the walk's three
+    products under ``head``, the passes over a block's logits under
+    ``loss``, in the loop's body too when the rule gives two blocks."""
+    from benchmark import scope_reduce
+    from multidisttorch_tpu.ops import head_loss
+
+    vocab, shape = 72, (2, 16)
+    monkeypatch.setattr(head_loss, "LOGITS_BLOCK_BYTES", shape[0] * shape[1] * vocab * 4 // blocks)
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    model = _walk_lms()[name](vocab_size=vocab, max_len=shape[1], remat=True)
+    tx = optax.adam(1e-3)
+    params = jax.eval_shape(
+        model.init, {"params": jax.random.key(0)}, jnp.zeros(shape, jnp.int32)
+    )["params"]
+    state = jax.eval_shape(
+        lambda p: TrainState(params=p, opt_state=tx.init(p), step=jnp.zeros((), jnp.int32)),
+        params,
+    )
+    tokens = jax.ShapeDtypeStruct(shape, jnp.int32)
+    text = make_lm_train_step(group, model, tx).lower(state, tokens).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    step = [n for n in names if n.startswith("jit(step_fn)")]
+    for scope in ("head", SCOPE_LOSS):
+        under = [n for n in step if scope in _components(n)]
+        assert {scope_reduce.classify(n) for n in under} == {(scope, "forward")}, scope
+        assert all(("while/body" in n) == (blocks > 1) for n in under if "jvp()" in n), scope
+    for op in ("exp", "reduce_max", "log"):  # the logsumexp's passes
+        assert any(_components(n)[-2:] == [SCOPE_LOSS, op] for n in step), op
+    # rows x weights, gradient x weights, rows x gradient: the head's three products
+    dots = re.findall(r' dot\([^\n]*op_name="([^"]*)"', text)
+    assert sum(_components(n)[-2:] == ["head", "dot_general"] for n in dots) == 3
