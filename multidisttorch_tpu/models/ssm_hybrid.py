@@ -149,13 +149,15 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     )
 
 
-def causal_conv(x, w, bias):
+def causal_conv(x, w, bias=None):
     """Depthwise causal convolution of ``x`` ``(B, T, E)`` with ``w``
     ``(taps, E)``, the last tap on the position itself: ``taps``
-    shifted multiply-adds, no padded copy through a convolution."""
+    shifted multiply-adds, no padded copy through a convolution.
+    ``bias`` ``(E,)`` where the convolution has one."""
     t, taps = x.shape[1], w.shape[0]
     ahead = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    return sum(ahead[:, j:j + t] * w[j].astype(x.dtype) for j in range(taps)) + bias.astype(x.dtype)
+    out = sum(ahead[:, j:j + t] * w[j].astype(x.dtype) for j in range(taps))
+    return out if bias is None else out + bias.astype(x.dtype)
 
 
 class SambaYBlock(nn.Module):

@@ -259,9 +259,27 @@ _TOKEN_BLOCK = 256  # tokens a block of the sums' kernel
 _SUM_TILE_ROWS = 128  # rows a step of the sums' kernel; about what a block of tokens has
 
 
-def _widest_tile(width: int) -> int:
-    """The widest tile up to 1,024 that divides ``width`` into whole tiles."""
-    return next(t for t in range(1024, 0, -128) if width % t == 0)
+def _widest_tile(width: int, most: int = 1024) -> int:
+    """The widest tile up to ``most`` that divides ``width`` into whole tiles."""
+    return next(t for t in range(most, 0, -128) if width % t == 0)
+
+
+# The backward's ``tgmm`` holds a ``(tile_k, tile_n)`` float32 sum and
+# its outgoing copies beside the operands' tiles: 896 x 1,024
+# (``xing4.0-29b-a4b``) fits the 16 MiB a kernel's stack may take, 1,024
+# x 1,024 with a float32 cotangent passes it by 124 KB (the TPU
+# compiler for a described v5e, PR 39).
+_MAX_TILE_AREA = 896 * 1024
+
+
+def _tiles(k: int, n: int) -> tuple[int, int]:
+    """``(tile_k, tile_n)`` of the experts' kernel for a ``(k, n)``
+    product: the widest of each, ``n``'s narrowed while the two pass
+    ``_MAX_TILE_AREA`` together."""
+    tile_k, tile_n = _widest_tile(k), _widest_tile(n)
+    while tile_k * tile_n > _MAX_TILE_AREA:
+        tile_n = _widest_tile(n, tile_n - 128)
+    return tile_k, tile_n
 
 
 def _visits(key, n: int, block: int, tile: int):
@@ -377,7 +395,7 @@ def _kernel_experts(lhs, rhs, sizes):
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     return gmm(
-        lhs, rhs, sizes, jnp.float32, (_TILE_ROWS, *map(_widest_tile, rhs.shape[1:])),
+        lhs, rhs, sizes, jnp.float32, (_TILE_ROWS, *_tiles(*rhs.shape[1:])),
         interpret=pallas_interpret(),
     )
 

@@ -81,6 +81,14 @@ SCOPE_SSM_CONV = "ssm_conv"
 SCOPE_SSM_SCAN = "ssm_scan"
 SCOPE_GMU = "gmu"
 SCOPE_HEAD = "head"
+# The gated short-convolution / attention expert model
+# (``models/conv_moe.py``): a conv operator's two projections (``W_in``
+# and ``W_out``), and its two gates and taps between them; an attention
+# layer's per-head norms of q and k and their rotation, which sit
+# between the projections and the core.
+SCOPE_CONV_PROJ = "conv_proj"
+SCOPE_CONV_MIX = "conv_mix"
+SCOPE_QK_NORM = "qk_norm"
 
 # Host spans of a trial's admission (:func:`span`), each opened where
 # the work is done: ``parallel/mesh.py::setup_groups``; the whole of

@@ -154,6 +154,60 @@ def test_kernels_at_width_64_match_the_masked_dense_form_and_the_plain_path(head
         assert _rel(other, need) < 1e-5
 
 
+# 4 query heads a KV head (``lfm2-24b-a2b``'s 32 over 8), the block edge the
+# layer would take: T a multiple of 1,024 (edge 512) and not (640: edge 128)
+@pytest.mark.parametrize(
+    "heads, t", [((8, 2), 1024), ((8, 2), 640), ((16, 4), 640)],
+    ids=["h8kv2-t1024", "h8kv2-t640", "h16kv4-t640"],
+)
+def test_kernels_at_width_64_with_four_query_heads_a_kv_head(heads, t):
+    """Forward and the gradients of q, k and v, full causal attention
+    at the edge ``grouped_attention`` chooses by itself, against the
+    masked softmax and ``blocked_window_attention``."""
+    keys = jax.random.split(jax.random.key(t + heads[0]), 4)
+    shape = lambda n: (1, t, n, 64)
+    q, k, v, co = (jax.random.normal(key, shape(n))
+                   for key, n in zip(keys, (heads[0], heads[1], heads[1], heads[0])))
+    loss = lambda fn: jax.value_and_grad(lambda q, k, v: jnp.sum(fn(q, k, v) * co), (0, 1, 2))
+    with jax.default_matmul_precision("highest"):
+        got = loss(lambda q, k, v: grouped_attention(q, k, v, window=None, q_rotation=None))(q, k, v)
+        want = loss(lambda q, k, v: _dense(q, k, v, None))(q, k, v)
+        plain = loss(lambda q, k, v: blocked_window_attention(q, k, v, window=None))(q, k, v)
+    for have, need, other in zip(*(jax.tree.leaves(x) for x in (got, want, plain)), strict=True):
+        assert _rel(have, need) < 5e-5  # the first leaf is a float32 sum of T x H x 64 terms
+        assert _rel(other, need) < 5e-5
+
+
+def test_rule_takes_rotary_heads_of_64_that_come_rotated():
+    """``lfm2-24b-a2b``'s attention layer, 32 query heads over 8 KV
+    heads of 64 at T = 8,192 on one v5e chip: the kernels, since the
+    block rotates q itself and asks with ``rotates_q`` false; a caller
+    that wants the kernels to rotate keeps the plain path."""
+    assert grouped_takes_kernel("TPU v5 lite", 1, 8192, 32, 8, 64)
+    assert grouped_takes_kernel("TPU v5 lite", 1, 8192, 32, 8, 64, rotates_q=False)
+    assert not grouped_takes_kernel("TPU v5 lite", 1, 8192, 32, 8, 64, rotates_q=True)
+    assert not grouped_takes_kernel("TPU v5 lite", 4, 8192, 32, 8, 64)
+    assert not grouped_takes_kernel("cpu", 1, 8192, 32, 8, 64)
+
+
+def test_operands_of_four_query_heads_a_kv_head_at_width_64_lower_for_tpu(monkeypatch):
+    # the cell moe-conv-t8192's attention as its block hands it over: 4 x
+    # 8,192, 32 query heads over 8 KV heads of 64; forward and backward,
+    # interpret mode off. No score matrix, no repeated KV head and no
+    # head padded to 128 lanes is made around the kernels.
+    monkeypatch.delenv("MDT_PALLAS_INTERPRET")
+    part = lambda heads: jax.ShapeDtypeStruct((4, 8192, heads, 64), jnp.bfloat16)
+    fwd = lambda q, k, v: grouped_attention(q, k, v, window=None, q_rotation=None)
+    bwd = jax.grad(lambda *x: fwd(*x).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    for fn, calls in ((fwd, 1), (bwd, 2)):
+        text = jax.jit(fn).trace(part(32), part(8), part(8)).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == calls
+        assert "grouped64_" in text and "8192x8192" not in text
+        assert "tensor<4x8192x32x128xbf16>" not in text
+        assert "tensor<4x8192x32x64xbf16>" not in text.split("custom_call")[1]
+
+
 def test_kernels_at_width_64_take_k_and_v_from_elsewhere():
     """A layer's own q over another's k and v (the cross-attention
     layer's call): the cotangents of k and v from two such calls and

@@ -671,10 +671,146 @@ def test_hybrid_scopes_stay_out_of_the_parameter_tree():
     assert set(params["block_7"]) == shared | {"q", "proj"}
 
 
+# --- the gated short-convolution / attention expert model (models/conv_moe.py) ---
+
+
+def _lowered_conv(remat, t=16, placed=False, **fields):
+    """``ShortConvMoELM``'s step lowered for ``(1, t)`` tokens;
+    ``placed`` as :func:`_lowered_latent`."""
+    from multidisttorch_tpu.models.conv_moe import ShortConvMoELM
+
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    model = ShortConvMoELM(**{"vocab_size": 64, "max_len": t, "remat": remat, **fields})
+    tx = optax.adam(1e-3)
+    tokens = jax.ShapeDtypeStruct((1, t), jnp.int32)
+    params = jax.eval_shape(
+        model.init, {"params": jax.random.key(0)}, jnp.zeros((1, t), jnp.int32)
+    )["params"]
+    state = jax.eval_shape(
+        lambda p: TrainState(params=p, opt_state=tx.init(p), step=jnp.zeros((), jnp.int32)),
+        params,
+    )
+    if placed:
+        on = lambda tree, sharding: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree
+        )
+        state, tokens = on(state, group.replicated_sharding), on(tokens, group.batch_sharding)
+    return make_lm_train_step(group, model, tx).lower(state, tokens), params
+
+
+# the default 4 layers (conv, conv, full_attention, conv; one dense): which blocks hold which scope
+_CONV_BLOCKS = {
+    "conv_proj": {"block_0", "block_1", "block_3"}, "conv_mix": {"block_0", "block_1", "block_3"},
+    "qk_norm": {"block_2"},
+}
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_conv_scopes_reach_the_compiled_step(remat):
+    """``conv_proj``, ``conv_mix`` and ``qk_norm`` in the compiled tiny
+    step (the plain path: the CPU), each in the blocks of its kind and
+    in every pass; ``attn_full`` inside ``attn_core``, the dense layer
+    under ``mlp`` and the expert layers' parts under ``moe``, as the
+    accepted splits read them; the step's other names as the other
+    models'."""
+    from benchmark import conv_scopes, moe_scopes, scope_reduce, swa_scopes
+    from multidisttorch_tpu.utils.profiling import (
+        SCOPE_ATTN_FULL, SCOPE_CONV_MIX, SCOPE_CONV_PROJ, SCOPE_EXPERT_DISPATCH, SCOPE_EXPERTS,
+        SCOPE_QK_NORM, SCOPE_ROUTER,
+    )
+
+    lowered, _ = _lowered_conv(remat)
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    step = [n for n in names if n.startswith("jit(step_fn)")]
+    assert len(step) > 400
+    every = {"forward", "backward"} | ({"recompute"} if remat else set())
+    assert conv_scopes.PARTS == (SCOPE_CONV_PROJ, SCOPE_CONV_MIX, SCOPE_QK_NORM)
+    for scope, blocks in _CONV_BLOCKS.items():
+        under = [n for n in step if conv_scopes.classify(n) == scope]
+        assert {_pass(n) for n in under} >= {"forward", "backward"}, scope
+        assert {c for n in under for c in _components(n) if c.startswith("block_")} == blocks
+        assert {scope_reduce.classify(n)[0] for n in under} == {"block_other"}, scope
+    # the recomputed conv block makes all of its operator again, W_out too
+    # (it keeps nothing). The attention layer's q, k and v are kept as the
+    # projections leave them: the head norms and the rotation run again,
+    # the three products and proj (the stream after it is kept) do not
+    again = [n for n in step if _pass(n) == "recompute"]
+    for name in ("in_proj", "out_proj"):
+        assert any(name in _components(n) for n in again) == remat, name
+    assert any(conv_scopes.classify(n) == SCOPE_CONV_MIX for n in again) == remat
+    assert any(conv_scopes.classify(n) == SCOPE_QK_NORM for n in again) == remat
+    for name in ("q", "k", "v", "proj"):
+        assert {_pass(n) for n in step if name in _components(n)} == {"forward", "backward"}, name
+    core = [n for n in step if scope_reduce.classify(n)[0] == "attn_core"]
+    assert core and {swa_scopes.classify(n) for n in core} == {SCOPE_ATTN_FULL}
+    assert {c for n in core for c in _components(n) if c.startswith("block_")} == {"block_2"}
+    assert {_pass(n) for n in core} >= every
+    for scope in (SCOPE_ROUTER, SCOPE_EXPERT_DISPATCH, SCOPE_EXPERTS):
+        under = [n for n in step if moe_scopes.classify(n) == scope]
+        assert {_pass(n) for n in under} >= every, scope
+        assert {scope_reduce.classify(n)[0] for n in under} == {"mlp"}, scope
+        assert {c for n in under for c in _components(n) if c.startswith("block_")} == {
+            "block_1", "block_2", "block_3"}  # block_0 is the dense layer
+    dense = [n for n in step if "block_0" in _components(n) and "mlp" in _components(n)]
+    assert {c for n in dense for c in _components(n)} >= {"gate", "up", "down"}
+    parts = {scope_reduce.classify(n)[0] for n in step}
+    assert {"attn_core", "attn_proj", "mlp", "norm", "embed", "head", "loss", "optimizer"} <= parts
+    unscoped = [n for n in step if scope_reduce.classify(n)[0] == "unscoped"]
+    assert len(unscoped) / len(step) < UNRECOGNISED_BOUND, sorted(set(unscoped))[:20]
+    # what neither the accepted split nor the three new scopes name
+    nowhere = [n for n in step if scope_reduce.classify(n)[0] == "block_other"
+               and conv_scopes.classify(n) is None]
+    assert len(nowhere) / len(step) < UNRECOGNISED_BOUND, sorted(set(nowhere))[:20]
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_conv_model_s_kernels_are_under_attn_full_once_a_pass(monkeypatch, remat):
+    """With the operands placed as a trial's and the device a v5e by
+    name, the attention layer of 8 heads over 2 KV heads of 64 (4 query
+    heads a KV head) lowers the 64-wide kernel pair under
+    ``attn_core/attn_full``, one forward and one backward call, no
+    kernel in the recomputed block, and q and k reach it normed and
+    rotated under ``qk_norm``."""
+    from benchmark import conv_scopes, scope_reduce, swa_scopes
+    from multidisttorch_tpu.models import transformer
+
+    real = transformer._placement
+    monkeypatch.setattr(
+        transformer, "_placement", lambda x: real(x) and ("TPU v5 lite", real(x)[1])
+    )
+    lowered, _ = _lowered_conv(
+        remat, t=256, placed=True, d_model=128, num_heads=8, num_kv_heads=2, head_dim=64,
+    )
+    text = lowered.compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    step = [n for n in names if n.startswith("jit(step_fn)")]
+    calls = (("jit(_grouped64_fwd_call)", "forward"), ("jit(_grouped64_bwd_call)", "backward"))
+    for call, where in calls:
+        under = [n for n in step if call in n.split("/")]
+        assert {scope_reduce.classify(n) for n in under} == {("attn_core", where)}, call
+        assert {(swa_scopes.classify(n), c) for n in under for c in _components(n)
+                if c.startswith("block_")} == {("attn_full", "block_2")}
+    normed = [n for n in step if conv_scopes.classify(n) == "qk_norm"]
+    assert {_pass(n) for n in normed} == {"forward", "backward"} | ({"recompute"} if remat else set())
+    assert any(n.endswith("/concatenate") for n in normed)  # the rotation, q's and k's
+
+
+def test_conv_scopes_stay_out_of_the_parameter_tree():
+    _, params = _lowered_conv(True)
+    assert set(params) == {"tok_embed", "ln_out"} | {f"block_{i}" for i in range(4)}  # no head
+    conv, norms = {"in_proj", "conv_w", "out_proj"}, {"ln_attn", "ln_mlp"}
+    attention = {"q", "k", "v", "q_norm", "k_norm", "proj"}
+    assert set(params["block_0"]) == norms | conv | {"gate", "up", "down"}
+    assert set(params["block_1"]) == set(params["block_3"]) == norms | conv | {"moe"}
+    assert set(params["block_2"]) == norms | attention | {"moe"}
+    assert set(params["block_1"]["moe"]) == {"router", "score_bias", "w_gate", "w_up", "w_down"}
+
+
 # --- the head and the loss as one walk (ops/head_loss.py), in every LM ---
 
 
 def _walk_lms():
+    from multidisttorch_tpu.models.conv_moe import ShortConvMoELM
     from multidisttorch_tpu.models.grouped_window_moe import GroupedWindowMoELM
     from multidisttorch_tpu.models.latent_moe import LatentMoELM
     from multidisttorch_tpu.models.ssm_hybrid import SambaYLM
@@ -684,11 +820,12 @@ def _walk_lms():
         "dense": lambda **kw: TransformerLM(**small, **kw),
         "moe": lambda **kw: MoETransformerLM(**small, **kw),
         "latent": LatentMoELM, "grouped": GroupedWindowMoELM, "hybrid": SambaYLM,
+        "conv": ShortConvMoELM,
     }
 
 
 @pytest.mark.parametrize("blocks", [1, 2], ids=["one-block", "loop"])
-@pytest.mark.parametrize("name", ["dense", "moe", "latent", "grouped", "hybrid"])
+@pytest.mark.parametrize("name", ["dense", "moe", "latent", "grouped", "hybrid", "conv"])
 def test_head_and_loss_of_the_walk_reach_the_compiled_step(monkeypatch, name, blocks):
     """``head`` and ``loss`` in the compiled tiny step of every LM, as
     ``benchmark/scope_reduce.classify`` files them: the walk's three
