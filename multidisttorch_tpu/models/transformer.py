@@ -25,6 +25,7 @@ from typing import Any, Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from multidisttorch_tpu.ops.hyper_connection import SAVED_MAPS, SAVED_Y
 from multidisttorch_tpu.ops.moe import SAVED_ROUTING
@@ -46,7 +47,11 @@ from multidisttorch_tpu.utils.profiling import SCOPE_ATTN_CORE, SCOPE_MLP
 # ``models/latent_moe.py`` and ``models/grouped_window_moe.py`` (4,096
 # and 3,584 wide in their cells) give the name; :class:`Block` (1,024 in
 # its cells) does not, where the same name made the step slower on the
-# chip than the product it spared (PERF.md section 6, PR 34).
+# chip than the product it spared (PERF.md section 6, PR 34). It keeps
+# q, k, v and ``up``'s output instead, each of which spares three to
+# four times ``proj``'s milliseconds, and on the chip displaced nothing
+# (PR 40); ``ln_mlp`` still reads the sum, so ``proj`` is the one
+# product its recomputed block makes again.
 SAVED_RESIDUAL = "residual_after_attention"
 
 # The operands an attention reads, q, k and v as its call receives them
@@ -54,13 +59,22 @@ SAVED_RESIDUAL = "residual_after_attention"
 # kernels read them), by the name :func:`remat_block` keeps: the
 # kernels' backward reads them, so with the three kept the recomputed
 # block multiplies its normed input by none of the three matrices and
-# rotates nothing. ``GroupedWindowMoEBlock`` alone gives the name (its
-# q is 3,584 wide from a 2,560-wide input); what the same operands are
-# worth a byte in the other blocks is less, and in :class:`Block` there
-# is no room for them (PERF.md section 6, PR 36). Defined here and not
-# beside the kernels: a line moved in ``ops/pallas_attention.py`` or
-# ``ops/moe.py`` changes every kernel's serialized module.
+# rotates nothing. ``GroupedWindowMoEBlock``, ``ShortConvMoEBlock``'s
+# attention layers and, on one TPU chip, :class:`Block` give the name;
+# ``LatentMoEBlock`` does not (its five operands, 2.6 GiB in its cell,
+# do not fit under the step's plan; PERF.md section 7). Named flat: a kept array has no reader that fixes
+# its layout, and a 4-D name cost copies in both passes (PERF.md
+# section 6, PR 36). Defined here and not beside the kernels: a line
+# moved in ``ops/pallas_attention.py`` or ``ops/moe.py`` changes every
+# kernel's serialized module.
 SAVED_QKV = "attention_operands"
+
+# :class:`Block`'s MLP pre-activation, ``up``'s output before ``gelu``,
+# ``(B, T, 4d)`` at the compute dtype, by the name :func:`remat_block`
+# keeps: ``gelu``'s backward and ``down``'s read it, so with it kept the
+# recomputed block makes no ``up`` product, only the elementwise
+# ``gelu`` again.
+SAVED_MLP_HIDDEN = "mlp_hidden"
 
 
 def _layer_ctors(mod):
@@ -75,9 +89,11 @@ def _layer_ctors(mod):
     return dense, ln
 
 
-def _attention_residual(mod, x, dense, ln):
+def _attention_residual(mod, x, dense, ln, keep=False):
     """The attention half shared by :class:`Block` and
-    :class:`MoEBlock` (one copy — the two must never drift).
+    :class:`MoEBlock` (one copy — the two must never drift); with
+    ``keep`` q, k and v are named ``SAVED_QKV`` as the projections
+    write them, before the reshape to heads.
 
     Separate q/k/v projections (not one fused 3d dense): each output's
     flat feature dim factors as [head, head_dim], so a tensor-parallel
@@ -88,9 +104,15 @@ def _attention_residual(mod, x, dense, ln):
     b, t, d = x.shape
     h = mod.num_heads
     y = ln("ln_attn")(x)
-    q = dense(d, "q")(y).reshape(b, t, h, d // h)
-    k = dense(d, "k")(y).reshape(b, t, h, d // h)
-    v = dense(d, "v")(y).reshape(b, t, h, d // h)
+
+    def operand(name):
+        a = dense(d, name)(y)
+        if keep:  # jax rounds a kept float where it is named: the projection's work
+            with jax.named_scope(name):
+                a = checkpoint_name(a, SAVED_QKV)
+        return a.reshape(b, t, h, d // h)
+
+    q, k, v = operand("q"), operand("k"), operand("v")
     with jax.named_scope(SCOPE_ATTN_CORE):
         attn = mod.attention(q, k, v)
     attn = attn.reshape(b, t, d)
@@ -108,11 +130,19 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         dense, ln = _layer_ctors(self)
-        x = _attention_residual(self, x, dense, ln)
+        # q, k, v and up's output are kept across remat on one TPU chip,
+        # where the room for them was measured beside the head and loss
+        # that train/lm.py walks in blocks (PERF.md section 6, PR 40); a
+        # trial over several chips still holds its logits.
+        placed = _placement(x)
+        keep = bool(placed) and placed[0].startswith("TPU") and placed[1] == 1
+        x = _attention_residual(self, x, dense, ln, keep)
         d = x.shape[-1]
         y = ln("ln_mlp")(x)
         with jax.named_scope(SCOPE_MLP):
             y = dense(4 * d, "up")(y)
+            if keep:
+                y = checkpoint_name(y, SAVED_MLP_HIDDEN)
             y = nn.gelu(y)
             y = dense(d, "down")(y)
         return x + y
@@ -122,7 +152,7 @@ class Block(nn.Module):
 # by identity.
 _KEEP_ACROSS_REMAT = jax.checkpoint_policies.save_only_these_names(
     SAVED_OUT, SAVED_LSE, SAVED_MAPS, SAVED_Y, SAVED_RESIDUAL, SAVED_ROUTING, SAVED_QKV,
-    SAVED_SCAN_OUT, SAVED_SCAN_STATES,
+    SAVED_SCAN_OUT, SAVED_SCAN_STATES, SAVED_MLP_HIDDEN,
 )
 
 
@@ -130,7 +160,7 @@ def remat_block(block_cls):
     """``block_cls`` under per-block rematerialization, the one rule of
     every model here that has a ``remat`` field: the backward pass
     recomputes a block from its input, and of what the block made it
-    keeps, by name, what costs most to remake a byte. Nine names, each
+    keeps, by name, what costs most to remake a byte. Ten names, each
     given where the value is made, and a block keeps those its trace
     holds:
 
@@ -138,10 +168,11 @@ def remat_block(block_cls):
       attention kernel's output and logsumexp, bf16 ``(B, T, H*Dv)``
       and f32 ``(B, H, T)``, so the recomputed forward holds no kernel;
     - ``SAVED_RESIDUAL`` (this file; given by ``LatentMoEBlock``
-      without streams and ``GroupedWindowMoEBlock``, on every attention
-      path, the dense one too): the residual stream after attention,
-      ``x + proj(o)``, one ``(B, T, d)`` array at the compute dtype, so
-      ``proj`` is not multiplied again;
+      without streams, ``GroupedWindowMoEBlock``, ``SambaYBlock`` and
+      ``ShortConvMoEBlock``'s attention layers, on every attention
+      path, the dense one too): the residual stream after
+      the token mixer, ``x + proj(o)``, one ``(B, T, d)`` array at the
+      compute dtype, so ``proj`` is not multiplied again;
     - ``SAVED_ROUTING`` (``ops/moe.py::RoutedExperts``): the router's
       float32 logits ``(N, E)``, with sigmoid scoring its choices and
       their scores ``(N, k)``, the order into expert order ``(N*k,)``
@@ -151,27 +182,33 @@ def remat_block(block_cls):
       connection's projections and norm factor, and its sublayer's
       output, which does there what ``SAVED_RESIDUAL`` does around a
       plain residual add;
-    - ``SAVED_QKV`` (this file; given by ``GroupedWindowMoEBlock``
-      alone, on either attention path): q, k and v as the attention
-      call receives them, k rotated, ``(B, T, (H + 2 Hkv) * head_dim)``
-      at the compute dtype, so the three products and the rotation are
-      not made again. ``remat`` is there to make a step fit, and this
-      is the largest of the seven: 9.2 KB a token and layer in
-      ``smallthinker-21b-a3b`` (bf16, 28 + 4 + 4 heads of 128), 151 MB
-      a layer at T = 16,384;
+    - ``SAVED_QKV`` (this file; given by ``GroupedWindowMoEBlock`` and
+      ``ShortConvMoEBlock``'s attention layers on either attention
+      path, and by :class:`Block` on one TPU chip): q, k and v flat,
+      ``(B, T, (H + 2 Hkv) * head_dim)`` at the compute dtype, k
+      rotated where the block rotates it before the call, so the three
+      products and that rotation are not made again: 9.2 KB a token
+      and layer in ``smallthinker-21b-a3b`` (bf16, 28 + 4 + 4 heads of
+      128), 6 KB in ``gpt2-medium`` (3 x 1,024), for 14.3 ms of its
+      step's 37.6 ms of recomputation in the 24 layers of ``lm-dense``;
     - ``SAVED_SCAN_OUT``, ``SAVED_SCAN_STATES``
       (``ops/selective_scan.py``; a Mamba layer of
       ``models/ssm_hybrid.py``): the selective scan's output ``(B, T,
       E)`` at the compute dtype and the state at each chunk's end,
       float32 ``(B, T / 256, N, E)``, which the scan's backward walks
       from, so the recomputed forward holds no scan: 10.3 KB and 1.3 KB
-      a token and layer in ``phi-4-mini-flash`` (E = 5,120, N = 16).
+      a token and layer in ``phi-4-mini-flash`` (E = 5,120, N = 16);
+    - ``SAVED_MLP_HIDDEN`` (this file; given by :class:`Block` on one
+      TPU chip): the MLP's pre-activation ``(B, T, 4d)`` at the compute
+      dtype, 8 KB a token and layer in ``gpt2-medium``, so the
+      recomputed block makes no ``up`` product (18.8 ms in
+      ``lm-dense``), only ``gelu`` again.
 
-    Everything else (the other blocks' q, k and v, the MLP's or the
-    experts' hidden activations, the norms) is made again from the
-    block's input; where the trace holds none of the names (a
-    ``TransformerLM`` on the dense path) nothing but the input is
-    saved."""
+    Everything else (``LatentMoEBlock``'s q, k and v, the experts' and
+    the other MLPs' hidden activations, :class:`Block`'s ``proj``, the
+    norms) is made again from the block's input; where the trace holds
+    none of the names (a ``TransformerLM`` on the CPU or over several
+    chips, on the dense path) nothing but the input is saved."""
     return nn.remat(block_cls, policy=_KEEP_ACROSS_REMAT)
 
 
@@ -293,12 +330,13 @@ class TransformerLM(nn.Module):
     dtype: Any = jnp.float32
     # Per-BLOCK rematerialization (remat_block): the block boundaries'
     # residual streams are saved, and with them the attention kernel's
-    # output and logsumexp where the kernel runs; each block's other
-    # activations (qkv, dense attention's probs, the 4x MLP) are
-    # recomputed in the backward pass. This is the placement that
-    # actually cuts peak HBM for a deep stack — checkpointing the
-    # whole forward would leave every layer's activations live during
-    # the backward and save nothing.
+    # output and logsumexp where the kernel runs and, on one TPU chip,
+    # q, k, v and the MLP's pre-activation; each block's other
+    # activations (proj's sum, the norms, gelu, dense attention's
+    # probs) are recomputed in the backward pass. This is the
+    # placement that actually cuts peak HBM for a deep stack —
+    # checkpointing the whole forward would leave every layer's
+    # activations live during the backward and save nothing.
     remat: bool = False
 
     @nn.compact

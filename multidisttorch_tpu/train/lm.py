@@ -187,9 +187,10 @@ def make_lm_train_step(
     the placement that actually cuts peak HBM (a whole-forward
     ``jax.checkpoint`` here would recompute everything and save
     nothing); a block's input is saved, and of what the block made the
-    few values that cost most to remake a byte, up to 9.2 KB a token
-    and layer where a block keeps its attention's operands
-    (``models/transformer.py::remat_block`` lists them). A model returning
+    few values that cost most to remake a byte, up to 14 KB a token
+    and layer where a block keeps its attention's operands and its
+    MLP's pre-activation (``models/transformer.py::remat_block`` lists
+    them). A model returning
     ``(logits, aux)`` with a scalar ``aux`` (the MoE LM's Switch
     load-balancing term) trains on
     ``lm_loss + aux_loss_weight * aux``; one returning ``(logits,

@@ -464,12 +464,15 @@ def test_default_and_injected_flash_are_the_same_program(as_tpu):
 # --- what rematerialization keeps (transformer.remat_block) ---
 
 
-def _loss_and_grads(model, init=TransformerLM(**CFG), t=T):
+def _loss_and_grads(model, init=TransformerLM(**CFG), t=T, group=None):
     """One jitted ``value_and_grad`` of an LM (by default a two-block
     one over the kernel, interpreted), parameters and inputs from fixed
-    seeds."""
+    seeds; with ``group`` both placed on it, as a trial places them, so
+    that the blocks see the mesh."""
     tokens = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, t)), jnp.int32)
     params = init.init(jax.random.key(0), tokens)["params"]
+    if group is not None:
+        params, tokens = group.device_put(params), group.device_put(tokens, group.batch_sharding)
 
     def loss(p):
         out = model.apply({"params": p}, tokens)
@@ -486,19 +489,25 @@ def _loss_and_grads(model, init=TransformerLM(**CFG), t=T):
     return step.compile(compiler_options={"xla_allow_excess_precision": False})(params)
 
 
+@pytest.mark.parametrize("placed", [False, True], ids=["unplaced", "one-v5e-chip"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-def test_saved_kernel_results_leave_the_gradients_bit_equal(monkeypatch, dtype):
+def test_saved_kernel_results_leave_the_gradients_bit_equal(request, monkeypatch, dtype, placed):
     """What the policy saves is what the second forward call would have
     made again: loss and every gradient leaf equal to the last bit
     under the models' remat rule, under ``nn.remat`` with no policy,
-    and with no remat at all."""
+    and with no remat at all. Placed on one TPU chip the blocks also
+    keep q, k, v and ``up``'s output, and the same holds."""
+    group = None
+    if placed:
+        request.getfixturevalue("as_tpu")
+        (group,) = setup_groups(1, devices=jax.devices()[:1])
     make = lambda remat: TransformerLM(
         attention=make_flash_attention(causal=True), dtype=dtype, remat=remat, **CFG
     )
-    plain = _loss_and_grads(make(False))
-    saved = _loss_and_grads(make(True))
+    plain = _loss_and_grads(make(False), group=group)
+    saved = _loss_and_grads(make(True), group=group)
     monkeypatch.setattr(transformer, "remat_block", nn.remat)  # only a block's input is saved
-    bare = _loss_and_grads(make(True))
+    bare = _loss_and_grads(make(True), group=group)
     assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(saved[1]))
     for other in (bare, plain):
         for a, b in zip(jax.tree.leaves(saved), jax.tree.leaves(other), strict=True):
@@ -522,6 +531,43 @@ def test_the_policy_is_inert_on_the_dense_path(monkeypatch):
     policy = re.compile(r"policy=[^\n\]]*")
     assert policy.findall(str(with_policy)) != policy.findall(str(bare))
     assert policy.sub("", str(with_policy)) == policy.sub("", str(bare))
+
+
+@pytest.mark.parametrize(
+    "device_kind, devices",
+    [(V5E, 1), (V5E, 4), ("cpu", 1)],
+    ids=["one-v5e-chip", "four-v5e-chips", "one-cpu-device"],
+)
+def test_one_chip_block_recomputes_proj_alone(request, monkeypatch, device_kind, devices):
+    """On one TPU chip ``Block`` names q, k and v as the projections
+    write them and ``up``'s output before ``gelu``, and the policy
+    keeps them: of a recomputed block's products only ``proj`` is made
+    again (``ln_mlp`` reads its sum), beside the backward's two products
+    of each of its six matrices. Over several chips and off the TPU
+    nothing is named, and the recomputed blocks are bare ``nn.remat``'s,
+    the parent's (the CPU's dense attention adds its own products)."""
+    if device_kind == V5E:
+        request.getfixturevalue("as_tpu")
+    (group,) = setup_groups(1, devices=jax.devices()[:devices])
+    model = TransformerLM(remat=True, **CFG)
+    params, tokens = _params_and_tokens(group, model)
+
+    def gradient():  # traced anew each time: make_jaxpr remembers a function's trace
+        summed = lambda p, tokens: model.apply({"params": p}, tokens).sum()
+        return jax.make_jaxpr(jax.grad(summed))(params, tokens)
+
+    kept = gradient()
+    names = {e.params["name"] for e in _equations(kept) if e.primitive.name == "name"}
+    monkeypatch.setattr(transformer, "remat_block", nn.remat)
+    bare = _recomputed(gradient())
+    kept = _recomputed(kept)
+    if (device_kind, devices) == (V5E, 1):
+        assert names == {transformer.SAVED_QKV, transformer.SAVED_MLP_HIDDEN, SAVED_OUT, SAVED_LSE}
+        assert kept["dot_general"] == LAYERS * (1 + 2 * 6)
+        assert bare["dot_general"] - kept["dot_general"] == LAYERS * 4  # q, k, v, up
+    else:
+        assert not names & {transformer.SAVED_QKV, transformer.SAVED_MLP_HIDDEN}
+        assert kept == bare
 
 
 # A GroupedWindowMoELM over the grouped kernels (an injected attention of
