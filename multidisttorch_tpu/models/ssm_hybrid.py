@@ -52,7 +52,9 @@ or 1 and the scan tests nothing); ``D`` at 1.
 
 Under ``remat`` (``transformer.remat_block``) a block keeps, beside its
 inputs, the scan's output and chunk states or the attention's output
-and logsumexp, and the stream after the mixer; the memory and the
+and logsumexp, the stream after the mixer and, on one TPU chip, the
+MLP's ``gate`` output before ``silu`` (``SAVED_MLP_HIDDEN``: the
+recomputed block makes ``up`` alone again); the memory and the
 shared k, v are outputs of the blocks that make them and inputs of the
 blocks that read them, and their gradients sum over the readers.
 
@@ -204,10 +206,16 @@ class SambaYBlock(nn.Module):
             out, made = self._attention(u, kv)
             handed = made if self.kind == "full_kv" else None
         x = checkpoint_name(x + out, transformer.SAVED_RESIDUAL)
+        # gate's output is kept across remat on one TPU chip, where the
+        # room for it was measured; both products do not fit (PERF.md
+        # section 6, PR 41)
         h = norm("ln_mlp")(x)
         with jax.named_scope(SCOPE_MLP):
+            gate = self._dense(self.mlp_width, "gate")(h)
+            if transformer._on_one_tpu_chip(x):  # flat, as the Dense writes it
+                gate = checkpoint_name(gate, transformer.SAVED_MLP_HIDDEN)
             h = self._dense(x.shape[-1], "down")(
-                nn.silu(self._dense(self.mlp_width, "gate")(h)) * self._dense(self.mlp_width, "up")(h)
+                nn.silu(gate) * self._dense(self.mlp_width, "up")(h)
             )
         return x + h, handed, state_rms
 

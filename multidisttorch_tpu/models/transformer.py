@@ -73,7 +73,15 @@ SAVED_QKV = "attention_operands"
 # ``(B, T, 4d)`` at the compute dtype, by the name :func:`remat_block`
 # keeps: ``gelu``'s backward and ``down``'s read it, so with it kept the
 # recomputed block makes no ``up`` product, only the elementwise
-# ``gelu`` again.
+# ``gelu`` again. ``models/ssm_hybrid.py::SambaYBlock`` gives the name,
+# on one TPU chip, to its gated MLP's ``gate`` output before ``silu``
+# (flat, ``(B, T, mlp_width)``): 20 KB a token and layer in
+# ``phi-4-mini-flash`` (bf16, width 10,240), which spares one of the
+# two products a recomputed block made again (0.86 TFLOP a layer at
+# 16,384 tokens: 28.9 ms of recomputation and 8.5 of the backward in
+# ``ssm-yoco-t16384``). Kept instead, ``up``'s output spared as much
+# recomputation and slowed the forward by 8.3 ms; both, 40 KB, plan
+# 15.14 GiB, more than any accepted cell runs (PERF.md section 6, PR 41).
 SAVED_MLP_HIDDEN = "mlp_hidden"
 
 
@@ -134,8 +142,7 @@ class Block(nn.Module):
         # where the room for them was measured beside the head and loss
         # that train/lm.py walks in blocks (PERF.md section 6, PR 40); a
         # trial over several chips still holds its logits.
-        placed = _placement(x)
-        keep = bool(placed) and placed[0].startswith("TPU") and placed[1] == 1
+        keep = _on_one_tpu_chip(x)
         x = _attention_residual(self, x, dense, ln, keep)
         d = x.shape[-1]
         y = ln("ln_mlp")(x)
@@ -198,17 +205,23 @@ def remat_block(block_cls):
       float32 ``(B, T / 256, N, E)``, which the scan's backward walks
       from, so the recomputed forward holds no scan: 10.3 KB and 1.3 KB
       a token and layer in ``phi-4-mini-flash`` (E = 5,120, N = 16);
-    - ``SAVED_MLP_HIDDEN`` (this file; given by :class:`Block` on one
-      TPU chip): the MLP's pre-activation ``(B, T, 4d)`` at the compute
-      dtype, 8 KB a token and layer in ``gpt2-medium``, so the
-      recomputed block makes no ``up`` product (18.8 ms in
-      ``lm-dense``), only ``gelu`` again.
+    - ``SAVED_MLP_HIDDEN`` (this file; given by :class:`Block` and by
+      ``SambaYBlock``, each on one TPU chip): the MLP's pre-activation
+      ``(B, T, 4d)`` at the compute dtype, 8 KB a token and layer in
+      ``gpt2-medium``, so the recomputed block makes no ``up`` product
+      (18.8 ms in ``lm-dense``), only ``gelu`` again; in
+      ``SambaYBlock`` the gated MLP's ``gate`` output ``(B, T,
+      mlp_width)``, 20 KB a token and layer in ``phi-4-mini-flash``
+      (40 KB with ``up``'s, which does not fit), so the recomputed
+      block makes ``up`` alone, and ``silu`` and the multiply again
+      (28.9 ms of ``ssm-yoco-t16384``'s 57.9 of MLP recomputation).
 
     Everything else (``LatentMoEBlock``'s q, k and v, the experts' and
-    the other MLPs' hidden activations, :class:`Block`'s ``proj``, the
-    norms) is made again from the block's input; where the trace holds
-    none of the names (a ``TransformerLM`` on the CPU or over several
-    chips, on the dense path) nothing but the input is saved."""
+    the other MLPs' hidden activations, ``SambaYBlock``'s ``up``,
+    :class:`Block`'s ``proj``, the norms) is made again from the block's
+    input; where the trace holds none of the names (a ``TransformerLM``
+    on the CPU or over several chips, on the dense path) nothing but the
+    input is saved."""
     return nn.remat(block_cls, policy=_KEEP_ACROSS_REMAT)
 
 
@@ -221,6 +234,14 @@ def _placement(x):
     uncommitted or single-device array, shapes alone."""
     mesh = jax.typeof(x).sharding.mesh
     return None if mesh.empty else (mesh.abstract_device.device_kind, mesh.size)
+
+
+def _on_one_tpu_chip(x) -> bool:
+    """Whether ``x`` lies on one TPU device, as :func:`_placement` sees
+    it: where :class:`Block` and ``SambaYBlock`` measured the room to
+    keep more across remat than their names elsewhere."""
+    placed = _placement(x)
+    return bool(placed) and placed[0].startswith("TPU") and placed[1] == 1
 
 
 def _default_causal(attn):

@@ -7,10 +7,12 @@ shared k, v's gradients summed over two readers; the model on the
 kernels (interpreted) against the plain path; the configuration's file
 against the built model and the catalog row. Nothing is timed."""
 
+import collections
 import json
 import math
 import os
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -44,17 +46,19 @@ def _rel(got, want):
     return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
 
 
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (value,):
+                if hasattr(getattr(inner, "jaxpr", inner), "eqns"):
+                    yield from _equations(inner)
+
+
 def _kernels(jaxpr) -> int:
     """``pallas_call`` equations of a jaxpr, the nested ones with them."""
-    count = 0
-    for eqn in jaxpr.eqns:
-        count += eqn.primitive.name == "pallas_call"
-        for value in eqn.params.values():
-            for inner in (value if isinstance(value, (tuple, list)) else (value,)):
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    count += _kernels(inner)
-    return count
+    return sum(eqn.primitive.name == "pallas_call" for eqn in _equations(jaxpr))
 
 
 def _tiny(**changes):
@@ -252,6 +256,119 @@ def test_model_on_the_kernels_is_the_model_on_the_plain_path(monkeypatch, remat)
         got = on_kernels(params, tokens)
     for have, need in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
         assert _rel(have, need) < 5e-5
+
+
+V5E = "TPU v5 lite"
+SIX_KINDS = ("mamba", "window", "mamba_memory", "full_kv", "gmu", "cross")
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The operands' mesh as ``transformer._placement`` sees it, its
+    device kind replaced by a v5e's (the scan asks its own rule, left
+    as it is: the ``lax`` form, as the attention's dense path at heads
+    16 wide)."""
+    real = transformer._placement
+    monkeypatch.setattr(transformer, "_placement", lambda x: real(x) and (V5E, real(x)[1]))
+
+
+def _recomputed(jaxpr) -> collections.Counter:
+    """Equations inside the gradient's recomputed blocks, by primitive."""
+    inside = collections.Counter()
+    for eqn in _equations(jaxpr):
+        if eqn.primitive.name == "remat2":
+            inside.update(e.primitive.name for e in _equations(eqn.params["jaxpr"]))
+    return inside
+
+
+def _without_mlp_hidden():
+    """``remat_block``'s policy less ``SAVED_MLP_HIDDEN``: what the
+    parent's recomputed ``SambaYBlock`` kept."""
+    return jax.checkpoint_policies.save_only_these_names(
+        transformer.SAVED_OUT, transformer.SAVED_LSE, transformer.SAVED_MAPS, transformer.SAVED_Y,
+        transformer.SAVED_RESIDUAL, transformer.SAVED_ROUTING, transformer.SAVED_QKV,
+        transformer.SAVED_SCAN_OUT, transformer.SAVED_SCAN_STATES)
+
+
+@pytest.mark.parametrize(
+    "device_kind, devices",
+    [(V5E, 1), (V5E, 4), ("cpu", 1)],
+    ids=["one-v5e-chip", "four-v5e-chips", "one-cpu-device"],
+)
+def test_one_chip_block_keeps_gate(request, monkeypatch, device_kind, devices):
+    """On one TPU chip ``SambaYBlock`` names gate's output before
+    ``silu`` (up's is made again: both do not fit), and the policy
+    keeps it: each layer's recomputed block holds one product fewer
+    than under the policy without the name (the parent's). Over several
+    chips and off the TPU nothing is named there, and the recomputed
+    blocks are the parent's. Against ``nn.remat`` with no policy the
+    kept stream after the mixer spares the same products everywhere."""
+    if device_kind == V5E:
+        request.getfixturevalue("as_tpu")
+    (group,) = setup_groups(1, devices=jax.devices()[:devices])
+    model = SambaYLM(vocab_size=64, layer_kinds=SIX_KINDS, mlp_width=128, remat=True)
+    state = create_lm_state(group, model, optax.sgd(1.0), jax.random.key(0))
+    tokens = group.device_put(np.zeros((4, 32), np.int32), group.batch_sharding)
+
+    def gradient():  # traced anew each time: make_jaxpr remembers a function's trace
+        summed = lambda p, t: model.apply({"params": p}, t)[0].sum()
+        return jax.make_jaxpr(jax.grad(summed))(state.params, tokens)
+
+    kept = gradient()
+    named = collections.Counter(
+        e.params["name"] for e in _equations(kept) if e.primitive.name == "name")
+    monkeypatch.setattr(transformer, "_KEEP_ACROSS_REMAT", _without_mlp_hidden())
+    parent = _recomputed(gradient())
+    monkeypatch.setattr(transformer, "remat_block", nn.remat)
+    bare = _recomputed(gradient())
+    kept = _recomputed(kept)
+    layers = len(SIX_KINDS)
+    assert bare["dot_general"] - parent["dot_general"] == layers + 2  # the mixers' outputs
+    if (device_kind, devices) == (V5E, 1):
+        assert named[transformer.SAVED_MLP_HIDDEN] == layers
+        assert parent["dot_general"] - kept["dot_general"] == layers  # gate
+        assert kept["logistic"] == parent["logistic"]  # silu made again from the kept gate
+    else:
+        assert transformer.SAVED_MLP_HIDDEN not in named
+        assert kept == parent
+
+
+def _loss_and_grads(model, tokens, group):
+    """Loss and gradients of a seeded ``model`` on ``tokens``, placed by
+    ``group`` where given, rounded where the program says so."""
+    params = model.init({"params": jax.random.key(0)}, tokens)["params"]
+    if group is not None:
+        params, tokens = group.device_put(params), group.device_put(tokens, group.batch_sharding)
+    loss = lambda p: lm_loss_mean(model.apply({"params": p}, tokens)[0], tokens)
+    # XLA may keep bf16 values at f32 between the operations it fuses,
+    # and fuses a recomputed block otherwise than the forward's
+    step = jax.jit(jax.value_and_grad(loss)).lower(params)
+    return step.compile(compiler_options={"xla_allow_excess_precision": False})(params)
+
+
+@pytest.mark.parametrize("placed", [False, True], ids=["unplaced", "one-v5e-chip"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_kept_gate_leaves_the_gradients_bit_equal(request, monkeypatch, dtype, placed):
+    """What the policy keeps of the MLP is what the recomputed block
+    would have made again: loss and every gradient leaf equal to the
+    last bit under ``remat_block``, under ``nn.remat`` with no policy
+    and with no remat at all, placed on one TPU chip (gate's output
+    named) and not."""
+    group = None
+    if placed:
+        request.getfixturevalue("as_tpu")
+        (group,) = setup_groups(1, devices=jax.devices()[:1])
+    tokens = jax.random.randint(jax.random.key(5), (2, 32), 0, 64)
+    make = lambda remat: SambaYLM(
+        vocab_size=64, layer_kinds=SIX_KINDS, mlp_width=128, dtype=dtype, remat=remat)
+    plain = _loss_and_grads(make(False), tokens, group)
+    saved = _loss_and_grads(make(True), tokens, group)
+    monkeypatch.setattr(transformer, "remat_block", nn.remat)  # only a block's input is saved
+    bare = _loss_and_grads(make(True), tokens, group)
+    assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(saved[1]))
+    for other in (bare, plain):
+        for a, b in zip(jax.tree.leaves(saved), jax.tree.leaves(other), strict=True):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # The catalog row's ``config`` (``architectures.jsonl`` beside the
