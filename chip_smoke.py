@@ -492,7 +492,7 @@ def phase_kernels(devices) -> None:
         _kernel_ring_flash(devices)
         say("  ring-flash on 4 chips matches dense")
     # With nothing injected a one-chip model takes the kernel by itself
-    # (models/transformer.py::_default_causal), so the dense side names
+    # (ops/attention.py::causal), so the dense side names
     # the dense function.
     dense = _lm_steps(
         devices, lambda q, k, v: dense_attention_reference(q, k, v, causal=True)

@@ -40,13 +40,11 @@ then the final RMSNorm and the head, the embedding's transpose under
 **Which attention runs where.** The block norms and rotates q and k
 itself (the norm sits between the projection and the rotation, so no
 kernel's ``q_rotation`` can carry the latter) and hands the core plain
-operands. Given no ``attention``, on one TPU chip, at a T that 128
-divides and heads 128 wide or 64 wide over an even number of KV heads
-(``ops.pallas_attention.grouped_takes_kernel``), the core is
-``ops.pallas_attention.grouped_attention``; everywhere else (the CPU,
-several chips, toy widths) ``blocked_window_attention``, XLA's masked
-softmax in query blocks. Decided while tracing, from the operands
-alone. An injected ``attention`` has ``grouped_attention``'s signature.
+operands: ``ops.pallas_attention.grouped_attention`` where
+``ops/attention.py::grouped_kernel`` says so (one TPU chip, heads 128
+wide or 64 wide over an even number of KV heads), else
+``blocked_window_attention``, XLA's masked softmax in query blocks. An
+injected ``attention`` has ``grouped_attention``'s signature.
 
 **One chip's share**, the embedding's deviation and
 ``absent_share_grad`` are ``GroupedWindowMoELM``'s, which says why a
@@ -75,24 +73,15 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from multidisttorch_tpu.models import transformer
-from multidisttorch_tpu.models.grouped_window_moe import rope_halves
-from multidisttorch_tpu.models.latent_moe import _default_grouped_dot, _rope_angles
-from multidisttorch_tpu.models.ssm_hybrid import causal_conv
-from multidisttorch_tpu.ops.moe import RoutedExperts
-from multidisttorch_tpu.ops.pallas_attention import (
-    blocked_window_attention,
-    grouped_attention,
-    grouped_takes_kernel,
-)
+from multidisttorch_tpu.models import decoder
+from multidisttorch_tpu.ops import attention as default_attention
+from multidisttorch_tpu.ops.pallas_attention import blocked_window_attention
 from multidisttorch_tpu.utils.profiling import (
     SCOPE_ATTN_CORE,
     SCOPE_ATTN_FULL,
     SCOPE_CONV_MIX,
     SCOPE_CONV_PROJ,
-    SCOPE_HEAD,
     SCOPE_K,
-    SCOPE_MLP,
     SCOPE_Q,
     SCOPE_QK_NORM,
     SCOPE_V,
@@ -107,14 +96,14 @@ def gated_short_conv(bcu, taps):
     depthwise convolution (the last tap on the position itself): the
     two gates are the operator's only nonlinearity."""
     b_gate, c_gate, u = jnp.split(bcu, 3, axis=-1)
-    return c_gate * causal_conv(b_gate * u, taps)
+    return c_gate * decoder.causal_conv(b_gate * u, taps)
 
 
 class ShortConvMoEBlock(nn.Module):
     """One pre-norm block: the operator ``kind`` names, then a dense
     MLP (``num_experts`` 0) or the expert layer, ``hidden_dim`` wide
     either way. Returns ``(x, counts)``, ``counts`` ``(count,)`` int32
-    and empty for a dense block. Under ``transformer.remat_block`` it
+    and empty for a dense block. Under ``decoder.remat_block`` it
     keeps the router's results, and an attention layer also the stream
     after attention, q, k and v as the projections leave them and the
     core's output and logsumexp: the recomputed block holds the norms
@@ -147,38 +136,28 @@ class ShortConvMoEBlock(nn.Module):
     def __call__(self, x):
         if self.kind not in LAYER_TYPES:
             raise ValueError(f"ShortConvMoEBlock: kind {self.kind!r} is none of {LAYER_TYPES}")
-        y = self._norm("ln_attn")(x)
+        y = decoder.rms_norm(self, "ln_attn")(x)
         if self.kind == "conv":
             x = x + self._conv(y)
         else:
-            x = checkpoint_name(x + self._attention(y), transformer.SAVED_RESIDUAL)
-        out, counts = self._ffn(self._norm("ln_mlp")(x))
+            x = checkpoint_name(x + self._attention(y), decoder.SAVED_RESIDUAL)
+        out, counts = decoder.feed_forward(
+            self, decoder.rms_norm(self, "ln_mlp")(x), absent_share_grad=self.absent_share_grad
+        )
         return x + out, counts
-
-    @nn.nowrap
-    def _norm(self, name, dtype=None):
-        return nn.RMSNorm(
-            epsilon=self.eps, dtype=dtype or self.dtype, param_dtype=jnp.float32, name=name
-        )
-
-    @nn.nowrap
-    def _dense(self, feats, name):
-        return nn.Dense(
-            feats, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32, name=name
-        )
 
     @nn.nowrap
     def _conv(self, y):
         d = y.shape[-1]
         with jax.named_scope(SCOPE_CONV_PROJ):
-            bcu = self._dense(3 * d, "in_proj")(y)
+            bcu = decoder.dense(self, 3 * d, "in_proj")(y)
         with jax.named_scope(SCOPE_CONV_MIX):
             taps = self.param(
                 "conv_w", nn.initializers.lecun_normal(), (self.conv_taps, d), jnp.float32
             )
             mixed = gated_short_conv(bcu, taps)
         with jax.named_scope(SCOPE_CONV_PROJ):
-            return self._dense(d, "out_proj")(mixed)
+            return decoder.dense(self, d, "out_proj")(mixed)
 
     @nn.nowrap
     def _attention(self, y):
@@ -191,55 +170,28 @@ class ShortConvMoEBlock(nn.Module):
         # recomputed block multiply for them again; it norms and rotates again.
         def projected(n, name, scope):
             with jax.named_scope(scope):
-                return checkpoint_name(self._dense(n * hd, name)(y), transformer.SAVED_QKV)
+                return checkpoint_name(decoder.dense(self, n * hd, name)(y), decoder.SAVED_QKV)
 
         q, k = projected(h, "q", SCOPE_Q), projected(hkv, "k", SCOPE_K)
         v = projected(hkv, "v", SCOPE_V)
         heads = lambda a: a.reshape(b, t, -1, hd)
         with jax.named_scope(SCOPE_QK_NORM):
-            angle = _rope_angles(jnp.arange(t), self.rope_theta, hd)
+            angle = decoder.rope_angles(jnp.arange(t), self.rope_theta, hd)
             cos, sin = jnp.cos(angle), jnp.sin(angle)
 
             def normed_rotated(a, name):  # float32 from the norm's statistics to the rotation's end
-                a = rope_halves(self._norm(name, jnp.float32)(heads(a)), cos, sin)
+                a = decoder.rms_norm(self, name, jnp.float32)(heads(a))
+                a = decoder.rope_halves(a, cos, sin)
                 return a.astype(self.dtype).reshape(b, t, -1)
 
             q, k = normed_rotated(q, "q_norm"), normed_rotated(k, "k_norm")
-        placed = transformer._placement(y)
-        attend = self.attention
-        if attend is None and placed and grouped_takes_kernel(*placed, t, h, hkv, hd):
-            attend = grouped_attention
+        attend = self.attention or default_attention.grouped_kernel(y, h, hkv, hd)
         with jax.named_scope(SCOPE_ATTN_CORE), jax.named_scope(SCOPE_ATTN_FULL):
             if attend is None:
                 attn = blocked_window_attention(heads(q), heads(k), heads(v), window=None)
             else:
                 attn = attend(heads(q), heads(k), heads(v), window=None, q_rotation=None)
-        return self._dense(d, "proj")(attn.reshape(b, t, h * hd))
-
-    @nn.nowrap
-    def _ffn(self, z):
-        b, t, d = z.shape
-        if not self.num_experts:
-            with jax.named_scope(SCOPE_MLP):
-                out = self._dense(d, "down")(
-                    nn.silu(self._dense(self.hidden_dim, "gate")(z))
-                    * self._dense(self.hidden_dim, "up")(z)
-                )
-            return out, jnp.zeros((0,), jnp.int32)
-        out, counts = RoutedExperts(
-            num_experts=self.num_experts,
-            experts_held=self.experts_held,
-            top_k=self.top_k,
-            hidden_dim=self.hidden_dim,
-            routed_scaling=self.routed_scaling,
-            dtype=self.dtype,
-            grouped_dot=_default_grouped_dot(z),
-            scoring="sigmoid",
-            activation="silu",
-            absent_share_grad=self.absent_share_grad,
-            name="moe",
-        )(z.reshape(b * t, d))
-        return out.reshape(b, t, d), counts
+        return decoder.dense(self, d, "proj")(attn.reshape(b, t, h * hd))
 
 
 class ShortConvMoELM(nn.Module):
@@ -278,27 +230,17 @@ class ShortConvMoELM(nn.Module):
     embed_stddev: Optional[float] = None  # None: nn.Embed's own 1 / sqrt(d_model)
     attention: Optional[Callable] = None
     dtype: Any = jnp.float32
-    remat: bool = False  # per-block checkpointing (transformer.remat_block)
+    remat: bool = False  # per-block checkpointing (decoder.remat_block)
 
     @nn.compact
     def __call__(self, tokens, head=True):
-        _, t = tokens.shape
-        if t > self.max_len:
-            raise ValueError(f"sequence length {t} exceeds max_len={self.max_len}")
         if not 0 <= self.num_dense_layers < len(self.layer_types):
             raise ValueError(
                 f"num_dense_layers={self.num_dense_layers} leaves no expert layer of "
                 f"{len(self.layer_types)}"
             )
-        drawn = {} if self.embed_stddev is None else {
-            "embedding_init": nn.initializers.normal(self.embed_stddev)
-        }
-        embed = nn.Embed(
-            self.vocab_size, self.d_model, dtype=self.dtype, param_dtype=jnp.float32,
-            name="tok_embed", **drawn,
-        )
-        x = embed(tokens)
-        block_cls = transformer.remat_block(ShortConvMoEBlock) if self.remat else ShortConvMoEBlock
+        x, table = decoder.embed_tokens(self, tokens, stddev=self.embed_stddev)
+        block_cls = decoder.block_class(self, ShortConvMoEBlock)
         shared = dict(
             num_heads=self.num_heads, num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
             rope_theta=self.rope_theta, conv_taps=self.conv_taps, attention=self.attention,
@@ -314,22 +256,10 @@ class ShortConvMoELM(nn.Module):
             ffn = dict(hidden_dim=self.dense_hidden_dim) if i < self.num_dense_layers else routed
             x, c = block_cls(kind=kind, **shared, **ffn, name=f"block_{i}")(x)
             counts.append(c)
-        x = nn.RMSNorm(
-            epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name="ln_out"
-        )(x)
-        if not head:
-            logits = x  # the normed state: transformer.head_weights
-        elif self.tie_embeddings:
-            with jax.named_scope(SCOPE_HEAD):  # float32, as the other models' heads
-                logits = jnp.einsum(
-                    "btd,vd->btv", x.astype(jnp.float32), embed.embedding.astype(jnp.float32)
-                )
-        else:
-            logits = nn.Dense(
-                self.vocab_size, use_bias=False, dtype=jnp.float32, param_dtype=jnp.float32,
-                name="head",
-            )(x)
+        logits = decoder.norm_and_head(
+            self, x, head, eps=self.eps, table=table if self.tie_embeddings else None
+        )
         return logits, {"expert_counts": jnp.stack(counts[self.num_dense_layers:])}
 
     def head_weights(self, params):
-        return transformer.head_weights(params, tied=self.tie_embeddings)
+        return decoder.head_weights(params, tied=self.tie_embeddings)

@@ -27,19 +27,15 @@ Per layer, with ``H`` query heads over ``Hkv`` KV heads of width
 
 then the final RMSNorm and an untied head; no biases.
 
-**Which attention runs where.** Given no ``attention``, on one TPU chip,
-at a T that 128 divides and heads 128 wide, or 64 wide in a layer
-without positions (``ops.pallas_attention.grouped_takes_kernel``; the
-kernels at 64 rotate nothing, so a rotary layer of such heads keeps the
-plain path), the core is
-``ops.pallas_attention.grouped_attention``: kernels that read q, k and v
-flat as the projections leave them, fetch a group's K/V block once for
-its query heads, visit only the blocks the mask keeps and rotate q as
-they load it; k is rotated here (``Hkv`` heads). Everywhere else (the
-CPU, several chips, toy widths) q is rotated here too and the core is
-``blocked_window_attention``, XLA's masked softmax in query blocks.
-Decided while tracing, from the operands alone. An injected
-``attention`` has ``grouped_attention``'s signature.
+**Which attention runs where.** Where ``ops/attention.py::grouped_kernel``
+says so (one TPU chip, heads 128 wide, or 64 wide in a layer without
+positions: the kernels at 64 rotate nothing) the core is
+``ops.pallas_attention.grouped_attention``, whose kernels read q, k and
+v flat as the projections leave them and rotate q as they load it; k is
+rotated here (``Hkv`` heads). Everywhere else (the CPU, several chips,
+toy widths) q is rotated here too and the core is
+``blocked_window_attention``, XLA's masked softmax in query blocks. An
+injected ``attention`` has ``grouped_attention``'s signature.
 
 **One chip's share.** ``experts_held = (first, count)`` as in
 ``LatentMoELM``; a sliced vocabulary is a smaller ``vocab_size``. A
@@ -71,6 +67,7 @@ schedules them.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -78,14 +75,10 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from multidisttorch_tpu.models import transformer
-from multidisttorch_tpu.models.latent_moe import _default_grouped_dot, _rope_angles
+from multidisttorch_tpu.models import decoder
+from multidisttorch_tpu.ops import attention as default_attention
 from multidisttorch_tpu.ops.moe import RoutedExperts
-from multidisttorch_tpu.ops.pallas_attention import (
-    blocked_window_attention,
-    grouped_attention,
-    grouped_takes_kernel,
-)
+from multidisttorch_tpu.ops.pallas_attention import blocked_window_attention
 from multidisttorch_tpu.utils.profiling import (
     SCOPE_ATTN_CORE,
     SCOPE_ATTN_FULL,
@@ -96,22 +89,11 @@ from multidisttorch_tpu.utils.profiling import (
 )
 
 
-def rope_halves(x, cos, sin):
-    """``x`` ``(B, T, H, width)`` rotated in the halves convention:
-    element ``i`` with ``i + width/2`` by the angle ``i`` of every
-    position, ``cos``, ``sin`` ``(T, width/2)``; the arithmetic
-    float32."""
-    half = x.shape[-1] // 2
-    x32, c, s = x.astype(jnp.float32), cos[:, None, :], sin[:, None, :]
-    lo, hi = x32[..., :half], x32[..., half:]
-    return jnp.concatenate([lo * c - hi * s, hi * c + lo * s], axis=-1).astype(x.dtype)
-
-
 class GroupedWindowMoEBlock(nn.Module):
     """One pre-norm block: grouped-head attention (``window`` ``None``:
     the whole past; ``rotary`` ``False``: no positions), then the
     expert layer, routed from the block's normed input. Returns ``(x,
-    counts)``. Under ``transformer.remat_block`` it keeps, beside the
+    counts)``. Under ``decoder.remat_block`` it keeps, beside the
     core's output and logsumexp and the router's results, the stream
     after attention and q, k and v as the core reads them: the
     recomputed block holds the two norms, the experts' first halves and
@@ -137,12 +119,7 @@ class GroupedWindowMoEBlock(nn.Module):
     def __call__(self, x):
         b, t, d = x.shape
         h, hkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        dense = lambda feats, name: nn.Dense(
-            feats, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32, name=name
-        )
-        norm = lambda name: nn.RMSNorm(
-            epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name=name
-        )
+        dense, norm = partial(decoder.dense, self), partial(decoder.rms_norm, self)
         y = norm("ln_attn")(x)
         # q, k and v stay flat, as the projections write them and the kernels
         # read them, and are heads only where something reads heads: the TPU
@@ -153,25 +130,24 @@ class GroupedWindowMoEBlock(nn.Module):
         heads = lambda a: a.reshape(b, t, -1, hd)
         rotation = None
         if self.rotary:
-            angle = _rope_angles(jnp.arange(t), self.rope_theta, hd)
+            angle = decoder.rope_angles(jnp.arange(t), self.rope_theta, hd)
             rotation = jnp.cos(angle), jnp.sin(angle)
             with jax.named_scope(SCOPE_K):
-                k = rope_halves(heads(k), *rotation).reshape(k.shape)
-        placed = transformer._placement(x)
-        attend = self.attention
-        if attend is None and placed and grouped_takes_kernel(
-            *placed, t, h, hkv, hd, rotates_q=self.rotary
-        ):
-            attend = grouped_attention
+                k = decoder.rope_halves(heads(k), *rotation).reshape(k.shape)
+        attend = self.attention or default_attention.grouped_kernel(
+            x, h, hkv, hd, rotates_q=self.rotary
+        )
         if attend is None and self.rotary:  # the plain path takes q as it is multiplied
             with jax.named_scope(SCOPE_Q):
-                q = rope_halves(heads(q), *rotation).reshape(q.shape)
+                q = decoder.rope_halves(heads(q), *rotation).reshape(q.shape)
         # What the attention reads, kept across remat by name: the call and
         # so the kernels' backward take the named copies, and the recomputed
-        # block makes none of the three products and no rotation again.
+        # block makes none of the three products and no rotation again (9.2
+        # KB a token and layer in smallthinker-21b-a3b: 28 + 4 + 4 heads of
+        # 128 in bf16; PERF.md section 6, PR 36).
         def kept(a, scope):  # jax rounds a kept float where it is named: the projection's work
             with jax.named_scope(scope):
-                return checkpoint_name(a, transformer.SAVED_QKV)
+                return checkpoint_name(a, decoder.SAVED_QKV)
 
         q, k, v = heads(kept(q, SCOPE_Q)), heads(kept(k, SCOPE_K)), heads(kept(v, SCOPE_V))
         kind = SCOPE_ATTN_FULL if self.window is None else SCOPE_ATTN_WINDOW
@@ -180,8 +156,10 @@ class GroupedWindowMoEBlock(nn.Module):
                 attn = blocked_window_attention(q, k, v, window=self.window)
             else:
                 attn = attend(q, k, v, window=self.window, q_rotation=rotation)
+        # kept across remat, as LatentMoEBlock keeps it: proj (3,584 wide in
+        # smallthinker-21b-a3b) is not multiplied again
         x = checkpoint_name(
-            x + dense(d, "proj")(attn.reshape(b, t, h * hd)), transformer.SAVED_RESIDUAL
+            x + dense(d, "proj")(attn.reshape(b, t, h * hd)), decoder.SAVED_RESIDUAL
         )
 
         z = norm("ln_mlp")(x)
@@ -191,7 +169,6 @@ class GroupedWindowMoEBlock(nn.Module):
             top_k=self.top_k,
             hidden_dim=self.hidden_dim,
             dtype=self.dtype,
-            grouped_dot=_default_grouped_dot(z),
             scoring="softmax",
             activation="relu",
             absent_share_grad=self.absent_share_grad,
@@ -231,28 +208,17 @@ class GroupedWindowMoELM(nn.Module):
     absent_share_grad: bool = True  # as RoutedExperts'; False for a chip's share trained alone
     attention: Optional[Callable] = None
     dtype: Any = jnp.float32
-    remat: bool = False  # per-block checkpointing (transformer.remat_block)
+    remat: bool = False  # per-block checkpointing (decoder.remat_block)
 
     @nn.compact
     def __call__(self, tokens, head=True):
-        _, t = tokens.shape
-        if t > self.max_len:
-            raise ValueError(f"sequence length {t} exceeds max_len={self.max_len}")
         if not len(self.window_layout) == len(self.rope_layout) == self.num_layers:
             raise ValueError(
                 f"window_layout and rope_layout name {len(self.window_layout)} and "
                 f"{len(self.rope_layout)} layers of {self.num_layers}"
             )
-        drawn = {} if self.embed_stddev is None else {
-            "embedding_init": nn.initializers.normal(self.embed_stddev)
-        }
-        x = nn.Embed(
-            self.vocab_size, self.d_model, dtype=self.dtype, param_dtype=jnp.float32,
-            name="tok_embed", **drawn,
-        )(tokens)
-        block_cls = (
-            transformer.remat_block(GroupedWindowMoEBlock) if self.remat else GroupedWindowMoEBlock
-        )
+        x, _ = decoder.embed_tokens(self, tokens, stddev=self.embed_stddev)
+        block_cls = decoder.block_class(self, GroupedWindowMoEBlock)
         counts = []
         for i, (windowed, rotary) in enumerate(zip(self.window_layout, self.rope_layout)):
             x, c = block_cls(
@@ -266,14 +232,8 @@ class GroupedWindowMoELM(nn.Module):
                 name=f"block_{i}",
             )(x)
             counts.append(c)
-        x = nn.RMSNorm(
-            epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name="ln_out"
-        )(x)
-        logits = nn.Dense(
-            self.vocab_size, use_bias=False, dtype=jnp.float32,
-            param_dtype=jnp.float32, name="head",
-        )(x) if head else x  # the normed state: transformer.head_weights
+        logits = decoder.norm_and_head(self, x, head, eps=self.eps)
         return logits, {"expert_counts": jnp.stack(counts)}
 
     def head_weights(self, params):
-        return transformer.head_weights(params)
+        return decoder.head_weights(params)

@@ -67,10 +67,11 @@ takes the five operands (it rotates q's rotary part itself). Everywhere
 else (an injected ``attention=``, the CPU, several chips, a T or widths
 the rule refuses, the toy widths of tests and examples) q and k are
 assembled as above and go to the ``(q, k, v)`` callable: the injected
-one, or ``transformer._default_causal``'s (the blockwise kernel at the
+one, or ``ops/attention.py::causal`` (the blockwise kernel at the
 padded width 256 where ``default_takes_kernel`` says so, else dense).
-Decided while tracing, from the operands alone; the parameters are the
-same tree, names and initial values on both paths.
+``ops/attention.py::latent_on_parts`` decides while tracing, from the
+operands alone; the parameters are the same tree, names and initial
+values on both paths.
 
 **One chip's share.** ``experts_held = (first, count)`` names the
 experts of every expert layer whose weights live here; the router keeps
@@ -100,6 +101,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -108,20 +110,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from multidisttorch_tpu.models import transformer
+from multidisttorch_tpu.models import decoder
+from multidisttorch_tpu.ops import attention as default_attention
 from multidisttorch_tpu.ops import hyper_connection
-from multidisttorch_tpu.ops.pallas_attention import latent_attention, latent_takes_kernel
-from multidisttorch_tpu.ops.moe import (
-    GroupedDot,
-    RoutedExperts,
-    grouped_dot_takes_kernel,
-    kernel_grouped_dot,
-    ragged_grouped_dot,
-)
+from multidisttorch_tpu.ops.pallas_attention import latent_attention
 from multidisttorch_tpu.utils.profiling import (
     SCOPE_ATTN_CORE,
     SCOPE_K,
-    SCOPE_MLP,
     SCOPE_Q,
     SCOPE_V,
 )
@@ -174,17 +169,6 @@ class YarnScaling:
         return self._mscale(self.mscale) / self._mscale(self.mscale_all_dim)
 
 
-def _rope_angles(positions, theta: float, width: int, scaling: Optional[YarnScaling] = None):
-    """``positions * theta**(-2i/width)`` for the pairs ``i`` of a
-    ``width``-wide rotary part, or ``positions`` times ``scaling``'s
-    blended frequencies: ``(T, width/2)`` float32."""
-    if scaling is None:
-        inv_freq = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
-    else:
-        inv_freq = jnp.asarray(scaling.inv_freq(theta, width), jnp.float32)
-    return positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-
-
 def _rotation_scaled(cos, sin, scaling: Optional[YarnScaling]):
     """``cos`` and ``sin`` times ``scaling``'s ``rotation_scale``, where
     that is not 1."""
@@ -200,7 +184,7 @@ def rope_interleaved(x, positions, theta: float, scaling: Optional[YarnScaling] 
     the arithmetic float32. Written with lane rolls rather than a
     ``(width/2, 2)`` reshape, which the TPU would have to relayout."""
     width = x.shape[-1]
-    angle = _rope_angles(positions, theta, width, scaling)
+    angle = decoder.rope_angles(positions, theta, width, scaling)
     cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)[:, None, :]
     sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)[:, None, :]
     cos, sin = _rotation_scaled(cos, sin, scaling)
@@ -232,30 +216,6 @@ class _DenseByParts(nn.Module):
         x, kernel = nn.dtypes.promote_dtype(x, kernel, dtype=self.dtype)
         columns = kernel.reshape(-1, self.heads, group)[..., at:at + width]
         return x @ columns.reshape(-1, self.heads * width)
-
-
-def _default_grouped_dot(x):
-    """The two grouped products of the expert layer whose input is
-    ``x`` (the experts' and the sums of the rows by token): each the
-    Pallas kernel where ``ops.moe.grouped_dot_takes_kernel`` says it
-    applies (a TPU, operands on one device, shapes it tiles) and XLA's
-    ragged dot everywhere else. Decided while tracing, from the
-    operands alone, as ``transformer._default_causal`` decides the
-    attention; the placement is read off the layer's input, because a
-    kernel's result no longer shows the mesh it was computed on."""
-    placed = transformer._placement(x)
-
-    def chosen(rows: int, k: int, n: int) -> GroupedDot:
-        kernel = placed and grouped_dot_takes_kernel(*placed, rows, k, n)
-        return kernel_grouped_dot if kernel else ragged_grouped_dot
-
-    def experts(lhs, rhs, sizes):
-        return chosen(*lhs.shape, rhs.shape[-1]).experts(lhs, rhs, sizes)
-
-    def token_sums(rows, weight, key, n):
-        return chosen(rows.shape[0], n, rows.shape[1]).token_sums(rows, weight, key, n)
-
-    return GroupedDot(experts, token_sums)
 
 
 class LatentMoEBlock(nn.Module):
@@ -298,7 +258,11 @@ class LatentMoEBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         if self.hc_mult == 1:
-            x = checkpoint_name(x + self._attention(x), transformer.SAVED_RESIDUAL)
+            # kept across remat: proj's backward reads its input and weights,
+            # never its output, so the recomputed block does not multiply by
+            # proj again (4,096 wide in joyai-llm-flash; PERF.md section 6,
+            # PR 34). With streams SAVED_Y does that around the connection.
+            x = checkpoint_name(x + self._attention(x), decoder.SAVED_RESIDUAL)
             y, counts = self._ffn(x)
             return x + y, counts
         connection = lambda name: hyper_connection.HyperConnection(
@@ -315,24 +279,16 @@ class LatentMoEBlock(nn.Module):
         return streams, counts, jnp.maximum(around_attn.marginal_err, around_ffn.marginal_err)
 
     @nn.nowrap
-    def _norm(self, name):
-        return nn.RMSNorm(
-            epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name=name
-        )
-
-    @nn.nowrap
     def _attention(self, x):
         """Latent attention of ``ln_attn(x)``, through ``proj``."""
-        dense, norm = self._dense, self._norm
+        dense, norm = partial(decoder.dense, self), partial(decoder.rms_norm, self)
         b, t, d = x.shape
         h, nope, rope, dv = self.num_heads, self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim
         positions = jnp.arange(t)
 
         y = norm("ln_attn")(x)
-        placed = transformer._placement(x)
-        by_parts = bool(
-            self.attention is None and placed
-            and latent_takes_kernel(*placed, t, h, nope, rope, dv)
+        by_parts = self.attention is None and default_attention.latent_on_parts(
+            x, h, nope, rope, dv
         )
         with jax.named_scope(SCOPE_Q):
             c_q = norm("q_norm")(dense(self.q_lora_rank, "q_a")(y))
@@ -351,33 +307,9 @@ class LatentMoEBlock(nn.Module):
     def _ffn(self, x):
         """``(y, counts)``: the dense MLP or the expert layer of
         ``ln_mlp(x)``."""
-        dense = self._dense
-        b, t, d = x.shape
-        y = self._norm("ln_mlp")(x)
-        if not self.num_experts:
-            with jax.named_scope(SCOPE_MLP):
-                y = dense(d, "down")(
-                    nn.silu(dense(self.hidden_dim, "gate")(y)) * dense(self.hidden_dim, "up")(y)
-                )
-            return y, jnp.zeros((0,), jnp.int32)
-        y, counts = RoutedExperts(
-            num_experts=self.num_experts,
-            experts_held=self.experts_held,
-            top_k=self.top_k,
-            hidden_dim=self.hidden_dim,
-            shared_hidden_dim=self.shared_experts * self.hidden_dim,
-            routed_scaling=self.routed_scaling,
-            dtype=self.dtype,
-            grouped_dot=_default_grouped_dot(y),
-            name="moe",
-        )(y.reshape(b * t, d))
-        return y.reshape(b, t, d), counts
-
-    @nn.nowrap
-    def _dense(self, feats, name):
-        return nn.Dense(
-            feats, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32, name=name
-        )
+        y = decoder.rms_norm(self, "ln_mlp")(x)
+        shared = self.shared_experts * self.hidden_dim
+        return decoder.feed_forward(self, y, shared_hidden_dim=shared)
 
     @nn.nowrap
     def _assembled(self, c_q, c_kv, k_rope, positions):
@@ -387,7 +319,7 @@ class LatentMoEBlock(nn.Module):
         b, t, _ = c_q.shape
         h, nope, rope, dv = self.num_heads, self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim
         with jax.named_scope(SCOPE_Q):
-            q = self._dense(h * (nope + rope), "q_b")(c_q).reshape(b, t, h, nope + rope)
+            q = decoder.dense(self, h * (nope + rope), "q_b")(c_q).reshape(b, t, h, nope + rope)
             q = jnp.concatenate(
                 [
                     q[..., :nope],
@@ -398,14 +330,14 @@ class LatentMoEBlock(nn.Module):
             if self.rope_scaling is not None and self.rope_scaling.score_scale != 1.0:
                 q = q * self.rope_scaling.score_scale  # the callable divides by sqrt(width)
         with jax.named_scope(SCOPE_V):
-            kv = self._dense(h * (nope + dv), "kv_b")(c_kv).reshape(b, t, h, nope + dv)
+            kv = decoder.dense(self, h * (nope + dv), "kv_b")(c_kv).reshape(b, t, h, nope + dv)
             v = kv[..., nope:]
         with jax.named_scope(SCOPE_K):
             k = jnp.concatenate(
                 [kv[..., :nope], jnp.broadcast_to(k_rope, (b, t, h, rope))], axis=-1
             )
         with jax.named_scope(SCOPE_ATTN_CORE):
-            return transformer._default_causal(self.attention)(q, k, v)
+            return (self.attention or default_attention.causal)(q, k, v)
 
     @nn.nowrap
     def _kernel_on_parts(self, c_q, c_kv, k_rope, positions):
@@ -421,7 +353,7 @@ class LatentMoEBlock(nn.Module):
         heads = lambda x: x.reshape(b, t, h, -1)  # free: the kernels read the flat array
         with jax.named_scope(SCOPE_Q):
             q_nope, q_rope = q_b(c_q, 0), q_b(c_q, 1)
-            angle = _rope_angles(positions, self.rope_theta, rope, self.rope_scaling)
+            angle = decoder.rope_angles(positions, self.rope_theta, rope, self.rope_scaling)
             # of q_rope: the kernels make it
             rotation = _rotation_scaled(jnp.cos(angle), jnp.sin(angle), self.rope_scaling)
         with jax.named_scope(SCOPE_K):
@@ -467,7 +399,7 @@ class LatentMoELM(nn.Module):
     max_len: int = 256
     attention: Optional[Callable] = None
     dtype: Any = jnp.float32
-    remat: bool = False  # per-block checkpointing (transformer.remat_block)
+    remat: bool = False  # per-block checkpointing (decoder.remat_block)
     rope_scaling: Optional[YarnScaling] = None
     # residual streams mixed by hyper-connections (ops/hyper_connection.py); 1: plain residuals
     hc_mult: int = 1
@@ -477,18 +409,12 @@ class LatentMoELM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, head=True):
-        _, t = tokens.shape
-        if t > self.max_len:
-            raise ValueError(f"sequence length {t} exceeds max_len={self.max_len}")
         if not 0 <= self.dense_layers < self.num_layers:
             raise ValueError(
                 f"dense_layers={self.dense_layers} leaves no expert layer of {self.num_layers}"
             )
-        x = nn.Embed(
-            self.vocab_size, self.d_model, dtype=self.dtype,
-            param_dtype=jnp.float32, name="tok_embed",
-        )(tokens)
-        block_cls = transformer.remat_block(LatentMoEBlock) if self.remat else LatentMoEBlock
+        x, _ = decoder.embed_tokens(self, tokens)
+        block_cls = decoder.block_class(self, LatentMoEBlock)
         shared = dict(
             num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
             kv_lora_rank=self.kv_lora_rank, qk_nope_dim=self.qk_nope_dim,
@@ -516,17 +442,11 @@ class LatentMoELM(nn.Module):
             errs += err
         if self.hc_mult != 1:
             x = hyper_connection.merge(x)
-        x = nn.RMSNorm(
-            epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name="ln_out"
-        )(x)
-        logits = nn.Dense(
-            self.vocab_size, use_bias=False, dtype=jnp.float32,
-            param_dtype=jnp.float32, name="head",
-        )(x) if head else x  # the normed state: transformer.head_weights
+        logits = decoder.norm_and_head(self, x, head, eps=self.eps)
         counters = {"expert_counts": jnp.stack(counts[self.dense_layers:])}
         if errs:
             counters["hc_marginal_err"] = jnp.max(jnp.stack(errs))
         return logits, counters
 
     def head_weights(self, params):
-        return transformer.head_weights(params)
+        return decoder.head_weights(params)

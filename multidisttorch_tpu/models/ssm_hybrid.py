@@ -38,11 +38,10 @@ convolution's, ``dt``'s and the norms'.
 **What runs where**, decided while tracing from the operands alone: on
 one TPU chip the scan is ``ops.selective_scan``'s kernel pair
 (``scan_takes_kernel``, which the scan asks itself) and the three kinds
-of attention
-``ops.pallas_attention.grouped_attention`` at head width 64
-(``grouped_takes_kernel``: two KV heads and their four query heads a
-grid step, the cross layer's call given the full layer's k and v);
-everywhere else the ``jax.lax`` scan and
+of attention ``ops.pallas_attention.grouped_attention`` at head width 64
+(``ops/attention.py::grouped_kernel``: two KV heads and their four query
+heads a grid step, the cross layer's call given the full layer's k and
+v); everywhere else the ``jax.lax`` scan and
 ``blocked_window_attention``.
 
 **Starts.** ``A_log`` starts at ``log(1..N)`` a channel and ``dt``'s
@@ -50,7 +49,7 @@ bias so that ``softplus`` gives steps log-uniform in ``[DT_MIN,
 DT_MAX]`` = [0.001, 0.1] (Mamba's own: with lecun-normal weights alone the decay is 0
 or 1 and the scan tests nothing); ``D`` at 1.
 
-Under ``remat`` (``transformer.remat_block``) a block keeps, beside its
+Under ``remat`` (``decoder.remat_block``) a block keeps, beside its
 inputs, the scan's output and chunk states or the attention's output
 and logsumexp, the stream after the mixer and, on one TPU chip, the
 MLP's ``gate`` output before ``silu`` (``SAVED_MLP_HIDDEN``: the
@@ -80,20 +79,17 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from multidisttorch_tpu.models import transformer
-from multidisttorch_tpu.ops.pallas_attention import (
-    blocked_window_attention,
-    grouped_attention,
-    grouped_takes_kernel,
-)
+from multidisttorch_tpu.models import decoder
+from multidisttorch_tpu.ops import attention as default_attention
+from multidisttorch_tpu.ops.pallas_attention import blocked_window_attention
 from multidisttorch_tpu.ops.selective_scan import selective_scan
+from multidisttorch_tpu.parallel import mesh
 from multidisttorch_tpu.utils.profiling import (
     SCOPE_ATTN_CORE,
     SCOPE_ATTN_CROSS,
     SCOPE_ATTN_FULL,
     SCOPE_ATTN_WINDOW,
     SCOPE_GMU,
-    SCOPE_HEAD,
     SCOPE_MLP,
     SCOPE_Q,
     SCOPE_SSM_CONV,
@@ -151,17 +147,6 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     )
 
 
-def causal_conv(x, w, bias=None):
-    """Depthwise causal convolution of ``x`` ``(B, T, E)`` with ``w``
-    ``(taps, E)``, the last tap on the position itself: ``taps``
-    shifted multiply-adds, no padded copy through a convolution.
-    ``bias`` ``(E,)`` where the convolution has one."""
-    t, taps = x.shape[1], w.shape[0]
-    ahead = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    out = sum(ahead[:, j:j + t] * w[j].astype(x.dtype) for j in range(taps))
-    return out if bias is None else out + bias.astype(x.dtype)
-
-
 class SambaYBlock(nn.Module):
     """One layer of ``kind``. ``__call__(x, memory, kv) -> (x, handed,
     state_rms)``: ``memory`` is read by ``gmu`` and ``kv`` (a pair) by
@@ -199,31 +184,29 @@ class SambaYBlock(nn.Module):
             handed = y if self.kind == "mamba_memory" else None
         elif self.kind == "gmu":
             with jax.named_scope(SCOPE_GMU):
-                out = self._dense(x.shape[-1], "out_proj")(
-                    memory * nn.silu(self._dense(memory.shape[-1], "in_proj")(u))
+                out = decoder.dense(self, x.shape[-1], "out_proj")(
+                    memory * nn.silu(decoder.dense(self, memory.shape[-1], "in_proj")(u))
                 )
         else:
             out, made = self._attention(u, kv)
             handed = made if self.kind == "full_kv" else None
-        x = checkpoint_name(x + out, transformer.SAVED_RESIDUAL)
+        x = checkpoint_name(x + out, decoder.SAVED_RESIDUAL)
         # gate's output is kept across remat on one TPU chip, where the
-        # room for it was measured; both products do not fit (PERF.md
-        # section 6, PR 41)
+        # room for it was measured: 20 KB a token and layer in
+        # phi-4-mini-flash (bf16, width 10,240), and the recomputed block
+        # makes up alone again (28.9 ms of ssm-yoco-t16384's 57.9 of MLP
+        # recomputation). Kept instead, up's output spared as much and
+        # slowed the forward by 8.3 ms; both plan 15.14 GiB, more than any
+        # accepted cell runs (PERF.md section 6, PR 41).
         h = norm("ln_mlp")(x)
         with jax.named_scope(SCOPE_MLP):
-            gate = self._dense(self.mlp_width, "gate")(h)
-            if transformer._on_one_tpu_chip(x):  # flat, as the Dense writes it
-                gate = checkpoint_name(gate, transformer.SAVED_MLP_HIDDEN)
-            h = self._dense(x.shape[-1], "down")(
-                nn.silu(gate) * self._dense(self.mlp_width, "up")(h)
+            gate = decoder.dense(self, self.mlp_width, "gate")(h)
+            if mesh.on_one_tpu_chip(x):  # flat, as the Dense writes it
+                gate = checkpoint_name(gate, decoder.SAVED_MLP_HIDDEN)
+            h = decoder.dense(self, x.shape[-1], "down")(
+                nn.silu(gate) * decoder.dense(self, self.mlp_width, "up")(h)
             )
         return x + h, handed, state_rms
-
-    @nn.nowrap
-    def _dense(self, feats, name):
-        return nn.Dense(
-            feats, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32, name=name
-        )
 
     @nn.nowrap
     def _mamba(self, u):
@@ -236,21 +219,24 @@ class SambaYBlock(nn.Module):
         skip = self.param("D", nn.initializers.ones, (e,), jnp.float32)
         dt_bias = self.param("dt_bias", _dt_bias_init, (e,), jnp.float32)
         with jax.named_scope(SCOPE_SSM_PROJ):
-            xz = self._dense(2 * e, "in_proj")(u)
+            xz = decoder.dense(self, 2 * e, "in_proj")(u)
             x, z = xz[..., :e], xz[..., e:]
         with jax.named_scope(SCOPE_SSM_CONV):
-            x = nn.silu(causal_conv(x, conv_w, conv_b))
+            x = nn.silu(decoder.causal_conv(x, conv_w, conv_b))
         with jax.named_scope(SCOPE_SSM_PROJ):
-            dbc = self._dense(r + 2 * n, "x_proj")(x)
-            dt = self._dense(e, "dt_proj")(dbc[..., :r])
-        with jax.named_scope(SCOPE_SSM_SCAN):  # the kernel pair or the plain form: the scan's own rule
+            dbc = decoder.dense(self, r + 2 * n, "x_proj")(x)
+            dt = decoder.dense(self, e, "dt_proj")(dbc[..., :r])
+        # the kernel pair or the plain form, by the scan's own rule; its
+        # output and chunk states are kept across remat (10.3 and 1.3 KB a
+        # token and layer in phi-4-mini-flash, E = 5,120, N = 16)
+        with jax.named_scope(SCOPE_SSM_SCAN):
             y, last = selective_scan(
                 x, dt, -jnp.exp(a_log), dbc[..., r:r + n], dbc[..., r + n:], skip, dt_bias,
                 return_last_state=True,
             )
             state_rms = jnp.sqrt(jnp.mean(jnp.square(jax.lax.stop_gradient(last))))
         with jax.named_scope(SCOPE_SSM_PROJ):
-            out = self._dense(d, "out_proj")(y * nn.silu(z))
+            out = decoder.dense(self, d, "out_proj")(y * nn.silu(z))
         return out, y, state_rms
 
     @nn.nowrap
@@ -262,24 +248,22 @@ class SambaYBlock(nn.Module):
         heads = lambda a: a.reshape(b, t, -1, hd)
         if self.kind == "cross":
             with jax.named_scope(SCOPE_Q):
-                q = heads(self._dense(h * hd, "q")(u))
+                q = heads(decoder.dense(self, h * hd, "q")(u))
             k, v = kv
         else:
             with jax.named_scope(SCOPE_Q):  # one product; k and v are its columns
-                qkv = self._dense((h + 2 * hkv) * hd, "qkv")(u)
+                qkv = decoder.dense(self, (h + 2 * hkv) * hd, "qkv")(u)
             q, k, v = (heads(a) for a in jnp.split(qkv, [h * hd, (h + hkv) * hd], axis=-1))
         window = self.window if self.kind == "window" else None
-        attend = self.attention
-        placed = transformer._placement(u)
-        if attend is None and placed and grouped_takes_kernel(*placed, t, h, hkv, hd):
-            attend = grouped_attention
-        if attend is None:
-            attend = blocked_window_attention
+        attend = (
+            self.attention or default_attention.grouped_kernel(u, h, hkv, hd)
+            or blocked_window_attention
+        )
         scope = {"window": SCOPE_ATTN_WINDOW, "full_kv": SCOPE_ATTN_FULL,
                  "cross": SCOPE_ATTN_CROSS}[self.kind]
         with jax.named_scope(SCOPE_ATTN_CORE), jax.named_scope(scope):
             attn = attend(q, k, v, window=window)
-        return self._dense(d, "proj")(attn.reshape(b, t, h * hd)), (k, v)
+        return decoder.dense(self, d, "proj")(attn.reshape(b, t, h * hd)), (k, v)
 
 
 class SambaYLM(nn.Module):
@@ -314,7 +298,7 @@ class SambaYLM(nn.Module):
     tie_embeddings: bool = True
     attention: Optional[Callable] = None
     dtype: Any = jnp.float32
-    remat: bool = False  # per-block checkpointing (transformer.remat_block)
+    remat: bool = False  # per-block checkpointing (decoder.remat_block)
 
     def kinds(self) -> tuple[str, ...]:
         if self.layer_kinds is None:
@@ -323,15 +307,8 @@ class SambaYLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, head=True):
-        _, t = tokens.shape
-        if t > self.max_len:
-            raise ValueError(f"sequence length {t} exceeds max_len={self.max_len}")
-        embed = nn.Embed(
-            self.vocab_size, self.d_model, dtype=self.dtype, param_dtype=jnp.float32,
-            name="tok_embed",
-        )
-        x = embed(tokens)
-        block_cls = transformer.remat_block(SambaYBlock) if self.remat else SambaYBlock
+        x, table = decoder.embed_tokens(self, tokens)
+        block_cls = decoder.block_class(self, SambaYBlock)
         memory = kv = None
         state_rms = []
         for i, kind in enumerate(self.kinds()):
@@ -350,22 +327,11 @@ class SambaYLM(nn.Module):
                 kv = handed
             if rms is not None:
                 state_rms.append(rms)
-        x = nn.LayerNorm(
-            epsilon=self.eps, dtype=self.dtype, param_dtype=jnp.float32, name="ln_out"
-        )(x)
-        if not head:
-            logits = x  # the normed state: transformer.head_weights
-        elif self.tie_embeddings:
-            with jax.named_scope(SCOPE_HEAD):  # float32, as the other models' heads
-                logits = jnp.einsum(
-                    "btd,vd->btv", x.astype(jnp.float32), embed.embedding.astype(jnp.float32)
-                )
-        else:
-            logits = nn.Dense(
-                self.vocab_size, use_bias=False, dtype=jnp.float32, param_dtype=jnp.float32,
-                name="head",
-            )(x)
+        logits = decoder.norm_and_head(
+            self, x, head, norm=nn.LayerNorm, eps=self.eps,
+            table=table if self.tie_embeddings else None,
+        )
         return logits, {"ssm_state_rms": jnp.stack(state_rms)}
 
     def head_weights(self, params):
-        return transformer.head_weights(params, tied=self.tie_embeddings)
+        return decoder.head_weights(params, tied=self.tie_embeddings)

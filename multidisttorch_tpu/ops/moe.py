@@ -35,8 +35,8 @@ experts held elsewhere. Who makes the grouped products and the sums by
 token is the layer's ``grouped_dot`` (a :class:`GroupedDot`): XLA
 (``jax.lax.ragged_dot``, a scatter-add) or, on one TPU chip, kernels
 (jax's Pallas grouped matmul; ``token_sums`` of this file, the sums as
-0/1 matrices times the rows on the MXU), chosen by the model from the
-operands' placement. Shapes are static: the buffer holds twice the mean
+0/1 matrices times the rows on the MXU), chosen by the layer from its
+input's placement. Shapes are static: the buffer holds twice the mean
 load, and a step whose routing sends more than that here (up to every
 token with all it can send) walks the expert order one buffer at a
 time, chosen by ``lax.cond`` on the step's own count.
@@ -46,7 +46,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -65,7 +65,7 @@ from multidisttorch_tpu.utils.profiling import (
 )
 
 # What ``RoutedExperts``' router makes and the backward pass reads, by
-# the name the models' remat rule (``models/transformer.py::remat_block``)
+# the name the models' remat rule (``models/decoder.py::remat_block``)
 # keeps: the recomputed block then runs neither the float32 product nor
 # the gathers and sorts after it, to remake arrays of a few MB.
 SAVED_ROUTING = "router_results"
@@ -417,9 +417,8 @@ def grouped_dot_takes_kernel(
     device_kind: str, num_devices: int, rows: int, k: int, n: int
 ) -> bool:
     """Whether an expert layer that was given no ``grouped_dot`` runs
-    a product as the Pallas kernel (``models/latent_moe.py`` asks, with
-    what tracing shows of the operands' placement, as
-    ``default_takes_kernel`` is asked for the attention) or as XLA's
+    a product as the Pallas kernel (:func:`default_grouped_dot` asks,
+    with what tracing shows of the layer input's placement) or as XLA's
     form: a TPU, operands on one device, whole tiles of rows and whole
     lanes of both widths. The experts' product is ``(rows, k)`` by
     ``(k, n)``; the sums by token take ``rows`` rows ``n`` wide to
@@ -431,6 +430,31 @@ def grouped_dot_takes_kernel(
         and k % 128 == 0
         and n % 128 == 0
     )
+
+
+def default_grouped_dot(x) -> GroupedDot:
+    """The two grouped products of an expert layer whose input is ``x``
+    (the experts' and the sums of the rows by token): each the kernel
+    where :func:`grouped_dot_takes_kernel` says so and XLA's form
+    everywhere else, decided while tracing. The placement is read off
+    the layer's input, because a kernel's result no longer shows the
+    mesh it was computed on."""
+    # imported here: a line added above the kernels moves them in their serialized modules
+    from multidisttorch_tpu.parallel import mesh
+
+    placed = mesh.placement(x)
+
+    def chosen(rows: int, k: int, n: int) -> GroupedDot:
+        kernel = placed and grouped_dot_takes_kernel(*placed, rows, k, n)
+        return kernel_grouped_dot if kernel else ragged_grouped_dot
+
+    def experts(lhs, rhs, sizes):
+        return chosen(*lhs.shape, rhs.shape[-1]).experts(lhs, rhs, sizes)
+
+    def token_sums(rows, weight, key, n):
+        return chosen(rows.shape[0], n, rows.shape[1]).token_sums(rows, weight, key, n)
+
+    return GroupedDot(experts, token_sums)
 
 
 def _buffer_rows(n: int, k: int, count: int, e: int) -> tuple[int, int]:
@@ -489,7 +513,7 @@ class RoutedExperts(nn.Module):
     shared_hidden_dim: int = 0
     routed_scaling: float = 1.0
     dtype: Any = jnp.float32
-    grouped_dot: GroupedDot = ragged_grouped_dot  # who makes the layer's two products
+    grouped_dot: Optional[GroupedDot] = None  # the two products' maker; None: default_grouped_dot
     scoring: str = "sigmoid"
     activation: str = "silu"
     absent_share_grad: bool = True
@@ -498,6 +522,7 @@ class RoutedExperts(nn.Module):
     def __call__(self, x: jnp.ndarray, router_input=None) -> tuple[jnp.ndarray, jnp.ndarray]:
         n, d = x.shape
         e, k, h = self.num_experts, self.top_k, self.hidden_dim
+        grouped_dot = default_grouped_dot(x) if self.grouped_dot is None else self.grouped_dot
         first, count = self.experts_held
         if not (0 <= first and first + count <= e and 0 < count and k <= e):
             raise ValueError(
@@ -570,7 +595,7 @@ class RoutedExperts(nn.Module):
         with jax.named_scope(SCOPE_EXPERTS):
             w_gate_up = jnp.concatenate([w_gate, w_up], axis=-1).astype(self.dtype)
 
-        def routed(start, rows: int, grouped_dot=self.grouped_dot):
+        def routed(start, rows: int, grouped_dot=grouped_dot):
             """The held experts' part of rows ``start .. start + rows``
             of the expert order, through a buffer of ``rows`` rows."""
             pair = jax.lax.dynamic_slice_in_dim(order, start, rows)
@@ -608,7 +633,7 @@ class RoutedExperts(nn.Module):
                 order = jnp.pad(order, (0, -worst % usual))
 
                 def walk():
-                    dots = self.grouped_dot._replace(experts=ragged_grouped_dot.experts)
+                    dots = grouped_dot._replace(experts=ragged_grouped_dot.experts)
                     nothing = lambda: jnp.zeros((n, d), jnp.float32)
                     # a buffer past the step's count holds no row and is not run
                     one = jax.checkpoint(lambda at: jax.lax.cond(

@@ -42,7 +42,7 @@ tracing, with what tracing shows of ``x``'s placement):
   one chunk at a time. A ``T`` the chunk does not divide is padded with
   steps of ``d_t = 0``, which leave the state as it is.
 
-Under ``models/transformer.py::remat_block`` the forward's ``y`` and
+Under ``models/decoder.py::remat_block`` the forward's ``y`` and
 chunk states carry the names ``SAVED_SCAN_OUT`` and
 ``SAVED_SCAN_STATES``, given in the ``custom_vjp``'s forward rule as
 the attention kernels give theirs, so a recomputed block holds no scan.
@@ -59,6 +59,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from multidisttorch_tpu.ops.pallas_mode import pallas_interpret
+from multidisttorch_tpu.parallel import mesh
 
 SAVED_SCAN_OUT = "selective_scan_out"
 SAVED_SCAN_STATES = "selective_scan_states"
@@ -90,14 +91,6 @@ def scan_takes_kernel(
         and channels % _LANES_A_STEP == 0
         and d_state % 8 == 0
     )
-
-
-def _placement(x):
-    """``(device_kind, num_devices)`` of the mesh ``x`` was placed on,
-    as tracing sees it, or ``None`` (``models/transformer.py::
-    _placement``, which the attention's rules are asked with)."""
-    mesh = jax.typeof(x).sharding.mesh
-    return None if mesh.empty else (mesh.abstract_device.device_kind, mesh.size)
 
 
 def _steps(delta, bias):
@@ -478,7 +471,7 @@ def selective_scan(x, delta, A, B, C, D, delta_bias, *, chunk: int = CHUNK,
     :func:`scan_takes_kernel` says so of these operands as ``x`` is
     placed, else the ``jax.lax`` form. ``chunk``: steps between two
     states kept for the backward pass."""
-    placed = _placement(x)
+    placed = mesh.placement(x)
     kernel = bool(placed) and scan_takes_kernel(*placed, x.shape[1], *A.shape, chunk)
     y, last = _scan(x, delta, A, B, C, D, delta_bias, chunk, kernel)
     return (y, last.transpose(0, 2, 1)) if return_last_state else y

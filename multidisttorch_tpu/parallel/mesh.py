@@ -60,6 +60,28 @@ def global_mesh(
     return Mesh(devs, (axis,))
 
 
+def placement(x) -> Optional[tuple[str, int]]:
+    """``(device_kind, num_devices)`` of the mesh ``x`` was placed on, as
+    tracing sees it: a jitted function's values carry the abstract mesh
+    of its committed ``NamedSharding`` arguments (every state
+    ``create_lm_state`` makes and every batch a :class:`TrialMesh`
+    places). ``None`` where there is none to see: an uncommitted or
+    single-device array, shapes alone, an array made inside the trace
+    or by a kernel. The one read of where a computation runs: every op
+    that picks between a kernel and its plain form, the remat rules that
+    keep more on one chip and the LM step's head walk ask it here, by
+    the module attribute, so that a test can tell them all at once that
+    the CPU devices are a TPU."""
+    mesh = jax.typeof(x).sharding.mesh
+    return None if mesh.empty else (mesh.abstract_device.device_kind, mesh.size)
+
+
+def on_one_tpu_chip(x) -> bool:
+    """Whether ``x`` lies on one TPU device, as :func:`placement` sees it."""
+    placed = placement(x)
+    return bool(placed) and placed[0].startswith("TPU") and placed[1] == 1
+
+
 @dataclass(frozen=True)
 class TrialMesh:
     """One carved device group — the analog of a torch process subgroup.

@@ -23,8 +23,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from multidisttorch_tpu.models.transformer import _placement
 from multidisttorch_tpu.ops.head_loss import lm_head_loss
+from multidisttorch_tpu.parallel import mesh
 from multidisttorch_tpu.parallel.mesh import DATA_AXIS, TrialMesh
 from multidisttorch_tpu.train.steps import TrainState
 from multidisttorch_tpu.utils.profiling import (
@@ -189,7 +189,7 @@ def make_lm_train_step(
     nothing); a block's input is saved, and of what the block made the
     few values that cost most to remake a byte, up to 14 KB a token
     and layer where a block keeps its attention's operands and its
-    MLP's pre-activation (``models/transformer.py::remat_block`` lists
+    MLP's pre-activation (``models/decoder.py::remat_block`` lists
     them). A model returning
     ``(logits, aux)`` with a scalar ``aux`` (the MoE LM's Switch
     load-balancing term) trains on
@@ -229,7 +229,7 @@ def _build_lm_step_fn(model, tx, aux_loss_weight):
     and loss's two paths a trial takes)."""
 
     def step_fn(state: TrainState, tokens: jax.Array):
-        placed = _placement(tokens)  # None: no mesh to see, so one device
+        placed = mesh.placement(tokens)  # None: no mesh to see, so one device
         walk = hasattr(model, "head_weights") and (placed is None or placed[1] == 1)
 
         def loss_fn(params):

@@ -25,7 +25,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from multidisttorch_tpu.models.transformer import Block, TransformerLM, remat_block
+from multidisttorch_tpu.models import decoder
+from multidisttorch_tpu.models.transformer import Block, TransformerLM
 from multidisttorch_tpu.parallel.mesh import TrialMesh
 from multidisttorch_tpu.parallel.pipeline import (
     pipeline_apply_stages,
@@ -109,7 +110,7 @@ def make_pipelined_lm(
     # within-stage math is unchanged). model.remat carries over, by
     # the models' own rule: per-block checkpointing composes with the
     # staged schedule.
-    block_cls = remat_block(Block) if model.remat else Block
+    block_cls = decoder.block_class(model, Block)
     block_mod = block_cls(
         d_model=model.d_model,
         num_heads=model.num_heads,

@@ -37,6 +37,32 @@ def pytest_collection_modifyitems(items):
             item.add_marker(pytest.mark.slow)
 
 
+V5E = "TPU v5 lite"  # what a v5e chip's device_kind reads
+
+
+@pytest.fixture
+def as_v5e(monkeypatch):
+    """Every placement read (``parallel/mesh.py::placement``: the
+    attention's rules, the expert layer's and the scan's, the blocks
+    that keep more across remat on one chip, the LM step's head walk)
+    told that the operands lie on v5e chips: the device kind a v5e's,
+    the count the real one where tracing sees a mesh, and one chip where
+    it sees none (an unplaced operand, shapes alone). The kernels then
+    run, interpreted, wherever their rules take the shapes. The scan's
+    rule still refuses the model tests' toy widths (its channels come in
+    blocks of 512: ``SambaYLM``'s toy default has 128), so a step at
+    those widths on the kernel path has the attention's kernels alone."""
+    from multidisttorch_tpu.parallel import mesh
+
+    real = mesh.placement
+
+    def placement(x):
+        seen = real(x)
+        return V5E, seen[1] if seen else 1
+
+    monkeypatch.setattr(mesh, "placement", placement)
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _assert_eight_devices():
     assert len(jax.devices()) == 8, (

@@ -24,14 +24,12 @@ import pytest
 
 from benchmark import cells
 from benchmark.entries import conv_moe_lm_trial
-from multidisttorch_tpu.models import transformer
+from multidisttorch_tpu.models import decoder
 from multidisttorch_tpu.models.conv_moe import (
     ShortConvMoEBlock,
     ShortConvMoELM,
     gated_short_conv,
 )
-from multidisttorch_tpu.models.grouped_window_moe import rope_halves
-from multidisttorch_tpu.models.latent_moe import _rope_angles
 from multidisttorch_tpu.ops.moe import RoutedExperts
 from multidisttorch_tpu.parallel.mesh import setup_groups
 from multidisttorch_tpu.train.lm import create_lm_state, make_lm_train_step
@@ -177,9 +175,9 @@ def test_qk_norm_then_rotation_is_the_written_out_form():
             want = jnp.concatenate(
                 [a[..., :4] * cos - a[..., 4:] * sin, a[..., 4:] * cos + a[..., :4] * sin], -1)
             assert _rel(seen[name], want) < 1e-5
-            angles = _rope_angles(jnp.arange(12), 10000.0, 8)  # the model's own helpers agree
+            angles = decoder.rope_angles(jnp.arange(12), 10000.0, 8)  # the model's own helpers agree
             np.testing.assert_allclose(
-                rope_halves(a, jnp.cos(angles), jnp.sin(angles)), want, rtol=1e-4, atol=1e-5)
+                decoder.rope_halves(a, jnp.cos(angles), jnp.sin(angles)), want, rtol=1e-4, atol=1e-5)
     assert seen["window"] is None and seen["q_rotation"] is None  # the kernels get plain operands
 
 
@@ -315,7 +313,7 @@ def test_the_tied_head_has_no_weights_of_its_own_and_its_gradient_is_the_embeddi
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_model_on_the_kernels_is_the_model_on_the_plain_path(monkeypatch, remat):
+def test_model_on_the_kernels_is_the_model_on_the_plain_path(request, remat):
     """A conv and an attention layer of 8 heads over 2 KV heads of 64
     (4 query heads a KV head, as the configuration's 32 over 8), the
     CPU device under a v5e's name: the 64-wide grouped kernels
@@ -334,9 +332,7 @@ def test_model_on_the_kernels_is_the_model_on_the_plain_path(monkeypatch, remat)
         lambda p, t: jnp.mean(model.apply({"params": p}, t)[0] ** 2)))
     with jax.default_matmul_precision("highest"):
         want = loss()(params, tokens)
-        monkeypatch.setattr(
-            transformer, "_placement",
-            lambda x, real=transformer._placement: real(x) and ("TPU v5 lite", real(x)[1]))
+        request.getfixturevalue("as_v5e")
         on_kernels = loss()
         text = str(jax.make_jaxpr(on_kernels)(params, tokens))
         assert "grouped64_fwd" in text and "grouped64_bwd" in text

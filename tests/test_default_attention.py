@@ -1,13 +1,13 @@
 """Which attention a model runs when none is injected.
 
-``models/transformer.py::_default_causal`` decides while tracing, from
-what it can see of the operands (``_placement``: device kind and device
-count of their mesh) and from their shapes, by asking
+``ops/attention.py`` decides while tracing, from what it can see of the
+operands (``parallel/mesh.py::placement``: device kind and device count
+of their mesh) and from their shapes, by asking
 ``ops.pallas_attention.default_takes_kernel``. The CPU suite's models
 stay dense; these tests ask the rule as a plain function, and reach the
-kernel path of a whole step by telling ``_placement`` that the virtual
-CPU devices are a TPU (the device count stays the real one). Counts in
-jaxprs only; nothing is timed.
+kernel path of a whole step by telling ``placement`` that the virtual
+CPU devices are a TPU (``as_v5e``: the device count stays the real one).
+Counts in jaxprs only; nothing is timed.
 """
 
 import collections
@@ -22,7 +22,7 @@ import numpy as np
 import optax
 import pytest
 
-import multidisttorch_tpu.models.transformer as transformer
+from multidisttorch_tpu.models import decoder
 from multidisttorch_tpu.models.transformer import (
     MoETransformerLM,
     TransformerLM,
@@ -36,6 +36,7 @@ from multidisttorch_tpu.ops.pallas_attention import (
     latent_takes_kernel,
     make_flash_attention,
 )
+from multidisttorch_tpu.parallel import mesh
 from multidisttorch_tpu.parallel.mesh import MODEL_AXIS, setup_groups
 from multidisttorch_tpu.train.lm import create_lm_state, make_lm_train_step
 from multidisttorch_tpu.train.steps import state_shardings
@@ -136,7 +137,7 @@ def _kernels(jaxpr):
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_one_chip_latent_attention_step_runs_the_kernel(as_tpu, remat):
+def test_one_chip_latent_attention_step_runs_the_kernel(as_v5e, remat):
     """``LatentMoELM`` given no attention: q and k 128 + 64 wide, v
     128. On one chip the kernel on the parts of q and k as the
     projections make them, one forward a block (under remat too: its
@@ -153,7 +154,7 @@ def test_one_chip_latent_attention_step_runs_the_kernel(as_tpu, remat):
     assert _count(_step_jaxpr(four, model), "pallas_call") == 0
 
 
-def test_injected_attention_gets_latent_attention_assembled(as_tpu):
+def test_injected_attention_gets_latent_attention_assembled(as_v5e):
     """An ``attention=`` of the ``(q, k, v)`` kind is handed q and k at
     192 a head, on one chip too: the kernel at the padded width."""
     (group,) = setup_groups(1, devices=jax.devices()[:1])
@@ -210,7 +211,7 @@ def test_latent_attention_step_elsewhere_is_the_assembled_one(devices, request):
     walk = _THE_WALK if devices == 1 else {}
     assert dict(counts) == {k: n + walk.get(k, 0) for k, n in _ASSEMBLED_STEP.items()}
     if devices == 4:
-        request.getfixturevalue("as_tpu")
+        request.getfixturevalue("as_v5e")
         assert _counts(_step_jaxpr(group, _latent_lm(remat=True))) == counts
     plain = sum(_counts(_step_jaxpr(group, _latent_lm())).values())
     assert plain == 1616 + 7 + sum(walk.values())  # the names
@@ -228,7 +229,7 @@ _LATENT_ATTENTION_TREE = {
 }
 
 
-def test_both_paths_share_one_parameter_tree_and_one_init(monkeypatch):
+def test_both_paths_share_one_parameter_tree_and_one_init(request):
     """Names, shapes and, at one seed, the initial values to the bit:
     whether ``model.init`` traces the assembled path (as it does on
     every backend: nobody placed its dummy batch) or the kernel on the
@@ -242,7 +243,7 @@ def test_both_paths_share_one_parameter_tree_and_one_init(monkeypatch):
     )
     assert set(shapes["block_0"]) - set(_LATENT_ATTENTION_TREE) == {"gate", "up", "down"}
     assert set(shapes["block_1"]) - set(_LATENT_ATTENTION_TREE) == {"moe"}
-    monkeypatch.setattr(transformer, "_placement", lambda x: (V5E, 1))
+    request.getfixturevalue("as_v5e")  # the unplaced dummy batch read as one chip
     traced = jax.make_jaxpr(lambda: model.init(jax.random.key(3), tokens))()
     assert _kernels(traced) == {"latent_fwd": LAYERS, "token_sums": 1}  # the expert layer's output
     by_parts = model.init(jax.random.key(3), tokens)["params"]
@@ -254,7 +255,7 @@ def test_both_paths_share_one_parameter_tree_and_one_init(monkeypatch):
 @pytest.mark.parametrize(
     "dtype, tol", [(jnp.float32, 1e-4), (jnp.bfloat16, 1e-1)], ids=["f32", "bf16"]
 )
-def test_kernel_on_the_parts_matches_the_assembled_path(monkeypatch, dtype, tol):
+def test_kernel_on_the_parts_matches_the_assembled_path(request, dtype, tol):
     """Loss and every gradient leaf of the two-block LM: the kernel on
     the parts (interpreted), with remat and without, against the dense
     path on the assembled q and k."""
@@ -276,8 +277,7 @@ def test_kernel_on_the_parts_matches_the_assembled_path(monkeypatch, dtype, tol)
 
     kernels, (want, want_grads) = loss_and_grads(_latent_lm(dtype=dtype))
     assert not kernels
-    real = transformer._placement
-    monkeypatch.setattr(transformer, "_placement", lambda x: real(x) and (V5E, real(x)[1]))
+    request.getfixturevalue("as_v5e")
     rel = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
     for remat in (False, True):
         kernels, (got, grads) = loss_and_grads(_latent_lm(dtype=dtype, remat=remat))
@@ -304,7 +304,7 @@ def test_grouped_dot_rule(device_kind, num_devices, rows, k, n, kernel):
     assert grouped_dot_takes_kernel(device_kind, num_devices, rows, k, n) is kernel
 
 
-def test_one_chip_expert_layer_runs_the_grouped_kernel(as_tpu):
+def test_one_chip_expert_layer_runs_the_grouped_kernel(as_v5e):
     """On one chip, at shapes the kernel tiles, the experts' products
     are Pallas calls too: two forward (gate and up as one, then down)
     and for each of them the two of its backward, and so are the two
@@ -359,19 +359,6 @@ def _count(jaxpr, primitive: str) -> int:
     return _counts(jaxpr)[primitive]
 
 
-@pytest.fixture
-def as_tpu(monkeypatch):
-    """The operands' mesh as tracing sees it, its device kind replaced
-    by a v5e's."""
-    real = transformer._placement
-
-    def placement(x):
-        seen = real(x)
-        return seen and (V5E, seen[1])
-
-    monkeypatch.setattr(transformer, "_placement", placement)
-
-
 def _step_jaxpr(group, model, param_shardings=None, batch=4, t=T):
     tx = optax.adam(1e-3)
     state = create_lm_state(
@@ -387,19 +374,19 @@ def _step_jaxpr(group, model, param_shardings=None, batch=4, t=T):
 
 def test_placement_is_what_the_state_and_batch_were_put_on():
     seen = []
-    spy = lambda q, k, v: seen.append(transformer._placement(q)) or q
+    spy = lambda q, k, v: seen.append(mesh.placement(q)) or q
     for n in (1, 4):
         (group,) = setup_groups(1, devices=jax.devices()[:n])
         _step_jaxpr(group, TransformerLM(attention=spy, remat=True, **CFG))
     # None: model.init's dummy batch, which nobody placed. Shapes alone
     # or such an array show nothing, and the default is dense there.
     assert set(seen) == {None, ("cpu", 1), ("cpu", 4)}
-    assert transformer._placement(jnp.zeros((2, 4))) is None
+    assert mesh.placement(jnp.zeros((2, 4))) is None
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
 @pytest.mark.parametrize("model_cls", [TransformerLM, MoETransformerLM], ids=["dense", "moe"])
-def test_one_chip_step_runs_the_kernel(as_tpu, model_cls, remat):
+def test_one_chip_step_runs_the_kernel(as_v5e, model_cls, remat):
     (group,) = setup_groups(1, devices=jax.devices()[:1])
     jaxpr = _step_jaxpr(group, model_cls(remat=remat, **CFG))
     # a forward kernel and one fused backward kernel a block: where
@@ -429,13 +416,13 @@ def test_cpu_default_stays_dense():
         ("a data-parallel batch over four chips", 4, T),
     ],
 )
-def test_falls_back_to_dense(as_tpu, why, devices, t):
+def test_falls_back_to_dense(as_v5e, why, devices, t):
     (group,) = setup_groups(1, devices=jax.devices()[:devices])
     model = TransformerLM(**dict(CFG, max_len=max(T, t)))
     assert _count(_step_jaxpr(group, model, t=t), "pallas_call") == 0, why
 
 
-def test_heads_sharded_by_auto_tp_stay_dense(as_tpu):
+def test_heads_sharded_by_auto_tp_stay_dense(as_v5e):
     """``transformer_tp_shardings(..., "auto")`` reads ``attention is
     None`` as per-head local and shards q/k/v/proj over the model axis:
     the default must then be the dense path, which GSPMD partitions
@@ -452,7 +439,7 @@ def test_heads_sharded_by_auto_tp_stay_dense(as_tpu):
     assert _count(_step_jaxpr(group, flash, unsharded), "pallas_call") == 2 * LAYERS
 
 
-def test_default_and_injected_flash_are_the_same_program(as_tpu):
+def test_default_and_injected_flash_are_the_same_program(as_v5e):
     (group,) = setup_groups(1, devices=jax.devices()[:1])
     default = _step_jaxpr(group, TransformerLM(remat=True, **CFG))
     injected = _step_jaxpr(
@@ -461,7 +448,7 @@ def test_default_and_injected_flash_are_the_same_program(as_tpu):
     assert str(default) == str(injected)
 
 
-# --- what rematerialization keeps (transformer.remat_block) ---
+# --- what rematerialization keeps (decoder.remat_block) ---
 
 
 def _loss_and_grads(model, init=TransformerLM(**CFG), t=T, group=None):
@@ -499,14 +486,14 @@ def test_saved_kernel_results_leave_the_gradients_bit_equal(request, monkeypatch
     keep q, k, v and ``up``'s output, and the same holds."""
     group = None
     if placed:
-        request.getfixturevalue("as_tpu")
+        request.getfixturevalue("as_v5e")
         (group,) = setup_groups(1, devices=jax.devices()[:1])
     make = lambda remat: TransformerLM(
         attention=make_flash_attention(causal=True), dtype=dtype, remat=remat, **CFG
     )
     plain = _loss_and_grads(make(False), group=group)
     saved = _loss_and_grads(make(True), group=group)
-    monkeypatch.setattr(transformer, "remat_block", nn.remat)  # only a block's input is saved
+    monkeypatch.setattr(decoder, "remat_block", nn.remat)  # only a block's input is saved
     bare = _loss_and_grads(make(True), group=group)
     assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(saved[1]))
     for other in (bare, plain):
@@ -523,7 +510,7 @@ def test_the_policy_is_inert_on_the_dense_path(monkeypatch):
     (group,) = setup_groups(1, devices=jax.devices()[:1])
     model = TransformerLM(remat=True, **CFG)
     with_policy = _step_jaxpr(group, model)
-    monkeypatch.setattr(transformer, "remat_block", nn.remat)
+    monkeypatch.setattr(decoder, "remat_block", nn.remat)
     bare = _step_jaxpr(group, model)
     counts = _counts(bare)
     assert _counts(with_policy) == counts
@@ -547,7 +534,7 @@ def test_one_chip_block_recomputes_proj_alone(request, monkeypatch, device_kind,
     nothing is named, and the recomputed blocks are bare ``nn.remat``'s,
     the parent's (the CPU's dense attention adds its own products)."""
     if device_kind == V5E:
-        request.getfixturevalue("as_tpu")
+        request.getfixturevalue("as_v5e")
     (group,) = setup_groups(1, devices=jax.devices()[:devices])
     model = TransformerLM(remat=True, **CFG)
     params, tokens = _params_and_tokens(group, model)
@@ -558,15 +545,15 @@ def test_one_chip_block_recomputes_proj_alone(request, monkeypatch, device_kind,
 
     kept = gradient()
     names = {e.params["name"] for e in _equations(kept) if e.primitive.name == "name"}
-    monkeypatch.setattr(transformer, "remat_block", nn.remat)
+    monkeypatch.setattr(decoder, "remat_block", nn.remat)
     bare = _recomputed(gradient())
     kept = _recomputed(kept)
     if (device_kind, devices) == (V5E, 1):
-        assert names == {transformer.SAVED_QKV, transformer.SAVED_MLP_HIDDEN, SAVED_OUT, SAVED_LSE}
+        assert names == {decoder.SAVED_QKV, decoder.SAVED_MLP_HIDDEN, SAVED_OUT, SAVED_LSE}
         assert kept["dot_general"] == LAYERS * (1 + 2 * 6)
         assert bare["dot_general"] - kept["dot_general"] == LAYERS * 4  # q, k, v, up
     else:
-        assert not names & {transformer.SAVED_QKV, transformer.SAVED_MLP_HIDDEN}
+        assert not names & {decoder.SAVED_QKV, decoder.SAVED_MLP_HIDDEN}
         assert kept == bare
 
 
@@ -619,12 +606,12 @@ def test_the_kept_residual_and_routing_leave_the_gradients_bit_equal(monkeypatch
         return jax.make_jaxpr(jax.grad(lambda p: logits(p).sum()))(params)
 
     saved, kept = _loss_and_grads(model, init=model, t=t), gradient()
-    monkeypatch.setattr(transformer, "remat_block", nn.remat)  # only a block's input is saved
+    monkeypatch.setattr(decoder, "remat_block", nn.remat)  # only a block's input is saved
     bare, again = _loss_and_grads(model, init=model, t=t), _recomputed(gradient())
     names = {e.params["name"] for e in _equations(kept) if e.primitive.name == "name"}
-    operands = {transformer.SAVED_QKV} if kind == "grouped" else set()
+    operands = {decoder.SAVED_QKV} if kind == "grouped" else set()
     kernels = {SAVED_OUT, SAVED_LSE} if model.attention else set()
-    assert names == {transformer.SAVED_RESIDUAL, SAVED_ROUTING} | operands | kernels
+    assert names == {decoder.SAVED_RESIDUAL, SAVED_ROUTING} | operands | kernels
     kept = _recomputed(kept)
     routers = model.num_layers - getattr(model, "dense_layers", 0)
     products = model.num_layers + routers + (3 * model.num_layers if operands else 0)
@@ -706,11 +693,11 @@ def _recomputed(jaxpr) -> collections.Counter:
     return inside
 
 
-def test_bare_remat_runs_the_forward_kernel_twice(as_tpu, monkeypatch):
+def test_bare_remat_runs_the_forward_kernel_twice(as_v5e, monkeypatch):
     """The other side of ``test_one_chip_step_runs_the_kernel``'s
     count: without the policy the recomputed block holds the forward
     kernel again."""
-    monkeypatch.setattr(transformer, "remat_block", nn.remat)
+    monkeypatch.setattr(decoder, "remat_block", nn.remat)
     (group,) = setup_groups(1, devices=jax.devices()[:1])
     jaxpr = _step_jaxpr(group, TransformerLM(remat=True, **CFG))
     assert _count(jaxpr, "pallas_call") == LAYERS * 3
@@ -729,7 +716,7 @@ def test_hops_under_checkpoint_keep_the_logsumexp_gradient(saved):
 
     hop = jax.checkpoint(
         lambda q, k, v: _attend(q, k, v, causal=False),
-        policy=transformer._KEEP_ACROSS_REMAT if saved else None,  # the models' own
+        policy=decoder._KEEP_ACROSS_REMAT if saved else None,  # the models' own
     )
     per_row = lambda x: x.transpose(0, 2, 1)[..., None]  # (B, H, T) on (B, T, H, D)
 

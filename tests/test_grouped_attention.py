@@ -9,8 +9,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from multidisttorch_tpu.models.grouped_window_moe import rope_halves
-from multidisttorch_tpu.models.latent_moe import _rope_angles
+from multidisttorch_tpu.models.decoder import rope_angles, rope_halves
 from multidisttorch_tpu.ops.pallas_attention import (
     blocked_window_attention,
     grouped_attention,
@@ -47,7 +46,7 @@ def test_grouped_kernels_match_the_masked_dense_form(group, window):
     blocks of 128, q rotated in the kernels where a window is set (as
     the model's window layers are)."""
     q, k, v, co = _operands(512, 2 * group, 2)
-    angle = _rope_angles(jnp.arange(512), 10000.0, 128)
+    angle = rope_angles(jnp.arange(512), 10000.0, 128)
     rotation = (jnp.cos(angle), jnp.sin(angle)) if window else None
 
     def kernel(q, k, v):

@@ -130,15 +130,13 @@ def test_model_on_the_kernels_is_the_model_on_the_plain_path(remat):
 
 
 @pytest.mark.parametrize("rope_layout", [(0, 1), (1, 1), (0, 0)], ids=["nope+rope", "rope", "nope"])
-def test_heads_64_wide_on_a_tpu_rotate_on_the_plain_path(monkeypatch, rope_layout):
+def test_heads_64_wide_on_a_tpu_rotate_on_the_plain_path(request, monkeypatch, rope_layout):
     """A full and a window layer of 4 heads over 2 KV heads of 64 with
     the operands placed as a trial's, the CPU device under a v5e's name:
     a layer without positions takes the 64-wide grouped kernels, a
     rotary layer the plain path (those kernels rotate nothing), and
     either way the step lowers for the TPU (interpret mode off) and
     gives the plain path's loss and gradients (interpreted)."""
-    from multidisttorch_tpu.models import transformer
-
     model = GroupedWindowMoELM(
         vocab_size=64, d_model=64, num_heads=4, num_kv_heads=2, head_dim=64, num_layers=2,
         window_layout=(0, 1), rope_layout=rope_layout, window=100, num_experts=4, top_k=2,
@@ -151,9 +149,7 @@ def test_heads_64_wide_on_a_tpu_rotate_on_the_plain_path(monkeypatch, rope_layou
     loss = lambda p, t: jnp.mean(model.apply({"params": p}, t)[0] ** 2)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(jax.value_and_grad(loss))(params, tokens)
-        real = transformer._placement
-        monkeypatch.setattr(
-            transformer, "_placement", lambda x: real(x) and ("TPU v5 lite", real(x)[1]))
+        request.getfixturevalue("as_v5e")
         on_tpu = jax.jit(jax.value_and_grad(loss))  # a new jit: traced under the v5e's name
         got = on_tpu(params, tokens)
         monkeypatch.delenv("MDT_PALLAS_INTERPRET")

@@ -24,8 +24,9 @@ import pytest
 
 from benchmark import cells
 from benchmark.entries import hc_moe_lm_trial, moe_lm_trial
+from multidisttorch_tpu.models import decoder
 from multidisttorch_tpu.models.latent_moe import (
-    LatentMoEBlock, LatentMoELM, YarnScaling, _rope_angles,
+    LatentMoEBlock, LatentMoELM, YarnScaling,
 )
 from multidisttorch_tpu.ops import hyper_connection
 from multidisttorch_tpu.ops.moe import RoutedExperts
@@ -351,7 +352,7 @@ def test_yarn_by_hand_at_factor_64():
     assert other.score_scale == 1.0 and abs(other.rotation_scale - 1.41589) < 1e-5
     # the reference works the same numbers out on its own
     np.testing.assert_allclose(REFERENCE.yarn_inv_freq(10000.0, 64, YARN), got, rtol=1e-12)
-    angle = _rope_angles(jnp.arange(4096), 10000.0, 64, yarn)
+    angle = decoder.rope_angles(jnp.arange(4096), 10000.0, 64, yarn)
     np.testing.assert_allclose(angle[4095, 31], 4095 * plain[31] / 64, rtol=1e-6)
 
 
@@ -360,8 +361,8 @@ def test_no_rope_scaling_gives_the_angles_of_before_bit_for_bit():
     for theta, width in ((10000.0, 8), (32000000.0, 64)):
         before = positions.astype(jnp.float32)[:, None] * (
             theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width))[None, :]
-        np.testing.assert_array_equal(_rope_angles(positions, theta, width), before)
-        np.testing.assert_array_equal(_rope_angles(positions, theta, width, None), before)
+        np.testing.assert_array_equal(decoder.rope_angles(positions, theta, width), before)
+        np.testing.assert_array_equal(decoder.rope_angles(positions, theta, width, None), before)
 
 
 def test_bf16_step_trains_and_counts():

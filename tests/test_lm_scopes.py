@@ -164,7 +164,7 @@ def test_scopes_are_metadata_only(monkeypatch):
 def _lowered_latent(remat, t=16, placed=False, **fields):
     """The latent-attention LM's step lowered for ``(2, t)`` tokens;
     ``placed``: state and batch carry the one-device group's shardings,
-    as a trial's do (what ``transformer._placement`` reads)."""
+    as a trial's do (what ``parallel/mesh.py::placement`` reads)."""
     from multidisttorch_tpu.models.latent_moe import LatentMoELM
 
     (group,) = setup_groups(1, devices=jax.devices()[:1])
@@ -240,7 +240,7 @@ def test_expert_layer_scopes_reach_the_compiled_step(remat):
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_latent_attention_on_the_parts_keeps_the_scopes(monkeypatch, remat):
+def test_latent_attention_on_the_parts_keeps_the_scopes(as_v5e, remat):
     """Where the block hands the kernel the parts of q and k (one TPU
     chip; here the CPU device under a v5e's name, the kernels
     interpreted), what it runs instead of the assembly carries the
@@ -249,12 +249,7 @@ def test_latent_attention_on_the_parts_keeps_the_scopes(monkeypatch, remat):
     under ``k``, the two kernels (which rotate q) under ``attn_core``
     once a pass, and nothing new without a name."""
     from benchmark import moe_scopes, scope_reduce
-    from multidisttorch_tpu.models import transformer
 
-    real = transformer._placement
-    monkeypatch.setattr(
-        transformer, "_placement", lambda x: real(x) and ("TPU v5 lite", real(x)[1])
-    )
     lowered, _ = _lowered_latent(
         remat, t=256, placed=True,  # 256: the shortest the kernels take
         d_model=128, num_heads=2, num_layers=LAYERS, qk_nope_dim=128, qk_rope_dim=64,
@@ -438,19 +433,14 @@ def test_window_and_full_scopes_reach_the_compiled_step(remat):
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_grouped_kernels_are_under_their_layer_s_scope_once_a_pass(monkeypatch, remat):
+def test_grouped_kernels_are_under_their_layer_s_scope_once_a_pass(as_v5e, remat):
     """Where the block takes the kernels (one TPU chip; here the CPU
     device under a v5e's name, the kernels interpreted): one forward and
     one backward call a layer under ``attn_core`` and the layer's kind,
     none in the recomputed block; k is rotated outside them and q is
     not."""
     from benchmark import scope_reduce, swa_scopes
-    from multidisttorch_tpu.models import transformer
 
-    real = transformer._placement
-    monkeypatch.setattr(
-        transformer, "_placement", lambda x: real(x) and ("TPU v5 lite", real(x)[1])
-    )
     lowered, _ = _lowered_grouped(
         remat, t=256, placed=True, d_model=128, num_heads=2, num_kv_heads=1, head_dim=128,
         num_layers=2, window_layout=(0, 1), rope_layout=(0, 1), window=128, num_experts=4,
@@ -472,7 +462,7 @@ def test_grouped_kernels_are_under_their_layer_s_scope_once_a_pass(monkeypatch, 
     assert len(unrecognised) / len(step) < UNRECOGNISED_BOUND
 
 
-# --- what remat keeps of a block (transformer.remat_block) ---
+# --- what remat keeps of a block (decoder.remat_block) ---
 
 _REMAT_STEPS = {
     "dense": lambda: _lowered(TransformerLM, True),  # the control: its block names nothing
@@ -621,7 +611,7 @@ def test_hybrid_scopes_reach_the_compiled_step(remat):
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_hybrid_kernels_are_under_their_layer_s_scope_once_a_pass(monkeypatch, remat):
+def test_hybrid_kernels_are_under_their_layer_s_scope_once_a_pass(as_v5e, remat):
     """Where the block takes the kernels (one TPU chip; here the CPU
     device under a v5e's name, the kernels interpreted): one forward and
     one backward scan a Mamba layer under ``ssm_scan`` and one forward
@@ -629,14 +619,7 @@ def test_hybrid_kernels_are_under_their_layer_s_scope_once_a_pass(monkeypatch, r
     ``attn_core`` and the layer's kind, none of either in the recomputed
     block."""
     from benchmark import scope_reduce, ssm_scopes, swa_scopes
-    from multidisttorch_tpu.models import transformer
-    from multidisttorch_tpu.ops import selective_scan
 
-    for module in (transformer, selective_scan):  # the attention's rules and the scan's own
-        monkeypatch.setattr(
-            module, "_placement",
-            lambda x, real=module._placement: real(x) and ("TPU v5 lite", real(x)[1]),
-        )
     lowered, _ = _lowered_hybrid(
         remat, t=256, placed=True, d_model=256, num_heads=4, num_kv_heads=2, head_dim=64,
         mlp_width=64, window=128,
@@ -764,7 +747,7 @@ def test_conv_scopes_reach_the_compiled_step(remat):
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_conv_model_s_kernels_are_under_attn_full_once_a_pass(monkeypatch, remat):
+def test_conv_model_s_kernels_are_under_attn_full_once_a_pass(as_v5e, remat):
     """With the operands placed as a trial's and the device a v5e by
     name, the attention layer of 8 heads over 2 KV heads of 64 (4 query
     heads a KV head) lowers the 64-wide kernel pair under
@@ -772,12 +755,7 @@ def test_conv_model_s_kernels_are_under_attn_full_once_a_pass(monkeypatch, remat
     kernel in the recomputed block, and q and k reach it normed and
     rotated under ``qk_norm``."""
     from benchmark import conv_scopes, scope_reduce, swa_scopes
-    from multidisttorch_tpu.models import transformer
 
-    real = transformer._placement
-    monkeypatch.setattr(
-        transformer, "_placement", lambda x: real(x) and ("TPU v5 lite", real(x)[1])
-    )
     lowered, _ = _lowered_conv(
         remat, t=256, placed=True, d_model=128, num_heads=8, num_kv_heads=2, head_dim=64,
     )

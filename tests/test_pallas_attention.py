@@ -107,7 +107,8 @@ def test_inside_remat_block_under_value_and_grad(dtype):
     # once (its output and logsumexp are saved for the recomputed
     # block) and the fused backward once, against the same block over
     # dense attention.
-    from multidisttorch_tpu.models.transformer import Block, remat_block
+    from multidisttorch_tpu.models.decoder import remat_block
+    from multidisttorch_tpu.models.transformer import Block
 
     x = jnp.asarray(np.random.default_rng(0).normal(0, 1, (2, 256, 128)), dtype)
     mk = lambda cls, attn: cls(d_model=128, num_heads=2, attention=attn, dtype=dtype)
@@ -425,12 +426,13 @@ def test_latent_operands_match_dense_on_the_assembled(t, h, block, causal, rotat
     rotates it, where the kernels are asked to): the output and all
     five gradients, the rotary key's against the sum over the heads of
     the assembled k's rotary part."""
-    from multidisttorch_tpu.models.latent_moe import _rope_angles, rope_interleaved
+    from multidisttorch_tpu.models.decoder import rope_angles
+    from multidisttorch_tpu.models.latent_moe import rope_interleaved
 
     parts = _latent_operands(2, t, h, dtype)
     w = jax.random.normal(jax.random.key(12), (2, t, h, 128), jnp.float32)
     positions, theta = jnp.arange(t), 1e4
-    angle = _rope_angles(positions, theta, 64)
+    angle = rope_angles(positions, theta, 64)
     rotation = (jnp.cos(angle), jnp.sin(angle)) if rotated else None
 
     def assembled(q_nope, q_rope, k_nope, k_rope, v):

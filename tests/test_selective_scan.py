@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from multidisttorch_tpu.models import transformer
+from multidisttorch_tpu.models import decoder
 from multidisttorch_tpu.ops import selective_scan as ss
 from multidisttorch_tpu.ops.selective_scan import scan_takes_kernel, selective_scan
 
@@ -54,13 +54,6 @@ def _loss(fn):
     return jax.value_and_grad(loss, argnums=tuple(range(7)))
 
 
-@pytest.fixture
-def on_a_v5e(monkeypatch):
-    """The operands read as placed on one v5e chip: the rule then takes
-    the kernel pair wherever the shapes tile."""
-    monkeypatch.setattr(ss, "_placement", lambda x: ("TPU v5 lite", 1))
-
-
 def _kernels(fn, *operands):
     return str(jax.make_jaxpr(fn)(*operands)).count("pallas_call")
 
@@ -85,7 +78,7 @@ def test_lax_form_is_the_step_by_step_loop(t, chunk):
 
 @pytest.mark.parametrize("t, e, n, chunk", [(64, 512, 16, 32), (32, 1024, 8, 16), (16, 512, 16, 16)],
                          ids=["two-chunks", "two-blocks-n8", "one-chunk"])
-def test_kernel_pair_is_the_step_by_step_loop(on_a_v5e, t, e, n, chunk):
+def test_kernel_pair_is_the_step_by_step_loop(as_v5e, t, e, n, chunk):
     _check(t, e, n, chunk, kernels=1)
 
 
@@ -104,7 +97,7 @@ def test_low_precision_operands_and_no_last_state():
     assert {g.dtype for g in grads} == {jnp.dtype(jnp.bfloat16)}
 
 
-def test_the_kept_names_spare_the_recomputed_scan(on_a_v5e):
+def test_the_kept_names_spare_the_recomputed_scan(as_v5e):
     """Under the models' remat policy the scan's output and chunk states
     are kept, so the gradient holds one forward and one backward scan;
     under a bare ``jax.checkpoint`` the forward runs again."""
@@ -117,11 +110,11 @@ def test_the_kept_names_spare_the_recomputed_scan(on_a_v5e):
         jaxpr = jax.make_jaxpr(jax.grad(lambda *o: scan(*o).sum(), argnums=(0, 1)))(*operands)
         return str(jaxpr).count("pallas_call")
 
-    assert count(transformer._KEEP_ACROSS_REMAT) == 2
+    assert count(decoder._KEEP_ACROSS_REMAT) == 2
     assert count(None) == 3
 
 
-def test_rule_takes_one_tpu_chip_in_whole_chunks_and_blocks(on_a_v5e):
+def test_rule_takes_one_tpu_chip_in_whole_chunks_and_blocks(as_v5e):
     assert scan_takes_kernel("TPU v5 lite", 1, 16384, 5120, 16)
     assert not scan_takes_kernel("cpu", 1, 16384, 5120, 16)
     assert not scan_takes_kernel("TPU v5 lite", 4, 16384, 5120, 16)
@@ -137,7 +130,7 @@ def test_rule_takes_one_tpu_chip_in_whole_chunks_and_blocks(on_a_v5e):
     assert _kernels(scan, *_operands(40, 512, 8)) == 0
 
 
-def test_scan_operands_lower_for_tpu(monkeypatch, on_a_v5e):
+def test_scan_operands_lower_for_tpu(monkeypatch, as_v5e):
     # the cell ssm-yoco-t16384's scan as its block hands it over: 1 x
     # 16,384 x 5,120, a state of 16, bf16 operands; interpret mode off.
     # One kernel forward, two with the backward; no (T, E, N) array

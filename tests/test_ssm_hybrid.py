@@ -21,9 +21,8 @@ import pytest
 
 from benchmark import cells
 from benchmark.entries import ssm_lm_trial
-from multidisttorch_tpu.models import transformer
+from multidisttorch_tpu.models import decoder
 from multidisttorch_tpu.models.ssm_hybrid import KINDS, SambaYLM, default_layer_kinds
-from multidisttorch_tpu.ops import selective_scan
 from multidisttorch_tpu.parallel.mesh import setup_groups
 from multidisttorch_tpu.train.lm import create_lm_state, lm_loss_mean, make_lm_train_step
 
@@ -226,7 +225,7 @@ def test_starts_of_the_scan_s_own_weights():
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_model_on_the_kernels_is_the_model_on_the_plain_path(monkeypatch, remat):
+def test_model_on_the_kernels_is_the_model_on_the_plain_path(request, remat):
     """One layer of each kind at heads 64 wide and 512 channels, the CPU
     device under a v5e's name: the scan's kernel pair and the 64-wide
     grouped kernels (interpreted; a window, none, and k, v from another
@@ -245,10 +244,7 @@ def test_model_on_the_kernels_is_the_model_on_the_plain_path(monkeypatch, remat)
         lambda p, t: jnp.mean(model.apply({"params": p}, t)[0] ** 2)))
     with jax.default_matmul_precision("highest"):
         want = loss(params, tokens)
-        for module in (transformer, selective_scan):  # the attention's rules and the scan's own
-            monkeypatch.setattr(
-                module, "_placement",
-                lambda x, real=module._placement: real(x) and ("TPU v5 lite", real(x)[1]))
+        request.getfixturevalue("as_v5e")  # the attention's rules and the scan's own
         on_kernels = jax.jit(jax.value_and_grad(
             lambda p, t: jnp.mean(model.apply({"params": p}, t)[0] ** 2)))
         # a forward and a backward kernel a Mamba and an attention layer, however nested
@@ -260,16 +256,6 @@ def test_model_on_the_kernels_is_the_model_on_the_plain_path(monkeypatch, remat)
 
 V5E = "TPU v5 lite"
 SIX_KINDS = ("mamba", "window", "mamba_memory", "full_kv", "gmu", "cross")
-
-
-@pytest.fixture
-def as_tpu(monkeypatch):
-    """The operands' mesh as ``transformer._placement`` sees it, its
-    device kind replaced by a v5e's (the scan asks its own rule, left
-    as it is: the ``lax`` form, as the attention's dense path at heads
-    16 wide)."""
-    real = transformer._placement
-    monkeypatch.setattr(transformer, "_placement", lambda x: real(x) and (V5E, real(x)[1]))
 
 
 def _recomputed(jaxpr) -> collections.Counter:
@@ -285,9 +271,9 @@ def _without_mlp_hidden():
     """``remat_block``'s policy less ``SAVED_MLP_HIDDEN``: what the
     parent's recomputed ``SambaYBlock`` kept."""
     return jax.checkpoint_policies.save_only_these_names(
-        transformer.SAVED_OUT, transformer.SAVED_LSE, transformer.SAVED_MAPS, transformer.SAVED_Y,
-        transformer.SAVED_RESIDUAL, transformer.SAVED_ROUTING, transformer.SAVED_QKV,
-        transformer.SAVED_SCAN_OUT, transformer.SAVED_SCAN_STATES)
+        decoder.SAVED_OUT, decoder.SAVED_LSE, decoder.SAVED_MAPS, decoder.SAVED_Y,
+        decoder.SAVED_RESIDUAL, decoder.SAVED_ROUTING, decoder.SAVED_QKV,
+        decoder.SAVED_SCAN_OUT, decoder.SAVED_SCAN_STATES)
 
 
 @pytest.mark.parametrize(
@@ -304,7 +290,7 @@ def test_one_chip_block_keeps_gate(request, monkeypatch, device_kind, devices):
     blocks are the parent's. Against ``nn.remat`` with no policy the
     kept stream after the mixer spares the same products everywhere."""
     if device_kind == V5E:
-        request.getfixturevalue("as_tpu")
+        request.getfixturevalue("as_v5e")
     (group,) = setup_groups(1, devices=jax.devices()[:devices])
     model = SambaYLM(vocab_size=64, layer_kinds=SIX_KINDS, mlp_width=128, remat=True)
     state = create_lm_state(group, model, optax.sgd(1.0), jax.random.key(0))
@@ -317,19 +303,19 @@ def test_one_chip_block_keeps_gate(request, monkeypatch, device_kind, devices):
     kept = gradient()
     named = collections.Counter(
         e.params["name"] for e in _equations(kept) if e.primitive.name == "name")
-    monkeypatch.setattr(transformer, "_KEEP_ACROSS_REMAT", _without_mlp_hidden())
+    monkeypatch.setattr(decoder, "_KEEP_ACROSS_REMAT", _without_mlp_hidden())
     parent = _recomputed(gradient())
-    monkeypatch.setattr(transformer, "remat_block", nn.remat)
+    monkeypatch.setattr(decoder, "remat_block", nn.remat)
     bare = _recomputed(gradient())
     kept = _recomputed(kept)
     layers = len(SIX_KINDS)
     assert bare["dot_general"] - parent["dot_general"] == layers + 2  # the mixers' outputs
     if (device_kind, devices) == (V5E, 1):
-        assert named[transformer.SAVED_MLP_HIDDEN] == layers
+        assert named[decoder.SAVED_MLP_HIDDEN] == layers
         assert parent["dot_general"] - kept["dot_general"] == layers  # gate
         assert kept["logistic"] == parent["logistic"]  # silu made again from the kept gate
     else:
-        assert transformer.SAVED_MLP_HIDDEN not in named
+        assert decoder.SAVED_MLP_HIDDEN not in named
         assert kept == parent
 
 
@@ -356,14 +342,14 @@ def test_kept_gate_leaves_the_gradients_bit_equal(request, monkeypatch, dtype, p
     named) and not."""
     group = None
     if placed:
-        request.getfixturevalue("as_tpu")
+        request.getfixturevalue("as_v5e")
         (group,) = setup_groups(1, devices=jax.devices()[:1])
     tokens = jax.random.randint(jax.random.key(5), (2, 32), 0, 64)
     make = lambda remat: SambaYLM(
         vocab_size=64, layer_kinds=SIX_KINDS, mlp_width=128, dtype=dtype, remat=remat)
     plain = _loss_and_grads(make(False), tokens, group)
     saved = _loss_and_grads(make(True), tokens, group)
-    monkeypatch.setattr(transformer, "remat_block", nn.remat)  # only a block's input is saved
+    monkeypatch.setattr(decoder, "remat_block", nn.remat)  # only a block's input is saved
     bare = _loss_and_grads(make(True), tokens, group)
     assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(saved[1]))
     for other in (bare, plain):
