@@ -17,7 +17,9 @@ once to the products' operand type) and from that one array the block's
 of it happens in the forward rule of a ``jax.custom_vjp``: the
 gradients are the rule's residuals and the backward rule scales them by
 the loss's cotangent. A rule that made the logits again in the backward
-pass would run four products for three.
+pass would run four products for three. :func:`lm_head_loss_weighted`
+is the same walk with each position's cross-entropy weighed (a looped
+model's passes by their exit probability, ``train/lm.py::_looped_loss``).
 
 ``lm_loss_mean`` stays the definition: ``tests/test_head_loss.py``
 holds the walk to it, differentiated through a plain float32 head.
@@ -76,13 +78,42 @@ def lm_head_loss(
     return _walk(hidden, weights, bias, tokens, dtype, tied)[0]
 
 
-def _walk(hidden, weights, bias, tokens, dtype, tied):
-    """``(loss, (d hidden, d weights, d bias))``, the gradients those of
-    a loss cotangent of 1."""
+def lm_head_loss_weighted(
+    hidden: jax.Array,
+    weights: jax.Array,
+    bias: Optional[jax.Array],
+    tokens: jax.Array,
+    dtype: Any,
+    tied: bool,
+    position_weights: jax.Array,
+) -> tuple[jax.Array, jax.Array]:
+    """:func:`lm_head_loss` with each position's cross-entropy weighed
+    by ``position_weights`` ``(B, T)``: ``(loss, CE)``, the loss
+    ``sum(w * CE) / positions`` (``positions`` the ``B x (T - 1)`` that
+    have a next token), differentiable in ``w`` too (its gradient ``CE /
+    positions``), and ``CE`` the masked ``(B, T)`` cross-entropies
+    themselves, 0 at each sequence's last position. ``CE`` is a reading,
+    for a caller to count: its gradient is stopped, and the loss is what
+    trains."""
+    loss, ce = _weighted(hidden, weights, bias, tokens, position_weights, dtype, tied)
+    return loss, jax.lax.stop_gradient(ce)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _weighted(hidden, weights, bias, tokens, position_weights, dtype, tied):
+    return _weighted_forward(hidden, weights, bias, tokens, position_weights, dtype, tied)[0]
+
+
+def _walk(hidden, weights, bias, tokens, dtype, tied, position_weights=None):
+    """``(loss, (d hidden, d weights, d bias), CE)``, the gradients those
+    of a loss cotangent of 1 and ``CE`` each position's cross-entropy
+    ``(B x T,)``, unmasked, where ``position_weights`` is given (else
+    ``None``)."""
     b, t, d = hidden.shape
     rows, vocab = b * t, weights.shape[0 if tied else 1]
     blocks = num_blocks(rows, vocab)
     by_block = (rows,) if blocks == 1 else (blocks, rows // blocks)
+    weighted = position_weights is not None
     with jax.named_scope(SCOPE_HEAD):
         x = hidden.reshape(*by_block, d).astype(dtype)
         w = weights.astype(dtype)
@@ -90,7 +121,10 @@ def _walk(hidden, weights, bias, tokens, dtype, tied):
         targets = jnp.roll(tokens, -1, axis=1).reshape(by_block)
         # lm_loss_mean's weights with its denominator folded in
         per_position = (jnp.arange(t) < t - 1).astype(jnp.float32) / ((t - 1) * b)
-        scale = jnp.broadcast_to(per_position, (b, t)).reshape(by_block)
+        scale = jnp.broadcast_to(per_position, (b, t))
+        if weighted:
+            scale = scale * position_weights.astype(jnp.float32)
+        scale = scale.reshape(by_block)
     # contracting dimensions of rows x weights, gradient x weights, rows x gradient
     out_dims, in_dims = (((1,), (1,)), ((1,), (0,))) if tied else (((1,), (0,)), ((1,), (1,)))
     product = partial(jax.lax.dot_general, preferred_element_type=jnp.float32)
@@ -107,7 +141,8 @@ def _walk(hidden, weights, bias, tokens, dtype, tied):
             top = jnp.max(logits, axis=-1, keepdims=True)
             lse = top + jnp.log(jnp.sum(jnp.exp(logits - top), axis=-1, keepdims=True))
             at_target = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
-            loss = loss + jnp.sum((lse[:, 0] - at_target) * weight)
+            per_token = lse[:, 0] - at_target
+            loss = loss + jnp.sum(per_token * weight)
             grad = ((jnp.exp(logits - lse) - hit) * weight[:, None]).astype(dtype)
         with jax.named_scope(SCOPE_HEAD):
             d_x = product(grad, w, (in_dims, ((), ()))).astype(hidden.dtype)
@@ -115,7 +150,7 @@ def _walk(hidden, weights, bias, tokens, dtype, tied):
             d_weights = d_weights + product(*pair, (((0,), (0,)), ((), ())))
             if bias is not None:
                 d_bias = d_bias + jnp.sum(grad, axis=0, dtype=jnp.float32)
-        return (loss, d_weights, d_bias), d_x
+        return (loss, d_weights, d_bias), (d_x, per_token if weighted else None)
 
     with jax.named_scope(SCOPE_HEAD):
         zeros = (
@@ -124,10 +159,15 @@ def _walk(hidden, weights, bias, tokens, dtype, tied):
             None if bias is None else jnp.zeros(bias.shape, jnp.float32),
         )
     walk = block if blocks == 1 else partial(jax.lax.scan, block)
-    (loss, d_weights, d_bias), d_x = walk(zeros, (x, targets, scale))
+    (loss, d_weights, d_bias), (d_x, ce) = walk(zeros, (x, targets, scale))
     with jax.named_scope(SCOPE_HEAD):
         as_given = lambda g, like: None if like is None else g.astype(like.dtype)
         gradients = d_x.reshape(b, t, d), as_given(d_weights, weights), as_given(d_bias, bias)
+    return loss, gradients, ce
+
+
+def _forward(hidden, weights, bias, tokens, dtype, tied):
+    loss, gradients, _ = _walk(hidden, weights, bias, tokens, dtype, tied)
     return loss, gradients
 
 
@@ -139,4 +179,23 @@ def _backward(dtype, tied, gradients, cotangent):
     return d_hidden, d_weights, d_bias, None
 
 
-lm_head_loss.defvjp(_walk, _backward)
+def _weighted_forward(hidden, weights, bias, tokens, position_weights, dtype, tied):
+    loss, gradients, ce = _walk(hidden, weights, bias, tokens, dtype, tied, position_weights)
+    b, t = tokens.shape
+    with jax.named_scope(SCOPE_LOSS):
+        ce = ce.reshape(b, t) * (jnp.arange(t) < t - 1)
+        d_position_weights = (ce / ((t - 1) * b)).astype(position_weights.dtype)
+    return (loss, ce), (gradients, d_position_weights)
+
+
+def _weighted_backward(dtype, tied, residuals, cotangents):
+    gradients, d_position_weights = residuals
+    cotangent, _ = cotangents  # CE's: zero, its gradient is stopped where it is handed out
+    d_hidden, d_weights, d_bias, d_tokens = _backward(dtype, tied, gradients, cotangent)
+    with jax.named_scope(SCOPE_HEAD):
+        d_position_weights = (cotangent * d_position_weights).astype(d_position_weights.dtype)
+    return d_hidden, d_weights, d_bias, d_tokens, d_position_weights
+
+
+lm_head_loss.defvjp(_forward, _backward)
+_weighted.defvjp(_weighted_forward, _weighted_backward)

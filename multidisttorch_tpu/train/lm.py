@@ -23,11 +23,12 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from multidisttorch_tpu.ops.head_loss import lm_head_loss
+from multidisttorch_tpu.ops.head_loss import lm_head_loss, lm_head_loss_weighted
 from multidisttorch_tpu.parallel import mesh
 from multidisttorch_tpu.parallel.mesh import DATA_AXIS, TrialMesh
 from multidisttorch_tpu.train.steps import TrainState
 from multidisttorch_tpu.utils.profiling import (
+    SCOPE_LOOP_EXIT,
     SCOPE_LOSS,
     SCOPE_OPTIMIZER,
     SPAN_INIT_OPT,
@@ -121,6 +122,17 @@ def _sample_token(logits, rng, temperature, top_k, top_p):
     return jax.random.categorical(sub, logits, axis=-1), rng
 
 
+def next_token_nll(logits: jax.Array, tokens: jax.Array) -> jax.Array:
+    """Each position's cross-entropy against the next token: logits
+    ``(..., B, T, V)``, ``tokens`` ``(B, T)``, the result ``(..., B,
+    T)`` float32. The last position's target wraps around the roll: the
+    caller masks it."""
+    targets = jnp.roll(tokens, -1, axis=1)
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    targets = jnp.broadcast_to(targets[..., None], (*logp.shape[:-1], 1))
+    return -jnp.take_along_axis(logp, targets, axis=-1)[..., 0]
+
+
 def lm_loss_mean(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     """Mean next-token cross-entropy; the last position is masked (its
     target would wrap around the roll).
@@ -132,9 +144,7 @@ def lm_loss_mean(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     loss and gradients made without the ``(B, T, V)`` logits, which
     ``tests/test_head_loss.py`` holds to this function differentiated
     through a float32 head."""
-    targets = jnp.roll(tokens, -1, axis=1)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    nll = next_token_nll(logits, tokens)
     t = tokens.shape[1]
     w = (jnp.arange(t) < t - 1).astype(jnp.float32)[None, :]
     return jnp.sum(nll * w) / jnp.sum(w) / tokens.shape[0]
@@ -209,7 +219,11 @@ def make_lm_train_step(
     stage), and any trial over several devices (a walk over blocks of
     rows of a batch- or sequence-sharded array would make GSPMD gather
     it), is asked for ``(B, T, V)`` logits and :func:`lm_loss_mean` is
-    differentiated through them."""
+    differentiated through them. A model whose blocks run several times
+    a token (one that offers ``exit_log_probs``, ``models/looped.py``)
+    trains on the loss of every pass weighted by its exit probability
+    (:func:`_looped_loss`), on either path, and its counters ``exit_p``
+    and ``loop_loss`` come out beside the loss."""
     repl, tokens_sh, _, state_sh = _lm_shardings(
         trial, sequence_parallel, shardings
     )
@@ -233,6 +247,8 @@ def _build_lm_step_fn(model, tx, aux_loss_weight):
         walk = hasattr(model, "head_weights") and (placed is None or placed[1] == 1)
 
         def loss_fn(params):
+            if hasattr(model, "exit_log_probs"):
+                return _looped_loss(model, params, tokens, walk)
             if walk:
                 out = model.apply({"params": params}, tokens, head=False)
                 weights, bias, tied = model.head_weights(params)
@@ -262,6 +278,49 @@ def _build_lm_step_fn(model, tx, aux_loss_weight):
         )
 
     return step_fn
+
+
+def _looped_loss(model, params, tokens, walk):
+    """``(loss, counters)`` of a model whose blocks run several times a
+    token (``models/looped.py``), which hands back ``(U, B, T, d)`` states,
+    or ``(U, B, T, V)`` logits, and its exit gates' ``(U, B, T)`` logits:
+    the expected next-token loss over the exit distribution minus
+    ``model.exit_entropy_weight`` times the distribution's entropy, the
+    mean over the positions that have a next token of ``sum_t p_t CE_t +
+    beta sum_t p_t log p_t``. On the walk the U states are stacked along
+    the batch and walked once, each position weighted by its loop's
+    ``p``: one accumulated gradient of the head's weights. Counters: ``exit_p`` and
+    ``loop_loss``, the mean ``p_t`` and the mean cross-entropy of each
+    loop, ``(U,)``."""
+    out, gate_logits = model.apply({"params": params}, tokens, head=not walk)
+    loops, b, t = gate_logits.shape
+    positions = b * (t - 1)
+    has_next = (jnp.arange(t) < t - 1).astype(jnp.float32)
+    with jax.named_scope(SCOPE_LOOP_EXIT):
+        log_p = model.exit_log_probs(gate_logits)
+        p = jnp.exp(log_p)
+    if walk:
+        weights, bias, tied = model.head_weights(params)
+        # the walk divides by all U x B x (T - 1) positions it is given, so
+        # its weights are U p: the loss's cotangent stays 1 and nothing of
+        # the walk's size is multiplied again in the backward pass
+        expected, ce = lm_head_loss_weighted(
+            out.reshape(loops * b, t, -1), weights, bias, jnp.tile(tokens, (loops, 1)),
+            model.dtype, tied, (loops * p).reshape(loops * b, t),
+        )
+        ce = ce.reshape(loops, b, t)
+    else:
+        with jax.named_scope(SCOPE_LOSS):
+            ce = next_token_nll(out, tokens) * has_next
+            expected = jnp.sum(p * ce) / positions
+    with jax.named_scope(SCOPE_LOOP_EXIT):
+        negentropy = jnp.sum(p * log_p * has_next) / positions
+        loss = expected + model.exit_entropy_weight * negentropy
+        counters = {
+            "exit_p": jnp.sum(p * has_next, axis=(1, 2)) / positions,
+            "loop_loss": jnp.sum(ce, axis=(1, 2)) / positions,
+        }
+    return loss, counters
 
 
 def make_lm_multi_step(

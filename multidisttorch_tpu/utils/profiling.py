@@ -89,6 +89,12 @@ SCOPE_HEAD = "head"
 SCOPE_CONV_PROJ = "conv_proj"
 SCOPE_CONV_MIX = "conv_mix"
 SCOPE_QK_NORM = "qk_norm"
+# The looped model (``models/looped.py``), whose blocks run several times
+# a token: each pass over the blocks runs under ``loop_<t>`` (t from 0),
+# outside every name above; the exit gate, and in ``train/lm.py`` the exit
+# distribution and its entropy, under ``loop_exit``.
+SCOPE_LOOP = "loop_{}"
+SCOPE_LOOP_EXIT = "loop_exit"
 
 # Host spans of a trial's admission (:func:`span`), each opened where
 # the work is done: ``parallel/mesh.py::setup_groups``; the whole of

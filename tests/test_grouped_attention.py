@@ -65,6 +65,32 @@ def test_grouped_kernels_match_the_masked_dense_form(group, window):
         assert _rel(have, need) < 1e-5
 
 
+def test_one_query_head_a_kv_head_with_q_rotated_in_the_kernels():
+    """Heads 128 wide, as many KV heads as query heads, no window, q
+    handed over unrotated with its angles (the looped model's blocks):
+    forward and the gradients of q, k and v against the dense form of q
+    rotated."""
+    q, k, v, co = _operands(512, 4, 4, seed=5)
+    angle = rope_angles(jnp.arange(512), 1e6, 128)
+    rotation = jnp.cos(angle), jnp.sin(angle)
+
+    def kernel(q, k, v):
+        out = grouped_attention(q, k, v, q_rotation=rotation, block=128)
+        return jnp.sum(out * co), out
+
+    def dense(q, k, v):
+        out = _dense(rope_halves(q, *rotation), k, v, None)
+        return jnp.sum(out * co), out
+
+    with jax.default_matmul_precision("highest"):
+        (_, got), got_grads = jax.value_and_grad(kernel, (0, 1, 2), has_aux=True)(q, k, v)
+        (_, want), want_grads = jax.value_and_grad(dense, (0, 1, 2), has_aux=True)(q, k, v)
+    assert _rel(got, want) < 1e-5
+    for have, need in zip(got_grads, want_grads, strict=True):
+        assert _rel(have, need) < 1e-5
+    assert grouped_takes_kernel("TPU v5 lite", 1, 4096, 16, 16, 128, rotates_q=True)
+
+
 def test_group_of_one_gives_the_flash_kernel_s_answers():
     """One KV head a query head and no window is what
     ``flash_attention`` computes."""

@@ -19,7 +19,7 @@ from multidisttorch_tpu.models.latent_moe import LatentMoELM
 from multidisttorch_tpu.models.ssm_hybrid import SambaYLM
 from multidisttorch_tpu.models.transformer import MoETransformerLM, TransformerLM
 from multidisttorch_tpu.ops import head_loss
-from multidisttorch_tpu.ops.head_loss import lm_head_loss, num_blocks
+from multidisttorch_tpu.ops.head_loss import lm_head_loss, lm_head_loss_weighted, num_blocks
 from multidisttorch_tpu.parallel.mesh import setup_groups
 from multidisttorch_tpu.train.lm import create_lm_state, lm_loss_mean, make_lm_train_step
 
@@ -108,6 +108,57 @@ def test_bf16_operands_are_the_products_roundings_and_no_other(blocks):
         np.testing.assert_allclose(
             got.astype(jnp.float32), wanted.astype(jnp.float32), atol=2**-7 * scale
         )
+
+
+def _weighted_defined(hidden, weights, bias, tokens, tied, position_weights):
+    """``(sum(w * CE) / positions, CE)`` through a plain float32 head,
+    ``CE`` 0 at each sequence's last position."""
+    logits = jnp.einsum(
+        "btd,vd->btv" if tied else "btd,dv->btv", hidden, weights, precision="highest"
+    )
+    logp = jax.nn.log_softmax(logits if bias is None else logits + bias, axis=-1)
+    targets = jnp.roll(tokens, -1, axis=1)
+    ce = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0] * (jnp.arange(T) < T - 1)
+    return jnp.sum(ce * position_weights) / (B * (T - 1)), ce
+
+
+@pytest.mark.parametrize("kind", ["bias", "no-bias", "tied"])
+@pytest.mark.parametrize("blocks", [1, 2, 4], indirect=True)
+def test_weighted_walk_is_the_weighted_sum_through_a_float32_head(blocks, kind):
+    """Per-position weights: the value, ``d hidden``, ``d head weights``,
+    ``d bias`` and ``d w`` (``CE / positions``) are those of
+    ``sum(w * CE) / positions`` differentiated through a float32 head,
+    and the cross-entropies handed back are ``CE``; weights of 1 give
+    the unweighted walk."""
+    hidden, weights, bias, tokens = _operands(kind)
+    tied = kind == "tied"
+    position_weights = jnp.asarray(np.random.default_rng(4).uniform(0, 2, (B, T)), jnp.float32)
+
+    def walk(h, w, b, pw):
+        return lm_head_loss_weighted(h, w, b, tokens, jnp.float32, tied, pw)
+
+    operands = (hidden, weights, bias, position_weights)
+    loss, ce = jax.jit(walk)(*operands)
+    grads = jax.jit(jax.grad(lambda *a: walk(*a)[0], argnums=(0, 1, 2, 3)))(*operands)
+    (want, want_ce), want_grads = jax.value_and_grad(
+        lambda h, w, b, pw: _weighted_defined(h, w, b, tokens, tied, pw),
+        argnums=(0, 1, 2, 3), has_aux=True,
+    )(hidden, weights, bias, position_weights)
+    np.testing.assert_allclose(loss, want, rtol=1e-6)
+    np.testing.assert_allclose(ce, want_ce, rtol=1e-5, atol=1e-6)
+    assert (grads[2] is None) == (kind != "bias")
+    for got, wanted in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads), strict=True):
+        assert got.shape == wanted.shape and got.dtype == wanted.dtype
+        assert float(jnp.abs(wanted).max()) > 1e-4
+        np.testing.assert_allclose(got, wanted, rtol=1e-5, atol=1e-6)
+    # the cross-entropies are a reading: nothing flows back through them
+    through_ce = jax.grad(lambda *a: jnp.sum(walk(*a)[1]), argnums=(0, 1, 3))(*operands)
+    assert not any(jnp.any(g) for g in through_ce)
+    unweighted = jax.jit(lambda h, w, b: lm_head_loss(h, w, b, tokens, jnp.float32, tied))
+    np.testing.assert_allclose(
+        walk(hidden, weights, bias, jnp.ones((B, T)))[0], unweighted(hidden, weights, bias),
+        rtol=1e-6,
+    )
 
 
 def test_blocks_come_from_the_shapes():

@@ -837,3 +837,100 @@ def test_head_and_loss_of_the_walk_reach_the_compiled_step(monkeypatch, name, bl
     # rows x weights, gradient x weights, rows x gradient: the head's three products
     dots = re.findall(r' dot\([^\n]*op_name="([^"]*)"', text)
     assert sum(_components(n)[-2:] == ["head", "dot_general"] for n in dots) == 3
+
+
+# --- the looped model (models/looped.py) ---
+
+
+def _lowered_looped(remat, t=16, placed=False, **fields):
+    """``LoopedLM``'s step lowered for ``(1, t)`` tokens: 2 layers run 4
+    times; ``placed`` as :func:`_lowered_latent`."""
+    from multidisttorch_tpu.models.looped import LoopedLM
+
+    (group,) = setup_groups(1, devices=jax.devices()[:1])
+    model = LoopedLM(**{"vocab_size": 64, "max_len": t, "remat": remat, **fields})
+    tx = optax.adam(1e-3)
+    tokens = jax.ShapeDtypeStruct((1, t), jnp.int32)
+    params = jax.eval_shape(
+        model.init, {"params": jax.random.key(0)}, jnp.zeros((1, t), jnp.int32)
+    )["params"]
+    state = jax.eval_shape(
+        lambda p: TrainState(params=p, opt_state=tx.init(p), step=jnp.zeros((), jnp.int32)),
+        params,
+    )
+    if placed:
+        on = lambda tree, sharding: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree
+        )
+        state, tokens = on(state, group.replicated_sharding), on(tokens, group.batch_sharding)
+    return make_lm_train_step(group, model, tx).lower(state, tokens), params
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_looped_scopes_reach_the_compiled_step(remat):
+    """``loop_exit`` in the compiled tiny looped step (the plain path:
+    the CPU), in both passes: the gate after every pass (under its
+    ``loop_<t>``), the exit distribution and its entropy; every pass's
+    blocks under their ``loop_<t>``; the two norms after the sublayers
+    counted as norms; the step's other names as the other models'."""
+    from benchmark import loop_scopes, scope_reduce
+    from multidisttorch_tpu.utils.profiling import SCOPE_LOOP, SCOPE_LOOP_EXIT
+
+    lowered, _ = _lowered_looped(remat)
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    step = [n for n in names if n.startswith("jit(step_fn)")]
+    assert len(step) > 400
+    loops = [SCOPE_LOOP.format(t) for t in range(4)]
+    under = [n for n in step if loop_scopes.classify(n) == SCOPE_LOOP_EXIT]
+    assert {_pass(n) for n in under} >= {"forward", "backward"}
+    gate = [n for n in under if "exit_gate" in _components(n)]
+    assert {c for n in gate for c in _components(n) if c.startswith("loop_")} >= set(loops)
+    assert any("exit_gate" not in _components(n) for n in under)  # the distribution, the entropy
+    assert {scope_reduce.classify(n)[0] for n in under} == {"unscoped"}
+    for loop in loops:
+        cores = [n for n in step if loop in _components(n)
+                 and scope_reduce.classify(n)[0] == "attn_core"]
+        assert {c for n in cores for c in _components(n) if c.startswith("block_")} == {
+            "block_0", "block_1"}, loop
+    post = [n for n in step if {"ln_attn_out", "ln_mlp_out"} & set(_components(n))]
+    assert post and {scope_reduce.classify(n)[0] for n in post} == {"norm"}
+    parts = {scope_reduce.classify(n)[0] for n in step}
+    assert {"attn_core", "attn_proj", "mlp", "norm", "embed", "head", "loss", "optimizer"} <= parts
+    unscoped = [n for n in step if scope_reduce.classify(n)[0] == "unscoped"
+                and loop_scopes.classify(n) is None]
+    assert len(unscoped) / len(step) < UNRECOGNISED_BOUND, sorted(set(unscoped))[:20]
+
+
+def test_looped_scopes_stay_out_of_the_parameter_tree():
+    _, params = _lowered_looped(True)
+    assert set(params) == {"tok_embed", "ln_out", "exit_gate", "head", "block_0", "block_1"}
+    assert set(params["block_0"]) == {
+        "ln_attn", "q", "k", "v", "proj", "ln_attn_out", "ln_mlp", "gate", "up", "down",
+        "ln_mlp_out",
+    }
+    assert set(params["exit_gate"]) == {"kernel", "bias"}
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_looped_kernels_are_under_attn_core_once_a_pass(as_v5e, remat):
+    """With the operands placed as a trial's and the device a v5e by
+    name, 2 query heads over 2 KV heads of 128 lower the grouped kernel
+    pair under ``attn_core`` in every loop, forward and backward, none
+    in the recomputed block; there q, k and v are kept (``SAVED_QKV``),
+    so their products run forward and backward only, and the MLP's
+    first half and ``proj`` are made again."""
+    from benchmark import scope_reduce
+
+    lowered, _ = _lowered_looped(remat, t=256, placed=True, d_model=256, num_heads=2,
+                                 num_kv_heads=2, head_dim=128)
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    step = [n for n in names if n.startswith("jit(step_fn)")]
+    for call, where in (("jit(_grouped_fwd_call)", "forward"),
+                        ("jit(_grouped_bwd_call)", "backward")):
+        under = [n for n in step if call in n.split("/")]
+        assert {scope_reduce.classify(n) for n in under} == {("attn_core", where)}, call
+        assert {c for n in under for c in _components(n) if c.startswith("loop_")} == {
+            f"loop_{t}" for t in range(4)}
+    again = {c for n in step if _pass(n) == "recompute" for c in _components(n)}
+    assert not {"q", "k", "v"} & again  # nor a kernel: both calls read forward and backward above
+    assert ({"gate", "up", "proj"} <= again) == remat
