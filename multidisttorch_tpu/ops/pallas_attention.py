@@ -56,9 +56,14 @@ What runs where:
   tile for all the query heads of a group against the group's one K/V
   block, fetched once for them; the tiles are listed at trace time
   (:func:`_visits`): only those that hold a pair with ``j <= i`` and
-  ``i - j < window``, the ones wholly inside unmasked, the diagonal
-  and the window's far edge masked, nothing outside fetched or
-  multiplied, in the one fused backward kernel as well. K and V come
+  ``i - j < window``, nothing outside fetched or multiplied, in the
+  one fused backward kernel as well. A tile wholly inside runs whole
+  and unmasked; the diagonal, and the far edge of a window of whole
+  blocks, run in sub-steps of queries, each against only the keys its
+  queries keep (:func:`_sub_steps`: the diagonal as the ``(q, k, v)``
+  kernels walk theirs, the far edge its mirror), so the triangle each
+  keeps is all that is multiplied; the far edge of any other window
+  runs whole under the mask. K and V come
   by the block, so T = 16,384 compiles (the forward's VMEM does not
   grow with T; the backward keeps one KV head's float32 dK and dV, 1.5
   KB a token with their outgoing copies). q of a window layer is
@@ -1104,7 +1109,12 @@ def _visits(t: int, blk: int, window: int | None):
     flag word: first and last visit of the query block, and whether the
     tile needs the mask (the diagonal, and the window's far edge).
     Static: numpy at trace time. Nothing outside these tiles is fetched
-    or multiplied, in either pass."""
+    or multiplied, in either pass. A masked visit the 128-wide pair
+    runs in sub-steps of queries that multiply only the part of the
+    tile holding kept pairs (:func:`_sub_steps`; on the diagonal each
+    sub-step's keys run up to its queries' own, so the order above
+    holds a sub-step at a time); the 64-wide pair multiplies it whole
+    under :func:`_kept`."""
     q_of, k_of, flags = [], [], []
     for i in range(t // blk):
         lo = 0 if window is None else max(0, (i * blk - window + 1) // blk)
@@ -1123,6 +1133,87 @@ def _kept(i, j, blk: int, window: int | None, keys_axis: int):
     key = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), keys_axis)
     ahead = (i - j) * blk + query - key
     return ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
+
+
+def _grouped_sub(blk: int) -> int:
+    """Queries a sub-step of a masked tile of the 128-wide grouped pair
+    takes, from the block edge: a quarter of it, 128 at the least
+    (raced on a TPU v5e at block 1,024, forward + backward, ms a layer:
+    a window layer of 4,096 at T 16,384, 28 heads over 4, 26.48 whole,
+    25.75 in steps of 512, 23.19 in steps of 256; a full layer 49.11,
+    45.79, 45.36; PERF.md section 6)."""
+    return max(blk // 4, _LANES)
+
+
+def _far_edge(s, n, keys_axis):
+    """Mask the queries' own ``n`` keys one window back, the first ``n``
+    along ``keys_axis`` of ``s``, to key > query (the mirror of
+    :func:`_triangle`)."""
+    key = jax.lax.broadcasted_iota(jnp.int32, (n, n), keys_axis)
+    query = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1 - keys_axis)
+    if s.shape[keys_axis] == n:
+        return jnp.where(key > query, s, _NEG_INF)
+    own, beyond = jnp.split(s, [n], axis=keys_axis)
+    return jnp.concatenate([jnp.where(key > query, own, _NEG_INF), beyond], axis=keys_axis)
+
+
+def _sub_steps(blk: int, window: int | None, diagonal: bool):
+    """How the 128-wide pair runs a masked visit of :func:`_visits`:
+    ``(steps, own)``, the sub-steps ``(at, n, lo, hi)`` (the tile's
+    queries ``at..at+n`` against its keys ``lo..hi``) and what masks the
+    ``n`` keys that are the queries' own. On the diagonal each step of
+    ``_grouped_sub(blk)`` queries meets the keys up to its own, the last
+    ``n`` under :func:`_triangle`, as ``_walk`` steps the flash pair's;
+    at the far edge of a window of whole blocks, which keeps key >
+    query, the mirror: the keys from its own on, the first ``n`` under
+    :func:`_far_edge`. Nothing a step leaves out holds a kept pair.
+    ``None`` where the mask is not one triangle (a window shorter than a
+    block, or not whole blocks, at its edge): the whole tile under
+    :func:`_kept`."""
+    sub = _grouped_sub(blk)
+    if diagonal and (window is None or window >= blk):
+        return [(at, sub, 0, at + sub) for at in range(0, blk, sub)], _triangle
+    if not diagonal and window is not None and window % blk == 0:
+        return [(at, sub, at, blk) for at in range(0, blk, sub)], _far_edge
+    return None
+
+
+def _tile_work(t: int, blk: int, window: int | None) -> float:
+    """The (query, key) products the 128-wide pair computes for one
+    query head, in whole tiles: what :func:`_visits` lists, each masked
+    visit as :func:`_sub_steps` runs it."""
+    q_of, k_of, flags = _visits(t, blk, window)
+    work = 0.0
+    for i, j, f in zip(q_of, k_of, flags):
+        plan = _sub_steps(blk, window, i == j) if f & _MASKED else None
+        work += 1.0 if plan is None else sum(n * (hi - lo) for _, n, lo, hi in plan[0]) / blk**2
+    return work
+
+
+def _grouped_plan(i, j, blk: int, window: int | None, masked: bool, diagonal: bool,
+                  keys_axis: int):
+    """A visit of the 128-wide pair as ``(steps, own)`` (:func:`_sub_steps`):
+    an unmasked tile one step whole and no mask; a masked one in its
+    sub-steps, or whole under :func:`_kept`."""
+    whole = [(0, blk, 0, blk)]
+    if not masked:
+        return whole, None
+    plan = _sub_steps(blk, window, diagonal)
+    if plan is not None:
+        return plan
+    keep = _kept(i, j, blk, window, keys_axis)
+    return whole, lambda s, n, axis: jnp.where(keep, s, _NEG_INF)
+
+
+def _grouped_visit(tile, i, j, flags, window: int | None):
+    """Run ``tile(masked, diagonal)`` for the visit's kind: an unmasked
+    tile, the diagonal (always a query block's first visit) or, under a
+    window, its far edge."""
+    masked = (flags & _MASKED) != 0
+    pl.when(jnp.logical_not(masked))(lambda: tile(False, False))
+    pl.when(masked & (i == j))(lambda: tile(True, True))
+    if window is not None:
+        pl.when(masked & (i != j))(lambda: tile(True, False))
 
 
 def _rotated_halves(x, cos_ref, sin_ref):
@@ -1169,21 +1260,22 @@ def _grouped_fwd_kernel(q_of, k_of, flags_of, *refs, scale, window, g, blk, rota
 
         jax.lax.fori_loop(0, g, head, 0)
 
-    def tile(masked: bool):
+    def tile(masked: bool, diagonal: bool):
         k, v_t = k_ref[0], _transposed(v_ref[0])  # (blk, 128), (128, blk)
-        keep = _kept(i, j, blk, window, 0) if masked else None
+        steps, own = _grouped_plan(i, j, blk, window, masked, diagonal, 0)
 
         def head(h, carry):
-            s = dot(k, q_t[h]) * scale  # (keys, queries) f32
-            if masked:
-                s = jnp.where(keep, s, _NEG_INF)
-            _softmax_step(s, v_t, h, slice(None), acc_t, m_sc, l_sc, dot)
+            for at, n, lo, hi in steps:
+                cols = slice(at, at + n)
+                s = dot(k[lo:hi], q_t[h, :, cols]) * scale  # (keys, queries) f32
+                if own is not None:
+                    s = own(s, n, 0)
+                _softmax_step(s, v_t[:, lo:hi], h, cols, acc_t, m_sc, l_sc, dot)
             return carry
 
         jax.lax.fori_loop(0, g, head, 0)
 
-    pl.when((flags & _MASKED) != 0)(lambda: tile(True))
-    pl.when((flags & _MASKED) == 0)(lambda: tile(False))
+    _grouped_visit(tile, i, j, flags, window)
     pl.when((flags & _LAST) != 0)(lambda: _finish_softmax(g, acc_t, m_sc, l_sc, o_ref, lse_ref))
 
 
@@ -1233,27 +1325,29 @@ def _grouped_bwd_kernel(q_of, k_of, flags_of, *refs, scale, window, g, blk, rota
 
         jax.lax.fori_loop(0, g, head, 0)
 
-    def tile(masked: bool):
-        k, v = k_ref[0], v_ref[0]  # (blk, 128)
-        keep = _kept(i, j, blk, window, 1) if masked else None
+    def tile(masked: bool, diagonal: bool):
+        k_all, v_all = k_ref[0], v_ref[0]  # (blk, 128)
+        steps, own = _grouped_plan(i, j, blk, window, masked, diagonal, 1)
 
         def head(h, carry):
             lanes = _head_at(h, _LANES)
-            s = dot(q_sc[:, lanes], k, _NT) * scale  # (queries, keys) f32
-            if masked:
-                s = jnp.where(keep, s, _NEG_INF)
-            p = jnp.exp(s - lse_sc[h])  # exact probabilities via saved lse
-            dp = dot(do_ref[0, :, lanes], v, _NT)
-            ds = (p * (dp - delta_sc[h])).astype(k.dtype)
-            dv_t[j] = dv_t[j] + dot(do_t[h], p.astype(v.dtype))
-            dk_t[j] = dk_t[j] + dot(q_t[h], ds)
-            dq_acc[:, lanes] = dq_acc[:, lanes] + dot(ds, k)
+            for at, n, lo, hi in steps:
+                rows, keys = slice(at, at + n), slice(lo, hi)
+                k, v = k_all[keys], v_all[keys]
+                s = dot(q_sc[rows, lanes], k, _NT) * scale  # (queries, keys) f32
+                if own is not None:
+                    s = own(s, n, 1)
+                p = jnp.exp(s - lse_sc[h, rows, :])  # exact probabilities via saved lse
+                dp = dot(do_ref[0, rows, lanes], v, _NT)
+                ds = (p * (dp - delta_sc[h, rows, :])).astype(k.dtype)
+                dv_t[j, :, keys] = dv_t[j, :, keys] + dot(do_t[h, :, rows], p.astype(v.dtype))
+                dk_t[j, :, keys] = dk_t[j, :, keys] + dot(q_t[h, :, rows], ds)
+                dq_acc[rows, lanes] = dq_acc[rows, lanes] + dot(ds, k)
             return carry
 
         jax.lax.fori_loop(0, g, head, 0)
 
-    pl.when((flags & _MASKED) != 0)(lambda: tile(True))
-    pl.when((flags & _MASKED) == 0)(lambda: tile(False))
+    _grouped_visit(tile, i, j, flags, window)
 
     @pl.when((flags & _LAST) != 0)
     def _emit_dq():
@@ -1414,12 +1508,13 @@ def grouped_attention(q, k, v, *, window: int | None = None, q_rotation=None,
     lanes of q against the group's one ``(block, 128)`` K and V block,
     fetched once for all of them. Only tiles that hold a kept pair are
     visited (:func:`_visits`): the ones wholly inside unmasked, the
-    diagonal and the window's far edge masked, the same list in the one
-    fused backward kernel, where dK and dV of a K/V block gather from
-    the query blocks within reach. K and V come by the block, so the
-    forward's VMEM does not grow with T; the backward keeps a KV
-    head's float32 dK and dV and their outgoing copies, 1.5 KB a
-    token (24 MiB at T = 16,384).
+    diagonal and the window's far edge in sub-steps of queries that
+    multiply only what the mask keeps (:func:`_sub_steps`), the same
+    list in the one fused backward kernel, where dK and dV of a K/V
+    block gather from the query blocks within reach. K and V come by
+    the block, so the forward's VMEM does not grow with T; the backward
+    keeps a KV head's float32 dK and dV and their outgoing copies, 1.5
+    KB a token (24 MiB at T = 16,384).
 
     ``q_rotation = (cos, sin)``, ``(T, 64)`` float32 each: q comes
     unrotated and the kernels rotate each head's block as they load it,

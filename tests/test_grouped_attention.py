@@ -65,6 +65,118 @@ def test_grouped_kernels_match_the_masked_dense_form(group, window):
         assert _rel(have, need) < 1e-5
 
 
+@pytest.fixture
+def sub_of(monkeypatch):
+    """Set the 128-wide pair's sub-step to a share of the block edge,
+    with jax's caches emptied around it (the kernels' calls are jitted
+    on the shapes alone)."""
+    from multidisttorch_tpu.ops import pallas_attention
+
+    def use(share):
+        monkeypatch.setattr(pallas_attention, "_grouped_sub", lambda blk: blk // share)
+
+    jax.clear_caches()
+    yield use
+    jax.clear_caches()
+
+
+# (window, query heads a KV head, q rotated in the kernels, sub-step as a share of
+# the block edge of 512): a full layer, a window of whole blocks (its far edge
+# keeps key > query), a window of blocks and a part and one shorter than a block
+# (their far edges run whole under the mask); 1 and 7 heads a KV head; both
+# candidate sub-steps, half and a quarter of the block.
+@pytest.mark.parametrize("window, group, rotated, share", [
+    (None, 1, True, 2), (None, 7, False, 4), (None, 7, True, 2),
+    (1024, 7, True, 2), (1024, 7, True, 4), (1024, 1, False, 4), (1024, 1, True, 2),
+    (700, 7, True, 4), (700, 1, False, 2), (300, 7, False, 2),
+], ids=lambda x: str(x))
+def test_sub_stepped_masked_tiles_match_the_plain_path(sub_of, window, group, rotated, share):
+    """The 128-wide pair with its diagonal and far-edge tiles walked in
+    sub-steps of queries, T = 1,536 as three blocks of 512: the output,
+    the logsumexp and the gradients of q, k and v against
+    ``blocked_window_attention`` and the masked dense scores."""
+    from multidisttorch_tpu.ops import pallas_attention
+
+    sub_of(share)
+    t, blk, hkv = 1536, 512, 2 if group == 1 else 1
+    q, k, v, co = (x[:1] for x in _operands(t, group * hkv, hkv, seed=group + share))
+    angle = rope_angles(jnp.arange(t), 1e6, 128)
+    rotation = (jnp.cos(angle), jnp.sin(angle)) if rotated else None
+    q_in = rope_halves(q, *rotation) if rotated else q
+    loss = lambda fn: jax.value_and_grad(lambda q, k, v: jnp.sum(fn(q, k, v) * co), (0, 1, 2))
+    kernel = lambda q, k, v: grouped_attention(
+        q, k, v, window=window, q_rotation=rotation, block=blk)
+    plain = lambda q, k, v: blocked_window_attention(
+        rope_halves(q, *rotation) if rotated else q, k, v, window=window, block=256)
+    with jax.default_matmul_precision("highest"):
+        got = kernel(q, k, v), loss(kernel)(q, k, v)
+        want = plain(q, k, v), loss(plain)(q, k, v)
+        tables = None if rotation is None else pallas_attention._halves_tables(*rotation)
+        flat = lambda x: x.reshape(1, t, -1)
+        _, lse = pallas_attention._grouped_fwd_call(
+            flat(q), flat(k), flat(v), tables, 1 / math.sqrt(128), window, blk, True)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q_in, jnp.repeat(k, group, axis=2)) / math.sqrt(128)
+    ahead = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    keep = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
+    want_lse = jax.nn.logsumexp(jnp.where(keep, s, -jnp.inf), axis=-1).reshape(1, hkv, group, t)
+    assert _rel(lse, want_lse) < 1e-6
+    for have, need in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert _rel(have, need) < 1e-5
+
+
+def test_masked_tiles_cost_only_what_their_sub_steps_keep(sub_of):
+    """The (query, key) products a query head's visits compute, in whole
+    tiles, at the cells' shapes (block 1,024): ``moe-swa-t16384``'s
+    window layer (T 16,384, window 4,096: 16 diagonals and 12 far edges
+    of 70 tiles), its full layer (16 diagonals of 136) and
+    ``loop-ut4-t4096``'s layer (4 of 10), each masked tile at 3/4 of a
+    tile with steps of half a block and 5/8 with steps of a quarter,
+    the kernels' own."""
+    from multidisttorch_tpu.ops.pallas_attention import _tile_work
+
+    shapes = [(16384, 4096), (16384, None), (4096, None)]
+    assert [len(_visits_of(t, 1024, w)) for t, w in shapes] == [70, 136, 10]
+    assert [_tile_work(t, 1024, w) for t, w in shapes] == [59.5, 130.0, 8.5]
+    for share, work in ((2, [63.0, 132.0, 9.0]), (4, [59.5, 130.0, 8.5])):
+        sub_of(share)
+        assert [_tile_work(t, 1024, w) for t, w in shapes] == work
+    # a window of blocks and a part keeps its far edges whole: only the diagonals shrink
+    assert _tile_work(16384, 1024, 4000) == len(_visits_of(16384, 1024, 4000)) - 16 * 3 / 8
+
+
+def _visits_of(t, blk, window):
+    """The tiles that hold a kept pair, by brute force over each tile's
+    spread of ``query - key``: (query block, K/V block, flags), query
+    block by query block from the diagonal down, flags first (the
+    diagonal), last (the farthest) and masked (a pair not kept)."""
+    out = []
+    for i in range(t // blk):
+        row = []
+        for j in range(i, -1, -1):
+            low, high = (i - j) * blk - (blk - 1), (i - j) * blk + (blk - 1)
+            reach = high if window is None else min(high, window - 1)
+            if max(low, 0) <= reach:
+                whole = low >= 0 and (window is None or high < window)
+                row.append([i, j, 4 * (not whole)])
+        row[0][2] |= 1
+        row[-1][2] |= 2
+        out += [tuple(x) for x in row]
+    return out
+
+
+@pytest.mark.parametrize("t, blk, window", [
+    (16384, 512, 512), (16384, 512, None), (8192, 512, None), (1024, 128, 100), (1024, 128, 300),
+], ids=lambda x: str(x))
+def test_the_64_wide_pair_walks_the_tiles_it_walked(t, blk, window):
+    """``_visits``, which the 64-wide pair shares, lists the tiles and
+    flags of a brute-force walk at that pair's shapes
+    (``ssm-yoco-t16384``'s window of 512 and full layer,
+    ``moe-conv-t8192``'s layer) and at small ones."""
+    from multidisttorch_tpu.ops.pallas_attention import _visits
+
+    assert list(zip(*(x.tolist() for x in _visits(t, blk, window)))) == _visits_of(t, blk, window)
+
+
 def test_one_query_head_a_kv_head_with_q_rotated_in_the_kernels():
     """Heads 128 wide, as many KV heads as query heads, no window, q
     handed over unrotated with its angles (the looped model's blocks):
