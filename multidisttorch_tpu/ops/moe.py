@@ -239,8 +239,12 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 # makes them (``GroupedDot``).
 #
 # ``experts(lhs, rhs, sizes)``: rows ``sum(sizes[:g]) .. sum(sizes[:g+1])``
-# of ``lhs`` ``(M, K)`` times ``rhs[g]``, float32 accumulated and out;
-# rows past ``sum(sizes)`` hold whatever (the layer masks them).
+# of ``lhs`` ``(M, K)`` times ``rhs[g]``, accumulated in float32 and
+# handed on in the operands' dtype (``jnp.dot``'s rule): bf16 by bf16
+# gives bf16, rounded once from the float32 sum, and its cotangent
+# comes back bf16, so the backward products (megablox's ``gmm`` and
+# ``tgmm``, or XLA's ragged dots) run bf16 by bf16 too; rows past
+# ``sum(sizes)`` hold whatever (the layer masks them).
 #
 # ``token_sums(rows, weight, key, n)``: ``out[t] = sum of weight[r] *
 # rows[r] over the r with key[r] == t``, ``(M, d) -> (n, d)``; ``weight``
@@ -250,7 +254,7 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 class GroupedDot(NamedTuple):
-    experts: Callable  # (lhs (M, K), rhs (G, K, N), sizes (G,)) -> (M, N) float32
+    experts: Callable  # (lhs (M, K), rhs (G, K, N), sizes (G,)) -> (M, N), the operands' dtype
     token_sums: Callable  # (rows (M, d), weight (M,) or None, key (M,), n) -> (n, d)
 
 
@@ -388,14 +392,15 @@ def _segment_token_sums(rows, weight, key, n: int):
 
 
 def _ragged_experts(lhs, rhs, sizes):
-    return jax.lax.ragged_dot(lhs, rhs, sizes, preferred_element_type=jnp.float32)
+    return jax.lax.ragged_dot(lhs, rhs, sizes, preferred_element_type=jnp.result_type(lhs, rhs))
 
 
 def _kernel_experts(lhs, rhs, sizes):
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
+    # the kernel sums in a float32 tile and stores it in this dtype
     return gmm(
-        lhs, rhs, sizes, jnp.float32, (_TILE_ROWS, *_tiles(*rhs.shape[1:])),
+        lhs, rhs, sizes, jnp.result_type(lhs, rhs), (_TILE_ROWS, *_tiles(*rhs.shape[1:])),
         interpret=pallas_interpret(),
     )
 
@@ -608,12 +613,15 @@ class RoutedExperts(nn.Module):
             in_buffer = lambda at: jnp.clip(at - start, 0, rows)
             sizes = in_buffer(ends) - in_buffer(ends - counts)
             with jax.named_scope(SCOPE_EXPERTS):
-                # operands as they come (bf16), float32 accumulated and
-                # out; gate and up as one product, so xs is read once
+                # operands and results in the layer's dtype, accumulated
+                # in float32 (``GroupedDot``): at bf16 no float32 copy of
+                # the buffer's rows is written, forward or backward; gate
+                # and up as one product, so xs is read once; the
+                # activation in float32 inside its fusion
                 gate_up = grouped_dot.experts(xs, w_gate_up, sizes)
-                act = (act_fn(gate_up[:, :h]) * gate_up[:, h:]).astype(self.dtype)
+                f32 = lambda a: a.astype(jnp.float32)
+                act = (act_fn(f32(gate_up[:, :h])) * f32(gate_up[:, h:])).astype(self.dtype)
                 ys = grouped_dot.experts(act, w_down.astype(self.dtype), sizes)
-                ys = ys.astype(self.dtype)
             ys = jnp.where(valid[:, None], ys, 0)
             return _combine(grouped_dot, ys, tok, key, pair, weights)
 

@@ -9,6 +9,10 @@ the chip run's comparison uses. Everything here is float32 at
 numbers; nothing is timed.
 """
 
+import hashlib
+import os
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -342,23 +346,27 @@ def test_bf16_step_trains_and_counts():
     assert isinstance(state, TrainState)
 
 
-def test_kernel_grouped_dot_matches_xla_ragged_dot():
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_kernel_grouped_dot_matches_xla_ragged_dot(dtype):
     """The Pallas grouped matmul a one-chip TPU model runs for its
     experts (interpreter here) against XLA's ragged dot, forward and
     both gradients, with rows past the groups' sum left out of the
-    comparison as the layer leaves them out."""
+    comparison as the layer leaves them out. Both hand the product on
+    in the operands' dtype: at bf16 the float32 sum rounded once, and
+    the cotangent that comes back is bf16, so the backward products
+    run bf16 by bf16."""
     from multidisttorch_tpu.ops.moe import kernel_grouped_dot, ragged_grouped_dot
 
     ks = jax.random.split(jax.random.key(10), 3)
-    lhs = jax.random.normal(ks[0], (1024, 128), jnp.float32).astype(jnp.bfloat16)
-    rhs = jax.random.normal(ks[1], (4, 128, 256), jnp.float32).astype(jnp.bfloat16)
+    lhs = jax.random.normal(ks[0], (1024, 128), jnp.float32).astype(dtype)
+    rhs = jax.random.normal(ks[1], (4, 128, 256), jnp.float32).astype(dtype)
     co = jax.random.normal(ks[2], (1024, 256), jnp.float32)
     sizes = jnp.array([300, 0, 411, 100], jnp.int32)  # 811 of 1,024 rows; one empty group
     valid = (jnp.arange(1024) < 811)[:, None]
 
     def run(dot):
         def loss(lhs, rhs):
-            out = jnp.where(valid, dot(jnp.where(valid, lhs, 0), rhs, sizes), 0.0)
+            out = jnp.where(valid, dot(jnp.where(valid, lhs, 0), rhs, sizes), 0)
             return jnp.sum(out * co), out
 
         (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(lhs, rhs)
@@ -366,10 +374,15 @@ def test_kernel_grouped_dot_matches_xla_ragged_dot():
 
     out, (g_lhs, g_rhs) = run(kernel_grouped_dot.experts)
     want, (w_lhs, w_rhs) = run(ragged_grouped_dot.experts)
-    assert out.dtype == jnp.float32 and out.shape == (1024, 256)
+    assert out.dtype == want.dtype == dtype and out.shape == (1024, 256)
+    assert g_lhs.dtype == w_lhs.dtype == g_rhs.dtype == w_rhs.dtype == dtype
+    exact = jnp.where(valid, ragged_grouped_dot.experts(
+        lhs.astype(jnp.float32), rhs.astype(jnp.float32), sizes), 0)
+    _assert_rounded_once(out, exact, dtype)
+    _assert_rounded_once(want, exact, dtype)
     rel = lambda a, b: _rel(a.astype(jnp.float32), b.astype(jnp.float32))
-    assert rel(out, want) < 1e-5
-    assert rel(g_lhs, w_lhs) < 2e-2 and rel(g_rhs, w_rhs) < 2e-2  # bf16 cotangents
+    close = 2e-2 if dtype == jnp.bfloat16 else 1e-5  # bf16: cotangents and gradients rounded
+    assert rel(g_lhs, w_lhs) < close and rel(g_rhs, w_rhs) < close
     assert not jnp.any(g_rhs[1])  # the empty group's weights get no gradient
 
 
@@ -516,3 +529,85 @@ def test_nothing_is_gathered_to_every_slot(path):
     # walk: kernel calls, or scatter-adds
     assert kernels == (4 if path == "kernel" else 0)
     assert scatters >= (0 if path == "kernel" else 4)
+
+
+# --- the dtype between the experts' products ---
+
+# A layer whose buffer's shapes are each its own: n tokens choosing k of
+# 16 experts, 4 held, through M = 1,024 rows (twice a tile of the
+# experts' kernel), d wide, the experts h wide; the walk is in the trace.
+_STEP = {"n": 512, "k": 4, "d": 128, "h": 256}
+FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
+
+
+def _layer_step(dtype, path):
+    """The layer's forward and backward, lowered as text, and traced."""
+    from multidisttorch_tpu.ops import moe
+
+    n, k, d, h = (_STEP[name] for name in "nkdh")
+    dot = {"plain": moe.ragged_grouped_dot, "kernel": moe.kernel_grouped_dot}[path]
+    layer = RoutedExperts(
+        num_experts=16, experts_held=(4, 4), top_k=k, hidden_dim=h, dtype=dtype, grouped_dot=dot
+    )
+    params = jax.eval_shape(lambda: layer.init(jax.random.key(0), jnp.zeros((n, d), dtype)))
+    x = jax.ShapeDtypeStruct((n, d), dtype)
+    step = jax.value_and_grad(
+        lambda p, x: jnp.sum(layer.apply(p, x)[0].astype(jnp.float32)), argnums=(0, 1)
+    )
+    return jax.jit(step).lower(params, x).as_text(), jax.make_jaxpr(step)(params, x)
+
+
+def _step_digest(text):
+    """SHA-256 of the text less the numbers jax appends to its private
+    functions' names, which count the trace's equations."""
+    return hashlib.sha256(re.sub(r"@(\w+?)_\d+\b", r"@\1", text).encode()).hexdigest()
+
+
+def record_layer_step_digests():
+    """Write the float32 layer's digests: ``MDT_PALLAS_INTERPRET=1
+    JAX_PLATFORMS=cpu python -c "import tests.test_latent_moe as t;
+    t.record_layer_step_digests()"``."""
+    with open(os.path.join(FIXTURES, "routed_experts_f32_step.sha256"), "w") as f:
+        for path in ("plain", "kernel"):
+            f.write(f"{path} {_step_digest(_layer_step(jnp.float32, path)[0])}\n")
+
+
+@pytest.mark.parametrize("path", ["plain", "kernel"])
+def test_bf16_experts_hand_on_bf16_and_f32_is_the_program_before(path):
+    """At bf16 every grouped product of the layer, forward, recomputed
+    and backward, in the usual buffer and in the walk, takes bf16 and
+    gives bf16, and nothing under the experts' scope is a float32
+    ``(M, 2h)`` or ``(M, d)`` array: no float32 ``gate_up``, ``ys`` or
+    cotangent of either between the products. At float32 the lowered
+    layer is, character for character, the one it lowered to when the
+    products handed on float32 whatever their operands (its SHA-256,
+    taken from that checkout, is in
+    ``tests/fixtures/routed_experts_f32_step.sha256``; a change to the
+    float32 layer on purpose records the new one with
+    :func:`record_layer_step_digests`)."""
+    from multidisttorch_tpu.ops import moe
+
+    n, k, d, h = (_STEP[name] for name in "nkdh")
+    m = moe._buffer_rows(n, k, 4, 16)[0]
+    assert (m, moe._TILE_ROWS) == (1024, 512)
+    _, jaxpr = _layer_step(jnp.bfloat16, path)
+    kernels = ragged = 0
+    for eqn in _all_equations(jaxpr):
+        name = eqn.primitive.name
+        if name == "ragged_dot_general" or (name == "pallas_call" and eqn.params["name"] != "token_sums"):
+            kernels += name == "pallas_call"
+            ragged += name == "ragged_dot_general"
+            floats = [v.aval.dtype for v in (*eqn.invars, *eqn.outvars)
+                      if jnp.issubdtype(v.aval.dtype, jnp.floating)]
+            assert floats and all(t == jnp.bfloat16 for t in floats), eqn
+        if re.search(r"\bexperts\b", str(eqn.source_info.name_stack)):
+            for var in eqn.outvars:
+                wide = var.aval.shape in ((m, 2 * h), (m, d))
+                assert not (wide and var.aval.dtype == jnp.float32), (eqn, var.aval)
+    # two products forward and four backward in the usual buffer (gmm and
+    # tgmm, or ragged dots), and the walk's ragged dots, forward, recomputed
+    # and backward
+    assert (kernels, ragged) == ((6, 8) if path == "kernel" else (0, 14))
+    with open(os.path.join(FIXTURES, "routed_experts_f32_step.sha256")) as f:
+        recorded = dict(line.split() for line in f if line.strip())
+    assert _step_digest(_layer_step(jnp.float32, path)[0]) == recorded[path]
